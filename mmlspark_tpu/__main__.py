@@ -4,7 +4,8 @@ Reference: tools/bin/mml-exec:1-40 launches spark-shell / pyspark /
 spark-submit / jupyter with ``--packages`` wired to the local MMLSpark
 build. The TPU-native launcher's job is the same — run user code or
 framework tooling inside a correctly-configured environment — minus the
-JVM: it resolves the backend (real TPU vs CPU mesh), then dispatches.
+JVM: it resolves the backend (real TPU vs CPU mesh), places the
+persistent compile cache, then dispatches.
 
 Subcommands:
   run <script.py> [args...]   run a user script (the spark-submit role)
@@ -41,18 +42,16 @@ def _apply_backend(args) -> None:
 
 
 def cmd_run(args) -> int:
-    _apply_backend(args)
     sys.argv = [args.script, *args.script_args]
     runpy.run_path(args.script, run_name="__main__")
     return 0
 
 
 def cmd_bench(args) -> int:
-    _apply_backend(args)
     if getattr(args, "telemetry_dir", None):
-        # bench.py runs via runpy (and re-execs itself on retry), so the
-        # flag travels through the environment; the serve metric group
-        # writes events.jsonl + metrics.json under it
+        # bench.py runs via runpy, so the flag travels through the
+        # environment; the serve metric group writes events.jsonl +
+        # metrics.json under it
         os.environ["MMLTPU_TELEMETRY_DIR"] = args.telemetry_dir
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     bench = os.path.join(repo, "bench.py")
@@ -68,7 +67,6 @@ def cmd_serve(args) -> int:
     """Continuous-batching serve demo: synthetic traffic through a
     ``ServeEngine`` slot pool, ONE JSON metrics line out (mirrors
     ``bench``)."""
-    _apply_backend(args)
     from mmlspark_tpu.serve.demo import run_demo
 
     metrics = run_demo(
@@ -79,6 +77,7 @@ def cmd_serve(args) -> int:
         seed=args.seed,
         decode_block=args.decode_block,
         mesh=args.mesh or None,
+        model=args.model or None,
         telemetry_dir=args.telemetry_dir or None,
         faults=args.faults or None,
         slo=args.slo or None,
@@ -108,7 +107,6 @@ def cmd_train(args) -> int:
     """Fault-tolerant training demo: synthetic data through an
     ``SPMDTrainer`` with crash-restart supervision, ONE JSON metrics
     line out (mirrors ``serve``)."""
-    _apply_backend(args)
     from mmlspark_tpu.train.demo import run_train_demo
 
     metrics = run_train_demo(
@@ -130,14 +128,12 @@ def cmd_train(args) -> int:
 
 
 def cmd_evidence(args) -> int:
-    """Run a repo evidence tool (flash kernels / resnet50 profile) on the
-    real backend — thin launcher so the proofs are one command away."""
-    _apply_backend(args)
+    """Run a repo evidence tool (resnet50 profile on the chip / feed
+    overhead on the CPU) — thin launcher so each is one command away.
+    The kernels' numerics on the chip are ``chip_smoke.py``'s job."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     script = {
-        "flash": "flash_tpu_evidence.py",
         "profile": "profile_resnet50.py",
-        "decode": "decode_tpu_evidence.py",
         "feed": "feed_overhead_bench.py",
     }[args.which]
     path = os.path.join(repo, "tools", script)
@@ -241,6 +237,15 @@ def main(argv: list[str] | None = None) -> int:
     sp.add_argument("--max-new-tokens", type=int, default=8)
     sp.add_argument("--arrivals-per-tick", type=int, default=2)
     sp.add_argument("--seed", type=int, default=0)
+    sp.add_argument(
+        "--model", default="", metavar="SPEC",
+        help="the demo model's shape as ':'-separated key=value fields "
+        "(the --models field grammar): vocab, d_model, heads, depth, "
+        "cache_len, max_prompt. Default: a tiny 2x32 model. GPT-2 small "
+        "is 'vocab=50257:d_model=768:heads=12:depth=12:cache_len=1024:"
+        "max_prompt=700'; attention is the model default (flash kernels "
+        "on a TPU, dense elsewhere)",
+    )
     sp.add_argument(
         "--decode-block", type=int, default=None, metavar="T",
         help="max fused decode-block size: up to T tokens per dispatch "
@@ -487,9 +492,9 @@ def main(argv: list[str] | None = None) -> int:
 
     sp = sub.add_parser(
         "evidence",
-        help="run a TPU evidence tool (flash | profile | decode | feed)",
+        help="run an evidence tool (profile | feed)",
     )
-    sp.add_argument("which", choices=["flash", "profile", "decode", "feed"])
+    sp.add_argument("which", choices=["profile", "feed"])
     sp.add_argument("tool_args", nargs=argparse.REMAINDER)
     sp.set_defaults(fn=cmd_evidence)
 
@@ -513,6 +518,13 @@ def main(argv: list[str] | None = None) -> int:
     args = p.parse_args(argv)
     if args.cmd == "zoo" and args.zoo_cmd == "download" and not args.name:
         p.error("zoo download requires a model name")
+    if args.cmd in ("run", "bench", "serve", "train", "evidence"):
+        # the commands that compile: backend first (jax reads the env at
+        # import), then the cache, before the first program
+        _apply_backend(args)
+        from mmlspark_tpu.core.env import enable_compile_cache
+
+        enable_compile_cache()
     return args.fn(args)
 
 

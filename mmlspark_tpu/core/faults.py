@@ -29,7 +29,7 @@ periodic-checkpoint ``serve.snapshot``), the supervisor's
 - **Typed.** Injected failures raise :class:`TransientFault` /
   :class:`ResourceExhausted` / :class:`EngineKilled`; the engine's
   classifiers (:func:`is_transient`, :func:`is_resource_exhausted`)
-  match the injected types AND the real runtime's ``XlaRuntimeError``
+  match the injected types AND the real runtime's ``JaxRuntimeError``
   status spellings, so the same retry/degrade/quarantine policy covers
   simulated and genuine failures.
 
@@ -149,13 +149,24 @@ class EngineKilled(InjectedFault):
     retry."""
 
 
+#: how the TPU compiler words a refusal (a kernel or program that does
+#: not fit SMEM/VMEM/HBM *as compiled*). It carries the same
+#: RESOURCE_EXHAUSTED status as a runtime allocation failure, but no
+#: amount of load shedding makes the program compile: it is a crash
+_COMPILE_REFUSAL = "compile permanent error"
+
+
 def is_resource_exhausted(exc: BaseException) -> bool:
-    """True for injected OOMs and for real runtime errors carrying the
+    """True for injected OOMs and for real RUNTIME errors carrying the
     ``RESOURCE_EXHAUSTED`` status (jax surfaces allocation failure as
-    ``XlaRuntimeError: RESOURCE_EXHAUSTED: ...``)."""
-    return isinstance(exc, ResourceExhausted) or (
-        "RESOURCE_EXHAUSTED" in str(exc)
-    )
+    ``JaxRuntimeError: RESOURCE_EXHAUSTED: ...``). A compile-time
+    refusal is NOT one: it propagates out of ``ServeEngine.step`` /
+    ``run`` instead of walking the degradation ladder and quarantining
+    requests as if the pool were merely full."""
+    if isinstance(exc, ResourceExhausted):
+        return True
+    msg = str(exc)
+    return "RESOURCE_EXHAUSTED" in msg and _COMPILE_REFUSAL not in msg
 
 
 #: real-runtime statuses safe to retry: the dispatch failed to START,
@@ -165,14 +176,16 @@ _TRANSIENT_STATUSES = ("UNAVAILABLE", "DEADLINE_EXCEEDED", "CANCELLED")
 
 
 def is_transient(exc: BaseException) -> bool:
-    """True for injected transients and for real ``XlaRuntimeError``s
-    whose status is a retryable one (UNAVAILABLE / DEADLINE_EXCEEDED /
-    CANCELLED)."""
+    """True for injected transients and for real
+    ``jax.errors.JaxRuntimeError``s whose status is a retryable one
+    (UNAVAILABLE / DEADLINE_EXCEEDED / CANCELLED)."""
     if isinstance(exc, TransientFault):
         return True
     if isinstance(exc, (ResourceExhausted, EngineKilled)):
         return False
-    if type(exc).__name__ == "XlaRuntimeError":
+    import jax
+
+    if isinstance(exc, jax.errors.JaxRuntimeError):
         msg = str(exc)
         return any(s in msg for s in _TRANSIENT_STATUSES)
     return False

@@ -59,9 +59,14 @@ _log = get_logger("perf")
 # device peaks
 # --------------------------------------------------------------------------
 
-#: device_kind prefix -> (peak dense bf16/f32 FLOP/s, peak HBM bytes/s)
-#: per chip, from published specs. Matched by longest prefix against
-#: ``jax.devices()[0].device_kind``.
+#: device_kind prefix -> (peak dense bf16 FLOP/s, peak HBM bytes/s) per
+#: chip, from the vendor's published specs (v5e: Google Cloud "TPU v5e"
+#: documentation, 197 TFLOP/s bf16, 819 GB/s). Matched by longest
+#: prefix against ``jax.devices()[0].device_kind``. This is the ONE
+#: peak table (bench.py reads it too); a kind that is not in it is an
+#: error, not a default. ``cpu`` is the explicit NOMINAL entry — one
+#: core's order of magnitude, so CPU test runs still compute ratios;
+#: ``peak_source`` labels it and it is never a hardware claim.
 DEVICE_PEAKS: dict[str, tuple[float, float]] = {
     "TPU v2": (45e12, 700e9),
     "TPU v3": (123e12, 900e9),
@@ -71,19 +76,15 @@ DEVICE_PEAKS: dict[str, tuple[float, float]] = {
     "TPU v5p": (459e12, 2765e9),
     "TPU v6 lite": (918e12, 1640e9),
     "TPU v6e": (918e12, 1640e9),
+    "cpu": (5e10, 2e10),
 }
-
-#: nominal single-core CPU figures used when the backend is not a known
-#: accelerator: MFU against them is a smoke-scale sanity number, not a
-#: hardware claim — ``peak_source`` says so.
-_CPU_NOMINAL = (5e10, 2e10)
 
 
 @dataclasses.dataclass(frozen=True)
 class DevicePeak:
     """Peak FLOP/s and HBM bandwidth one device can sustain, plus where
     the figure came from (``"table"`` for known accelerators,
-    ``"nominal"`` for the CPU fallback, ``"env"`` for the
+    ``"nominal"`` for the table's ``cpu`` entry, ``"env"`` for the
     ``MMLTPU_PEAK_FLOPS`` / ``MMLTPU_PEAK_HBM_BYTES_PER_S``
     overrides)."""
 
@@ -103,30 +104,32 @@ class DevicePeak:
 
 def device_peak(device=None) -> DevicePeak:
     """Resolve the peak figures for ``device`` (default: the first jax
-    device). Env overrides win; unknown kinds get the nominal CPU
-    figures so MFU is always computable (and labeled)."""
+    device) from :data:`DEVICE_PEAKS`. Env overrides win, each over its
+    own figure. A ``device_kind`` the table does not know raises unless
+    BOTH overrides are given: a ratio against a guessed peak is a
+    number about another machine."""
     env_flops = os.environ.get("MMLTPU_PEAK_FLOPS")
     env_bw = os.environ.get("MMLTPU_PEAK_HBM_BYTES_PER_S")
-    kind = "unknown"
-    try:
-        if device is None:
-            import jax
+    if device is None:
+        import jax
 
-            device = jax.devices()[0]
-        kind = getattr(device, "device_kind", "unknown") or "unknown"
-    except Exception:  # noqa: BLE001 — analytics must never raise
-        pass
+        device = jax.devices()[0]
+    kind = device.device_kind
+    hit = _lookup_peak(kind)
+    if hit is None and not (env_flops and env_bw):
+        raise FriendlyError(
+            f"no peak FLOP/s and HBM bandwidth known for device_kind "
+            f"{kind!r}: add it to core/perf.py DEVICE_PEAKS with its "
+            "source, or set both MMLTPU_PEAK_FLOPS and "
+            "MMLTPU_PEAK_HBM_BYTES_PER_S"
+        )
     if env_flops or env_bw:
-        base = _lookup_peak(kind) or _CPU_NOMINAL
         return DevicePeak(
-            float(env_flops) if env_flops else base[0],
-            float(env_bw) if env_bw else base[1],
+            float(env_flops) if env_flops else hit[0],
+            float(env_bw) if env_bw else hit[1],
             "env", kind,
         )
-    hit = _lookup_peak(kind)
-    if hit is not None:
-        return DevicePeak(hit[0], hit[1], "table", kind)
-    return DevicePeak(*_CPU_NOMINAL, "nominal", kind)
+    return DevicePeak(*hit, "nominal" if kind == "cpu" else "table", kind)
 
 
 def _lookup_peak(kind: str) -> tuple[float, float] | None:
@@ -151,11 +154,16 @@ class ProgramCost:
     ``source`` is ``"xla"`` when the cost model answered and
     ``"unavailable"`` on backends where it returns nothing (the
     interpreter fallback path) — figures are then ``None`` and every
-    derived ratio (MFU, bandwidth) follows suit instead of erroring."""
+    derived ratio (MFU, bandwidth) follows suit instead of erroring.
+    ``kernel_calls`` counts the Pallas TPU kernels (``tpu_custom_call``)
+    in the lowered program: 0 means the kernels' interpreter or a dense
+    path was traced instead, which is how ``chip_smoke.py`` tells that
+    the program a phase ran is the one the chip is meant to run."""
 
     flops: float | None
     bytes_accessed: float | None
     source: str = "xla"
+    kernel_calls: int | None = None
 
     @classmethod
     def unavailable(cls) -> "ProgramCost":
@@ -172,14 +180,20 @@ class ProgramCost:
 def _as_abstract(leaf):
     """Array-like leaves -> ShapeDtypeStruct; everything else (static
     ints, None) passes through. Holding no buffers means the lowering
-    below can never touch donated device memory."""
+    below can never touch donated device memory. A committed array's
+    sharding is kept, so the program lowered for analysis is the one
+    dispatched (dropping it under a mesh lowers a program whose
+    donations cannot match the pinned out_shardings, and warns)."""
     shape = getattr(leaf, "shape", None)
     dtype = getattr(leaf, "dtype", None)
     if shape is None or dtype is None:
         return leaf
     import jax
 
-    return jax.ShapeDtypeStruct(tuple(shape), dtype)
+    sharding = getattr(leaf, "sharding", None)
+    if not isinstance(sharding, jax.sharding.Sharding):
+        sharding = None
+    return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
 
 
 def analyze_jit_cost(jitted, *args, **kwargs) -> ProgramCost:
@@ -200,19 +214,18 @@ def analyze_jit_cost(jitted, *args, **kwargs) -> ProgramCost:
 
         a, kw = jax.tree_util.tree_map(_as_abstract, (args, kwargs))
         lowered = jitted.lower(*a, **kw)
+        kernels = lowered.as_text().count("tpu_custom_call")
         ca = lowered.cost_analysis()
         if isinstance(ca, (list, tuple)):
             ca = ca[0] if ca else None
-        if not ca:
-            return ProgramCost.unavailable()
-        flops = ca.get("flops")
-        bts = ca.get("bytes accessed")
+        flops = ca.get("flops") if ca else None
+        bts = ca.get("bytes accessed") if ca else None
         if flops is None and bts is None:
-            return ProgramCost.unavailable()
+            return ProgramCost(None, None, "unavailable", kernels)
         return ProgramCost(
             float(flops) if flops is not None else None,
             float(bts) if bts is not None else None,
-            "xla",
+            "xla", kernels,
         )
     except Exception as e:  # noqa: BLE001 — analytics must never raise
         _log.info("cost analysis unavailable: %s", e)
@@ -406,6 +419,7 @@ class PerfAnalytics:
                 "flops": st.cost.flops,
                 "bytes_accessed": st.cost.bytes_accessed,
                 "cost_source": st.cost.source,
+                "kernel_calls": st.cost.kernel_calls,
                 "dispatches": st.dispatches,
                 "device_s": round(st.device_s, 6),
                 "queued_s": round(st.queued_s, 6),

@@ -16,7 +16,7 @@ surgery.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Any, Sequence
 
 import jax
@@ -119,6 +119,20 @@ class NamedGraph:
             compute_dtype=self.compute_dtype,
             extra=dict(self.extra),
         )
+
+    def with_mesh(self, mesh) -> "NamedGraph":
+        """This graph with ``mesh`` handed to every block that takes a
+        mesh and has none. The sharded engine and trainer call it with
+        their own mesh, so that attention runs its Pallas kernels per
+        shard — the TPU compiler cannot partition a kernel on its own
+        (ops/flash_attention.py ``_kernel_axes``). Variables are
+        unaffected: a mesh is a static attribute, not a parameter."""
+        blocks = [
+            (name, mod.clone(mesh=mesh))
+            if getattr(mod, "mesh", mesh) is None else (name, mod)
+            for name, mod in self.blocks
+        ]
+        return replace(self, blocks=blocks)
 
     def param_count(self, variables) -> int:
         return count_params(variables)

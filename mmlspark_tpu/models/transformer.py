@@ -228,6 +228,7 @@ class SelfAttention(nn.Module):
                     ptab,
                     k_scale=cscales[0] if cscales else None,
                     v_scale=cscales[1] if cscales else None,
+                    mesh=self.mesh,
                 )
             else:
                 ck, cv, *cscales = cache
@@ -311,7 +312,23 @@ class SelfAttention(nn.Module):
                         decode_live_lengths(pos, b, live=live),
                         k_scale=cscales[0] if cscales else None,
                         v_scale=cscales[1] if cscales else None,
+                        mesh=self.mesh,
                     )
+                elif impl == FLASH and isinstance(pos, int) and pos == 0:
+                    # a PREFILL from position 0 (static, so this is
+                    # decided at trace time) sees exactly this call's
+                    # own K/V — the cache beyond t is unwritten and
+                    # causally invisible — so it runs the same flash
+                    # kernel as scoring instead of materializing the
+                    # (t, cache) score matrix. Resume/chunk prefills
+                    # (traced pos, a live prefix in the cache) keep the
+                    # dense read: the kernel has no query offset.
+                    from mmlspark_tpu.ops.flash_attention import (
+                        flash_attention,
+                    )
+
+                    o = flash_attention(q, k, v, causal=True,
+                                        window=self.window, mesh=self.mesh)
                 else:
                     o = dense_attention(q, ck, cv, causal=True,
                                         window=self.window, q_offset=pos)
@@ -319,7 +336,7 @@ class SelfAttention(nn.Module):
             from mmlspark_tpu.ops.flash_attention import flash_attention
 
             o = flash_attention(q, k, v, causal=self.causal,
-                                window=self.window)
+                                window=self.window, mesh=self.mesh)
         elif impl == DENSE or self.mesh is None:
             # ring/ulysses degrade to dense when no mesh is provided
             o = dense_attention(q, k, v, causal=self.causal,

@@ -35,32 +35,43 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 # the finite in-kernel masking value (-inf minus -inf would poison the
 # running max); ONE home for both masking conventions lives in
 # ops/attention.py — see the note there before touching either
 from mmlspark_tpu.ops.attention import KERNEL_NEG_INF as NEG_INF
+from mmlspark_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 LANES = 128
 SUBLANES = 8  # min f32 sublane tile; single-row decode broadcasts to it
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept both so the
-# kernels import (and the interpret-mode CPU tests run) on either side
-# of the rename
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 # all three kernels share a (batch·heads, outer-block, streamed-block)
 # grid: the first two dims own disjoint outputs/scratch, only the last
 # carries accumulator state across iterations
-_GRID_SEMANTICS = _CompilerParams(
+_GRID_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL, pltpu.ARBITRARY),
 )
 
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
+
+
+def _kernel_axes(mesh, batch: int, kv_heads: int):
+    """Mesh axes a kernel call splits its batch and head dims over.
+
+    The TPU compiler cannot partition a Mosaic kernel by itself ("wrap
+    the call in a shard_map"), so under a mesh every public op below
+    runs its kernel per shard: batch rows over the data axis, heads
+    over the model axis — the layout the Megatron param rules and the
+    serve pools already give the operands. A dim the axis does not
+    divide stays whole (the operand is gathered, as GSPMD would)."""
+    def axis(name, size):
+        n = mesh.shape.get(name, 1)
+        return name if n > 1 and size % n == 0 else None
+
+    return axis(DATA_AXIS, batch), axis(MODEL_AXIS, kv_heads)
 
 
 # ---------------------------------------------------------------------------
@@ -512,7 +523,8 @@ def _build(causal: bool, window: int | None, scale_key, block: int,
 
 def flash_attention(q, k, v, *, causal: bool = False,
                     window: int | None = None, scale=None,
-                    block: int = 128, interpret: bool | None = None):
+                    block: int = 128, interpret: bool | None = None,
+                    mesh=None):
     """Blockwise fused attention, (B, S, H, D) layout, exact output AND
     exact gradients — both directions O(S·d) memory.
 
@@ -532,6 +544,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
     ``interpret=None`` auto-selects: compiled kernel on TPU, interpreter
     elsewhere (tests). Sequences are padded to the block size internally;
     padded keys are masked, padded query rows are sliced away.
+
+    ``mesh``: the caller's (data, model) mesh when the operands are
+    sharded over one — the kernel then runs per shard (see
+    :func:`_kernel_axes`). Leave it None inside a ``shard_map`` body.
     """
     if not (q.dtype == k.dtype == v.dtype):
         # matmuls feed the MXU native-dtype operands (no f32 upcast),
@@ -560,7 +576,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
         from mmlspark_tpu.core.env import is_tpu
 
         interpret = not is_tpu()
-    return _build(causal, window, scale, block, bool(interpret))(q, k, v)
+    fn = _build(causal, window, scale, block, bool(interpret))
+    if mesh is None:
+        return fn(q, k, v)
+    b_ax, h_ax = _kernel_axes(mesh, q.shape[0], k.shape[2])
+    spec = P(b_ax, None, h_ax, None)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -578,7 +600,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
 # how much each request has actually generated, not with pool capacity.
 
 # grid (batch·heads, kv-block): only the streamed kv dim carries scratch
-_DECODE_SEMANTICS = _CompilerParams(
+_DECODE_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY),
 )
 
@@ -725,19 +747,23 @@ def _validate_kv_scales(q, kv_dtype, hk: int, b: int, k_scale, v_scale,
 
 
 def _decode_block(cache_len: int, block: int) -> int:
-    """Largest divisor of ``cache_len`` in [8, block] when one exists —
-    dividing evenly means the cache streams with NO pad copy, which is
-    the point on the serving hot path; otherwise fall back to the padded
-    layout (_to_bh pads, masking hides the tail)."""
+    """Largest KV block in [8, block] that divides ``cache_len`` AND
+    that the TPU lowering accepts — whole sublanes, or the whole cache
+    when it fits one block. Dividing evenly means the cache streams
+    with NO pad copy, which is the point on the serving hot path;
+    otherwise fall back to the padded layout (_to_bh pads, masking
+    hides the tail)."""
     for cand in range(min(block, cache_len), 7, -1):
-        if cache_len % cand == 0:
+        if cache_len % cand == 0 and (
+            cand % SUBLANES == 0 or cand == cache_len
+        ):
             return cand
     return min(block, _round_up(cache_len, 8))
 
 
 def flash_decode(q, k, v, lengths, *, scale=None, block: int = 128,
                  interpret: bool | None = None,
-                 k_scale=None, v_scale=None):
+                 k_scale=None, v_scale=None, mesh=None):
     """Length-aware split-KV attention for ONE query token per row.
 
     int8 mode: when ``k``/``v`` are int8, ``k_scale``/``v_scale`` —
@@ -763,7 +789,8 @@ def flash_decode(q, k, v, lengths, *, scale=None, block: int = 128,
 
     ``interpret=None`` auto-selects like :func:`flash_attention`:
     compiled on TPU, interpreter elsewhere so CPU tests run the same
-    code path.
+    code path. ``mesh`` as in :func:`flash_attention`: slots split over
+    the data axis, heads over the model axis, one kernel per shard.
     """
     if k.dtype != v.dtype:
         raise ValueError(
@@ -812,6 +839,22 @@ def flash_decode(q, k, v, lengths, *, scale=None, block: int = 128,
         from mmlspark_tpu.core.env import is_tpu
 
         interpret = not is_tpu()
+    if mesh is not None:
+        b_ax, h_ax = _kernel_axes(mesh, b, k.shape[2])
+        kv_spec, sc_spec = P(b_ax, None, h_ax, None), P(b_ax, h_ax)
+        scales = (k_scale, v_scale) if quantized else ()
+
+        def local(q, k, v, lengths, *scales):
+            ks, vs = scales or (None, None)
+            return flash_decode(q, k, v, lengths, scale=scale, block=block,
+                                interpret=interpret, k_scale=ks, v_scale=vs)
+
+        return jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(kv_spec, kv_spec, kv_spec, P(b_ax))
+            + (sc_spec,) * len(scales),
+            out_specs=kv_spec, check_vma=False,
+        )(q, k, v, lengths, *scales)
     lengths = jnp.clip(lengths.astype(jnp.int32), 0, L)
 
     blk = _decode_block(L, block)
@@ -939,23 +982,24 @@ def _paged_decode_kernel(len_ref, pt_ref, q_ref, k_ref, v_ref, o_ref,
         ).astype(o_ref.dtype)
 
 
-def _paged_decode_kernel_q8(len_ref, pt_ref, ks_ref, vs_ref,
-                            q_ref, k_ref, v_ref, o_ref,
+def _paged_decode_kernel_q8(len_ref, pt_ref, q_ref, k_ref, v_ref,
+                            ks_ref, vs_ref, o_ref,
                             m_scr, l_scr, acc_scr, *,
                             scale: float, blk: int, heads: int,
                             group: int):
-    """:func:`_paged_decode_kernel` over int8 pages with PER-PAGE
-    f32 scales scalar-prefetched next to the lengths and page table.
-    Inside a live block the logical page coordinate ``kb`` is already
-    valid (the ``pl.when`` guard implies ``kb <= last``), so the
-    kernel reads the same table entry the index map fetched the page
-    with and looks its scales up directly — V's scale varies per page,
-    so it lands on each block's P·V contribution before accumulation,
-    which is exactly where per-page granularity is exact."""
+    """:func:`_paged_decode_kernel` over int8 pages with PER-PAGE f32
+    scales. The scales are NOT scalar-prefetched: a prefetched
+    ``(num_pages, Hkv)`` array pads its minor dim to 128 lanes in SMEM
+    (512 B per page), which the compiler refuses past ~1,000 pages.
+    Instead each grid step's ``(1, 1, Hkv)`` scale row is fetched into
+    SMEM through a BlockSpec driven by the same clamped page coordinate
+    as the page itself, so SMEM use is one row whatever the pool size.
+    V's scale varies per page, so it lands on each block's P·V
+    contribution before accumulation, which is exactly where per-page
+    granularity is exact."""
     bh = pl.program_id(0)
     kb = pl.program_id(1)
-    row = bh // heads
-    length = len_ref[row]
+    length = len_ref[bh // heads]
     kvh = (bh % heads) // group
 
     @pl.when(kb == 0)
@@ -966,9 +1010,8 @@ def _paged_decode_kernel_q8(len_ref, pt_ref, ks_ref, vs_ref,
 
     @pl.when(kb * blk < length)
     def _update():
-        page = pt_ref[row, kb]
-        ks = ks_ref[page, kvh]
-        vs = vs_ref[page, kvh]
+        ks = ks_ref[0, 0, kvh]
+        vs = vs_ref[0, 0, kvh]
         q = jnp.broadcast_to(
             q_ref[0].astype(jnp.float32), (SUBLANES, q_ref.shape[-1])
         )
@@ -1003,15 +1046,15 @@ def _paged_decode_kernel_q8(len_ref, pt_ref, ks_ref, vs_ref,
 
 def paged_flash_decode(q, k_pages, v_pages, lengths, page_table, *,
                        scale=None, interpret: bool | None = None,
-                       k_scale=None, v_scale=None):
+                       k_scale=None, v_scale=None, mesh=None):
     """:func:`flash_decode` over PAGED caches.
 
     int8 mode: when the page stores are int8, ``k_scale``/``v_scale``
     — (num_pages, Hkv) f32, the paged pool's PER-PAGE quantization
-    scales — must be passed; they scalar-prefetch alongside the
-    lengths and page table and the kernel dequantizes each fetched
-    page face in-VMEM, so the page-store HBM traffic halves vs bf16
-    while the softmax carry stays f32.
+    scales — must be passed; each grid step fetches its page's scale
+    row beside the page and the kernel dequantizes the page face
+    in-VMEM, so the page-store HBM traffic halves vs bf16 while the
+    softmax carry stays f32.
 
     ``q`` is (B, 1, H, D); ``k_pages``/``v_pages`` are the physical page
     stores ``(num_pages, Hkv, page_size, D)`` shared by all rows;
@@ -1027,6 +1070,11 @@ def paged_flash_decode(q, k_pages, v_pages, lengths, page_table, *,
     page_size`` — and both scalar-prefetch arguments feed the kv index
     map: the live-length clamp picks the logical block, the table turns
     it physical. Per-row work and HBM traffic remain O(lengths[b]).
+
+    ``mesh`` as in :func:`flash_attention`. Pages split over the data
+    axis with the rows: the pool keeps every page a row maps on the
+    row's own shard, so each shard's kernel reads its local page store
+    with the table rebased to local page ids.
     """
     if k_pages.dtype != v_pages.dtype:
         raise ValueError(
@@ -1095,53 +1143,77 @@ def paged_flash_decode(q, k_pages, v_pages, lengths, page_table, *,
         from mmlspark_tpu.core.env import is_tpu
 
         interpret = not is_tpu()
+    if mesh is not None:
+        b_ax, h_ax = _kernel_axes(mesh, b, k_pages.shape[1])
+        if b_ax is not None and k_pages.shape[0] % mesh.shape[b_ax]:
+            b_ax = None  # pages cannot follow the rows: gather both
+        q_spec, page_spec = P(b_ax, None, h_ax, None), P(b_ax, h_ax, None, None)
+        scales = (k_scale, v_scale) if quantized else ()
+
+        def local(q, k_pages, v_pages, lengths, page_table, *scales):
+            if b_ax is not None:
+                page_table = page_table - (
+                    jax.lax.axis_index(b_ax) * k_pages.shape[0]
+                )
+            ks, vs = scales or (None, None)
+            return paged_flash_decode(
+                q, k_pages, v_pages, lengths, page_table, scale=scale,
+                interpret=interpret, k_scale=ks, v_scale=vs,
+            )
+
+        return jax.shard_map(
+            local, mesh=mesh,
+            in_specs=(q_spec, page_spec, page_spec, P(b_ax), P(b_ax, None))
+            + (P(b_ax, h_ax),) * len(scales),
+            out_specs=q_spec, check_vma=False,
+        )(q, k_pages, v_pages, lengths, page_table, *scales)
     lengths = jnp.clip(lengths.astype(jnp.int32), 0, L)
     page_table = page_table.astype(jnp.int32)
 
     qb = _to_bh(q, 1)  # (B*H, 1, D)
 
-    def kv_im(bh, j, lens, pt, *scales):
+    def page_of(bh, j, lens, pt):
         # same last-live-block clamp as flash_decode, then the page
-        # table makes the surviving LOGICAL coordinate physical; the
-        # head coordinate picks the kv head inside the page. *scales
-        # absorbs the int8 mode's extra scalar-prefetch refs.
+        # table makes the surviving LOGICAL coordinate physical
         row = bh // h
-        length = lens[row]
-        last = jnp.maximum((length + ps - 1) // ps - 1, 0)
-        page = pt[row, jnp.minimum(j, last)]
-        return (page, (bh % h) // g, 0, 0)
+        last = jnp.maximum((lens[row] + ps - 1) // ps - 1, 0)
+        return pt[row, jnp.minimum(j, last)]
 
+    def kv_im(bh, j, lens, pt):
+        # the head coordinate picks the kv head inside the page
+        return (page_of(bh, j, lens, pt), (bh % h) // g, 0, 0)
+
+    q_spec = pl.BlockSpec((1, 1, d), lambda bh, j, lens, pt: (bh, 0, 0),
+                          memory_space=pltpu.VMEM)
+    page_spec = pl.BlockSpec((1, 1, ps, d), kv_im, memory_space=pltpu.VMEM)
+    in_specs = [q_spec, page_spec, page_spec]
+    operands = [lengths, page_table, qb, k_pages, v_pages]
     if quantized:
         kernel = partial(
             _paged_decode_kernel_q8, scale=scale, blk=ps, heads=h, group=g,
         )
-        n_prefetch = 4
-        operands = (
-            lengths, page_table, k_scale, v_scale, qb, k_pages, v_pages,
+        # one (1, 1, Hkv) scale row per grid step, fetched into SMEM by
+        # the page's own coordinate (see _paged_decode_kernel_q8); the
+        # middle unit dim makes the block's last two dims equal the
+        # array's, which the TPU lowering requires of a sub-tile block
+        hk = k_pages.shape[1]
+        scale_spec = pl.BlockSpec(
+            (1, 1, hk),
+            lambda bh, j, lens, pt: (page_of(bh, j, lens, pt), 0, 0),
+            memory_space=pltpu.SMEM,
         )
+        in_specs += [scale_spec, scale_spec]
+        operands += [k_scale[:, None, :], v_scale[:, None, :]]
     else:
         kernel = partial(_paged_decode_kernel, scale=scale, blk=ps, heads=h)
-        n_prefetch = 2
-        operands = (lengths, page_table, qb, k_pages, v_pages)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=n_prefetch,
+            num_scalar_prefetch=2,
             grid=(b * h, n_pages),
-            in_specs=[
-                pl.BlockSpec((1, 1, d),
-                             lambda bh, j, lens, pt, *scales: (bh, 0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, ps, d), kv_im,
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, ps, d), kv_im,
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, d), lambda bh, j, lens, pt, *scales: (bh, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
+            in_specs=in_specs,
+            out_specs=q_spec,
             scratch_shapes=[
                 pltpu.VMEM((SUBLANES, LANES), jnp.float32),  # running max
                 pltpu.VMEM((SUBLANES, LANES), jnp.float32),  # normalizer

@@ -4,8 +4,11 @@ Plays the role of the reference's ``NativeLoader``
 (core/env/src/main/scala/NativeLoader.java: extract shared lib from jar
 resources, ``System.load`` once per JVM): here we compile each ``.cpp`` with
 the system toolchain on first use, cache the ``.so`` next to the source, and
-``ctypes.CDLL`` it once per process. Each library degrades gracefully: a
-missing toolchain returns None and callers fall back to pure Python.
+``ctypes.CDLL`` it once per process. The ``.so`` files are build outputs
+(``.gitignore``), so a fresh checkout builds from the committed ``.cpp``.
+Each library degrades to pure Python when it cannot be built or loaded —
+LOUDLY: one stderr line per library per process says whether it was built
+or which fallback is in use and why.
 """
 
 from __future__ import annotations
@@ -13,6 +16,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 from dataclasses import dataclass, field
 from typing import Callable
@@ -83,6 +87,16 @@ _LIBS: dict[str, _NativeLib] = {
 }
 
 
+def _say(message: str) -> None:
+    print(f"mmlspark_tpu native ops: {message}", file=sys.stderr, flush=True)
+
+
+def _fall_back(entry: _NativeLib, why: str) -> None:
+    entry.build_failed = True
+    _say(f"{os.path.basename(entry.src)} unavailable ({why}); using the "
+         "pure-Python fallback")
+
+
 def _compile(entry: _NativeLib) -> bool:
     from mmlspark_tpu.core import config
 
@@ -93,12 +107,15 @@ def _compile(entry: _NativeLib) -> bool:
     try:
         res = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
     except (OSError, subprocess.TimeoutExpired) as e:  # no toolchain
-        _log.warning("native build unavailable for %s: %s", entry.src, e)
+        _fall_back(entry, f"no toolchain: {e}")
         return False
     if res.returncode != 0:
         _log.warning("native build failed for %s:\n%s", entry.src,
                      res.stderr[-2000:])
+        _fall_back(entry, f"{cmd[0]} exited {res.returncode}")
         return False
+    _say(f"built {os.path.basename(entry.so)} from "
+         f"{os.path.basename(entry.src)} with {cmd[0]}")
     return True
 
 
@@ -119,13 +136,11 @@ def load_native(name: str) -> ctypes.CDLL | None:
             entry.so
         ) < os.path.getmtime(entry.src):
             if not _compile(entry):
-                entry.build_failed = True
                 return None
         try:
             lib = ctypes.CDLL(entry.so)
         except OSError as e:
-            _log.warning("native load failed for %s: %s", entry.so, e)
-            entry.build_failed = True
+            _fall_back(entry, f"load failed: {e}")
             return None
         entry.configure(lib)
         entry.lib = lib

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 from functools import partial
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
@@ -35,7 +36,7 @@ from mmlspark_tpu.ops.attention import (
     finalize_softmax,
     softmax_block_update,
 )
-from mmlspark_tpu.parallel.mesh import DATA_AXIS, SEQUENCE_AXIS, axis_size, shard_map
+from mmlspark_tpu.parallel.mesh import DATA_AXIS, SEQUENCE_AXIS
 
 
 def _ring_window_steps(n: int, chunk: int, window: int | None,
@@ -67,7 +68,7 @@ def _ring_inner(q, k, v, *, axis_name: str, causal: bool,
     rotations: device i holds K/V chunk (i - step) mod n, which gives
     the global kv offset for causal masking.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, sq, h, d = q.shape
     sk, hk = k.shape[1], k.shape[2]
@@ -139,7 +140,7 @@ def _sharded_call(inner, q, k, v, mesh, axis: str, batch_axis: str):
         else None
     )
     spec = P(batch, axis, None, None)
-    return shard_map(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -139,53 +139,3 @@ def initialize_distributed(
         num_processes=num_processes,
         process_id=process_id,
     )
-
-
-def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions: the public spelling when
-    the installed jax has it, else the experimental one (where the
-    replication check is named ``check_rep``, not ``check_vma``)."""
-    import jax
-
-    public = getattr(jax, "shard_map", None)
-    if public is not None:
-        return public(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as experimental
-
-    # the rep check predates varying-axes typing (lax.pcast) — bodies
-    # written against check_vma cannot mark replication for it, so it
-    # stays off on the fallback path (a soundness check, not numerics)
-    return experimental(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis from inside a ``shard_map``
-    body: ``lax.axis_size`` where the installed jax has it, else the
-    axis-env frame lookup older versions expose."""
-    from jax import lax
-
-    size = getattr(lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    import jax.core as jax_core
-
-    return int(jax_core.axis_frame(axis_name))
-
-
-def pcast_varying(x, vary_axes):
-    """``lax.pcast(x, axes, to="varying")`` where the installed jax has
-    varying-axes typing; identity otherwise (the fallback
-    :func:`shard_map` path runs with the replication check off, so the
-    marking is only needed on new-jax)."""
-    from jax import lax
-
-    pcast = getattr(lax, "pcast", None)
-    if pcast is None or not vary_axes:
-        return x
-    return pcast(x, vary_axes, to="varying")

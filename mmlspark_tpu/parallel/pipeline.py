@@ -40,7 +40,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from mmlspark_tpu.core.exceptions import FriendlyError
-from mmlspark_tpu.parallel.mesh import DATA_AXIS, PIPELINE_AXIS, axis_size, shard_map
+from mmlspark_tpu.parallel.mesh import DATA_AXIS, PIPELINE_AXIS
 
 #: param-sharding rule stacking pipeline stages over the ``pipe`` axis
 #: (leading stacked dim); used with SPMDTrainer.param_rules for the
@@ -63,7 +63,7 @@ def _pipeline_inner(
     size 1). ``mb``: (M, b, ...) microbatch buffer, replicated over the
     pipe axis. Returns (M, b, ...) outputs, identical on every pipe rank.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     local = jax.tree_util.tree_map(lambda a: a[0], params)
     n_micro = mb.shape[0]
@@ -141,7 +141,7 @@ def pipeline_apply(
     )
     mb_spec = P(None, batch)
     inner = partial(_pipeline_inner, stage_fn, axis_name=axis)
-    return shard_map(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(P(axis), mb_spec),

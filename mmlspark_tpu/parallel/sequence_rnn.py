@@ -33,7 +33,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from mmlspark_tpu.parallel.mesh import pcast_varying, shard_map
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -64,10 +63,13 @@ def _chain(cell, params, x_local, hidden: int, axis: str, reverse: bool,
     # mark the zeros varying over every mesh axis for shard_map's
     # manual-axes typing: the chain's carries and outputs differ per
     # device (the scanned x_local varies over all of them)
-    zero = pcast_varying(jnp.zeros((b, hidden), x_local.dtype), vary_axes)
+    def varying(t):
+        return lax.pcast(t, vary_axes, to="varying") if vary_axes else t
+
+    zero = varying(jnp.zeros((b, hidden), x_local.dtype))
     # flax LSTM carry is (c, h)
     carry = (zero, zero)
-    ys = pcast_varying(jnp.zeros((b, tc, hidden), x_local.dtype), vary_axes)
+    ys = varying(jnp.zeros((b, tc, hidden), x_local.dtype))
     # state flows downstream in time: to higher ranks forward, lower
     # ranks backward. No wraparound — rank 0 (resp. n-1) starts from
     # zeros, matching the dense scan's initial carry.
@@ -152,7 +154,7 @@ def bilstm_seq_parallel_apply(
         out = out + head["bias"].astype(jnp.bfloat16)
         return out.astype(jnp.float32)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), io_spec),

@@ -1,8 +1,10 @@
 """Synthetic-traffic serving demo — the ``serve`` subcommand's body and
 ``bench.py``'s ``serve`` metric group.
 
-Drives a ``ServeEngine`` over a small random-init ``transformer_lm``
-with a deterministic staggered arrival schedule (a few submits per tick,
+Drives a ``ServeEngine`` over a random-init ``transformer_lm`` (tiny by
+default; ``model=`` / ``--model`` gives it any shape, GPT-2 small
+included, with the model's own default attention — the flash kernels on
+a TPU) with a deterministic staggered arrival schedule (a few submits per tick,
 prompt lengths drawn from a seeded rng), mirroring ``bench``'s contract:
 ONE parseable JSON line out, carrying queue-depth, TTFT, per-token
 latency, slot-utilization, and throughput metrics. With
@@ -35,7 +37,8 @@ import numpy as np
 def run_demo(*, slots: int = 4, n_requests: int = 8,
              max_new_tokens: int = 8, arrivals_per_tick: int = 2,
              vocab: int = 64, d_model: int = 32, heads: int = 2,
-             depth: int = 2, cache_len: int = 64, seed: int = 0,
+             depth: int = 2, cache_len: int = 64, max_prompt: int = 16,
+             model: str | None = None, seed: int = 0,
              deadline_ticks: int | None = None,
              decode_block: int | None = None,
              mesh: str | None = None,
@@ -68,7 +71,10 @@ def run_demo(*, slots: int = 4, n_requests: int = 8,
     :class:`~mmlspark_tpu.serve.fleet.DisaggFleet` of dedicated
     prefill/decode replicas (docs/SERVING.md "Disaggregated fleet");
     ``autoscale`` takes the ``"max_decode=4,queue_high=2"``-style
-    policy spec."""
+    policy spec. ``model`` is the CLI's shape spec: ':'-separated
+    ``key=value`` fields over this function's own shape arguments
+    (``vocab``, ``d_model``, ``heads``, ``depth``, ``cache_len``,
+    ``max_prompt`` — prompts are drawn from 4..max_prompt tokens)."""
     import jax
     import jax.numpy as jnp
 
@@ -91,9 +97,17 @@ def run_demo(*, slots: int = 4, n_requests: int = 8,
             metrics_port=metrics_port,
         )
 
+    if model:
+        shape = _parse_model_spec(model)
+        vocab = shape.get("vocab", vocab)
+        d_model = shape.get("d_model", d_model)
+        heads = shape.get("heads", heads)
+        depth = shape.get("depth", depth)
+        cache_len = shape.get("cache_len", cache_len)
+        max_prompt = shape.get("max_prompt", max_prompt)
     graph = build_model(
         "transformer_lm", vocab_size=vocab, d_model=d_model, heads=heads,
-        depth=depth, max_len=cache_len, attn_impl="dense",
+        depth=depth, max_len=cache_len,
     )
     variables = graph.init(
         jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32)
@@ -170,7 +184,7 @@ def run_demo(*, slots: int = 4, n_requests: int = 8,
         server = MetricsServer(hub, port=metrics_port)
 
     rng = np.random.default_rng(seed)
-    lo, hi = 4, max(5, min(16, cache_len - max_new_tokens))
+    lo, hi = 4, max(5, min(max_prompt, cache_len - max_new_tokens))
     lengths = rng.integers(lo, hi + 1, size=n_requests)
     prompts = [rng.integers(0, vocab, size=int(p)) for p in lengths]
 
@@ -260,6 +274,28 @@ def run_demo(*, slots: int = 4, n_requests: int = 8,
 
             export_chrome_trace(recorder, path=trace_out,
                                 extra_meta={"model": graph.name})
+    return out
+
+
+_MODEL_KEYS = ("vocab", "d_model", "heads", "depth", "cache_len",
+               "max_prompt")
+
+
+def _parse_model_spec(spec: str) -> dict:
+    """``"d_model=768:heads=12"`` -> ``{"d_model": 768, "heads": 12}``:
+    the ``--models`` field grammar over :func:`run_demo`'s shape
+    arguments; anything else is a typed error naming the vocabulary."""
+    from mmlspark_tpu.core.exceptions import FriendlyError
+
+    out = {}
+    for field in filter(None, (f.strip() for f in spec.split(":"))):
+        key, eq, value = field.partition("=")
+        if not eq or key not in _MODEL_KEYS or not value.isdigit():
+            raise FriendlyError(
+                f"bad --model field {field!r}: expected key=<int> with "
+                f"key one of {', '.join(_MODEL_KEYS)}"
+            )
+        out[key] = int(value)
     return out
 
 

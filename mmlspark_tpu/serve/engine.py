@@ -236,6 +236,9 @@ class ServeEngine:
         # single-device engine, and the compile-count pins hold because
         # every per-tick input is committed to a fixed NamedSharding
         self.mesh = _resolve_mesh(mesh)
+        if self.mesh is not None:
+            # attention runs its kernels per shard of THIS mesh
+            graph = self.graph = graph.with_mesh(self.mesh)
         # weight-only int8 serving (docs/PERFORMANCE.md "Quantized
         # decode"): the device-resident weights are per-channel int8
         # (min_size=0 — at decode batch sizes EVERY matmul is

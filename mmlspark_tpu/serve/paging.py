@@ -90,6 +90,13 @@ from mmlspark_tpu.serve.cache_pool import (
 #: tile
 MIN_PAGE_SIZE = 8
 
+#: bytes of TPU scalar memory the page table may take. The paged decode
+#: kernel scalar-prefetches its shard's ``(slots, max_pages)`` int32 table
+#: into one core's 1 MiB SMEM, where rows pad to 8 and columns to 128;
+#: compiling for a described v5e accepts 1,015,808 padded bytes
+#: (64 x 3968) and refuses 1,048,576 (tests/test_aot_tpu_compile.py)
+PAGE_TABLE_SMEM_BYTES = (1 << 20) - (32 << 10)
+
 
 def default_page_size(cache_len: int) -> int:
     """Smallest multiple of the sublane tile in [8, cache_len] dividing
@@ -206,6 +213,20 @@ class PagedCachePool:
         self.max_pages = cache_len // page_size
         self._data = data
         self._slots_per_shard = slots // data
+        # each data shard's kernel prefetches its own rows of the table
+        table_bytes = (
+            4 * -(-self._slots_per_shard // 8) * 8
+            * -(-self.max_pages // 128) * 128
+        )
+        if table_bytes > PAGE_TABLE_SMEM_BYTES:
+            raise FriendlyError(
+                f"page table of {self._slots_per_shard} slots x "
+                f"{self.max_pages} pages per data shard pads to "
+                f"{table_bytes} bytes of TPU scalar memory; the paged "
+                f"decode kernel stops compiling past "
+                f"{PAGE_TABLE_SMEM_BYTES}. Use a larger page_size, fewer "
+                "slots or a wider data axis"
+            )
         if num_pages is None:
             # worst case: every slot fully paged, plus one trash page
             # per shard — a budget that can never exhaust. Callers size

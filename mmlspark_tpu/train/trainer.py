@@ -82,11 +82,10 @@ class TrainConfig:
     log_every: int = 50
     shuffle: bool = True
     # chain K optimizer steps inside ONE compiled call (lax.scan over K
-    # stacked batches): cuts per-step host dispatch to 1/K — decisive on
-    # high-latency links (TPU behind a relay). Semantics are exact: every
-    # batch is still one optimizer step; epoch tails that don't fill a
-    # chunk run through the single-step program. Ignored (forced 1) under
-    # tensor-parallel param_rules.
+    # stacked batches): cuts per-step host dispatch to 1/K. Semantics are
+    # exact: every batch is still one optimizer step; epoch tails that
+    # don't fill a chunk run through the single-step program. Ignored
+    # (forced 1) under tensor-parallel param_rules.
     steps_per_dispatch: int = 1
     # rematerialize the forward pass in the backward (jax.checkpoint):
     # trades ~33% more FLOPs for not keeping activations in HBM — the
@@ -262,6 +261,9 @@ class SPMDTrainer:
             else FlightRecorder()
         self._faults = faults
         self._step = 0  # current global step, for the fault listener's tick
+        #: (jitted single-step program, abstract signature of its first
+        #: dispatch) — what :meth:`step_cost` lowers on demand
+        self._step_program: tuple | None = None
         if faults is not None and faults.listener is None:
             # injected faults land in the same metrics + event timeline
             # as their consequences (retries, quarantines, degradation)
@@ -292,6 +294,21 @@ class SPMDTrainer:
         self.telemetry.gauge("train.grad_accum").set(
             max(int(config.grad_accum), 1)
         )
+
+    def step_cost(self):
+        """Analytic cost (:class:`~mmlspark_tpu.core.perf.ProgramCost`:
+        FLOPs, bytes, Pallas kernel count) of the single-step program
+        this trainer last ran, lowered on demand from the abstract
+        signature of its first dispatch — tracing only, no compile, no
+        device work."""
+        from mmlspark_tpu.core.perf import analyze_jit_cost
+
+        if self._step_program is None:
+            raise FriendlyError(
+                "step_cost() needs a step to have run: call train() first"
+            )
+        jitted, args = self._step_program
+        return analyze_jit_cost(jitted, *args)
 
     # -- checkpointing ------------------------------------------------------
 
@@ -466,7 +483,12 @@ class SPMDTrainer:
 
         data_sh = batch_spec(mesh)
         rep_sh = replicated_spec(mesh)
+        # attention runs its kernels per shard of the step's mesh (duck-
+        # typed graphs that own their mesh, e.g. the pipelined family,
+        # have no with_mesh and need none)
         graph = self.graph
+        if mesh.size > 1 and hasattr(graph, "with_mesh"):
+            graph = graph.with_mesh(mesh)
         loss_kind = cfg.loss
 
         aux_w = cfg.moe_aux_weight
@@ -1129,11 +1151,20 @@ class SPMDTrainer:
                             )
                             audit_due = audit_due or due
                         else:
+                            step_args = (params, rest, opt_state,
+                                         streak_dev, anoms_dev, bx, by, bm)
+                            if self._step_program is None:
+                                # shapes only: the arrays are donated
+                                self._step_program = (
+                                    jitted,
+                                    jax.tree_util.tree_map(
+                                        lambda a: jax.ShapeDtypeStruct(
+                                            a.shape, a.dtype),
+                                        step_args,
+                                    ),
+                                )
                             (params, rest, opt_state, streak_dev,
-                             anoms_dev, loss, gnorm) = jitted(
-                                params, rest, opt_state, streak_dev,
-                                anoms_dev, bx, by, bm,
-                            )
+                             anoms_dev, loss, gnorm) = jitted(*step_args)
                         if self._faults is not None:
                             # the train.step silent-corruption drill: a
                             # seeded bit-flip lands in ONE device's copy

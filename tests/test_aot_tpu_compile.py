@@ -1,0 +1,199 @@
+"""Compile the serve and train kernels for a TPU v5e that is described,
+not attached (the on-chip-measurement guide, section 2 step 3).
+
+Interpret mode cannot see what the chip's compiler refuses: SMEM and
+VMEM budgets, tile alignment. These cases hold every Pallas kernel of
+the main path, and the two serve step programs, to the GPT-2-small
+shapes ``chip_smoke.py`` runs — so a kernel that stops compiling at a
+real size fails here, at no chip time. Nothing executes; a compile
+that passes is not a chip run.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from mmlspark_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_decode,
+    paged_flash_decode,
+)
+
+HEADS, HEAD_DIM, CACHE = 12, 64, 1024
+GPT2_SMALL = dict(vocab_size=50257, d_model=768, heads=HEADS, depth=12,
+                  d_ff=3072, max_len=CACHE)
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one described v5e chip; the persistent compilation
+    cache is off around these compiles (an entry written for a described
+    device cannot be read back without one, and warns)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu, no topology
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _qkv(b, s):
+    return (jax.ShapeDtypeStruct((b, s, HEADS, HEAD_DIM), jnp.bfloat16),) * 3
+
+
+def _attention(**kw):
+    return (lambda q, k, v: flash_attention(q, k, v, interpret=False, **kw),
+            _qkv(2, CACHE))
+
+
+def _attention_bwd():
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2)), _qkv(2, CACHE)
+
+
+def _decode(slots, int8, cache=CACHE):
+    kv = jax.ShapeDtypeStruct(
+        (slots, cache, HEADS, HEAD_DIM), jnp.int8 if int8 else jnp.bfloat16
+    )
+    args = [jax.ShapeDtypeStruct((slots, 1, HEADS, HEAD_DIM), jnp.bfloat16),
+            kv, kv, jax.ShapeDtypeStruct((slots,), jnp.int32)]
+    if not int8:
+        return (lambda q, k, v, n: flash_decode(q, k, v, n, interpret=False),
+                args)
+    sc = jax.ShapeDtypeStruct((slots, HEADS), jnp.float32)
+    return (lambda q, k, v, n, ks, vs: flash_decode(
+        q, k, v, n, k_scale=ks, v_scale=vs, interpret=False),
+        args + [sc, sc])
+
+
+def _paged(slots, page_size, int8):
+    """The pool's own worst-case geometry: every slot fully paged plus
+    the trash page — 64 slots at page_size 16 is the 4,097-page case,
+    64 slots at page_size 8 the 64 x 128 page table."""
+    max_pages = CACHE // page_size
+    num_pages = slots * max_pages + 1
+    pages = jax.ShapeDtypeStruct(
+        (num_pages, HEADS, page_size, HEAD_DIM),
+        jnp.int8 if int8 else jnp.bfloat16,
+    )
+    args = [jax.ShapeDtypeStruct((slots, 1, HEADS, HEAD_DIM), jnp.bfloat16),
+            pages, pages, jax.ShapeDtypeStruct((slots,), jnp.int32),
+            jax.ShapeDtypeStruct((slots, max_pages), jnp.int32)]
+    if not int8:
+        return (lambda q, k, v, n, pt: paged_flash_decode(
+            q, k, v, n, pt, interpret=False), args)
+    sc = jax.ShapeDtypeStruct((num_pages, HEADS), jnp.float32)
+    return (lambda q, k, v, n, pt, ks, vs: paged_flash_decode(
+        q, k, v, n, pt, k_scale=ks, v_scale=vs, interpret=False),
+        args + [sc, sc])
+
+
+def _gpt2_small():
+    from mmlspark_tpu.models import build_model
+
+    graph = build_model("transformer_lm", **GPT2_SMALL)
+    assert graph.extra["attn_impl"] == "flash"  # is_tpu patched -> auto
+    variables = jax.eval_shape(
+        graph.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )
+    return graph, variables
+
+
+def _decode_block(slots=8, t=32):
+    from mmlspark_tpu.models.generate import cache_geometry, make_decode_block
+
+    graph, variables = _gpt2_small()
+    buffers = {
+        name: (jax.ShapeDtypeStruct((slots, CACHE, hk, d), jnp.bfloat16),) * 2
+        for name, (hk, d) in cache_geometry(graph, variables).items()
+    }
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_)
+    block = make_decode_block(graph)
+    return (lambda v, b, pos, lv, tok, rem, eos: block(
+        v, b, pos, lv, tok, rem, eos, t),
+        [variables, buffers, ints, live, ints, ints, ints])
+
+
+def _prefill():
+    from mmlspark_tpu.models.generate import _cached_apply, init_cache
+
+    graph, variables = _gpt2_small()
+
+    def prefill(v, prompt):
+        cache = init_cache(graph, v, 1, prompt.shape[1])
+        return _cached_apply(graph, v, prompt, cache, 0)
+
+    return prefill, [variables, jax.ShapeDtypeStruct((1, CACHE), jnp.int32)]
+
+
+CASES = {
+    "flash_fwd_full": lambda: _attention(),
+    "flash_fwd_causal": lambda: _attention(causal=True),
+    "flash_fwd_windowed": lambda: _attention(causal=True, window=256),
+    "flash_bwd_causal": _attention_bwd,
+    "flash_decode_bf16_8": lambda: _decode(8, False),
+    "flash_decode_bf16_64": lambda: _decode(64, False),
+    # generate()'s cache is prompt + new tokens long: 700 + 48 has no
+    # divisor that is whole sublanes, so the read takes the padded layout
+    "flash_decode_bf16_len748": lambda: _decode(1, False, cache=748),
+    "flash_decode_int8_8": lambda: _decode(8, True),
+    "flash_decode_int8_64": lambda: _decode(64, True),
+    "paged_bf16_ps8_1025_pages": lambda: _paged(8, 8, False),
+    "paged_int8_ps8_1025_pages": lambda: _paged(8, 8, True),
+    "paged_bf16_ps8_table_64x128": lambda: _paged(64, 8, False),
+    "paged_bf16_ps16_4097_pages": lambda: _paged(64, 16, False),
+    "paged_int8_ps16_4097_pages": lambda: _paged(64, 16, True),
+    "paged_bf16_ps128": lambda: _paged(64, 128, False),
+    "paged_int8_ps128": lambda: _paged(64, 128, True),
+    "gpt2_small_decode_block_t32": _decode_block,
+    "gpt2_small_prefill_1x1024": _prefill,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_compiles_for_v5e(case, chip, monkeypatch):
+    # code that asks "is this a TPU" sees the CPU here and would take
+    # its dense / interpret branch; the test steers it, not an option
+    monkeypatch.setattr("mmlspark_tpu.core.env.is_tpu", lambda: True)
+    fn, args = CASES[case]()
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        args,
+    )
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pool_refuses_a_page_table_the_kernel_cannot_hold():
+    """The one size the kernel cannot be made to compile at — a page
+    table past scalar memory — is refused when the pool is built, with
+    the limit named; it never reaches a dispatch."""
+    from mmlspark_tpu.core.exceptions import FriendlyError
+    from mmlspark_tpu.models import build_model
+    from mmlspark_tpu.serve.paging import PagedCachePool
+
+    graph = build_model("transformer_lm", vocab_size=16, d_model=16,
+                        heads=2, depth=1, max_len=8, attn_impl="dense")
+    variables = jax.eval_shape(
+        graph.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )
+    with pytest.raises(FriendlyError, match="scalar memory"):
+        PagedCachePool(graph, variables, 64, 32768, page_size=8)

@@ -1,14 +1,15 @@
 """Unit tests for bench.py's emission envelope (no backend needed).
 
 The envelope is the part the driver depends on when everything else goes
-wrong (BENCH_r01-r03 all failed differently), so its rules are pinned
-directly: headline-value provenance, failure classification, smoke-mode
-labeling, and scratch persistence.
+wrong, so its rules are pinned directly: headline-value provenance, no
+number without the chip unless smoke mode was asked for and labelled,
+and scratch persistence.
 """
 
 import importlib
 import json
 import os
+import subprocess
 import sys
 
 
@@ -28,7 +29,6 @@ def test_headline_null_unless_tpu_provenance(monkeypatch, tmp_path):
     cpu = bench._final_line(
         {"images_per_sec_per_chip": 700.0,
          "group_backends": {"inference": "cpu"}},
-        attempt=1,
     )
     assert cpu["value"] is None
     assert cpu["images_per_sec_per_chip"] == 700.0  # stays in the body
@@ -36,53 +36,59 @@ def test_headline_null_unless_tpu_provenance(monkeypatch, tmp_path):
     tpu = bench._final_line(
         {"images_per_sec_per_chip": 427020.0,
          "group_backends": {"inference": "tpu"}},
-        attempt=1,
     )
     assert tpu["value"] == 427020.0
     assert "images_per_sec_per_chip" not in tpu or tpu["value"] is not None
 
 
-def test_smoke_mode_scale_labels(monkeypatch, tmp_path):
+def test_smoke_mode_is_labelled_and_never_a_headline(monkeypatch, tmp_path):
     bench = _bench(monkeypatch, tmp_path, MMLTPU_BENCH_CPU_SMOKE="1")
     smoke = bench._final_line(
         {"images_per_sec_per_chip": 700.0,
          "group_backends": {"inference": "cpu"}},
-        attempt=3, error="backend probe failed: probe hung >60s",
     )
     assert smoke["scale"] == "cpu_smoke"
     assert smoke["value"] is None
-    assert smoke["error_class"] == "backend_unreachable"
+    # the smoke run proved the bench path: exit 0, not the old "5 is fine"
+    assert bench._exit_code(smoke) == 0
+    assert bench._exit_code(bench._final_line({})) == 5
+    assert bench._exit_code(bench._final_line({}), hung=True) == 7
 
-    partial = bench._final_line(
-        {"images_per_sec_per_chip": 427020.0,
-         "group_backends": {"inference": "tpu", "train": "cpu"}},
-        attempt=3, error="TPU unreachable",
+
+def test_no_tpu_and_no_smoke_variable_exits_nonzero(tmp_path):
+    """The CPU is reached only by asking for it: with no TPU the command
+    exits non-zero before any metric and prints no result line — it never
+    re-execs onto the CPU on its own."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "MMLTPU_BENCH_CPU_SMOKE"}
+    env.update(JAX_PLATFORMS="cpu",
+               MMLTPU_BENCH_SCRATCH=str(tmp_path / "scratch.json"))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(repo, "bench.py")],
+        env=env, capture_output=True, text=True, timeout=120, cwd=repo,
     )
-    assert partial["scale"] == "partial_tpu_then_cpu_smoke"
-    assert partial["value"] == 427020.0
+    assert proc.returncode == 2
+    assert proc.stdout.strip() == ""
+    assert "no TPU" in proc.stderr
 
 
-def test_error_classifier(monkeypatch, tmp_path):
+def test_one_peak_table(monkeypatch, tmp_path):
+    """bench.py reads core/perf.py's table; it keeps none of its own."""
     bench = _bench(monkeypatch, tmp_path)
-    for err, cls in [
-        ("backend init hung for 900s (watchdog)", "backend_unreachable"),
-        ("backend probe failed: spawn error", "backend_unreachable"),
-        ("RPC UNAVAILABLE: relay", "backend_unreachable"),
-        ("TPU unreachable", "backend_unreachable"),
-        ("TypeError: bad shape", "bench_failure"),
-    ]:
-        line = bench._final_line({}, attempt=3, error=err)
-        assert line["error_class"] == cls, (err, line["error_class"])
+    from mmlspark_tpu.core.perf import DEVICE_PEAKS
 
+    assert not hasattr(bench, "_PEAK_FLOPS")
+    # this suite's CPU hits the nominal entry: no MFU against a made-up peak
+    assert bench._peak_flops() is None
 
-def test_probe_key_dropped_on_success_kept_on_failure(monkeypatch, tmp_path):
-    bench = _bench(monkeypatch, tmp_path)
-    ok = bench._final_line({"probe": "1 tpu TPU v5 lite"}, attempt=1)
-    assert "probe" not in ok
-    bad = bench._final_line(
-        {"probe": "probe hung >60s"}, attempt=2, error="x failed"
-    )
-    assert bad["probe"] == "probe hung >60s"
+    class _V5e:
+        device_kind = "TPU v5 lite"
+
+    import jax
+
+    monkeypatch.setattr(jax, "devices", lambda: [_V5e()])
+    assert bench._peak_flops() == DEVICE_PEAKS["TPU v5 lite"][0] == 197e12
 
 
 def test_scratch_merge_roundtrip_and_missing_groups(monkeypatch, tmp_path):
@@ -90,7 +96,7 @@ def test_scratch_merge_roundtrip_and_missing_groups(monkeypatch, tmp_path):
     merged = bench._scratch_merge({"images_per_sec_per_chip": 1.0, "mfu": 0.1})
     assert bench._group_done(merged, "inference")
     assert not bench._group_done(merged, "flash")
-    line = bench._final_line(bench._scratch_load(), attempt=1)
+    line = bench._final_line(bench._scratch_load())
     assert set(line["missing_metrics"]) == {
         "stage", "resnet50", "train", "trees", "flash", "flash_long",
         "int8_serving", "feed_synth", "decode", "serve", "serve_paged",
@@ -104,10 +110,10 @@ def test_scratch_merge_roundtrip_and_missing_groups(monkeypatch, tmp_path):
 
 
 def test_chained_op_seconds_contract(monkeypatch, tmp_path):
-    """The dispatch-cancelling timing harness (shared with
-    tools/flash_tpu_evidence.py) returns positive per-iteration seconds
-    plus a fallback flag, and traces the step per chain — not per
-    iteration (the chained iterations live inside one lax.scan)."""
+    """The dispatch-cancelling timing harness returns positive
+    per-iteration seconds plus a fallback flag, and traces the step per
+    chain — not per iteration (the chained iterations live inside one
+    lax.scan)."""
     bench = _bench(monkeypatch, tmp_path)
     import jax
     import jax.numpy as jnp
@@ -150,7 +156,7 @@ def test_final_stdout_line_is_compact_json(monkeypatch, tmp_path, capsys):
         },
         "serve": {"tokens_per_sec": 512.5, "blob": ["y" * 64] * 64},
     }
-    line = bench._final_line(results, attempt=1)
+    line = bench._final_line(results)
     assert len(json.dumps(line).encode()) > bench._COMPACT_LIMIT_BYTES
     assert bench._emit(line) is True
     out = capsys.readouterr().out.strip().splitlines()[-1]
@@ -175,12 +181,11 @@ def test_compact_line_sheds_until_under_budget(monkeypatch, tmp_path):
     bench = _bench(monkeypatch, tmp_path)
     line = bench._final_line(
         {"group_seconds": {f"g{i}": 1.0 for i in range(40)}},
-        attempt=3, error="E" * 5000,
+        error="E" * 5000,
     )
     compact = bench._compact_line(line)
     assert len(json.dumps(compact).encode()) <= bench._COMPACT_LIMIT_BYTES
     assert compact["error"].startswith("E")
-    assert compact["error_class"] == "bench_failure"
 
 
 def test_vs_baseline_is_own_committed_record(monkeypatch, tmp_path):
@@ -200,7 +205,6 @@ def test_vs_baseline_is_own_committed_record(monkeypatch, tmp_path):
     line = bench._final_line(
         {"images_per_sec_per_chip": 3.0e6,
          "group_backends": {"inference": "tpu"}},
-        attempt=1,
     )
     assert line["vs_baseline"] == 1.5  # vs r10 (numeric sort), not r4
     assert "BENCH_LOCAL_r10" in line["vs_baseline_source"]
@@ -208,7 +212,6 @@ def test_vs_baseline_is_own_committed_record(monkeypatch, tmp_path):
     cpu_line = bench._final_line(
         {"images_per_sec_per_chip": 700.0,
          "group_backends": {"inference": "cpu"}},
-        attempt=1,
     )
     assert cpu_line["value"] is None
     assert cpu_line["vs_baseline"] is None
@@ -217,9 +220,8 @@ def test_vs_baseline_is_own_committed_record(monkeypatch, tmp_path):
     ok = bench._final_line(
         {"images_per_sec_per_chip": 3.0e6,
          "group_backends": {"inference": "tpu"}},
-        attempt=1,
     )
     assert ok["value"] == 3.0e6  # emission survived
-    null_line = bench._final_line({}, attempt=1)
+    null_line = bench._final_line({})
     assert null_line["vs_baseline"] is None
     assert "vs_baseline_source" not in null_line

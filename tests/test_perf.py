@@ -162,7 +162,7 @@ def test_device_peak_env_override_and_table_prefix(monkeypatch):
     p = device_peak(FakeTpu())
     assert p.source == "table" and p.flops_per_s == 459e12
 
-    # the CPU backend of this suite is not in the table -> nominal
+    # the CPU backend of this suite hits the table's labelled cpu entry
     assert device_peak().source == "nominal"
 
     monkeypatch.setenv("MMLTPU_PEAK_FLOPS", "2e12")

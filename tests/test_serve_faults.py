@@ -141,15 +141,13 @@ def test_classifiers_cover_injected_and_real_spellings():
     assert not is_transient(ResourceExhausted("x"))
     assert not is_transient(EngineKilled("x"))
     assert is_resource_exhausted(ResourceExhausted("x"))
-    # the REAL runtime's status spellings match by name + message
+    # the REAL runtime's status spellings match by class + message
     assert is_resource_exhausted(RuntimeError("RESOURCE_EXHAUSTED: pool"))
+    from jax.errors import JaxRuntimeError
 
-    class XlaRuntimeError(RuntimeError):
-        pass
-
-    assert is_transient(XlaRuntimeError("UNAVAILABLE: link down"))
-    assert is_transient(XlaRuntimeError("DEADLINE_EXCEEDED: slow"))
-    assert not is_transient(XlaRuntimeError("INTERNAL: compiler bug"))
+    assert is_transient(JaxRuntimeError("UNAVAILABLE: link down"))
+    assert is_transient(JaxRuntimeError("DEADLINE_EXCEEDED: slow"))
+    assert not is_transient(JaxRuntimeError("INTERNAL: compiler bug"))
     # status text in a non-runtime error type is NOT retryable
     assert not is_transient(RuntimeError("UNAVAILABLE"))
 
