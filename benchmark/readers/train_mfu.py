@@ -1,0 +1,12 @@
+"""Model FLOPs of the tokens the window's steps trained, over the window
+and the peak of all the chips: 6 x the weights that multiply a token plus
+its share of the causal attention, recomputation not counted."""
+from benchmark import flops
+from benchmark.readers import train_rate
+
+
+def read(state, spec):
+    rate = train_rate.read(state, spec)
+    per_token = flops.train_flops_per_token(state["sz"], state["seq"])
+    return 100.0 * rate * per_token / (
+        state["chips"] * state["peak"]["flops_per_s"])
