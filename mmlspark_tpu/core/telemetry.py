@@ -19,7 +19,10 @@ lazily and only by the watchdog's shape formatter):
   arrival order, with bounded relative error (one bucket's growth
   factor) and exact count/sum/min/max.
 - :class:`Span` + :class:`SpanTracer`: structured events (name, attrs,
-  tick, monotonic wall time) grouped by span id.
+  tick, monotonic wall time) grouped by span id; and
+  :meth:`SpanTracer.region`, the one way a hot path marks a host
+  INTERVAL: a ``jax.profiler`` annotation on the device trace's clock
+  and one recorder event on the recorder's.
 - :class:`FlightRecorder`: a bounded ring buffer of those events that
   can dump the last N as JSON-lines on demand
   (:meth:`FlightRecorder.dump`) and automatically when a
@@ -33,8 +36,8 @@ lazily and only by the watchdog's shape formatter):
   retraces are the classic TPU serving regression and this makes them
   loud at the moment they happen.
 
-``utils/profiling.py`` re-exports everything here next to the
-jax.profiler hooks, so call sites have one observability import.
+``utils/profiling.py`` re-exports everything here next to
+``trace_profile``, so call sites have one observability import.
 """
 
 from __future__ import annotations
@@ -52,6 +55,7 @@ from typing import Any, Callable, Iterator
 from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.core.logging_utils import get_logger
 from mmlspark_tpu.core.metrics_contracts import MetricData
+from mmlspark_tpu.testing.compile_guard import backend_compiles
 
 _log = get_logger("telemetry")
 
@@ -600,20 +604,146 @@ class Span:
         )
 
 
+class Region:
+    """One host interval of a hot path, recorded twice: as a
+    ``jax.profiler`` annotation on the device trace's clock while a
+    profiler session runs, and as ONE flight-recorder event on exit.
+    Made by :meth:`SpanTracer.region`; ``t0`` and ``t1`` (monotonic
+    seconds) and ``ms`` are the caller's to read once the block has
+    ended, so a loop that needs the interval takes no clock of its own.
+    """
+
+    __slots__ = ("name", "tick", "t0", "t1", "_tracer", "_annotation",
+                 "_attrs", "_stack", "_compiles0", "_dropped")
+
+    def __init__(self, tracer: "SpanTracer", name: str, tick: int | None,
+                 annotation, attrs: dict):
+        self.name, self.tick = name, tick
+        self.t0 = self.t1 = None
+        self._tracer, self._annotation, self._attrs = tracer, annotation, attrs
+        self._dropped = False
+
+    @property
+    def ms(self) -> float:
+        return (self.t1 - self.t0) * 1e3
+
+    def count(self, **counts) -> None:
+        """Numbers (or a late ``request=``) the block learned on its way,
+        for the event's ``attrs``."""
+        self._attrs.update(counts)
+
+    def drop(self) -> None:
+        """Record no event on exit: the interval turned out to hold no
+        work (the trainer's pull that found the epoch's end)."""
+        self._dropped = True
+
+    def __enter__(self) -> "Region":
+        self._stack = self._tracer._open_regions()
+        if self._stack:
+            self._attrs["parent"] = self._stack[-1].name
+        self._stack.append(self)
+        self._compiles0 = backend_compiles()
+        self._annotation.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.t1 = time.monotonic()
+        self._annotation.__exit__(exc_type, exc, tb)
+        self._stack.pop()
+        if self._dropped:
+            return False
+        attrs = self._attrs
+        compiles = backend_compiles() - self._compiles0
+        if compiles:
+            attrs["compiles"] = compiles
+        if exc_type is not None:
+            attrs["error"] = exc_type.__name__
+        self._tracer.recorder.record(
+            self.name, tick=self.tick, t0=self.t0,
+            ms=round(self.ms, 3), **attrs,
+        )
+        return False   # transparent: whatever was raised goes on
+
+
 class SpanTracer:
     """Hands out :class:`Span` objects with process-unique ids over one
-    :class:`FlightRecorder`."""
+    :class:`FlightRecorder`, and :class:`Region` intervals over the same
+    recorder."""
 
     def __init__(self, recorder: FlightRecorder):
         self.recorder = recorder
         self._next_id = 0
         self._lock = threading.Lock()
+        self._local = threading.local()
 
     def span(self, name: str, *, tick: int | None = None, **attrs) -> Span:
         with self._lock:
             sid = self._next_id
             self._next_id += 1
         return Span(self.recorder, name, sid, tick=tick, **attrs)
+
+    def _open_regions(self) -> list:
+        """This thread's open regions, outermost first."""
+        try:
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = []
+            return self._local.stack
+
+    def region(self, name: str, *, tick: int | None = None,
+               request: int | None = None, **attrs) -> Region:
+        """The one way a hot path marks a host interval::
+
+            with tracer.region("serve.pool_write", request=rid) as r:
+                ...
+                r.count(dispatches=n)
+
+        On entry a ``jax.profiler.TraceAnnotation(name)`` opens with
+        ``tick`` and ``request`` as its metadata: inside a profiler
+        session the interval lies in the trace's host plane, on the
+        device's clock, where idle gaps of the chip can be laid under
+        it. On exit ONE recorder event ``name`` is written, with
+        ``tick`` and, in ``attrs``: ``t0`` (the start,
+        ``time.monotonic()``), ``ms``, ``parent`` (the enclosing region
+        of this thread; absent at the top), ``request`` (the id that
+        the intervals of one request share), ``compiles`` (backend
+        compiles the process made inside; absent when 0), ``error``
+        (the type of an exception that passed through; it is never
+        swallowed), the keyword ``attrs`` and whatever ``count()`` set.
+        The recorder's event is there with the profiler off.
+
+        ``name`` is ``<layer>.<what>``: the dot keeps it apart from the
+        lifecycle events (``prefill``, ``decode``, ``tick``, ``step``
+        ...) that readers of the recorder filter on."""
+        import jax
+
+        meta = {}
+        if tick is not None:
+            meta["tick"] = tick
+        if request is not None:
+            meta["request"] = attrs["request"] = request
+        return Region(self, _region_name(name), tick,
+                      jax.profiler.TraceAnnotation(name, **meta), attrs)
+
+    def step_region(self, name: str, *, tick: int) -> Region:
+        """A :meth:`region` that is one step of a training loop: its
+        annotation is ``jax.profiler.StepTraceAnnotation(name,
+        step_num=tick)``, which the profiler's own step view reads."""
+        import jax
+
+        return Region(self, _region_name(name), tick,
+                      jax.profiler.StepTraceAnnotation(name, step_num=tick),
+                      {})
+
+
+def _region_name(name: str) -> str:
+    if "." not in name:
+        raise FriendlyError(
+            f"a region is named '<layer>.<what>' (got {name!r}): a bare "
+            "name could pass for one of the recorder's lifecycle events"
+        )
+    return name
 
 
 # --------------------------------------------------------------------------

@@ -351,13 +351,23 @@ class SlotCachePool:
     # -- data path ---------------------------------------------------------
 
     def write_prefill(self, slot: int, prefill_cache: dict,
-                      length: int, start: int = 0) -> None:
+                      length: int, start: int = 0) -> tuple[int, int]:
         """Copy a batch-1 prefill cache (buffers holding valid K/V for
         positions ``[0, length)``) into positions ``[start, length)``
         of the slot's row — ``start=0`` is the classic full prefill;
         ``start>0`` resumes a partial fill whose prefix ``[0, start)``
         the slot already holds (same contract as the paged pool's
-        ``write_prefill``, which prefix-cache resume uses)."""
+        ``write_prefill``, which prefix-cache resume uses).
+
+        Returns ``(dispatches, bytes)``: the separate array operations
+        this write launched eagerly, counted beside each launch (a
+        slice, a cast where the dtypes differ and a scatter for each
+        K and each V array, then positions and live, and each pinned
+        ``device_put`` under a mesh; a helper that runs several
+        primitives, as the int8 path's scales and quantization do,
+        counts once), and the K/V bytes it wrote into the pool. The
+        engine stamps both on its ``serve.pool_write`` region: ONE
+        jitted write would read 1."""
         if slot not in self._leased:
             raise FriendlyError(f"slot {slot} is not leased")
         if length > self.cache_len:
@@ -382,6 +392,7 @@ class SlotCachePool:
                 "(use the paged pool for resumable int8 fills)"
             )
         new_buffers = {}
+        dispatches = nbytes = 0
         for name, entry in self.buffers.items():
             ck, cv = prefill_cache[name]
             if quantized:
@@ -398,6 +409,8 @@ class SlotCachePool:
                     nk, nv,
                     pks.at[slot].set(k_scl), pvs.at[slot].set(v_scl),
                 )
+                # 2 slices, 2 scales, 2 quantizations, 4 scatters
+                dispatches += 10
             else:
                 pk, pv = entry
                 nk = pk.at[slot, start:length].set(
@@ -407,6 +420,13 @@ class SlotCachePool:
                     cv[0, start:length].astype(pv.dtype)
                 )
                 new_buffers[name] = (nk, nv)
+                # a slice and a scatter each, and a cast where it is one
+                dispatches += 4 + (ck.dtype != pk.dtype) \
+                    + (cv.dtype != pv.dtype)
+            # K and V alike: (slots, cache_len, hk, d) in the pool's dtype
+            nbytes += 2 * (length - start) * (
+                math.prod(nk.shape[2:]) * nk.dtype.itemsize
+            )
         if self._kv_shardings is not None:
             # the eager scatters' output shardings are whatever GSPMD
             # propagated from mixing the pool rows with the prefill
@@ -416,6 +436,7 @@ class SlotCachePool:
             # the whole pytree, not one per K/V per block: the admit
             # path runs this once per joiner.
             new_buffers = jax.device_put(new_buffers, self._kv_shardings)
+            dispatches += 1
         self.buffers = new_buffers
         # the slot's first decode step writes its first generated
         # token's K/V at position ``length`` (the prompt fills [0, P))
@@ -423,6 +444,8 @@ class SlotCachePool:
             self.positions.at[slot].set(length),
             self.live.at[slot].set(True),
         )
+        dispatches += 2 + (self._slot_sharding is not None)
+        return dispatches, nbytes
 
     # -- accounting for telemetry ------------------------------------------
 

@@ -92,7 +92,6 @@ from mmlspark_tpu.testing.compile_guard import (
     ProgramCountingJit,
     jit_cache_size,
 )
-from mmlspark_tpu.utils.profiling import annotate
 
 
 def _resolve_mesh(mesh):
@@ -924,8 +923,12 @@ class ServeEngine:
             raise
 
     def _step_inner(self) -> list[RequestResult]:
-        t0 = time.perf_counter()
         tick = self._sched.tick_count
+        with self._tracer.region("serve.tick", tick=tick) as timed:
+            return self._tick(tick, timed.t0)
+
+    def _tick(self, tick: int, t0: float) -> list[RequestResult]:
+        """The body of one tick, which began at ``t0`` (monotonic)."""
         finished = self._sched.expire(tick)
         tokens_this_tick = 0
 
@@ -945,7 +948,8 @@ class ServeEngine:
                 queue_depth=self._sched.queue_depth,
             )
 
-        with annotate("serve.admit"):
+        with self._tracer.region("serve.admit", tick=tick) as admit:
+            admitted = 0
             while (
                 not shedding
                 and self._sched.queue_depth
@@ -954,357 +958,25 @@ class ServeEngine:
                 # fewer concurrent requests than the pool has slots
                 and self.pool.leased_count < self._admit_cap
             ):
-                req = self._sched.pop_next()
-                slot = self.pool.lease()
-                span = self._spans.get(req.id)
-                if span is not None:
-                    span.event("admitted", tick=tick, slot=slot)
-                # preempted/restored requests re-prefill prompt + the
-                # tokens already emitted: greedy determinism makes the
-                # resumed stream bit-identical to an uninterrupted one
-                seq = (
-                    np.concatenate([req.prompt, req.prefix])
-                    if len(req.prefix) else req.prompt
-                )
-                first = None
-                attempts = 0
-                # cross-replica KV hand-off adoption (serve/fleet.py):
-                # the payload's cache is another replica's prefill
-                # program output for this EXACT sequence, so a direct
-                # write into the leased slot is bit-identical to
-                # running prefill here — no forward pass, no XLA
-                # program. The write travels the ``serve.handoff``
-                # fault hook; a payload that cannot land falls back to
-                # the full local prefill below (greedy determinism
-                # keeps the resulting stream bit-identical).
-                payload = self._handoffs.pop(req.id, None)
-                adopted = False
-                if payload is not None and self._faults is not None:
-                    # the serve.handoff silent-corruption drill: a
-                    # seeded bit-flip in one KV leaf between production
-                    # and adoption
-                    cseed = self._faults.corrupt_spec(
-                        "serve.handoff", tick=tick, request=req.id,
-                        replica=self._replica,
-                    )
-                    if cseed is not None:
-                        payload = integrity.corrupt_payload(payload,
-                                                            cseed)
-                if payload is not None:
-                    ok, expected, actual = integrity.verify_payload(
-                        payload
-                    )
-                    if not ok:
-                        # checksum mismatch: the payload is untrusted —
-                        # discard it and rebuild the same KV from the
-                        # prompt via the full-prefill path below
-                        # (greedy determinism keeps the stream
-                        # bit-identical)
-                        self.metrics.record_integrity_handoff_failure()
-                        self.recorder.record(
-                            "integrity.handoff_checksum", tick=tick,
-                            id=req.id, expected=expected, actual=actual,
-                        )
-                        self.metrics.record_handoff_fallback()
-                        self.recorder.record(
-                            "handoff_fallback", tick=tick, id=req.id,
-                        )
-                        payload = None
-                if payload is not None:
-                    with annotate("serve.handoff"):
-                        p = len(seq)
-                        bucket = self.prefill_bucket(p)
-                        cache = payload["kv"]
-                        tp = time.perf_counter()
-                        while True:
-                            try:
-                                if self._faults is not None:
-                                    self._faults.fire(
-                                        "serve.handoff", tick=tick,
-                                        request=req.id,
-                                        replica=self._replica,
-                                    )
-                                self.pool.write_prefill(slot, cache, p)
-                                if self._prefix_cache:
-                                    self.pool.prefix_insert(slot, seq)
-                                first = int(payload["first_token"])
-                                adopted = True
-                                break
-                            except Exception as e:
-                                if is_resource_exhausted(e):
-                                    self._note_oom(tick,
-                                                   "serve.handoff")
-                                elif not is_transient(e):
-                                    raise
-                                attempts += 1
-                                if attempts > self._retry_limit:
-                                    break
-                                self._backoff(attempts)
-                    if not adopted:
-                        # lost/undeliverable hand-off: the request
-                        # stays, the payload is discarded, and the
-                        # full-prefill path below rebuilds the same
-                        # KV from the prompt (attempts carry over
-                        # into its retry budget)
-                        self.metrics.record_handoff_fallback()
-                        self.recorder.record(
-                            "handoff_fallback", tick=tick, id=req.id,
-                        )
-                if not adopted and self._prefill_chunk is not None:
-                    # chunked prefill: admission only STARTS the fill
-                    # (prefix probe + carry allocation — no forward
-                    # pass); _advance_fills below dispatches bounded
-                    # chunk windows, one per tick per fill, so a long
-                    # prompt can never monopolize a tick. A fill no
-                    # wider than one chunk still completes on its
-                    # admission tick — short-prompt TTFT is unchanged.
-                    self._start_fill(req, slot, seq, tick)
-                    continue
-                # prefix-cache probe: a hit swaps the full-prompt
-                # prefill for a REMAINDER resume against the cached
-                # prefix's pages (shared, refcounted — the prefix
-                # prefilled once, ever)
-                hit = (
-                    self.pool.prefix_lookup(
-                        seq, self.prefill_bucket, slot=slot
-                    )
-                    if self._prefix_cache and not adopted else None
-                )
-                keep = 0
-                with annotate("serve.prefill"):
-                    p = len(seq)
-                    if hit is not None:
-                        entry, keep = hit
-                        r = p - keep
-                        bucket = self.prefill_bucket(r)
-                        padded = np.full((bucket,), self.pad_id,
-                                         np.int32)
-                        padded[:r] = seq[keep:]
-                        # the resume input: the prefix's K/V gathered
-                        # back into a linear cache (an eager page read,
-                        # no donation — retries reuse it)
-                        lin = self.pool.gather_prefix(entry, keep)
-                        family = f"resume[{bucket}]"
-                        if self.metrics.perf.wants_program(family):
-                            self.metrics.perf.register_program(
-                                family,
-                                analyze_jit_cost(
-                                    self._resume._fn._fn,
-                                    self.variables, padded[None], lin,
-                                    keep, r - 1,
-                                ),
-                            )
-                        tp = time.perf_counter()
-                        while True:
-                            try:
-                                if self._faults is not None:
-                                    self._faults.fire(
-                                        "serve.prefill", tick=tick,
-                                        request=req.id,
-                                        replica=self._replica,
-                                    )
-                                first_d, cache = self._resume(
-                                    self.variables,
-                                    jnp.asarray(padded[None]), lin,
-                                    keep, r - 1,
-                                )
-                                # map the shared pages FIRST (the
-                                # slot's references keep them alive
-                                # through any eviction the remainder
-                                # write triggers), then scatter only
-                                # the remainder [keep, p)
-                                if not self.pool.map_prefix(
-                                    slot, entry, keep
-                                ):
-                                    # entry evicted since the lookup
-                                    # (a prior attempt's own page
-                                    # pressure): its pages may already
-                                    # be free or reallocated, so the
-                                    # remainder cache cannot seed the
-                                    # slot — fall back to the full
-                                    # prefill below
-                                    hit = None
-                                    keep = 0
-                                    break
-                                self.pool.write_prefill(
-                                    slot, cache, p, start=keep
-                                )
-                                first = int(first_d[0])
-                                break
-                            except Exception as e:
-                                if is_resource_exhausted(e):
-                                    self._note_oom(tick,
-                                                   "serve.prefill")
-                                elif not is_transient(e):
-                                    raise
-                                attempts += 1
-                                if attempts > self._retry_limit:
-                                    break
-                                self._backoff(attempts)
-                    if hit is None and not adopted:
-                        # the miss path — also the landing spot for a
-                        # stale-prefix fallback above and a failed
-                        # hand-off adoption (attempts carry over into
-                        # this loop's retry budget)
-                        bucket = self.prefill_bucket(p)
-                        padded = np.full((bucket,), self.pad_id,
-                                         np.int32)
-                        padded[:p] = seq
-                        # device analytics: analyze each prefill
-                        # bucket's program ONCE, from abstract shapes —
-                        # lowering only, no backend compile, no device
-                        # work, so the prefill_compile_count pin is
-                        # untouched
-                        family = f"prefill[{bucket}]"
-                        if self.metrics.perf.wants_program(family):
-                            self.metrics.perf.register_program(
-                                family,
-                                analyze_jit_cost(
-                                    self._prefill._fn._fn,
-                                    self.variables, padded[None], p - 1,
-                                ),
-                            )
-                        tp = time.perf_counter()
-                        while True:
-                            try:
-                                if self._faults is not None:
-                                    self._faults.fire(
-                                        "serve.prefill", tick=tick,
-                                        request=req.id,
-                                        replica=self._replica,
-                                    )
-                                first_d, cache = self._prefill(
-                                    self.variables,
-                                    jnp.asarray(padded[None]), p - 1,
-                                )
-                                # only the REAL prompt's K/V enter the
-                                # slot; the pad tail of the bucket
-                                # cache is dropped here
-                                self.pool.write_prefill(slot, cache, p)
-                                if self._prefix_cache:
-                                    self.pool.prefix_insert(slot, seq)
-                                first = int(first_d[0])
-                                break
-                            except Exception as e:
-                                if is_resource_exhausted(e):
-                                    self._note_oom(tick,
-                                                   "serve.prefill")
-                                elif not is_transient(e):
-                                    raise
-                                attempts += 1
-                                if attempts > self._retry_limit:
-                                    break
-                                self._backoff(attempts)
-                if first is None:
-                    # retries exhausted: quarantine THIS request only —
-                    # the admit loop moves on to the next joiner
-                    finished.append(self._quarantine_unactivated(
-                        req, slot, tick, "prefill_failed"
-                    ))
-                    continue
-                if self._faults is not None:
-                    poison = self._faults.poison_value(
-                        "serve.handoff" if adopted else "serve.prefill",
-                        tick=tick, request=req.id,
-                        replica=self._replica,
-                    )
-                    if poison is not None:
-                        first = int(poison)
-                prefill_s = time.perf_counter() - tp
-                if adopted:
-                    # no program ran: the KV landed by direct write, so
-                    # nothing feeds the dispatch analytics — the event
-                    # timeline records the adoption instead
-                    self.metrics.record_handoff_adopt()
-                    if span is not None:
-                        span.event(
-                            "handoff_adopted", tick=tick, seq_len=p,
-                            ms=round(prefill_s * 1e3, 3),
-                        )
-                    self.recorder.record(
-                        "handoff_adopted", tick=tick, id=req.id,
-                        seq_len=p, ms=round(prefill_s * 1e3, 3),
-                    )
+                head = self._sched.queue[0]
+                if (self._prefill_chunk is not None
+                        and head.id not in self._handoffs):
+                    # chunked prefill: the admit loop only STARTS the
+                    # fill; the request's ``serve.admit_one`` is the
+                    # final chunk, in ``_advance_fills``
+                    self._admit_one(tick, finished)
                 else:
-                    if span is not None:
-                        span.event(
-                            "prefill", tick=tick, bucket=bucket,
-                            ms=round(prefill_s * 1e3, 3), reused=keep,
+                    with self._tracer.region(
+                        "serve.admit_one", tick=tick, request=head.id,
+                        prompt_len=len(head.prompt) + len(head.prefix),
+                    ) as one:
+                        tokens_this_tick += self._admit_one(
+                            tick, finished, one
                         )
-                    # the dispatch interval ends at prefill's EXISTING
-                    # host sync (int(first_d[0]) above) — analytics
-                    # adds none of its own
-                    self.metrics.perf.record_dispatch(
-                        family, prefill_s, tokens=1
-                    )
-                    self.recorder.record(
-                        "dispatch", tick=tick, family=family,
-                        ms=round(prefill_s * 1e3, 3), tokens=1,
-                    )
-                if not self._token_ok(first):
-                    # corrupted first token: quarantine before it can
-                    # enter results or seed the decode frontier
-                    finished.append(self._quarantine_unactivated(
-                        req, slot, tick, "poisoned_token"
-                    ))
-                    continue
-                self.metrics.record_first_token(
-                    req, tick, bucket=None if adopted else bucket
-                )
-                tokens_this_tick += 1
-                if self.role == "prefill" and not (
-                    len(req.prefix) + 1 >= req.max_new_tokens
-                    or (req.eos_id is not None and first == req.eos_id)
-                ):
-                    # prefill-role terminal (docs/SERVING.md
-                    # "Disaggregated fleet"): the slot's work is done —
-                    # the raw prefill/resume output cache (rows [0, p)
-                    # valid) and the first token ship to a decode
-                    # replica via the outbox. The slot frees; under a
-                    # prefix cache the inserted entry keeps the pages
-                    # alive for future local hits. A request the first
-                    # token already FINISHES (budget or EOS) skips the
-                    # hand-off and completes here via activate below.
-                    self.pool.free(slot)
-                    payload = {
-                        "id": req.id,
-                        "prompt": np.asarray(req.prompt, np.int32),
-                        "prefix": np.asarray(req.prefix, np.int32),
-                        "length": p,
-                        "first_token": int(first),
-                        "kv": cache,
-                        "max_new_tokens": req.max_new_tokens,
-                        "eos_id": req.eos_id,
-                        # trace context rides the hand-off: the decode
-                        # replica's span carries the SAME id, which is
-                        # what lets the hub draw the prefill->decode
-                        # flow arrow (checksum covers only the
-                        # integrity-bearing fields, so this is free)
-                        "trace_id": req.trace_id,
-                    }
-                    # stamped at PRODUCTION: the adopting replica
-                    # re-hashes before writing the cache into a slot,
-                    # so wire/at-rest corruption downgrades to the
-                    # full-local-prefill fallback instead of silently
-                    # poisoning a stream (docs/SERVING.md)
-                    payload["checksum"] = integrity.payload_checksum(
-                        payload
-                    )
-                    self._outbox.append(payload)
-                    self.recorder.record(
-                        "handoff_out", tick=tick, id=req.id, seq_len=p,
-                        trace=req.trace_id,
-                    )
-                    finished.append(
-                        self._sched.handoff_result(req, first, tick)
-                    )
-                    continue
-                done = self._sched.activate(slot, req, first, tick)
-                if done is not None:
-                    finished.append(done)
-
-        if self._sched.filling:
-            tokens_this_tick += self._advance_fills(tick, finished)
+                admitted += 1
+            if self._sched.filling:
+                tokens_this_tick += self._advance_fills(tick, finished)
+            admit.count(admitted=admitted)
 
         # slot occupancy AS OF the decode dispatch: with fused blocks a
         # request can join and retire inside one tick, so sampling after
@@ -1317,7 +989,10 @@ class ServeEngine:
             tokens_this_tick += self._decode_phase(tick, finished)
 
         self._sched.tick_count += 1
-        tick_s = time.perf_counter() - t0
+        # the ``tick`` event's interval ends HERE, before the finish feed,
+        # the SLO evaluation and a due checkpoint; ``serve.tick`` goes on
+        # to the end
+        tick_s = time.monotonic() - t0
         self.metrics.sample_tick(
             self._sched.queue_depth, leased_this_tick,
             tick_s, tokens_emitted=tokens_this_tick,
@@ -1349,6 +1024,377 @@ class ServeEngine:
         ):
             self.checkpoint()
         return finished
+
+    def _admit_one(self, tick: int, finished: list, region=None) -> int:
+        """Bring the queue's next request into a leased slot: adopt a
+        KV hand-off, start a chunked fill, or prefill (a prefix hit
+        resumes the remainder) and activate. A request that cannot be
+        brought in is quarantined alone, into ``finished``. ``region``
+        is the ``serve.admit_one`` this runs under, where it does.
+        Returns the first tokens emitted (0 or 1)."""
+        req = self._sched.pop_next()
+        slot = self.pool.lease()
+        if region is not None:
+            region.count(slot=slot)
+        span = self._spans.get(req.id)
+        if span is not None:
+            span.event("admitted", tick=tick, slot=slot)
+        # preempted/restored requests re-prefill prompt + the
+        # tokens already emitted: greedy determinism makes the
+        # resumed stream bit-identical to an uninterrupted one
+        seq = (
+            np.concatenate([req.prompt, req.prefix])
+            if len(req.prefix) else req.prompt
+        )
+        first = None
+        attempts = 0
+        # cross-replica KV hand-off adoption (serve/fleet.py):
+        # the payload's cache is another replica's prefill
+        # program output for this EXACT sequence, so a direct
+        # write into the leased slot is bit-identical to
+        # running prefill here — no forward pass, no XLA
+        # program. The write travels the ``serve.handoff``
+        # fault hook; a payload that cannot land falls back to
+        # the full local prefill below (greedy determinism
+        # keeps the resulting stream bit-identical).
+        payload = self._handoffs.pop(req.id, None)
+        adopted = False
+        if payload is not None and self._faults is not None:
+            # the serve.handoff silent-corruption drill: a
+            # seeded bit-flip in one KV leaf between production
+            # and adoption
+            cseed = self._faults.corrupt_spec(
+                "serve.handoff", tick=tick, request=req.id,
+                replica=self._replica,
+            )
+            if cseed is not None:
+                payload = integrity.corrupt_payload(payload,
+                                                    cseed)
+        if payload is not None:
+            ok, expected, actual = integrity.verify_payload(
+                payload
+            )
+            if not ok:
+                # checksum mismatch: the payload is untrusted —
+                # discard it and rebuild the same KV from the
+                # prompt via the full-prefill path below
+                # (greedy determinism keeps the stream
+                # bit-identical)
+                self.metrics.record_integrity_handoff_failure()
+                self.recorder.record(
+                    "integrity.handoff_checksum", tick=tick,
+                    id=req.id, expected=expected, actual=actual,
+                )
+                self.metrics.record_handoff_fallback()
+                self.recorder.record(
+                    "handoff_fallback", tick=tick, id=req.id,
+                )
+                payload = None
+        p = len(seq)
+        if payload is not None:
+            bucket = self.prefill_bucket(p)
+            cache = payload["kv"]
+            with self._tracer.region("serve.handoff", tick=tick,
+                                     request=req.id) as timed:
+                while True:
+                    try:
+                        if self._faults is not None:
+                            self._faults.fire(
+                                "serve.handoff", tick=tick,
+                                request=req.id,
+                                replica=self._replica,
+                            )
+                        self._pool_write(req.id, slot, cache, p)
+                        if self._prefix_cache:
+                            self.pool.prefix_insert(slot, seq)
+                        first = int(payload["first_token"])
+                        adopted = True
+                        break
+                    except Exception as e:
+                        if is_resource_exhausted(e):
+                            self._note_oom(tick,
+                                           "serve.handoff")
+                        elif not is_transient(e):
+                            raise
+                        attempts += 1
+                        if attempts > self._retry_limit:
+                            break
+                        self._backoff(attempts)
+            if not adopted:
+                # lost/undeliverable hand-off: the request
+                # stays, the payload is discarded, and the
+                # full-prefill path below rebuilds the same
+                # KV from the prompt (attempts carry over
+                # into its retry budget)
+                self.metrics.record_handoff_fallback()
+                self.recorder.record(
+                    "handoff_fallback", tick=tick, id=req.id,
+                )
+        if not adopted and self._prefill_chunk is not None:
+            # chunked prefill: admission only STARTS the fill
+            # (prefix probe + carry allocation — no forward
+            # pass); _advance_fills below dispatches bounded
+            # chunk windows, one per tick per fill, so a long
+            # prompt can never monopolize a tick. A fill no
+            # wider than one chunk still completes on its
+            # admission tick — short-prompt TTFT is unchanged.
+            self._start_fill(req, slot, seq, tick)
+            return 0
+        # prefix-cache probe: a hit swaps the full-prompt
+        # prefill for a REMAINDER resume against the cached
+        # prefix's pages (shared, refcounted — the prefix
+        # prefilled once, ever)
+        hit = (
+            self.pool.prefix_lookup(
+                seq, self.prefill_bucket, slot=slot
+            )
+            if self._prefix_cache and not adopted else None
+        )
+        keep = 0
+        if hit is not None:
+            entry, keep = hit
+            r = p - keep
+            bucket = self.prefill_bucket(r)
+            padded = np.full((bucket,), self.pad_id, np.int32)
+            padded[:r] = seq[keep:]
+            # the resume input: the prefix's K/V gathered back into a
+            # linear cache (an eager page read, no donation — retries
+            # reuse it)
+            lin = self.pool.gather_prefix(entry, keep)
+            family = f"resume[{bucket}]"
+            if self.metrics.perf.wants_program(family):
+                self.metrics.perf.register_program(
+                    family,
+                    analyze_jit_cost(
+                        self._resume._fn._fn, self.variables,
+                        padded[None], lin, keep, r - 1,
+                    ),
+                )
+            with self._tracer.region("serve.prefill", tick=tick,
+                                     request=req.id,
+                                     bucket=bucket) as timed:
+                while True:
+                    try:
+                        if self._faults is not None:
+                            self._faults.fire(
+                                "serve.prefill", tick=tick,
+                                request=req.id, replica=self._replica,
+                            )
+                        with self._tracer.region(
+                            "serve.prefill_dispatch", request=req.id
+                        ):
+                            first_d, cache = self._resume(
+                                self.variables,
+                                jnp.asarray(padded[None]), lin,
+                                keep, r - 1,
+                            )
+                        # map the shared pages FIRST (the slot's
+                        # references keep them alive through any
+                        # eviction the remainder write triggers), then
+                        # scatter only the remainder [keep, p)
+                        if not self.pool.map_prefix(slot, entry, keep):
+                            # entry evicted since the lookup (a prior
+                            # attempt's own page pressure): its pages
+                            # may already be free or reallocated, so
+                            # the remainder cache cannot seed the slot
+                            # — fall back to the full prefill below
+                            hit = None
+                            keep = 0
+                            break
+                        self._pool_write(req.id, slot, cache, p,
+                                         start=keep)
+                        first = self._first_token(req.id, first_d)
+                        break
+                    except Exception as e:
+                        if is_resource_exhausted(e):
+                            self._note_oom(tick, "serve.prefill")
+                        elif not is_transient(e):
+                            raise
+                        attempts += 1
+                        if attempts > self._retry_limit:
+                            break
+                        self._backoff(attempts)
+        if hit is None and not adopted:
+            # the miss path — also the landing spot for a stale-prefix
+            # fallback above and a failed hand-off adoption (attempts
+            # carry over into this loop's retry budget)
+            bucket = self.prefill_bucket(p)
+            padded = np.full((bucket,), self.pad_id, np.int32)
+            padded[:p] = seq
+            # device analytics: analyze each prefill bucket's program
+            # ONCE, from abstract shapes — lowering only, no backend
+            # compile, no device work, so the prefill_compile_count pin
+            # is untouched
+            family = f"prefill[{bucket}]"
+            if self.metrics.perf.wants_program(family):
+                self.metrics.perf.register_program(
+                    family,
+                    analyze_jit_cost(
+                        self._prefill._fn._fn,
+                        self.variables, padded[None], p - 1,
+                    ),
+                )
+            with self._tracer.region("serve.prefill", tick=tick,
+                                     request=req.id,
+                                     bucket=bucket) as timed:
+                while True:
+                    try:
+                        if self._faults is not None:
+                            self._faults.fire(
+                                "serve.prefill", tick=tick,
+                                request=req.id, replica=self._replica,
+                            )
+                        with self._tracer.region(
+                            "serve.prefill_dispatch", request=req.id
+                        ):
+                            first_d, cache = self._prefill(
+                                self.variables,
+                                jnp.asarray(padded[None]), p - 1,
+                            )
+                        # only the REAL prompt's K/V enter the slot;
+                        # the pad tail of the bucket cache is dropped
+                        # here
+                        self._pool_write(req.id, slot, cache, p)
+                        if self._prefix_cache:
+                            self.pool.prefix_insert(slot, seq)
+                        first = self._first_token(req.id, first_d)
+                        break
+                    except Exception as e:
+                        if is_resource_exhausted(e):
+                            self._note_oom(tick, "serve.prefill")
+                        elif not is_transient(e):
+                            raise
+                        attempts += 1
+                        if attempts > self._retry_limit:
+                            break
+                        self._backoff(attempts)
+        if first is None:
+            # retries exhausted: quarantine THIS request only —
+            # the admit loop moves on to the next joiner
+            finished.append(self._quarantine_unactivated(
+                req, slot, tick, "prefill_failed"
+            ))
+            return 0
+        if self._faults is not None:
+            poison = self._faults.poison_value(
+                "serve.handoff" if adopted else "serve.prefill",
+                tick=tick, request=req.id,
+                replica=self._replica,
+            )
+            if poison is not None:
+                first = int(poison)
+        # the interval of the hand-off or prefill region that landed the
+        # first token: the retry loop, ending at prefill's EXISTING host
+        # sync
+        prefill_s = timed.ms / 1e3
+        if adopted:
+            # no program ran: the KV landed by direct write, so
+            # nothing feeds the dispatch analytics — the event
+            # timeline records the adoption instead
+            self.metrics.record_handoff_adopt()
+            if span is not None:
+                span.event(
+                    "handoff_adopted", tick=tick, seq_len=p,
+                    ms=round(prefill_s * 1e3, 3),
+                )
+            self.recorder.record(
+                "handoff_adopted", tick=tick, id=req.id,
+                seq_len=p, ms=round(prefill_s * 1e3, 3),
+            )
+        else:
+            if span is not None:
+                span.event(
+                    "prefill", tick=tick, bucket=bucket,
+                    ms=round(prefill_s * 1e3, 3), reused=keep,
+                )
+            # the dispatch interval ends at prefill's EXISTING
+            # host sync (int(first_d[0]) above) — analytics
+            # adds none of its own
+            self.metrics.perf.record_dispatch(
+                family, prefill_s, tokens=1
+            )
+            self.recorder.record(
+                "dispatch", tick=tick, family=family,
+                ms=round(prefill_s * 1e3, 3), tokens=1,
+            )
+        if not self._token_ok(first):
+            # corrupted first token: quarantine before it can
+            # enter results or seed the decode frontier
+            finished.append(self._quarantine_unactivated(
+                req, slot, tick, "poisoned_token"
+            ))
+            return 0
+        self.metrics.record_first_token(
+            req, tick, bucket=None if adopted else bucket
+        )
+        if self.role == "prefill" and not (
+            len(req.prefix) + 1 >= req.max_new_tokens
+            or (req.eos_id is not None and first == req.eos_id)
+        ):
+            # prefill-role terminal (docs/SERVING.md
+            # "Disaggregated fleet"): the slot's work is done —
+            # the raw prefill/resume output cache (rows [0, p)
+            # valid) and the first token ship to a decode
+            # replica via the outbox. The slot frees; under a
+            # prefix cache the inserted entry keeps the pages
+            # alive for future local hits. A request the first
+            # token already FINISHES (budget or EOS) skips the
+            # hand-off and completes here via activate below.
+            self.pool.free(slot)
+            payload = {
+                "id": req.id,
+                "prompt": np.asarray(req.prompt, np.int32),
+                "prefix": np.asarray(req.prefix, np.int32),
+                "length": p,
+                "first_token": int(first),
+                "kv": cache,
+                "max_new_tokens": req.max_new_tokens,
+                "eos_id": req.eos_id,
+                # trace context rides the hand-off: the decode
+                # replica's span carries the SAME id, which is
+                # what lets the hub draw the prefill->decode
+                # flow arrow (checksum covers only the
+                # integrity-bearing fields, so this is free)
+                "trace_id": req.trace_id,
+            }
+            # stamped at PRODUCTION: the adopting replica
+            # re-hashes before writing the cache into a slot,
+            # so wire/at-rest corruption downgrades to the
+            # full-local-prefill fallback instead of silently
+            # poisoning a stream (docs/SERVING.md)
+            payload["checksum"] = integrity.payload_checksum(
+                payload
+            )
+            self._outbox.append(payload)
+            self.recorder.record(
+                "handoff_out", tick=tick, id=req.id, seq_len=p,
+                trace=req.trace_id,
+            )
+            finished.append(
+                self._sched.handoff_result(req, first, tick)
+            )
+            return 1
+        done = self._sched.activate(slot, req, first, tick)
+        if done is not None:
+            finished.append(done)
+        return 1
+
+    def _pool_write(self, request: int, slot: int, cache: dict,
+                    length: int, start: int = 0) -> None:
+        """``pool.write_prefill`` as the region ``serve.pool_write``,
+        with the pool's own count of what the write launched."""
+        with self._tracer.region("serve.pool_write",
+                                 request=request) as r:
+            dispatches, nbytes = self.pool.write_prefill(
+                slot, cache, length, start=start
+            )
+            r.count(dispatches=dispatches, bytes=nbytes)
+
+    def _first_token(self, request: int, first_d) -> int:
+        """The host's wait for a prefill's first token: the admit
+        path's one sync."""
+        with self._tracer.region("serve.first_token", request=request):
+            return int(first_d[0])
 
     # -- chunked prefill (docs/SERVING.md "Chunked prefill") ---------------
 
@@ -1410,130 +1456,87 @@ class ServeEngine:
         tokens = 0
         for slot in sorted(self._sched.filling):
             fs = self._sched.filling[slot]
-            req = fs.req
-            seq = (
-                np.concatenate([req.prompt, req.prefix])
-                if len(req.prefix) else req.prompt
-            )
-            r = fs.total - fs.filled
-            final = r <= self._prefill_chunk
-            if final:
-                bucket = self.chunk_bucket(r)
-                # final-chunk WINDOW TRICK: the padded bucket window
-                # must not overflow cache_len (a clamped
-                # dynamic_update_slice would corrupt earlier carry
-                # positions), so slide its start down and RECOMPUTE the
-                # overlap [start, filled) — same tokens at the same
-                # positions against the same carry prefix produce
-                # identical K/V, so the overwrite is a no-op by value
-                # and the program width stays on the ladder
-                start = min(fs.filled, self.cache_len - bucket)
-                width = bucket
-                padded = np.full((bucket,), self.pad_id, np.int32)
-                padded[: fs.total - start] = seq[start:fs.total]
-                last = (fs.total - 1) - start
+            if fs.total - fs.filled <= self._prefill_chunk:
+                # the fill's FINAL chunk lands the request: with chunked
+                # prefill this, not the admit loop, is where one request
+                # is written into the pool, waited for and activated
+                with self._tracer.region(
+                    "serve.admit_one", tick=tick, request=fs.req.id,
+                    slot=slot, prompt_len=fs.total,
+                ):
+                    tokens += self._advance_fill(slot, fs, tick, finished)
             else:
-                start = fs.filled
-                width = self._prefill_chunk
-                padded = np.ascontiguousarray(
-                    seq[start:start + width], dtype=np.int32
-                )
-                last = width - 1
-            family = f"chunk[{width}]"
-            if self.metrics.perf.wants_program(family):
-                self.metrics.perf.register_program(
-                    family,
-                    analyze_jit_cost(
-                        self._chunk._fn._fn, self.variables,
-                        padded[None], fs.carry["cache"], start, last,
-                    ),
-                )
-            attempts = 0
-            tp = time.perf_counter()
-            if not final:
-                ok = False
-                with annotate("serve.prefill"):
-                    while True:
-                        try:
-                            if self._faults is not None:
-                                self._faults.fire(
-                                    "serve.prefill", tick=tick,
-                                    request=req.id,
-                                    replica=self._replica,
-                                )
-                            _tok_d, cache = self._chunk(
-                                self.variables,
-                                jnp.asarray(padded[None]),
-                                fs.carry["cache"], start, last,
-                            )
-                            # the chunk program is NOT donated: the old
-                            # carry survives until this rebind, so a
-                            # faulted dispatch retries on intact state
-                            fs.carry["cache"] = cache
-                            ok = True
-                            break
-                        except Exception as e:
-                            if is_resource_exhausted(e):
-                                self._note_oom(tick, "serve.prefill")
-                            elif not is_transient(e):
-                                raise
-                            attempts += 1
-                            if attempts > self._retry_limit:
-                                break
-                            self._backoff(attempts)
-                if not ok:
-                    self._sched.fill_done(slot)
-                    finished.append(self._quarantine_unactivated(
-                        req, slot, tick, "prefill_failed"
-                    ))
-                    continue
-                fs.filled += width
-                chunk_s = time.perf_counter() - tp
-                self.metrics.record_prefill_chunk()
-                # no host sync here — the measured interval is
-                # enqueue-side only; device-time attribution rides the
-                # final chunk's sync
-                self.metrics.perf.record_dispatch(family, chunk_s)
-                self.recorder.record(
-                    "prefill_chunk", tick=tick, id=req.id,
-                    filled=fs.filled, total=fs.total,
-                    ms=round(chunk_s * 1e3, 3),
-                )
-                span = self._spans.get(req.id)
-                if span is not None:
-                    span.event("prefill_chunk", tick=tick,
-                               filled=fs.filled, total=fs.total)
-                continue
+                tokens += self._advance_fill(slot, fs, tick, finished)
+        return tokens
 
-            # -- final chunk: compute, land in the slot, sync ----------
-            entry = fs.carry.get("entry")
-            first = None
-            stale = False
-            with annotate("serve.prefill"):
+    def _advance_fill(self, slot: int, fs, tick: int,
+                      finished: list) -> int:
+        """One chunk of one fill; the first tokens it emitted (0 or 1)."""
+        req = fs.req
+        seq = (
+            np.concatenate([req.prompt, req.prefix])
+            if len(req.prefix) else req.prompt
+        )
+        r = fs.total - fs.filled
+        final = r <= self._prefill_chunk
+        if final:
+            bucket = self.chunk_bucket(r)
+            # final-chunk WINDOW TRICK: the padded bucket window
+            # must not overflow cache_len (a clamped
+            # dynamic_update_slice would corrupt earlier carry
+            # positions), so slide its start down and RECOMPUTE the
+            # overlap [start, filled) — same tokens at the same
+            # positions against the same carry prefix produce
+            # identical K/V, so the overwrite is a no-op by value
+            # and the program width stays on the ladder
+            start = min(fs.filled, self.cache_len - bucket)
+            width = bucket
+            padded = np.full((bucket,), self.pad_id, np.int32)
+            padded[: fs.total - start] = seq[start:fs.total]
+            last = (fs.total - 1) - start
+        else:
+            start = fs.filled
+            width = self._prefill_chunk
+            padded = np.ascontiguousarray(
+                seq[start:start + width], dtype=np.int32
+            )
+            last = width - 1
+        family = f"chunk[{width}]"
+        if self.metrics.perf.wants_program(family):
+            self.metrics.perf.register_program(
+                family,
+                analyze_jit_cost(
+                    self._chunk._fn._fn, self.variables,
+                    padded[None], fs.carry["cache"], start, last,
+                ),
+            )
+        attempts = 0
+        if not final:
+            ok = False
+            with self._tracer.region("serve.prefill", tick=tick,
+                                     request=req.id,
+                                     bucket=width) as timed:
                 while True:
                     try:
                         if self._faults is not None:
                             self._faults.fire(
                                 "serve.prefill", tick=tick,
-                                request=req.id, replica=self._replica,
+                                request=req.id,
+                                replica=self._replica,
                             )
-                        first_d, cache = self._chunk(
-                            self.variables, jnp.asarray(padded[None]),
-                            fs.carry["cache"], start, last,
-                        )
-                        # map the shared prefix pages FIRST (as the
-                        # monolithic resume path does), then scatter
-                        # only [keep, total)
-                        if entry is not None and not self.pool.map_prefix(
-                            slot, entry, fs.keep
+                        with self._tracer.region(
+                            "serve.prefill_dispatch", request=req.id
                         ):
-                            stale = True
-                            break
-                        self.pool.write_prefill(
-                            slot, cache, fs.total, start=fs.keep
-                        )
+                            _tok_d, cache = self._chunk(
+                                self.variables,
+                                jnp.asarray(padded[None]),
+                                fs.carry["cache"], start, last,
+                            )
+                        # the chunk program is NOT donated: the old
+                        # carry survives until this rebind, so a
+                        # faulted dispatch retries on intact state
                         fs.carry["cache"] = cache
-                        first = int(first_d[0])
+                        ok = True
                         break
                     except Exception as e:
                         if is_resource_exhausted(e):
@@ -1544,89 +1547,154 @@ class ServeEngine:
                         if attempts > self._retry_limit:
                             break
                         self._backoff(attempts)
-            if stale:
-                # the prefix entry evicted since the fill started: the
-                # slot can no longer map pages for [0, keep), so the
-                # fill restarts from scratch — the chunked analog of
-                # the monolithic stale-hit full-prefill fallback, and
-                # equally deterministic (the eventual stream is
-                # unchanged)
-                fs.filled = 0
-                fs.keep = 0
-                fs.carry = {"cache": self._fresh_carry(), "entry": None}
-                continue
-            if first is None:
+            if not ok:
                 self._sched.fill_done(slot)
                 finished.append(self._quarantine_unactivated(
                     req, slot, tick, "prefill_failed"
                 ))
-                continue
-            fs.filled = fs.total
-            chunk_s = time.perf_counter() - tp
+                return 0
+            fs.filled += width
+            chunk_s = timed.ms / 1e3
             self.metrics.record_prefill_chunk()
-            if self._faults is not None:
-                poison = self._faults.poison_value(
-                    "serve.prefill", tick=tick, request=req.id,
-                    replica=self._replica,
-                )
-                if poison is not None:
-                    first = int(poison)
-            if self._prefix_cache and entry is None:
-                self.pool.prefix_insert(slot, seq)
-            self._sched.fill_done(slot)
+            # no host sync here — the measured interval is
+            # enqueue-side only; device-time attribution rides the
+            # final chunk's sync
+            self.metrics.perf.record_dispatch(family, chunk_s)
+            self.recorder.record(
+                "prefill_chunk", tick=tick, id=req.id,
+                filled=fs.filled, total=fs.total,
+                ms=round(chunk_s * 1e3, 3),
+            )
             span = self._spans.get(req.id)
             if span is not None:
-                span.event(
-                    "prefill", tick=tick, bucket=bucket,
-                    ms=round(chunk_s * 1e3, 3), reused=fs.keep,
-                )
-            self.metrics.perf.record_dispatch(family, chunk_s, tokens=1)
-            self.recorder.record(
-                "dispatch", tick=tick, family=family,
-                ms=round(chunk_s * 1e3, 3), tokens=1,
+                span.event("prefill_chunk", tick=tick,
+                           filled=fs.filled, total=fs.total)
+            return 0
+
+        # -- final chunk: compute, land in the slot, sync ----------
+        entry = fs.carry.get("entry")
+        first = None
+        stale = False
+        with self._tracer.region("serve.prefill", tick=tick,
+                                 request=req.id, bucket=width) as timed:
+            while True:
+                try:
+                    if self._faults is not None:
+                        self._faults.fire(
+                            "serve.prefill", tick=tick,
+                            request=req.id, replica=self._replica,
+                        )
+                    with self._tracer.region(
+                        "serve.prefill_dispatch", request=req.id
+                    ):
+                        first_d, cache = self._chunk(
+                            self.variables, jnp.asarray(padded[None]),
+                            fs.carry["cache"], start, last,
+                        )
+                    # map the shared prefix pages FIRST (as the
+                    # monolithic resume path does), then scatter
+                    # only [keep, total)
+                    if entry is not None and not self.pool.map_prefix(
+                        slot, entry, fs.keep
+                    ):
+                        stale = True
+                        break
+                    self._pool_write(req.id, slot, cache, fs.total,
+                                     start=fs.keep)
+                    fs.carry["cache"] = cache
+                    first = self._first_token(req.id, first_d)
+                    break
+                except Exception as e:
+                    if is_resource_exhausted(e):
+                        self._note_oom(tick, "serve.prefill")
+                    elif not is_transient(e):
+                        raise
+                    attempts += 1
+                    if attempts > self._retry_limit:
+                        break
+                    self._backoff(attempts)
+        if stale:
+            # the prefix entry evicted since the fill started: the
+            # slot can no longer map pages for [0, keep), so the
+            # fill restarts from scratch — the chunked analog of
+            # the monolithic stale-hit full-prefill fallback, and
+            # equally deterministic (the eventual stream is
+            # unchanged)
+            fs.filled = 0
+            fs.keep = 0
+            fs.carry = {"cache": self._fresh_carry(), "entry": None}
+            return 0
+        if first is None:
+            self._sched.fill_done(slot)
+            finished.append(self._quarantine_unactivated(
+                req, slot, tick, "prefill_failed"
+            ))
+            return 0
+        fs.filled = fs.total
+        chunk_s = timed.ms / 1e3
+        self.metrics.record_prefill_chunk()
+        if self._faults is not None:
+            poison = self._faults.poison_value(
+                "serve.prefill", tick=tick, request=req.id,
+                replica=self._replica,
             )
-            if not self._token_ok(first):
-                finished.append(self._quarantine_unactivated(
-                    req, slot, tick, "poisoned_token"
-                ))
-                continue
-            self.metrics.record_first_token(req, tick, bucket=bucket)
-            tokens += 1
-            if self.role == "prefill" and not (
-                len(req.prefix) + 1 >= req.max_new_tokens
-                or (req.eos_id is not None and first == req.eos_id)
-            ):
-                # prefill-role hand-off fires at FILL COMPLETION: the
-                # carry's rows [0, total) are exactly the monolithic
-                # prefill output the payload contract expects
-                self.pool.free(slot)
-                payload = {
-                    "id": req.id,
-                    "prompt": np.asarray(req.prompt, np.int32),
-                    "prefix": np.asarray(req.prefix, np.int32),
-                    "length": fs.total,
-                    "first_token": int(first),
-                    "kv": fs.carry["cache"],
-                    "max_new_tokens": req.max_new_tokens,
-                    "eos_id": req.eos_id,
-                    "trace_id": req.trace_id,
-                }
-                payload["checksum"] = integrity.payload_checksum(
-                    payload
-                )
-                self._outbox.append(payload)
-                self.recorder.record(
-                    "handoff_out", tick=tick, id=req.id,
-                    seq_len=fs.total, trace=req.trace_id,
-                )
-                finished.append(
-                    self._sched.handoff_result(req, first, tick)
-                )
-                continue
-            done = self._sched.activate(slot, req, first, tick)
-            if done is not None:
-                finished.append(done)
-        return tokens
+            if poison is not None:
+                first = int(poison)
+        if self._prefix_cache and entry is None:
+            self.pool.prefix_insert(slot, seq)
+        self._sched.fill_done(slot)
+        span = self._spans.get(req.id)
+        if span is not None:
+            span.event(
+                "prefill", tick=tick, bucket=bucket,
+                ms=round(chunk_s * 1e3, 3), reused=fs.keep,
+            )
+        self.metrics.perf.record_dispatch(family, chunk_s, tokens=1)
+        self.recorder.record(
+            "dispatch", tick=tick, family=family,
+            ms=round(chunk_s * 1e3, 3), tokens=1,
+        )
+        if not self._token_ok(first):
+            finished.append(self._quarantine_unactivated(
+                req, slot, tick, "poisoned_token"
+            ))
+            return 0
+        self.metrics.record_first_token(req, tick, bucket=bucket)
+        if self.role == "prefill" and not (
+            len(req.prefix) + 1 >= req.max_new_tokens
+            or (req.eos_id is not None and first == req.eos_id)
+        ):
+            # prefill-role hand-off fires at FILL COMPLETION: the
+            # carry's rows [0, total) are exactly the monolithic
+            # prefill output the payload contract expects
+            self.pool.free(slot)
+            payload = {
+                "id": req.id,
+                "prompt": np.asarray(req.prompt, np.int32),
+                "prefix": np.asarray(req.prefix, np.int32),
+                "length": fs.total,
+                "first_token": int(first),
+                "kv": fs.carry["cache"],
+                "max_new_tokens": req.max_new_tokens,
+                "eos_id": req.eos_id,
+                "trace_id": req.trace_id,
+            }
+            payload["checksum"] = integrity.payload_checksum(
+                payload
+            )
+            self._outbox.append(payload)
+            self.recorder.record(
+                "handoff_out", tick=tick, id=req.id,
+                seq_len=fs.total, trace=req.trace_id,
+            )
+            finished.append(
+                self._sched.handoff_result(req, first, tick)
+            )
+            return 1
+        done = self._sched.activate(slot, req, first, tick)
+        if done is not None:
+            finished.append(done)
+        return 1
 
     # -- pipelined async host loop (docs/SERVING.md "Async host loop") -----
 
@@ -1645,23 +1713,30 @@ class ServeEngine:
         prev = self._inflight
         self._inflight = None
         status = self._dispatch_block(tick, prev)
-        n_tokens = self._fetch_inflight(prev, tick, finished)
-        if status == "failed":
-            # the batch stayed undispatchable through retries AND
-            # degradation — quarantine what is left of it, AFTER the
-            # previous block's tokens were committed above
-            for slot in list(self._sched.active):
-                finished.append(self._quarantine_slot(
-                    slot, tick, "decode_failed"
-                ))
-        if self._inflight is not None and not self._sched.busy:
-            # every request retired at the fetch above (e.g. EOS swept
-            # the batch) while a speculative block is still in flight:
-            # drain it now — its rows all fail the identity fence, so
-            # it contributes nothing, but run() must not exit with an
-            # open deferred-free window
-            inf, self._inflight = self._inflight, None
-            n_tokens += self._fetch_inflight(inf, tick, finished)
+        fetched = self._fetch_block(prev, tick)
+        with self._tracer.region("serve.retire", tick=tick) as retire:
+            before = len(finished)
+            n_tokens = self._consume_inflight(prev, fetched, tick,
+                                              finished)
+            if status == "failed":
+                # the batch stayed undispatchable through retries AND
+                # degradation — quarantine what is left of it, AFTER
+                # the previous block's tokens were committed above
+                for slot in list(self._sched.active):
+                    finished.append(self._quarantine_slot(
+                        slot, tick, "decode_failed"
+                    ))
+            if self._inflight is not None and not self._sched.busy:
+                # every request retired at the fetch above (e.g. EOS
+                # swept the batch) while a speculative block is still
+                # in flight: drain it now — its rows all fail the
+                # identity fence, so it contributes nothing, but run()
+                # must not exit with an open deferred-free window
+                inf, self._inflight = self._inflight, None
+                n_tokens += self._consume_inflight(
+                    inf, self._fetch_block(inf, tick), tick, finished
+                )
+            retire.count(finished=len(finished) - before)
         return n_tokens
 
     def _dispatch_block(self, tick: int, prev: dict | None) -> str:
@@ -1746,8 +1821,8 @@ class ServeEngine:
                     ),
                 )
             try:
-                with annotate("serve.decode"):
-                    issued = time.perf_counter()
+                with self._tracer.region("serve.decode", tick=tick,
+                                         block=t_block) as issue:
                     if self._paged:
                         self.pool.ensure_decode_pages(pre_pos, t_block)
                     if self._faults is not None:
@@ -1784,7 +1859,7 @@ class ServeEngine:
             self._inflight = {
                 "toks": toks, "live": live, "states": states,
                 "pre_pos": pre_pos, "t_block": t_block,
-                "family": family, "issued": issued,
+                "family": family, "issued": issue.t0,
                 "gen": self._dispatch_gen, "tick": tick,
                 "n_active": len(states),
                 "overlapped": prev is not None,
@@ -1794,10 +1869,44 @@ class ServeEngine:
             return "ok"
         return "idle"
 
-    def _fetch_inflight(self, inflight: dict | None, tick: int,
-                        finished: list) -> int:
-        """Fetch and consume one previously dispatched block (async
-        mode): the block's ONE host sync, then the same poison/
+    def _fetch_block(self, block: dict | None, tick: int):
+        """A dispatched block's ONE host sync, as the region
+        ``serve.fetch``, behind its own retry loop (re-dispatching
+        would decode past the block and skip its tokens): the ``(S,
+        T)`` tokens and the per-slot live vector come back together.
+        Returns ``(toks_h, live_h, done)``, ``done`` being the
+        monotonic end of the fetch and the arrays None when the
+        retries ran out; None for no block."""
+        if block is None:
+            return None
+        toks_h = live_h = None
+        attempts = 0
+        with self._tracer.region("serve.fetch", tick=tick,
+                                 block=block["t_block"]) as fetch:
+            while True:
+                try:
+                    if self._faults is not None:
+                        self._faults.fire("serve.device_get", tick=tick,
+                                          replica=self._replica)
+                    toks_h, live_h = jax.device_get(
+                        (block["toks"], block["live"])
+                    )
+                    break
+                except Exception as e:
+                    if not (is_transient(e) or is_resource_exhausted(e)):
+                        raise
+                    attempts += 1
+                    if attempts > self._retry_limit:
+                        break
+                    self._backoff(attempts)
+        # the host stood still for the fetch: the numerator the async
+        # loop exists to shrink
+        self.metrics.record_host_sync(fetch.ms / 1e3)
+        return toks_h, live_h, fetch.t1
+
+    def _consume_inflight(self, inflight: dict | None, fetched,
+                          tick: int, finished: list) -> int:
+        """Consume one fetched block (async mode): the same poison/
         validation/consume/accounting pipeline as the synchronous
         loop — except every row passes the IDENTITY FENCE (the slot
         must still hold the request captured at dispatch) and the
@@ -1821,27 +1930,7 @@ class ServeEngine:
                 if self._sched.active.get(s) is st
             ]
 
-        toks_h = live_h = None
-        fetch_attempts = 0
-        wait0 = time.perf_counter()
-        while True:
-            try:
-                if self._faults is not None:
-                    self._faults.fire("serve.device_get", tick=tick,
-                                      replica=self._replica)
-                toks_h, live_h = jax.device_get(
-                    (inflight["toks"], inflight["live"])
-                )
-                break
-            except Exception as e:
-                if not (is_transient(e) or is_resource_exhausted(e)):
-                    raise
-                fetch_attempts += 1
-                if fetch_attempts > self._retry_limit:
-                    break
-                self._backoff(fetch_attempts)
-        done = time.perf_counter()
-        self.metrics.record_host_sync(done - wait0)
+        toks_h, live_h, done = fetched
         prev_done = self._prev_block_done
         self._prev_block_done = done
         if toks_h is None:
@@ -1991,8 +2080,8 @@ class ServeEngine:
                     ),
                 )
             try:
-                with annotate("serve.decode"):
-                    td = time.perf_counter()
+                with self._tracer.region("serve.decode", tick=tick,
+                                         block=t_block) as issue:
                     # paged pool: pre-map every page this block can
                     # write (the tables are read-only DURING the block,
                     # preserving its one host sync). Page exhaustion
@@ -2037,119 +2126,118 @@ class ServeEngine:
                 self._backoff(attempts)
                 continue
 
-            # the dispatch SUCCEEDED and the pool is rebound, so the
-            # fetch gets its OWN retry loop — re-dispatching here would
-            # decode past this block and skip its tokens
-            toks_h = live_h = None
-            fetch_attempts = 0
-            wait0 = time.perf_counter()
-            while True:
-                try:
-                    if self._faults is not None:
-                        self._faults.fire("serve.device_get", tick=tick,
-                                          replica=self._replica)
-                    # the ONE host sync per block: (S, T) tokens + the
-                    # per-slot finished vector come back together
-                    toks_h, live_h = jax.device_get((toks, live))
-                    break
-                except Exception as e:
-                    if not (is_transient(e) or is_resource_exhausted(e)):
-                        raise
-                    fetch_attempts += 1
-                    if fetch_attempts > self._retry_limit:
-                        break
-                    self._backoff(fetch_attempts)
-            decode_s = time.perf_counter() - td
-            # the sync loop pays its block's full device time here —
-            # the host-idle numerator the async loop exists to shrink
-            self.metrics.record_host_sync(time.perf_counter() - wait0)
-            if toks_h is None:
-                # the block's tokens are unrecoverable on host: every
-                # active stream now has a gap — definite failure beats
-                # silently resuming with missing tokens
-                for slot, _st in states:
-                    if slot in self._sched.active:
-                        finished.append(self._quarantine_slot(
-                            slot, tick, "device_get_failed"
-                        ))
-                return 0
-
-            toks_h = np.asarray(toks_h)
-            if toks_h.ndim == 1:
-                toks_h = toks_h[:, None]
-            if self._faults is not None:
-                toks_h = self._faults.poison_block(
-                    "serve.device_get", toks_h, tick=tick,
-                    slots=[s for s, _ in states
-                           if s in self._sched.active],
-                    replica=self._replica,
+            # the dispatch SUCCEEDED and the pool is rebound: the sync
+            # loop pays its block's full device time in the fetch
+            block = {
+                "toks": toks, "live": live, "t_block": t_block,
+                "states": states, "pre_pos": pre_pos,
+                "n_active": n_active, "family": family,
+            }
+            toks_h, live_h, done = self._fetch_block(block, tick)
+            with self._tracer.region("serve.retire", tick=tick) as retire:
+                before = len(finished)
+                n_tokens = self._consume_block(
+                    block, done - issue.t0, toks_h, live_h, tick, finished
                 )
-            # token-stream validation (always on — one vectorized pass
-            # over an (S, T) int block): greedy tokens are argmax
-            # indices in [0, vocab), so anything else is corruption;
-            # quarantine the row BEFORE consume() folds it into results
-            bad_rows = (toks_h < 0).any(axis=1)
-            if self._vocab is not None:
-                bad_rows |= (toks_h >= int(self._vocab)).any(axis=1)
-            quarantined: set[int] = set()
-            if bad_rows.any():
-                for slot, _st in states:
-                    if slot in self._sched.active and bad_rows[slot]:
-                        finished.append(self._quarantine_slot(
-                            slot, tick, "poisoned_token"
-                        ))
-                        quarantined.add(slot)
-
-            blk_finished, consumed = self._sched.consume(toks_h, tick)
-            n_tokens = sum(consumed.values())
-            # live KV rows the block actually attended, per slot: its
-            # c consumed micro-steps read frontiers pos0+1 .. pos0+c
-            # (an arithmetic series) — vs the c * cache_len rows a
-            # dense read would touch, the FLOP-utilization figure
-            live_kv = sum(
-                c * (pre_pos[slot] + 1) + c * (c - 1) // 2
-                for slot, c in consumed.items()
-            )
-            self.metrics.record_decode(
-                n_active, decode_s, tokens_emitted=n_tokens,
-                block=t_block, live_kv=live_kv, cache_len=self.cache_len,
-            )
-            # the dispatch interval spans issue -> the block's ONE
-            # existing device_get; analytics adds no sync of its own
-            self.metrics.perf.record_dispatch(
-                family, decode_s, tokens=n_tokens
-            )
-            self.recorder.record(
-                "dispatch", tick=tick, family=family,
-                ms=round(decode_s * 1e3, 3), tokens=n_tokens,
-            )
-            if __debug__:
-                # the device live mask and the host's retirement
-                # bookkeeping must agree slot for slot — the parity
-                # contract's cheap runtime cross-check (quarantined
-                # slots are exempt: the host retired them while the
-                # fetched mask still shows them live)
-                for slot, _st in states:
-                    if slot in quarantined:
-                        continue
-                    assert bool(live_h[slot]) == (
-                        slot in self._sched.active
-                    ), (
-                        f"device live mask and host retirement disagree "
-                        f"for slot {slot} (block T={t_block})"
-                    )
-            decode_ms = round(decode_s * 1e3, 3)
-            for slot, st in states:
-                span = self._spans.get(st.req.id)
-                if span is not None:
-                    span.event("decode", tick=tick, pos=pre_pos[slot],
-                               n_active=n_active, block=t_block,
-                               tokens=consumed.get(slot, 0),
-                               step_ms=decode_ms)
-            finished.extend(blk_finished)
-            self._note_clean_dispatch(tick)
+                retire.count(finished=len(finished) - before)
             return n_tokens
         return 0
+
+    def _consume_block(self, block: dict, decode_s: float, toks_h,
+                       live_h, tick: int, finished: list) -> int:
+        """Fold one fetched block of the synchronous loop into the
+        scheduler: validate, consume, account, retire. ``decode_s`` runs
+        from the block's issue to the end of its fetch. Returns the
+        real tokens consumed."""
+        states, pre_pos = block["states"], block["pre_pos"]
+        n_active, t_block = block["n_active"], block["t_block"]
+        family = block["family"]
+        if toks_h is None:
+            # the block's tokens are unrecoverable on host: every
+            # active stream now has a gap — definite failure beats
+            # silently resuming with missing tokens
+            for slot, _st in states:
+                if slot in self._sched.active:
+                    finished.append(self._quarantine_slot(
+                        slot, tick, "device_get_failed"
+                    ))
+            return 0
+
+        toks_h = np.asarray(toks_h)
+        if toks_h.ndim == 1:
+            toks_h = toks_h[:, None]
+        if self._faults is not None:
+            toks_h = self._faults.poison_block(
+                "serve.device_get", toks_h, tick=tick,
+                slots=[s for s, _ in states
+                       if s in self._sched.active],
+                replica=self._replica,
+            )
+        # token-stream validation (always on — one vectorized pass
+        # over an (S, T) int block): greedy tokens are argmax
+        # indices in [0, vocab), so anything else is corruption;
+        # quarantine the row BEFORE consume() folds it into results
+        bad_rows = (toks_h < 0).any(axis=1)
+        if self._vocab is not None:
+            bad_rows |= (toks_h >= int(self._vocab)).any(axis=1)
+        quarantined: set[int] = set()
+        if bad_rows.any():
+            for slot, _st in states:
+                if slot in self._sched.active and bad_rows[slot]:
+                    finished.append(self._quarantine_slot(
+                        slot, tick, "poisoned_token"
+                    ))
+                    quarantined.add(slot)
+
+        blk_finished, consumed = self._sched.consume(toks_h, tick)
+        n_tokens = sum(consumed.values())
+        # live KV rows the block actually attended, per slot: its
+        # c consumed micro-steps read frontiers pos0+1 .. pos0+c
+        # (an arithmetic series) — vs the c * cache_len rows a
+        # dense read would touch, the FLOP-utilization figure
+        live_kv = sum(
+            c * (pre_pos[slot] + 1) + c * (c - 1) // 2
+            for slot, c in consumed.items()
+        )
+        self.metrics.record_decode(
+            n_active, decode_s, tokens_emitted=n_tokens,
+            block=t_block, live_kv=live_kv, cache_len=self.cache_len,
+        )
+        # the dispatch interval spans issue -> the block's ONE
+        # existing device_get; analytics adds no sync of its own
+        self.metrics.perf.record_dispatch(
+            family, decode_s, tokens=n_tokens
+        )
+        self.recorder.record(
+            "dispatch", tick=tick, family=family,
+            ms=round(decode_s * 1e3, 3), tokens=n_tokens,
+        )
+        if __debug__:
+            # the device live mask and the host's retirement
+            # bookkeeping must agree slot for slot — the parity
+            # contract's cheap runtime cross-check (quarantined
+            # slots are exempt: the host retired them while the
+            # fetched mask still shows them live)
+            for slot, _st in states:
+                if slot in quarantined:
+                    continue
+                assert bool(live_h[slot]) == (
+                    slot in self._sched.active
+                ), (
+                    f"device live mask and host retirement disagree "
+                    f"for slot {slot} (block T={t_block})"
+                )
+        decode_ms = round(decode_s * 1e3, 3)
+        for slot, st in states:
+            span = self._spans.get(st.req.id)
+            if span is not None:
+                span.event("decode", tick=tick, pos=pre_pos[slot],
+                           n_active=n_active, block=t_block,
+                           tokens=consumed.get(slot, 0),
+                           step_ms=decode_ms)
+        finished.extend(blk_finished)
+        self._note_clean_dispatch(tick)
+        return n_tokens
 
     def run(self, max_ticks: int = 100_000) -> dict[int, RequestResult]:
         """Step until queue and slots drain; results keyed by request

@@ -651,14 +651,19 @@ class PagedCachePool:
     # -- data path ---------------------------------------------------------
 
     def write_prefill(self, slot: int, prefill_cache: dict, length: int,
-                      start: int = 0) -> None:
+                      start: int = 0) -> tuple[int, int]:
         """Scatter a batch-1 LINEAR cache's positions ``[start,
         length)`` into the slot's pages (allocating/privatizing them as
         needed) and mark the slot live at write frontier ``length``.
         ``start > 0`` is the prefix-cache resume path: positions
         ``[0, start)`` are already mapped to shared pages and only the
         remainder lands — the first write into a shared partial page is
-        where copy-on-extend fires."""
+        where copy-on-extend fires.
+
+        Returns ``(dispatches, bytes)`` as the slot pool's
+        ``write_prefill`` does: the array operations launched here,
+        counted beside each launch (the page copies of a copy-on-extend
+        are not among them), and the K/V bytes written."""
         if slot not in self._leased:
             raise FriendlyError(f"slot {slot} is not leased")
         if length > self.cache_len:
@@ -675,10 +680,16 @@ class PagedCachePool:
         pos = np.arange(start, length)
         pages = jnp.asarray(self._pt_host[slot, pos // self.page_size])
         offs = jnp.asarray(pos % self.page_size)
+        dispatches, nbytes = 2, 0
         quantized = self.kv_dtype == "int8"
         for name, (pk, pv, pt, *scales) in self.buffers.items():
             ck, cv = prefill_cache[name][0], prefill_cache[name][1]
             hidx = jnp.arange(pk.shape[1])
+            dispatches += 1
+            # K and V alike: (num_pages, hk, page_size, d)
+            nbytes += 2 * (length - start) * (
+                pk.shape[1] * pk.shape[3] * pk.dtype.itemsize
+            )
             if quantized:
                 ks, vs = scales
                 # Per-page scales are fixed at each page's FIRST write:
@@ -706,6 +717,9 @@ class PagedCachePool:
                         pks, pvs = ks[page], vs[page]
                     k_rows.append(quantize_kv(sk, pks))
                     v_rows.append(quantize_kv(sv, pvs))
+                    # a slice and a cast each; two scales and their
+                    # scatters, or two reads; two quantizations
+                    dispatches += 10 if pg * self.page_size >= start else 8
                 qk = jnp.concatenate(k_rows, axis=0)
                 qv = jnp.concatenate(v_rows, axis=0)
                 nk = pk.at[
@@ -715,6 +729,7 @@ class PagedCachePool:
                     pages[:, None], hidx[None, :], offs[:, None]
                 ].set(qv)
                 self.buffers[name] = (nk, nv, pt, ks, vs)
+                dispatches += 4   # two concatenations, two scatters
             else:
                 nk = pk.at[
                     pages[:, None], hidx[None, :], offs[:, None]
@@ -723,12 +738,20 @@ class PagedCachePool:
                     pages[:, None], hidx[None, :], offs[:, None]
                 ].set(cv[0, start:length].astype(pv.dtype))
                 self.buffers[name] = (nk, nv, pt)
+                # a slice and a scatter each, and a cast where it is one
+                dispatches += 4 + (ck.dtype != pk.dtype) \
+                    + (cv.dtype != pv.dtype)
+        sharded = self._kv_shardings is not None
+        if self._pt_dirty:   # one table a block, pinned under a mesh
+            dispatches += len(self.buffers) * (1 + sharded)
         self._commit_kv()
         self._commit_pt()
         self._commit_slot_pair(
             self.positions.at[slot].set(length),
             self.live.at[slot].set(True),
         )
+        dispatches += sharded + 2 + (self._slot_sharding is not None)
+        return dispatches, nbytes
 
     def ensure_decode_pages(self, positions: dict[int, int],
                             t_block: int) -> None:

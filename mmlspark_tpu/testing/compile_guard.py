@@ -63,6 +63,9 @@ _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _tls = threading.local()
 _listener_installed = False
 _listener_lock = threading.Lock()
+#: every backend compile the process has made since the listener was
+#: installed, whichever thread made it (``backend_compiles`` reads it)
+_compiles_total = 0
 
 
 def _install_compile_listener() -> None:
@@ -75,14 +78,27 @@ def _install_compile_listener() -> None:
         from jax._src import monitoring
 
         def _on_event(event: str, duration: float, **_kw) -> None:
+            global _compiles_total
             if event != _BACKEND_COMPILE_EVENT:
                 return
+            with _listener_lock:
+                _compiles_total += 1
             owner = getattr(_tls, "owner", None)
             if owner is not None:
                 owner._events += 1
 
         monitoring.register_event_duration_secs_listener(_on_event)
         _listener_installed = True
+
+
+def backend_compiles() -> int:
+    """Backend (XLA) compiles the whole process has made since the first
+    call of this function: the difference between two readings counts
+    what compiled in between, jitted families and eager operations
+    alike (``SpanTracer.region`` stamps it on a host interval)."""
+    if not _listener_installed:
+        _install_compile_listener()
+    return _compiles_total
 
 
 class ProgramCountingJit:

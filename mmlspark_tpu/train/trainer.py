@@ -52,7 +52,11 @@ from mmlspark_tpu.core.faults import (
 )
 from mmlspark_tpu.core.integrity import CheckpointCorruption
 from mmlspark_tpu.core.logging_utils import get_logger
-from mmlspark_tpu.core.telemetry import FlightRecorder, MetricRegistry
+from mmlspark_tpu.core.telemetry import (
+    FlightRecorder,
+    MetricRegistry,
+    SpanTracer,
+)
 from mmlspark_tpu.models.graph import NamedGraph
 from mmlspark_tpu.parallel.mesh import DATA_AXIS, batch_spec, make_mesh, replicated_spec
 
@@ -259,6 +263,9 @@ class SPMDTrainer:
         #: the anomaly abort) escapes ``train()``
         self.recorder = recorder if recorder is not None \
             else FlightRecorder()
+        #: host intervals of the loop (``train.step`` and the four
+        #: inside it), into the same recorder and the profiler's trace
+        self._tracer = SpanTracer(self.recorder)
         self._faults = faults
         self._step = 0  # current global step, for the fault listener's tick
         #: (jitted single-step program, abstract signature of its first
@@ -1082,174 +1089,226 @@ class SPMDTrainer:
             tokens_per_step = batch * (
                 x.shape[1] if np.ndim(x) == 2 else 1
             )
-            for group in grouped(it):
-                t_group = time.perf_counter()
-                self._step = step
-                audit_due = False
-                if self._faults is not None:
-                    group = [pull_guard(b, step + i)
-                             for i, b in enumerate(group)]
-                if k_steps > 1 and len(group) == k_steps:
-                    guarded_fire(step)
-                    stacks = tuple(
-                        jax.device_put(
-                            jnp.stack([jnp.asarray(b[c]) for b in group]),
-                            chunk_sh,
-                        )
-                        for c in ("x", "y", MASK_COL)
-                    )
-                    if audit:
-                        audit_buf.append(("chunk", tuple(
-                            np.stack([np.asarray(b[c]) for b in group])
-                            for c in ("x", "y", MASK_COL)
-                        )))
-                        due = any(
-                            (s + 1) % audit_every == 0
-                            for s in range(step, step + len(group))
-                        )
-                        (params, rest, opt_state, streak_dev, anoms_dev,
-                         chk_dev, loss, gnorm) = chunk_jitted(
-                            params, rest, opt_state, streak_dev,
-                            anoms_dev, chk_dev, *stacks,
-                            flag_on if due else flag_off,
-                        )
-                        audit_due = audit_due or due
-                    else:
-                        (params, rest, opt_state, streak_dev, anoms_dev,
-                         loss, gnorm) = chunk_jitted(
-                            params, rest, opt_state, streak_dev,
-                            anoms_dev, *stacks,
-                        )
-                    if self._faults is not None:
-                        cseed = self._faults.corrupt_spec("train.step",
-                                                          tick=step)
-                        if cseed is not None and not cfg.param_rules:
-                            params, _ = _integrity.corrupt_replica(
-                                params, cseed
-                            )
-                    n_done = len(group)
-                else:
-                    for i, b in enumerate(group):
-                        guarded_fire(step + i)
-                        bx = jax.device_put(jnp.asarray(b["x"]), data_sh)
-                        by = jax.device_put(jnp.asarray(b["y"]), data_sh)
-                        bm = jax.device_put(
-                            jnp.asarray(b[MASK_COL]), data_sh
-                        )
-                        if audit:
-                            audit_buf.append((
-                                "single", np.asarray(b["x"]),
-                                np.asarray(b["y"]),
-                                np.asarray(b[MASK_COL]),
-                            ))
-                            due = (step + i + 1) % audit_every == 0
-                            (params, rest, opt_state, streak_dev,
-                             anoms_dev, chk_dev, loss, gnorm) = jitted(
-                                params, rest, opt_state, streak_dev,
-                                anoms_dev, chk_dev, bx, by, bm,
-                                flag_on if due else flag_off,
-                            )
-                            audit_due = audit_due or due
-                        else:
-                            step_args = (params, rest, opt_state,
-                                         streak_dev, anoms_dev, bx, by, bm)
-                            if self._step_program is None:
-                                # shapes only: the arrays are donated
-                                self._step_program = (
-                                    jitted,
-                                    jax.tree_util.tree_map(
-                                        lambda a: jax.ShapeDtypeStruct(
-                                            a.shape, a.dtype),
-                                        step_args,
-                                    ),
-                                )
-                            (params, rest, opt_state, streak_dev,
-                             anoms_dev, loss, gnorm) = jitted(*step_args)
+            groups = grouped(it)
+            while True:
+                # one group of the loop is one ``train.step``, and the
+                # pull of the group lies inside it: no host time of the
+                # loop falls between one step's region and the next
+                first = step
+                step_region = self._tracer.step_region("train.step",
+                                                       tick=first)
+                with step_region:
+                    with self._tracer.region("train.feed",
+                                             tick=first) as feed:
+                        group = next(groups, None)
+                        if group is None:
+                            # the epoch's end: nothing was fed, no step
+                            feed.drop()
+                            step_region.drop()
+                            break
+                        self._step = step
+                        audit_due = False
                         if self._faults is not None:
-                            # the train.step silent-corruption drill: a
-                            # seeded bit-flip lands in ONE device's copy
-                            # of one param leaf AFTER the dispatch, so
-                            # the in-graph fold precedes the flip and
-                            # the next audit's host folds see it
-                            cseed = self._faults.corrupt_spec(
-                                "train.step", tick=step + i
-                            )
-                            if cseed is not None and not cfg.param_rules:
-                                params, _ = _integrity.corrupt_replica(
-                                    params, cseed
+                            group = [pull_guard(b, step + i)
+                                     for i, b in enumerate(group)]
+                        chunked = k_steps > 1 and len(group) == k_steps
+                        if chunked:
+                            fed = [tuple(
+                                jax.device_put(
+                                    jnp.stack([jnp.asarray(b[c])
+                                               for b in group]),
+                                    chunk_sh,
                                 )
-                    n_done = len(group)
-                # log once if any step in [step, step+n) hits the cadence;
-                # the fetched loss is the group's LAST step's, so label it
-                # with that step (chunking coarsens cadence, never lies)
-                next_log = step + (-step) % log_every
-                step += n_done
-                self._step = step
-                if next_log < step:
-                    loss_val = float(loss)
-                    gnorm_val = float(gnorm)
-                    # the group's dispatch+device wall, amortized per
-                    # step — async dispatch means the host-side fetch of
-                    # ``loss`` above is what synchronizes the clock
-                    step_s = max(
-                        (time.perf_counter() - t_group) / n_done, 1e-9
-                    )
-                    tel = self.telemetry
-                    tel.histogram("train.step_ms").record(step_s * 1e3)
-                    tel.histogram("train.tokens_per_sec").record(
-                        tokens_per_step / step_s
-                    )
-                    # a quarantined step's loss/gnorm is non-finite by
-                    # definition — keep it out of the log-bucketed
-                    # histograms (history and the anomaly counters carry
-                    # the honest record)
-                    if np.isfinite(loss_val):
-                        tel.histogram("train.loss").record(loss_val)
-                    if np.isfinite(gnorm_val):
-                        tel.histogram("train.grad_norm").record(gnorm_val)
-                    self.history.append(
-                        {"step": step - 1, "epoch": epoch, "loss": loss_val,
-                         "grad_norm": gnorm_val}
-                    )
-                    self.recorder.record(
-                        "step", tick=step - 1, epoch=epoch, loss=loss_val,
-                        grad_norm=gnorm_val,
-                    )
-                    _log.info(
-                        "step %d epoch %d loss %.5f grad_norm %.4f "
-                        "step_ms %.1f", step - 1, epoch, loss_val,
-                        gnorm_val, step_s * 1e3,
-                    )
-                    # anomaly accounting rides the log-cadence sync the
-                    # loss fetch above already paid for: the quarantine
-                    # itself is in-graph; the host only reads the
-                    # counters here, so the N-consecutive abort lags the
-                    # Nth bad step by < log_every steps
-                    self._check_anomalies(streak_dev, anoms_dev,
-                                          seen_anoms, step - 1)
-                    seen_anoms = max(seen_anoms, int(anoms_dev))
-                if audit and audit_due:
-                    # the interval's ONE audit host sync: read the
-                    # in-graph fold and every replica's copy, adjudicate
-                    # (runs BEFORE the checkpoint save so a detected
-                    # corruption never gets committed to disk)
-                    run_audit(step - 1)
-                if (
-                    store is not None
-                    and cfg.checkpoint_every
-                    # any step of the finished group on the save cadence
-                    # triggers a save of the current (group-end) state —
-                    # with chunked dispatch the exact cadence step has no
-                    # materialized state of its own
-                    and any(
-                        s % cfg.checkpoint_every == 0
-                        for s in range(step - n_done, step)
-                    )
-                ):
-                    # gate BEFORE fetching: save_checkpoint device_gets
-                    # the whole (possibly TP-sharded) state, which would
-                    # stall async dispatch on every non-checkpoint step
-                    save_checkpoint(step - 1)
+                                for c in ("x", "y", MASK_COL)
+                            )]
+                            if audit:
+                                audit_buf.append(("chunk", tuple(
+                                    np.stack([np.asarray(b[c])
+                                              for b in group])
+                                    for c in ("x", "y", MASK_COL)
+                                )))
+                        else:
+                            fed = [tuple(
+                                jax.device_put(jnp.asarray(b[c]), data_sh)
+                                for c in ("x", "y", MASK_COL)
+                            ) for b in group]
+                            if audit:
+                                audit_buf.extend((
+                                    "single", np.asarray(b["x"]),
+                                    np.asarray(b["y"]),
+                                    np.asarray(b[MASK_COL]),
+                                ) for b in group)
+                    with self._tracer.region("train.dispatch", tick=first):
+                        if chunked:
+                            guarded_fire(step)
+                            stacks = fed[0]
+                            if audit:
+                                due = any(
+                                    (s + 1) % audit_every == 0
+                                    for s in range(step, step + len(group))
+                                )
+                                (params, rest, opt_state, streak_dev,
+                                 anoms_dev, chk_dev, loss,
+                                 gnorm) = chunk_jitted(
+                                    params, rest, opt_state, streak_dev,
+                                    anoms_dev, chk_dev, *stacks,
+                                    flag_on if due else flag_off,
+                                )
+                                audit_due = audit_due or due
+                            else:
+                                (params, rest, opt_state, streak_dev,
+                                 anoms_dev, loss, gnorm) = chunk_jitted(
+                                    params, rest, opt_state, streak_dev,
+                                    anoms_dev, *stacks,
+                                )
+                            if self._faults is not None:
+                                cseed = self._faults.corrupt_spec(
+                                    "train.step", tick=step
+                                )
+                                if cseed is not None \
+                                        and not cfg.param_rules:
+                                    params, _ = _integrity.corrupt_replica(
+                                        params, cseed
+                                    )
+                        else:
+                            for i, (bx, by, bm) in enumerate(fed):
+                                guarded_fire(step + i)
+                                if audit:
+                                    due = (step + i + 1) % audit_every == 0
+                                    (params, rest, opt_state, streak_dev,
+                                     anoms_dev, chk_dev, loss,
+                                     gnorm) = jitted(
+                                        params, rest, opt_state,
+                                        streak_dev, anoms_dev, chk_dev,
+                                        bx, by, bm,
+                                        flag_on if due else flag_off,
+                                    )
+                                    audit_due = audit_due or due
+                                else:
+                                    step_args = (params, rest, opt_state,
+                                                 streak_dev, anoms_dev,
+                                                 bx, by, bm)
+                                    if self._step_program is None:
+                                        # shapes only: the arrays are
+                                        # donated
+                                        self._step_program = (
+                                            jitted,
+                                            jax.tree_util.tree_map(
+                                                lambda a:
+                                                jax.ShapeDtypeStruct(
+                                                    a.shape, a.dtype),
+                                                step_args,
+                                            ),
+                                        )
+                                    (params, rest, opt_state, streak_dev,
+                                     anoms_dev, loss,
+                                     gnorm) = jitted(*step_args)
+                                if self._faults is not None:
+                                    # the train.step silent-corruption
+                                    # drill: a seeded bit-flip lands in
+                                    # ONE device's copy of one param
+                                    # leaf AFTER the dispatch, so the
+                                    # in-graph fold precedes the flip
+                                    # and the next audit's host folds
+                                    # see it
+                                    cseed = self._faults.corrupt_spec(
+                                        "train.step", tick=step + i
+                                    )
+                                    if cseed is not None \
+                                            and not cfg.param_rules:
+                                        params, _ = \
+                                            _integrity.corrupt_replica(
+                                                params, cseed
+                                            )
+                        n_done = len(group)
+                    # log once if any step in [step, step+n) hits the
+                    # cadence; the fetched loss is the group's LAST
+                    # step's, so label it with that step (chunking
+                    # coarsens cadence, never lies)
+                    next_log = step + (-step) % log_every
+                    step += n_done
+                    self._step = step
+                    if next_log < step:
+                        # the log cadence's ONE sync: async dispatch
+                        # means the host-side fetch of ``loss`` is what
+                        # synchronizes the clock, and the anomaly
+                        # carries ride it (the quarantine itself is
+                        # in-graph, so the N-consecutive abort lags the
+                        # Nth bad step by < log_every steps)
+                        with self._tracer.region("train.sync",
+                                                 tick=first) as sync:
+                            loss_val = float(loss)
+                            gnorm_val = float(gnorm)
+                            streak_val = int(streak_dev)
+                            anoms_val = int(anoms_dev)
+                        with self._tracer.region("train.log", tick=first):
+                            # the group's dispatch+device wall,
+                            # amortized per step: from the step
+                            # region's start to the sync's end
+                            step_s = max(
+                                (sync.t1 - step_region.t0) / n_done, 1e-9
+                            )
+                            tel = self.telemetry
+                            tel.histogram("train.step_ms").record(
+                                step_s * 1e3
+                            )
+                            tel.histogram("train.tokens_per_sec").record(
+                                tokens_per_step / step_s
+                            )
+                            # a quarantined step's loss/gnorm is
+                            # non-finite by definition — keep it out of
+                            # the log-bucketed histograms (history and
+                            # the anomaly counters carry the honest
+                            # record)
+                            if np.isfinite(loss_val):
+                                tel.histogram("train.loss").record(
+                                    loss_val
+                                )
+                            if np.isfinite(gnorm_val):
+                                tel.histogram("train.grad_norm").record(
+                                    gnorm_val
+                                )
+                            self.history.append(
+                                {"step": step - 1, "epoch": epoch,
+                                 "loss": loss_val, "grad_norm": gnorm_val}
+                            )
+                            self.recorder.record(
+                                "step", tick=step - 1, epoch=epoch,
+                                loss=loss_val, grad_norm=gnorm_val,
+                            )
+                            _log.info(
+                                "step %d epoch %d loss %.5f grad_norm "
+                                "%.4f step_ms %.1f", step - 1, epoch,
+                                loss_val, gnorm_val, step_s * 1e3,
+                            )
+                            self._check_anomalies(streak_val, anoms_val,
+                                                  seen_anoms, step - 1)
+                            seen_anoms = max(seen_anoms, anoms_val)
+                    if audit and audit_due:
+                        # the interval's ONE audit host sync: read the
+                        # in-graph fold and every replica's copy,
+                        # adjudicate (runs BEFORE the checkpoint save so
+                        # a detected corruption never gets committed to
+                        # disk)
+                        run_audit(step - 1)
+                    if (
+                        store is not None
+                        and cfg.checkpoint_every
+                        # any step of the finished group on the save
+                        # cadence triggers a save of the current
+                        # (group-end) state — with chunked dispatch the
+                        # exact cadence step has no materialized state
+                        # of its own
+                        and any(
+                            s % cfg.checkpoint_every == 0
+                            for s in range(step - n_done, step)
+                        )
+                    ):
+                        # gate BEFORE fetching: save_checkpoint
+                        # device_gets the whole (possibly TP-sharded)
+                        # state, which would stall async dispatch on
+                        # every non-checkpoint step
+                        save_checkpoint(step - 1)
             if eval_fn is not None:
                 variables = _merge_variables(
                     jax.device_get(params), jax.device_get(rest)
@@ -1259,8 +1318,10 @@ class SPMDTrainer:
 
         # end-of-run anomaly sweep: catches a terminal bad streak that
         # never crossed a log-cadence sync point
-        self._check_anomalies(streak_dev, anoms_dev, seen_anoms, step - 1)
-        seen_anoms = max(seen_anoms, int(anoms_dev))
+        anoms_val = int(anoms_dev)
+        self._check_anomalies(int(streak_dev), anoms_val, seen_anoms,
+                              step - 1)
+        seen_anoms = max(seen_anoms, anoms_val)
         if store is not None and store.latest_step() != step - 1:
             save_checkpoint(step - 1)
         final_loss = next(
@@ -1270,13 +1331,12 @@ class SPMDTrainer:
                   final_loss)
         return _merge_variables(jax.device_get(params), jax.device_get(rest))
 
-    def _check_anomalies(self, streak_dev, anoms_dev, seen_anoms: int,
-                         at_step: int) -> None:
-        """Host-side read of the in-graph anomaly carries: sync the
-        skipped-step counter and abort on a streak past the limit."""
+    def _check_anomalies(self, streak_val: int, anoms_val: int,
+                         seen_anoms: int, at_step: int) -> None:
+        """Account for the in-graph anomaly carries as the host read
+        them: count newly skipped steps and abort on a streak past the
+        limit."""
         cfg = self.config
-        streak_val = int(streak_dev)
-        anoms_val = int(anoms_dev)
         if anoms_val > seen_anoms:
             self.telemetry.counter("train.anomalies_skipped").inc(
                 anoms_val - seen_anoms

@@ -38,6 +38,7 @@ from mmlspark_tpu.core.telemetry import (  # noqa: F401 — re-exports
     Gauge,
     Histogram,
     MetricRegistry,
+    Region,
     RetraceWatchdog,
     Span,
     SpanTracer,
@@ -65,12 +66,3 @@ def trace_profile(log_dir: str, create_perfetto_link: bool = False):
     ):
         yield log_dir
     _log.info("profiler trace written under %s", log_dir)
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named region in the device trace (jax.profiler.TraceAnnotation)."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield

@@ -46,12 +46,16 @@ def test_docgen_param_table_shape(tmp_path):
 def test_trace_profile_writes_trace(tmp_path):
     import jax.numpy as jnp
 
-    from mmlspark_tpu.utils.profiling import annotate, trace_profile
+    from mmlspark_tpu.utils.profiling import (
+        FlightRecorder, SpanTracer, trace_profile,
+    )
 
+    tracer = SpanTracer(FlightRecorder())
     out = str(tmp_path / "trace")
     with trace_profile(out):
-        with annotate("matmul"):
+        with tracer.region("test.matmul"):
             (jnp.ones((64, 64)) @ jnp.ones((64, 64))).block_until_ready()
+    assert [e["name"] for e in tracer.recorder.events()] == ["test.matmul"]
     found = [
         f for root, _, files in os.walk(out) for f in files
         if f.endswith((".pb", ".json.gz", ".trace.json.gz"))

@@ -384,6 +384,117 @@ def test_mixed_length_soak_pins_compile_counts():
     assert d["decode_live_kv_tokens"] < d["decode_dense_kv_tokens"]
 
 
+# -- regions of the admit path and the tick ----------------------------------
+
+ADMISSION = ("serve.admit_one", "serve.prefill", "serve.prefill_dispatch",
+             "serve.pool_write", "serve.first_token", "serve.handoff")
+TICK = ("serve.tick", "serve.admit", "serve.decode", "serve.fetch",
+        "serve.retire")
+
+
+@pytest.mark.parametrize("options", [
+    {},
+    {"async_host": True},
+    {"prefill_chunk": 8},
+    {"paged": True, "page_size": 8},
+], ids=["sync", "async_host", "prefill_chunk", "paged"])
+def test_every_admission_and_every_tick_leave_their_regions(options):
+    """Whichever option is on, an admitted request leaves exactly one
+    ``serve.admit_one`` with one ``serve.pool_write`` and one
+    ``serve.first_token`` inside it, at most 8 region events an admission
+    and 6 a tick: counts, so nothing here can flake on a timing."""
+    m = _tiny()
+    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    engine = ServeEngine(m, v, slots=2, cache_len=32, **options)
+    rng = np.random.default_rng(0)
+    rids = [engine.submit(rng.integers(0, 8, size=n).astype(np.int32),
+                          max_new_tokens=6) for n in (4, 6, 7, 12, 5)]
+    results = engine.run()
+    assert all(results[r].status == "completed" for r in rids)
+
+    events = engine.recorder.events()
+    regions = [e for e in events if e["name"].startswith("serve.")]
+    assert {e["name"] for e in regions} <= set(ADMISSION + TICK)
+    assert all(e["attrs"]["ms"] >= 0 and e["attrs"]["t0"] > 0
+               for e in regions)
+    by_request: dict = {}
+    for e in regions:
+        if e["name"] in ADMISSION:
+            by_request.setdefault(e["attrs"]["request"], []).append(e)
+    assert sorted(by_request) == sorted(rids)
+    chunks = 0
+    for rid, evs in by_request.items():
+        names = [e["name"] for e in evs]
+        assert names.count("serve.admit_one") == 1, names
+        assert names.count("serve.pool_write") == 1
+        assert names.count("serve.first_token") == 1
+        assert len(evs) <= 8, names
+        parent = {e["name"]: e["attrs"]["parent"] for e in evs}
+        # the write and the wait lie in the prefill that made them, and
+        # that in the request's one admission
+        assert parent["serve.pool_write"] == "serve.prefill"
+        assert parent["serve.first_token"] == "serve.prefill"
+        assert parent["serve.admit_one"] == "serve.admit"
+        one = next(e for e in evs if e["name"] == "serve.admit_one")
+        inside = [e for e in evs if e is not one
+                  and one["attrs"]["t0"] <= e["attrs"]["t0"]
+                  and e["t"] <= one["t"]]
+        assert {"serve.pool_write", "serve.first_token"} <= {
+            e["name"] for e in inside}
+        assert one["attrs"]["prompt_len"] in (4, 5, 6, 7, 12)
+        assert one["attrs"]["slot"] in (0, 1)
+        chunks += names.count("serve.prefill") - 1
+    # a chunked fill adds a prefill and its dispatch for every chunk
+    # before the last: only the 12-token prompt has one
+    assert chunks == (1 if "prefill_chunk" in options else 0)
+    per_tick: dict = {}
+    for e in regions:
+        if e["name"] in TICK:
+            per_tick[e["tick"]] = per_tick.get(e["tick"], 0) + 1
+    assert len(per_tick) == engine.tick and max(per_tick.values()) <= 6
+    # one fetch and one consume for every dispatched block
+    count = {n: sum(e["name"] == n for e in regions) for n in TICK}
+    assert count["serve.decode"] == count["serve.fetch"] > 0
+    assert count["serve.tick"] == count["serve.admit"] == engine.tick
+    finished = sum(e["attrs"]["finished"] for e in regions
+                   if e["name"] == "serve.retire")
+    assert finished == len(rids)
+    # what the pool counts: a slice and a scatter for each K and each V
+    # array (the prefill cache has the pool's dtype), then positions and
+    # live; the paged pool adds the page and offset vectors, a head
+    # index a block and, when the tables changed, one table a block
+    writes = [e["attrs"] for e in regions if e["name"] == "serve.pool_write"]
+    blocks = len(engine.pool.buffers)
+    if "paged" in options:
+        assert {w["dispatches"] for w in writes} <= {
+            2 + 5 * blocks + 2, 2 + 5 * blocks + blocks + 2}
+    else:
+        assert {w["dispatches"] for w in writes} == {4 * blocks + 2}
+    row = 2 * 32 * 2          # K and V, d_model 32, bfloat16
+    assert sorted(w["bytes"] for w in writes) == sorted(
+        blocks * row * n for n in (4, 6, 7, 12, 5))
+    # the lifecycle events that readers filter on keep their form
+    assert sum(e["name"] == "tick" for e in events) == engine.tick
+    assert sum(e["name"] == "prefill" and e.get("span_name") == "request"
+               for e in events) == len(rids)
+
+
+def test_fetch_region_feeds_the_host_sync_account():
+    """``serve.fetch``'s own interval is what ``record_host_sync`` gets,
+    in both loops: the fetch is timed once."""
+    m = _tiny()
+    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    for options in ({}, {"async_host": True}):
+        engine = ServeEngine(m, v, slots=2, cache_len=32, **options)
+        engine.submit(np.arange(5, dtype=np.int32) % 8, max_new_tokens=6)
+        engine.run()
+        fetches = [e["attrs"]["ms"] for e in engine.recorder.events()
+                   if e["name"] == "serve.fetch"]
+        assert fetches
+        assert engine.metrics.host_sync_wait_s == pytest.approx(
+            sum(fetches) / 1e3, abs=1e-5 * len(fetches))
+
+
 def test_compile_guard_raises_on_violation():
     calls = {"n": 0}
 

@@ -141,6 +141,148 @@ def test_span_lifecycle_and_idempotent_end():
     assert tracer.span("request").id != s.id  # process-unique ids
 
 
+# -- regions ----------------------------------------------------------------
+
+
+def test_region_records_one_event_with_its_interval_and_its_parent():
+    rec = FlightRecorder()
+    tracer = SpanTracer(rec)
+    before = time.monotonic()
+    with tracer.region("serve.admit_one", tick=4, request=7, slot=2) as one:
+        with tracer.region("serve.pool_write", request=7) as write:
+            time.sleep(0.002)
+            write.count(dispatches=146, bytes=1 << 20)
+        with tracer.region("serve.first_token", request=7):
+            pass
+    with tracer.region("serve.decode", tick=4):
+        pass
+    evs = rec.events()
+    # ONE event a region, written when it ends: inner ones first
+    assert [e["name"] for e in evs] == [
+        "serve.pool_write", "serve.first_token", "serve.admit_one",
+        "serve.decode"]
+    w, f, a, d = (e["attrs"] for e in evs)
+    assert evs[2]["tick"] == 4 and "tick" not in evs[0]
+    assert w["parent"] == f["parent"] == "serve.admit_one"
+    assert "parent" not in a and "parent" not in d   # absent at the top
+    assert w["request"] == f["request"] == a["request"] == 7
+    assert "request" not in d
+    assert (w["dispatches"], w["bytes"], a["slot"]) == (146, 1 << 20, 2)
+    assert before <= a["t0"] <= w["t0"] <= f["t0"] <= d["t0"]
+    assert w["ms"] >= 2.0 and a["ms"] >= w["ms"] + f["ms"]
+    # the event is stamped at the region's end, on the recorder's clock
+    assert evs[0]["t"] == pytest.approx(w["t0"] + w["ms"] / 1e3, abs=1e-3)
+    assert "compiles" not in a and "error" not in a
+    # the caller reads the interval off the region and takes no clock
+    assert one.ms == pytest.approx(a["ms"], abs=1e-3)
+    assert one.t1 - one.t0 == pytest.approx(one.ms / 1e3)
+    # no span id: the walkers of a request's lifecycle pass regions by
+    assert all("span" not in e and "span_name" not in e for e in evs)
+
+
+def test_region_is_transparent_to_exceptions_and_can_be_dropped():
+    rec = FlightRecorder()
+    tracer = SpanTracer(rec)
+
+    class WindowClosed(Exception):
+        pass
+
+    with pytest.raises(WindowClosed):
+        with tracer.region("train.step", tick=1):
+            with tracer.region("train.log", tick=1):
+                raise WindowClosed
+    evs = rec.events()
+    assert [(e["name"], e["attrs"]["error"]) for e in evs] == [
+        ("train.log", "WindowClosed"), ("train.step", "WindowClosed")]
+    assert evs[0]["attrs"]["parent"] == "train.step"
+    # the stack unwound: the next region has no parent
+    with tracer.region("train.feed", tick=2) as feed:
+        feed.drop()          # an interval that held no work leaves no event
+    with tracer.region("train.feed", tick=2):
+        pass
+    assert [e["name"] for e in rec.events()[2:]] == ["train.feed"]
+    assert "parent" not in rec.events()[2]["attrs"]
+    # a bare name could pass for a lifecycle event that readers filter on
+    for taken in ("prefill", "decode", "tick", "step", "dispatch"):
+        with pytest.raises(FriendlyError, match="layer"):
+            tracer.region(taken)
+
+
+def test_region_counts_compiles_made_inside_it():
+    import jax
+    import jax.numpy as jnp
+
+    rec = FlightRecorder()
+    tracer = SpanTracer(rec)
+    fn = jax.jit(lambda x: jnp.sum(x * 3 + 1))
+    with tracer.region("test.first"):
+        fn(jnp.zeros((5,), jnp.float32)).block_until_ready()
+    with tracer.region("test.again"):
+        fn(jnp.ones((5,), jnp.float32)).block_until_ready()
+    with tracer.region("test.outer"):
+        with tracer.region("test.new_shape"):
+            # an eager operation on a new shape compiles too, and no
+            # watchdog watches it
+            (jnp.zeros((6, 3), jnp.float32)[1, 1:3] * 2).block_until_ready()
+    by_name = {e["name"]: e["attrs"] for e in rec.events()}
+    assert by_name["test.first"]["compiles"] >= 1
+    assert "compiles" not in by_name["test.again"]
+    assert by_name["test.new_shape"]["compiles"] >= 1
+    assert (by_name["test.outer"]["compiles"]
+            == by_name["test.new_shape"]["compiles"])
+
+
+def test_regions_nest_in_the_trace_and_the_two_clocks_differ_by_a_constant(
+        tmp_path):
+    """Inside a profiler session every region is also an interval of the
+    trace's host plane, as ``benchmark/trace_reduce.read_xplane`` reads
+    it, and ``start_ns / 1e9 - t0`` is one constant: the offset that
+    joins the trace's clock to the recorder's."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import trace_reduce
+
+    rec = FlightRecorder()
+    tracer = SpanTracer(rec)
+    x = jnp.ones((32, 32))
+    with jax.profiler.trace(str(tmp_path)):
+        for tick in range(6):
+            with tracer.step_region("train.step", tick=tick):
+                with tracer.region("train.feed", tick=tick):
+                    time.sleep(0.003)
+                with tracer.region("train.dispatch", tick=tick):
+                    (x @ x).block_until_ready()
+            with tracer.region("serve.admit", tick=tick):
+                with tracer.region("serve.admit_one", request=tick):
+                    time.sleep(0.001)
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    spans = sorted(trace_reduce.read_xplane(path, chips=1).spans)
+    events = sorted(rec.events(), key=lambda e: e["attrs"]["t0"])
+    assert [sp[2] for sp in spans] == [e["name"] for e in events]
+    assert len(spans) == 6 * 5
+    # nesting, on the trace's clock, is what ``parent`` says
+    for start, end, name in spans:
+        inside = [sp for sp in spans
+                  if sp[0] <= start and end <= sp[1] and sp[2] != name]
+        want = {"train.feed": "train.step", "train.dispatch": "train.step",
+                "serve.admit_one": "serve.admit"}.get(name)
+        assert [sp[2] for sp in inside] == ([want] if want else [])
+    parents = {e["name"]: e["attrs"].get("parent") for e in events}
+    assert parents == {"train.step": None, "train.feed": "train.step",
+                       "train.dispatch": "train.step", "serve.admit": None,
+                       "serve.admit_one": "serve.admit"}
+    offsets = [sp[0] / 1e9 - e["attrs"]["t0"]
+               for sp, e in zip(spans, events)]
+    assert max(offsets) - min(offsets) < 1e-3
+    # and the lengths agree
+    for (start, end, _), e in zip(spans, events):
+        assert (end - start) / 1e6 == pytest.approx(e["attrs"]["ms"],
+                                                    abs=1.0)
+
+
 # -- retrace watchdog -------------------------------------------------------
 
 

@@ -347,3 +347,88 @@ def test_grad_accum_divisibility_guard():
     )
     with pytest.raises(FriendlyError, match="grad_accum"):
         tr.train(x, y)
+
+
+# -- regions of the step ------------------------------------------------------
+
+STEP_REGIONS = ("train.feed", "train.dispatch", "train.sync", "train.log")
+
+
+def _step_regions(events):
+    return [e for e in events if e["name"].startswith("train.")]
+
+
+@pytest.mark.parametrize("steps_per_dispatch", [1, 2])
+def test_every_step_leaves_five_regions(steps_per_dispatch):
+    """One group of the loop is one ``train.step`` with the feed, the
+    dispatch, the sync and the log inside it, in that order; the pull that
+    finds the epoch's end leaves nothing."""
+    x, y = _two_blob_data(n=256)
+    g = build_model("mlp", num_outputs=2, hidden=(8,))
+    trainer = SPMDTrainer(g, TrainConfig(
+        epochs=2, batch_size=64, learning_rate=1e-2, log_every=1,
+        steps_per_dispatch=steps_per_dispatch))
+    trainer.train(x, y)
+    groups = 2 * 4 // steps_per_dispatch
+    regions = _step_regions(trainer.recorder.events())
+    assert len(regions) == 5 * groups
+    for i in range(groups):
+        five = regions[5 * i:5 * i + 5]
+        # each event is written when its region ends: the step's last
+        assert [e["name"] for e in five] == [*STEP_REGIONS, "train.step"]
+        first = i * steps_per_dispatch
+        assert {e["tick"] for e in five} == {first}
+        assert [e["attrs"].get("parent") for e in five] == [
+            "train.step"] * 4 + [None]
+        step = five[-1]["attrs"]
+        inner = [e["attrs"] for e in five[:4]]
+        assert all(a["t0"] >= step["t0"] for a in inner)
+        assert sum(a["ms"] for a in inner) <= step["ms"] + 0.01
+        assert [a["t0"] for a in inner] == sorted(a["t0"] for a in inner)
+    # the program compiles in the first dispatch and in no later one
+    dispatches = [e["attrs"] for e in regions if e["name"] == "train.dispatch"]
+    assert dispatches[0]["compiles"] >= 1
+    assert all("compiles" not in a for a in dispatches[2:])
+    # the step events keep their form, one a group at this cadence
+    steps = [e for e in trainer.recorder.events() if e["name"] == "step"]
+    assert [e["tick"] for e in steps] == [
+        (i + 1) * steps_per_dispatch - 1 for i in range(groups)]
+    assert trainer.telemetry.histogram("train.step_ms").count == groups
+
+
+def test_a_raise_out_of_the_step_event_leaves_the_recorder_whole():
+    """The benchmark ends its training window by raising out of
+    ``recorder.record("step", ...)``: the regions that were open record
+    themselves with the error and let it through."""
+    from mmlspark_tpu.core.telemetry import FlightRecorder
+
+    class WindowClosed(Exception):
+        pass
+
+    class Closing(FlightRecorder):
+        def record(self, name, **kw):
+            super().record(name, **kw)
+            if name == "step" and kw["tick"] == 2:
+                raise WindowClosed
+
+    x, y = _two_blob_data(n=256)
+    g = build_model("mlp", num_outputs=2, hidden=(8,))
+    recorder = Closing()
+    trainer = SPMDTrainer(g, TrainConfig(
+        epochs=2, batch_size=64, learning_rate=1e-2, log_every=1),
+        recorder=recorder)
+    with pytest.raises(WindowClosed):
+        trainer.train(x, y)
+    events = recorder.events()
+    assert [e["tick"] for e in events if e["name"] == "step"] == [0, 1, 2]
+    regions = _step_regions(events)
+    assert len(regions) == 5 * 3
+    assert [e["name"] for e in regions[-5:]] == [*STEP_REGIONS, "train.step"]
+    errors = [e["attrs"].get("error") for e in regions]
+    assert errors == [None] * 13 + ["WindowClosed"] * 2
+    # nothing is left open: a second call starts at the top again
+    with pytest.raises(WindowClosed):
+        trainer.train(x, y)
+    again = _step_regions(recorder.events())[15:]
+    assert [e["attrs"].get("parent") for e in again[:5]] == [
+        "train.step"] * 4 + [None]
