@@ -24,12 +24,13 @@ With ``mesh`` set (docs/SERVING.md "Sharded serving") the pool is the
 engine's device-placement anchor: every buffer is allocated COMMITTED
 to a fixed :class:`~jax.sharding.NamedSharding` — the slot dim over the
 ``data`` axis, the KV-head dim over the ``model`` axis when it divides
-evenly — and every eager update (``write_prefill``, ``free``) is
-re-committed to the same sharding before the decode block sees it.
-That fixed-point is what keeps the sharded engine's jitted programs at
-ONE signature-cache entry per program family: the fused block's
-donated inputs and ``out_shardings``-pinned outputs present byte-for-
-byte identical shardings on every tick.
+evenly. ``write_prefill`` is one jitted program whose ``out_shardings``
+are those same shardings, and the eager updates of ``free`` are
+re-committed to them before the decode block sees them. That
+fixed-point is what keeps the sharded engine's jitted programs at ONE
+signature-cache entry per program family: the fused block's donated
+inputs and ``out_shardings``-pinned outputs present byte-for-byte
+identical shardings on every tick.
 """
 
 from __future__ import annotations
@@ -38,6 +39,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.models.generate import cache_geometry
@@ -79,7 +81,7 @@ def validate_kv_dtype(kv_dtype: str, geometry: dict) -> None:
 def quantize_kv(values, scales):
     """Symmetric int8 quantization of K/V ``values`` (..., hk, d) with
     per-kv-head ``scales`` broadcastable over (..., hk); out-of-range
-    values saturate at ±127. ONE definition shared by the pools' eager
+    values saturate at ±127. ONE definition shared by the pools'
     prefill writes and the transformer's in-graph decode-step writes,
     so both paths land bit-identical int8 for identical inputs."""
     q = jnp.round(values.astype(jnp.float32) / scales[..., None])
@@ -95,6 +97,59 @@ def kv_head_scales(values, axes) -> jnp.ndarray:
     amax = jnp.abs(values.astype(jnp.float32)).max(axis=axes)
     scale = amax * (KV_SCALE_MARGIN / 127.0)
     return jnp.where(scale == 0.0, 1.0, scale)
+
+
+def _put_rows(pool, values, slot, written):
+    """``pool`` with the rows of ``slot`` that ``written`` marks taken
+    from ``values`` (rows, hk, d), cast to the pool's dtype: a read of
+    the slot's first rows, a select and one dynamic-update-slice, which
+    XLA does in place on a donated pool."""
+    at = (slot, 0, 0, 0)
+    old = jax.lax.dynamic_slice(pool, at, (1, *values.shape))
+    values = jnp.where(written, values.astype(pool.dtype), old[0])
+    return jax.lax.dynamic_update_slice(pool, values[None], at)
+
+
+def _write_slot(buffers, positions, live, prefill_cache, slot, start,
+                length):
+    """The whole of :meth:`SlotCachePool.write_prefill` as one program:
+    rows ``[start, length)`` of ``slot`` take the batch-1
+    ``prefill_cache``'s rows, every other row of the pool stays as it
+    is, and the slot goes live at position ``length``. ``slot``,
+    ``start`` and ``length`` are traced scalars, so the program is keyed
+    by the prefill cache's shape alone. A pool entry of four leaves is
+    an int8 one, whose per-head scales are fixed here from the rows
+    below ``length``."""
+    new_buffers = {}
+    for name, entry in buffers.items():
+        rows = min(prefill_cache[name][0].shape[1], entry[0].shape[1])
+        ck, cv = (c[0, :rows] for c in prefill_cache[name])
+        row = jnp.arange(rows)[:, None, None]
+        written = (row >= start) & (row < length)
+        if len(entry) == 2:
+            pk, pv = entry
+            new_buffers[name] = (
+                _put_rows(pk, ck, slot, written),
+                _put_rows(pv, cv, slot, written),
+            )
+            continue
+        pk, pv, pks, pvs = entry
+        # the prompt amax (+ margin) FIXES this lease's scales: decode
+        # steps quantize against them in-graph, so they must be set
+        # before the first block dispatch. The bucket's pad rows are
+        # zeroed first, which leaves the amax that of the prompt alone
+        ck, cv = (jnp.where(row < length, c, 0) for c in (ck, cv))
+        k_scl = kv_head_scales(ck, axes=(0, 2))  # (hk,)
+        v_scl = kv_head_scales(cv, axes=(0, 2))
+        new_buffers[name] = (
+            _put_rows(pk, quantize_kv(ck, k_scl), slot, written),
+            _put_rows(pv, quantize_kv(cv, v_scl), slot, written),
+            pks.at[slot].set(k_scl), pvs.at[slot].set(v_scl),
+        )
+    # the slot's first decode step writes its first generated token's
+    # K/V at position ``length`` (the prompt fills [0, P))
+    return (new_buffers, positions.at[slot].set(length),
+            live.at[slot].set(True))
 
 
 class SlotCachePool:
@@ -224,6 +279,22 @@ class SlotCachePool:
         # garbage that the slot's next prefill overwrites.
         self.positions = self._commit_slot(jnp.zeros((slots,), jnp.int32))
         self.live = self._commit_slot(jnp.zeros((slots,), bool))
+        # write_prefill's program. ``buffers`` is donated, so each K/V
+        # array is updated in place, as the fused decode block does it.
+        # ``positions`` and ``live`` are not: the async host loop still
+        # holds ``live`` as its in-flight block's fetch target when the
+        # next tick admits, and S scalars gain nothing from an update
+        # in place. Nor is the prefill cache: a chunked fill keeps it
+        # as its carry and a hand-off ships it afterwards. Under a mesh
+        # the outputs are pinned to the pool's own shardings, so the
+        # decode block's donated inputs never change signature (the
+        # compile-count pins depend on it)
+        pinned = None
+        if mesh is not None:
+            pinned = (self._kv_shardings, self._slot_sharding,
+                      self._slot_sharding)
+        self._write = jax.jit(_write_slot, donate_argnums=(0,),
+                              out_shardings=pinned)
 
     # -- sharding anchors --------------------------------------------------
 
@@ -338,9 +409,8 @@ class SlotCachePool:
 
     def _commit_slot_pair(self, positions, live) -> None:
         """Rebind positions+live behind ONE pinned update — committing
-        them separately would issue two eager dispatches per
-        retire/admit, and the retire path runs once per finished
-        request."""
+        them separately would issue two eager dispatches per retire,
+        and the retire path runs once per finished request."""
         if self._slot_sharding is not None:
             positions, live = jax.device_put(
                 (positions, live),
@@ -359,15 +429,11 @@ class SlotCachePool:
         the slot already holds (same contract as the paged pool's
         ``write_prefill``, which prefix-cache resume uses).
 
-        Returns ``(dispatches, bytes)``: the separate array operations
-        this write launched eagerly, counted beside each launch (a
-        slice, a cast where the dtypes differ and a scatter for each
-        K and each V array, then positions and live, and each pinned
-        ``device_put`` under a mesh; a helper that runs several
-        primitives, as the int8 path's scales and quantization do,
-        counts once), and the K/V bytes it wrote into the pool. The
-        engine stamps both on its ``serve.pool_write`` region: ONE
-        jitted write would read 1."""
+        Returns ``(dispatches, bytes)``: the programs this write
+        launched, counted beside the launch (1: the pool's one jitted
+        write, which donates ``buffers`` and leaves ``prefill_cache``
+        alone), and the K/V bytes it wrote into the pool. The engine
+        stamps both on its ``serve.pool_write`` region."""
         if slot not in self._leased:
             raise FriendlyError(f"slot {slot} is not leased")
         if length > self.cache_len:
@@ -380,8 +446,7 @@ class SlotCachePool:
                 f"write_prefill start {start} must lie in [0, length "
                 f"{length})"
             )
-        quantized = self.kv_dtype == "int8"
-        if quantized and start:
+        if self.kv_dtype == "int8" and start:
             # a lease's int8 scales are FIXED from its whole-prompt
             # amax before the first decode dispatch; a partial write
             # cannot re-derive them without dequantizing the resident
@@ -391,61 +456,26 @@ class SlotCachePool:
                 "scales are fixed per lease from the whole prompt "
                 "(use the paged pool for resumable int8 fills)"
             )
-        new_buffers = {}
-        dispatches = nbytes = 0
+        nbytes = 0
         for name, entry in self.buffers.items():
-            ck, cv = prefill_cache[name]
-            if quantized:
-                pk, pv, pks, pvs = entry
-                # the prompt amax (+ margin) FIXES this lease's scales:
-                # decode steps quantize against them in-graph, so they
-                # must be set before the first block dispatch
-                ck0, cv0 = ck[0, :length], cv[0, :length]
-                k_scl = kv_head_scales(ck0, axes=(0, 2))  # (hk,)
-                v_scl = kv_head_scales(cv0, axes=(0, 2))
-                nk = pk.at[slot, :length].set(quantize_kv(ck0, k_scl))
-                nv = pv.at[slot, :length].set(quantize_kv(cv0, v_scl))
-                new_buffers[name] = (
-                    nk, nv,
-                    pks.at[slot].set(k_scl), pvs.at[slot].set(v_scl),
+            rows = prefill_cache[name][0].shape[1]
+            if rows < length:
+                raise FriendlyError(
+                    f"prefill cache of block '{name}' holds {rows} "
+                    f"rows, fewer than the prefill length {length}"
                 )
-                # 2 slices, 2 scales, 2 quantizations, 4 scatters
-                dispatches += 10
-            else:
-                pk, pv = entry
-                nk = pk.at[slot, start:length].set(
-                    ck[0, start:length].astype(pk.dtype)
-                )
-                nv = pv.at[slot, start:length].set(
-                    cv[0, start:length].astype(pv.dtype)
-                )
-                new_buffers[name] = (nk, nv)
-                # a slice and a scatter each, and a cast where it is one
-                dispatches += 4 + (ck.dtype != pk.dtype) \
-                    + (cv.dtype != pv.dtype)
             # K and V alike: (slots, cache_len, hk, d) in the pool's dtype
             nbytes += 2 * (length - start) * (
-                math.prod(nk.shape[2:]) * nk.dtype.itemsize
+                math.prod(entry[0].shape[2:]) * entry[0].dtype.itemsize
             )
-        if self._kv_shardings is not None:
-            # the eager scatters' output shardings are whatever GSPMD
-            # propagated from mixing the pool rows with the prefill
-            # cache — re-commit to the pool's canonical shardings so
-            # the decode block's donated inputs never change signature
-            # (the compile-count pins depend on it). ONE device_put of
-            # the whole pytree, not one per K/V per block: the admit
-            # path runs this once per joiner.
-            new_buffers = jax.device_put(new_buffers, self._kv_shardings)
-            dispatches += 1
-        self.buffers = new_buffers
-        # the slot's first decode step writes its first generated
-        # token's K/V at position ``length`` (the prompt fills [0, P))
-        self._commit_slot_pair(
-            self.positions.at[slot].set(length),
-            self.live.at[slot].set(True),
+        # after the donation the old K/V arrays are gone: the pool's
+        # state is rebound from the program's outputs and from nothing
+        # else
+        self.buffers, self.positions, self.live = self._write(
+            self.buffers, self.positions, self.live, prefill_cache,
+            np.int32(slot), np.int32(start), np.int32(length),
         )
-        dispatches += 2 + (self._slot_sharding is not None)
-        return dispatches, nbytes
+        return 1, nbytes
 
     # -- accounting for telemetry ------------------------------------------
 

@@ -182,6 +182,49 @@ def test_compiles_for_v5e(case, chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_pool_write_updates_the_pool_in_place_on_v5e(kv_dtype, chip):
+    """The dense pool's jitted prefill write at GPT-2-small shapes, 8
+    slots, a 256-row bucket: every pool buffer is aliased to its output
+    and the temporaries stay under ONE buffer's size, so the donation
+    took and no copy of the pool exists beside the pool (a failed one
+    would add the pool's bytes to the chip's peak on every admission)."""
+    import math
+
+    from mmlspark_tpu.models import build_model
+    from mmlspark_tpu.serve.cache_pool import SlotCachePool
+
+    # the program is the pool's own, and keyed by its arguments' shapes:
+    # a pool of any size lowers it at the real one
+    graph = build_model("transformer_lm", vocab_size=16, d_model=16,
+                        heads=2, depth=1, max_len=8, attn_impl="dense")
+    variables = graph.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))
+    write = SlotCachePool(graph, variables, 2, 8, kv_dtype=kv_dtype)._write
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    slots, rows, blocks = 8, 256, GPT2_SMALL["depth"]
+    store = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
+    kv = sds((slots, CACHE, HEADS, HEAD_DIM), store)
+    entry = (kv, kv)
+    if kv_dtype == "int8":
+        entry += (sds((slots, HEADS), jnp.float32),) * 2
+    buffers = {f"block{i}": entry for i in range(blocks)}
+    source = sds((1, rows, HEADS, HEAD_DIM), jnp.bfloat16)
+    cache = {name: (source, source) for name in buffers}
+    scalar = sds((), jnp.int32)
+    compiled = write.lower(
+        buffers, sds((slots,), jnp.int32), sds((slots,), jnp.bool_),
+        cache, scalar, scalar, scalar,
+    ).compile()
+    memory = compiled.memory_analysis()
+    one = math.prod(kv.shape) * kv.dtype.itemsize
+    assert memory.alias_size_in_bytes >= 2 * blocks * one
+    assert memory.temp_size_in_bytes < one
+
+
 def test_pool_refuses_a_page_table_the_kernel_cannot_hold():
     """The one size the kernel cannot be made to compile at — a page
     table past scalar memory — is refused when the pool is built, with

@@ -37,6 +37,10 @@ def test_serve_phase(log):
     assert out["bf16_dense_pool"]["tokens_identical_to_generate"] == "18/18"
     assert out["bf16_dense_pool"]["worst_scaled_logit_gap"] <= 1e-3
     assert out["int8_paged_pool"]["page_size"] == 8
+    behind = out["bf16_dense_pool_async_host"]
+    assert behind["flip_rate_vs_sync"] == 0.0
+    assert behind["overlapped_dispatches"] > 0
+    assert behind["pool_write_dispatches"] == [1]
     assert out["bf16_dense_pool"]["compiles"] > 0
 
 

@@ -24,8 +24,10 @@ from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.core.metrics_contracts import MetricData
 from mmlspark_tpu.models import build_model, generate
 from mmlspark_tpu.serve import ServeEngine, SlotCachePool
+from mmlspark_tpu.serve.cache_pool import KV_SCALE_MARGIN, kv_head_scales
 from mmlspark_tpu.testing.compile_guard import (
     compile_guard,
+    jit_cache_size,
     serve_compile_guard,
 )
 
@@ -81,8 +83,182 @@ def test_slot_pool_guards():
         SlotCachePool(m, v, slots=2, cache_len=1)
 
 
-# -- token parity (the acceptance test) ------------------------------------
+# -- the pool's one jitted write -------------------------------------------
 
+
+def _random_pool(kv_dtype, slots, cache_len, seed=0):
+    """A pool whose every array holds seeded noise, so a row the write
+    must leave alone is told from one it never touched, with all but
+    one slot leased."""
+    m = _tiny(max_len=64)
+    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    pool = SlotCachePool(m, v, slots=slots, cache_len=cache_len,
+                         kv_dtype=kv_dtype)
+    rng = np.random.default_rng(seed)
+    noisy = {}
+    for name, entry in pool.buffers.items():
+        kv = [rng.integers(-127, 128, size=a.shape) for a in entry[:2]]
+        scales = [rng.uniform(0.5, 2.0, size=a.shape) for a in entry[2:]]
+        noisy[name] = tuple(
+            jnp.asarray(x, a.dtype) for x, a in zip(kv + scales, entry)
+        )
+    pool.buffers = noisy
+    for _ in range(slots - 1):
+        pool.lease()
+    return pool
+
+
+def _source_cache(pool, rows, length, dtype, seed=1):
+    """A batch-1 prefill cache of ``rows`` rows whose rows from
+    ``length`` on hold a sentinel no prompt row comes near."""
+    rng = np.random.default_rng(seed)
+    cache = {}
+    for name, entry in pool.buffers.items():
+        pair = []
+        for _ in range(2):
+            x = rng.normal(size=(1, rows) + entry[0].shape[2:]) * 3.0
+            x[0, length:] = 1e4
+            pair.append(jnp.asarray(x, dtype))
+        cache[name] = tuple(pair)
+    return cache
+
+
+def _host(tree):
+    return jax.tree_util.tree_map(np.array, tree)
+
+
+@pytest.mark.parametrize(
+    "kv_dtype,src_dtype,rows,slot,start,length",
+    [
+        ("bf16", jnp.bfloat16, 16, 1, 0, 11),
+        ("bf16", jnp.bfloat16, 16, 2, 4, 13),
+        ("bf16", jnp.float32, 8, 2, 0, 5),
+        # the chunked fill's carry: as many rows as the pool
+        ("bf16", jnp.bfloat16, 24, 1, 7, 19),
+        ("bf16", jnp.bfloat16, 16, 0, 0, 16),
+        ("int8", jnp.bfloat16, 16, 1, 0, 11),
+        ("int8", jnp.float32, 24, 2, 0, 24),
+    ],
+    ids=["bf16", "bf16-resume", "bf16-cast", "bf16-carry", "bf16-full",
+         "int8", "int8-carry"],
+)
+def test_write_prefill_matches_the_eager_write_bit_for_bit(
+        kv_dtype, src_dtype, rows, slot, start, length):
+    """The jitted, donated write against a NumPy oracle of the eager
+    one it replaced: rows ``[start, length)`` of one slot change and
+    nothing else does."""
+    pool = _random_pool(kv_dtype, slots=4, cache_len=24)
+    cache = _source_cache(pool, rows, length, src_dtype)
+    want = _host(pool.buffers)
+    want_pos, want_live = _host((pool.positions, pool.live))
+    for name, entry in want.items():
+        for i, c in enumerate(cache[name]):
+            values = np.asarray(c)[0, start:length]
+            if kv_dtype == "int8":
+                scale = np.asarray(kv_head_scales(c[0, :length],
+                                                  axes=(0, 2)))
+                f32 = values.astype(np.float32)
+                amax = np.abs(f32).max(axis=(0, 2))
+                np.testing.assert_array_equal(
+                    scale, amax * np.float32(KV_SCALE_MARGIN / 127.0))
+                entry[2 + i][slot] = scale
+                values = np.clip(np.round(f32 / scale[:, None]),
+                                 -127, 127)
+            entry[i][slot, start:length] = values.astype(entry[i].dtype)
+    want_pos[slot], want_live[slot] = length, True
+
+    dispatches, nbytes = pool.write_prefill(slot, cache, length,
+                                            start=start)
+
+    assert dispatches == 1
+    hk_d = 32                       # d_model 32: heads x head_dim
+    width = 1 if kv_dtype == "int8" else 2
+    assert nbytes == len(want) * 2 * (length - start) * hk_d * width
+    got = _host(pool.buffers)
+    for name, entry in want.items():
+        assert len(got[name]) == len(entry)
+        for g, w in zip(got[name], entry):
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(g, w)
+    np.testing.assert_array_equal(np.asarray(pool.positions), want_pos)
+    np.testing.assert_array_equal(np.asarray(pool.live), want_live)
+    # the source was not donated: a chunked fill keeps it as its carry
+    for pair in cache.values():
+        for c in pair:
+            assert not c.is_deleted()
+            assert float(np.asarray(c, np.float32)[0, -1, 0, 0]) != 0.0
+
+
+def test_write_prefill_refusals_leave_the_pool_untouched():
+    """What the write refuses it refuses before the donation."""
+    pool = _random_pool("int8", slots=2, cache_len=24)
+    before = _host(pool.buffers)
+    cache = _source_cache(pool, 8, 8, jnp.bfloat16)
+    for args, match in (((1, cache, 6), "not leased"),
+                        ((0, cache, 25), "exceeds"),
+                        ((0, cache, 6, 6), "must lie in"),
+                        ((0, cache, 6, 2), "start=0"),
+                        ((0, cache, 12), "fewer than")):
+        with pytest.raises(FriendlyError, match=match):
+            pool.write_prefill(*args)
+    for name, entry in _host(pool.buffers).items():
+        for g, w in zip(entry, before[name]):
+            np.testing.assert_array_equal(g, w)
+
+
+def test_write_prefill_compiles_one_program_a_source_shape():
+    """``slot``, ``start`` and ``length`` are data: five lengths into
+    four slots from one source shape are ONE program, a second source
+    shape one more. The geometry is this test's own, so nothing an
+    earlier test compiled can stand in for either."""
+    m = _tiny(d_model=48, heads=3, max_len=64)
+    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    pool = SlotCachePool(m, v, slots=4, cache_len=40)
+    for _ in range(4):
+        pool.lease()
+    seen = jit_cache_size(pool._write)
+    writes = ((0, 3, 0), (1, 16, 0), (2, 9, 2), (3, 12, 0), (1, 5, 4))
+    for slot, length, start in writes:
+        pool.write_prefill(slot, _source_cache(pool, 16, length,
+                                               jnp.bfloat16),
+                           length, start=start)
+    assert jit_cache_size(pool._write) - seen == 1
+    for slot, length, start in writes:
+        pool.write_prefill(slot, _source_cache(pool, 32, length,
+                                               jnp.bfloat16),
+                           length, start=start)
+    assert jit_cache_size(pool._write) - seen == 2
+    assert np.asarray(pool.positions).tolist() == [3, 5, 9, 12]
+
+
+def test_pool_write_compiles_once_a_prefill_bucket():
+    """On a request's own timeline: of the ``serve.pool_write`` regions
+    of one prefill bucket only the first may report a compile, whatever
+    the prompts' lengths."""
+    m = _tiny(d_model=48, heads=3, max_len=64)
+    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    engine = ServeEngine(m, v, slots=3, cache_len=48)
+    rng = np.random.default_rng(0)
+    lengths = (9, 13, 16, 11, 20, 31, 10, 27, 17)
+    for n in lengths:
+        engine.submit(rng.integers(0, 8, size=n).astype(np.int32),
+                      max_new_tokens=3)
+    results = engine.run()
+    assert all(r.status == "completed" for r in results.values())
+    events = engine.recorder.events()
+    bucket_of = {e["attrs"]["request"]: e["attrs"]["bucket"]
+                 for e in events if e["name"] == "serve.prefill"}
+    writes: dict = {}
+    for e in events:
+        if e["name"] == "serve.pool_write":
+            writes.setdefault(bucket_of[e["attrs"]["request"]], []).append(
+                e["attrs"].get("compiles", 0))
+    assert {b: len(c) for b, c in writes.items()} == {16: 5, 32: 4}
+    for compiles in writes.values():
+        assert compiles[0] == 1 and not any(compiles[1:]), writes
+
+
+# -- token parity (the acceptance test) ------------------------------------
 
 @pytest.mark.parametrize("config", [
     {},                                        # learned positions
@@ -459,17 +635,18 @@ def test_every_admission_and_every_tick_leave_their_regions(options):
     finished = sum(e["attrs"]["finished"] for e in regions
                    if e["name"] == "serve.retire")
     assert finished == len(rids)
-    # what the pool counts: a slice and a scatter for each K and each V
-    # array (the prefill cache has the pool's dtype), then positions and
-    # live; the paged pool adds the page and offset vectors, a head
-    # index a block and, when the tables changed, one table a block
+    # what the pool counts: the dense pool's one jitted write; in the
+    # paged pool a slice and a scatter for each K and each V array (the
+    # prefill cache has the pool's dtype), positions and live, the page
+    # and offset vectors, a head index a block and, when the tables
+    # changed, one table a block
     writes = [e["attrs"] for e in regions if e["name"] == "serve.pool_write"]
     blocks = len(engine.pool.buffers)
     if "paged" in options:
         assert {w["dispatches"] for w in writes} <= {
             2 + 5 * blocks + 2, 2 + 5 * blocks + blocks + 2}
     else:
-        assert {w["dispatches"] for w in writes} == {4 * blocks + 2}
+        assert {w["dispatches"] for w in writes} == {1}
     row = 2 * 32 * 2          # K and V, d_model 32, bfloat16
     assert sorted(w["bytes"] for w in writes) == sorted(
         blocks * row * n for n in (4, 6, 7, 12, 5))
@@ -477,6 +654,45 @@ def test_every_admission_and_every_tick_leave_their_regions(options):
     assert sum(e["name"] == "tick" for e in events) == engine.tick
     assert sum(e["name"] == "prefill" and e.get("span_name") == "request"
                for e in events) == len(rids)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_an_admission_behind_a_block_in_flight_keeps_its_fetch_target(
+        kv_dtype):
+    """The async host loop admits while its last block is still to be
+    fetched, and that block's ``live`` output IS ``pool.live``: the
+    pool's write must leave it readable (it donates the K/V buffers
+    alone). Arrivals into an engine that is not full are what reaches
+    that state: no retirement has rebound ``pool.live`` in between."""
+    m = _tiny()
+    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 8, size=n).astype(np.int32)
+               for n in (5, 9, 3, 12)]
+    streams = {}
+    for async_host in (False, True):
+        engine = ServeEngine(m, v, slots=4, cache_len=32, decode_block=2,
+                             kv_dtype=kv_dtype, async_host=async_host)
+        write, behind = engine.pool._write, []
+
+        def watched(buffers, positions, live, *rest):
+            block = engine._inflight
+            behind.append(block is not None and block["live"] is live)
+            out = write(buffers, positions, live, *rest)
+            assert not live.is_deleted() and not positions.is_deleted()
+            return out
+
+        engine.pool._write = watched
+        rids, results = [], {}
+        for prompt in prompts:
+            # one arrival a tick, each behind the last one's first block
+            rids.append(engine.submit(prompt, max_new_tokens=8))
+            results.update((r.id, r) for r in engine.step())
+        results.update(engine.run())
+        assert all(results[r].status == "completed" for r in rids)
+        assert any(behind) == async_host, behind
+        streams[async_host] = [results[r].tokens.tolist() for r in rids]
+    assert streams[True] == streams[False]
 
 
 def test_fetch_region_feeds_the_host_sync_account():
