@@ -1,7 +1,9 @@
-"""Where the reference's leaves sit in the tree that
+"""Where the ``gpt2`` reference's leaves sit in the tree that
 ``mmlspark_tpu.models.build_model("transformer_lm", ...)`` builds: the one
 place the benchmark knows the program's parameter layout. A model family
-with another tree brings an adapter file of its own."""
+with another tree, or other leaves over the same builder, brings an
+adapter file of its own. ``sz`` is the reference's sizes, of which this
+adapter reads ``layers``."""
 
 from __future__ import annotations
 
@@ -37,24 +39,24 @@ def _get(tree: dict, path: tuple):
     return tree
 
 
-def to_program(params: dict, layers: int) -> dict:
+def to_program(params: dict, sz: dict) -> dict:
     """The reference's (layer-stacked) parameters as the program's
     variables. Traceable."""
     out: dict = {}
     for name, path in _GLOBAL.items():
         _put(out, path, params[name])
-    for i in range(layers):
+    for i in range(sz["layers"]):
         for name, path in _LAYER.items():
             _put(out, (f"block{i}", "params") + path, params[name][i])
     return out
 
 
-def from_program(variables: dict, layers: int, stack) -> dict:
+def from_program(variables: dict, sz: dict, stack) -> dict:
     """The program's variables under the reference's names, per-layer
     leaves stacked with ``stack`` (``numpy.stack`` or ``jnp.stack``)."""
     out = {name: _get(variables, path) for name, path in _GLOBAL.items()}
     for name, path in _LAYER.items():
         out[name] = stack([
             _get(variables, (f"block{i}", "params") + path)
-            for i in range(layers)])
+            for i in range(sz["layers"])])
     return out

@@ -1,5 +1,7 @@
 """The comparisons that decide ``correct``: what the timed path produced,
-held against the plain reference (:mod:`benchmark.reference`).
+held against the plain reference of the cell's family (``ref`` below: the
+module that :func:`benchmark.family.resolve` found by the name in the
+configuration's file). No family's equations are known here.
 
 Serving: a seeded sample of the requests the window finished, the longest
 among them. The reference runs once over each prompt with its served
@@ -26,9 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark import reference as ref
+from benchmark.family import seed_key
 
-ADAM = dict(b1=0.9, b2=0.999, eps=1e-8)
 #: a leaf whose first reference gradient is under this share of the median
 #: leaf's is nought to rounding (the key bias under softmax) and moves
 #: under Adam by round-off alone: left out of the change comparison
@@ -66,16 +67,16 @@ def sample_requests(finished: list, n: int, seed: int) -> list:
     return [finished[i] for i in picked]
 
 
-def served_gaps(sz: dict, seed: int, samples: list, length: int,
+def served_gaps(ref, sz: dict, seed: int, samples: list, length: int,
                 mode: str = "f32") -> dict:
     """``samples`` is a list of (prompt ids, served token ids). Returns
     ``served_gap``: the widest gap of a served token below the reference's
     best; with a ``mode`` other than ``f32`` also ``control_gap``: the
     widest gap of the token that ``mode`` puts first, at the same
-    positions. One compiled program for all samples: every sequence is
-    padded to ``length`` (causality hides the padding)."""
-    params = jax.jit(lambda k: ref.init_params(k, sz))(ref.seed_key(seed))
-    fn = jax.jit(functools.partial(ref.served_gaps, sz=sz, mode=mode))
+    positions. Every sequence is padded to ``length`` (causality hides
+    the padding), so the reference can compile one program for all
+    samples; how it holds its parameters is its own affair."""
+    fn = ref.served_gaps_fn(sz, seed_key(seed), mode)
     worst_served, worst_control, tokens = 0.0, 0.0, 0
     for prompt, served in samples:
         n = len(served)
@@ -84,7 +85,7 @@ def served_gaps(sz: dict, seed: int, samples: list, length: int,
         seq = np.zeros((1, length), np.int32)
         seq[0, :len(prompt)] = prompt
         seq[0, len(prompt):len(prompt) + n] = served
-        s, c = fn(params, jnp.asarray(seq), len(prompt), n)
+        s, c = fn(jnp.asarray(seq), len(prompt), n)
         s, c = np.asarray(s)[:n], np.asarray(c)[:n]
         worst_served = max(worst_served, float(s.max()))
         worst_control = max(worst_control, float(c.max()))
@@ -99,30 +100,28 @@ def served_gaps(sz: dict, seed: int, samples: list, length: int,
 # -- training ----------------------------------------------------------------
 
 
-def split_leaves(tree: dict) -> dict:
-    """Leaves at the grain they are compared at: every layer of a stacked
-    leaf apart, and the fused QKV leaves in their three parts (the key
-    bias has no gradient under softmax while the query's and the value's
-    have). Values are 1-D float64 numpy arrays."""
-    out = {}
-    for name, leaf in tree.items():
-        leaf = np.asarray(leaf, np.float64)
-        layers = leaf if name in ref.LAYER_LEAVES else leaf[None]
-        for i, row in enumerate(layers):
-            tag = f"{name}[{i}]" if name in ref.LAYER_LEAVES else name
-            if name.startswith("qkv_"):
-                for part, piece in zip("qkv", np.split(row, 3, axis=-1)):
-                    out[f"{tag}.{part}"] = piece.reshape(-1)
-            else:
-                out[tag] = row.reshape(-1)
-    return out
+def adam_step(params, m, v, grads, step: int, lr: float,
+              b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8):
+    """One Adam update (Kingma & Ba 2015, with bias correction); ``step``
+    counts from 1."""
+    m = jax.tree_util.tree_map(lambda a, g: b1 * a + (1 - b1) * g, m, grads)
+    v = jax.tree_util.tree_map(
+        lambda a, g: b2 * a + (1 - b2) * g * g, v, grads)
+    c1, c2 = 1 - b1 ** step, 1 - b2 ** step
+    params = jax.tree_util.tree_map(
+        lambda p, a, s: p - lr * (a / c1) / (jnp.sqrt(s / c2) + eps),
+        params, m, v)
+    return params, m, v
 
 
-def leaf_norms(tree: dict) -> dict:
-    return {k: float(np.linalg.norm(v)) for k, v in split_leaves(tree).items()}
+def leaf_norms(ref, tree: dict) -> dict:
+    """The norm of every leaf of ``tree``, at the grain the reference cuts
+    its leaves to (``ref.split_leaves``)."""
+    return {k: float(np.linalg.norm(v))
+            for k, v in ref.split_leaves(tree).items()}
 
 
-def reference_steps(sz: dict, seed: int, batches: list, lr: float,
+def reference_steps(ref, sz: dict, seed: int, batches: list, lr: float,
                     mode: str = "f32", rows_per_block: int = 4,
                     fault: str | None = None) -> dict:
     """Follow ``batches`` (a list of (x, y) int arrays, one per step) from
@@ -134,14 +133,14 @@ def reference_steps(sz: dict, seed: int, batches: list, lr: float,
     every batch left out, the mean taken over the rest), ``no_exchange``
     (only the first quarter of the batch: what one of four chips sees
     when the gradient exchange is left out)."""
-    params = jax.jit(lambda k: ref.init_params(k, sz))(ref.seed_key(seed))
+    params = jax.jit(lambda k: ref.init_params(k, sz))(seed_key(seed))
     start = jax.device_get(params)
     m = jax.tree_util.tree_map(jnp.zeros_like, params)
     v = jax.tree_util.tree_map(jnp.zeros_like, params)
     grad_fn = jax.jit(jax.value_and_grad(
         functools.partial(ref.loss_sum, sz=sz, mode=mode)))
     add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b))
-    update = jax.jit(functools.partial(ref.adam_step, lr=lr, **ADAM),
+    update = jax.jit(functools.partial(adam_step, lr=lr),
                      static_argnames=("step",), donate_argnums=(0, 1, 2))
     scale = jax.jit(lambda t, s: jax.tree_util.tree_map(lambda a: a / s, t))
     # blocks of rows go round the local chips (dispatch does not wait), the
@@ -181,7 +180,7 @@ def reference_steps(sz: dict, seed: int, batches: list, lr: float,
             "start": start, "end": end}
 
 
-def train_gaps(ref_run: dict, losses: list, first_grad_norm: float,
+def train_gaps(ref, ref_run: dict, losses: list, first_grad_norm: float,
                end_params: dict) -> dict:
     """The program's three numbers against the reference's run.
 
@@ -194,12 +193,12 @@ def train_gaps(ref_run: dict, losses: list, first_grad_norm: float,
     whichever is larger. Leaves with a dead gradient are left out."""
     ref_losses = ref_run["losses"]
     loss_gap = max(abs(a - b) / abs(b) for a, b in zip(losses, ref_losses))
-    g = leaf_norms(ref_run["first_grad"])
+    g = leaf_norms(ref, ref_run["first_grad"])
     g_global = float(np.sqrt(sum(n * n for n in g.values())))
     grad_gap = abs(first_grad_norm - g_global) / g_global
-    start = split_leaves(ref_run["start"])
-    ref_end = split_leaves(ref_run["end"])
-    got_end = split_leaves(end_params)
+    start = ref.split_leaves(ref_run["start"])
+    ref_end = ref.split_leaves(ref_run["end"])
+    got_end = ref.split_leaves(end_params)
     g_median = float(np.median(list(g.values())))
     counted = [k for k in g if g[k] >= DEAD_GRADIENT_SHARE * g_median]
     ref_delta = {k: float(np.linalg.norm(ref_end[k] - start[k]))

@@ -25,28 +25,37 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import check, reference, run, setup_log, traffic  # noqa: E402
+from benchmark import check, family, run, setup_log, traffic  # noqa: E402
 
 TRAIN_VARIANTS = ({"mode": "control"}, {"fault": "half_batch"},
                   {"fault": "no_exchange"})
 
 
-def serving_seed(files: dict, seed: int, seconds: float, modes) -> dict:
+def family_of(files: dict, mode: str, root: str = run.ROOT):
+    """The cell's family, checked for a control in ``mode``."""
+    return family.resolve(files["config"], files["mix"]["kind"], mode, root)
+
+
+def serving_seed(files: dict, seed: int, seconds: float, modes,
+                 root: str = run.ROOT) -> dict:
     from benchmark import serving
 
+    fams = [family_of(files, mode, root) for mode in modes]
     clock = setup_log.SetupClock(setup_log.process_start())
-    state = serving.run(files["config"], files["mix"], seed, seconds, None,
+    state = serving.run(fams[0], files["mix"], seed, seconds, None,
                         clock, None, control_modes=tuple(modes))
     return state["numbers"]
 
 
-def training_seed(files: dict, seed: int, variants=TRAIN_VARIANTS) -> dict:
+def training_seed(files: dict, seed: int, variants=TRAIN_VARIANTS,
+                  root: str = run.ROOT) -> dict:
     import math
 
     import numpy as np
 
     cfg, mix = files["config"], files["mix"]
-    sz = reference.sizes(cfg)
+    fam = family_of(files, files["control_mode"], root)
+    ref, sz = fam.reference, fam.sz
     spec = cfg["program"]["trainer"]
     rows = int(mix["rows_per_chip"]) * math.prod(spec["mesh_axes"].values())
     steps = int(mix["check_steps"])
@@ -54,16 +63,16 @@ def training_seed(files: dict, seed: int, variants=TRAIN_VARIANTS) -> dict:
     batches = [(x[i * rows:(i + 1) * rows], y[i * rows:(i + 1) * rows])
                for i in range(steps)]
     lr = spec["learning_rate"]
-    ref_run = check.reference_steps(sz, seed, batches, lr)
+    ref_run = check.reference_steps(ref, sz, seed, batches, lr)
     out = {}
     for variant in variants:
         kw = dict(variant)
         name = kw.get("fault") or "control"
         if kw.get("mode") == "control":
             kw["mode"] = files["control_mode"]
-        other = check.reference_steps(sz, seed, batches, lr, **kw)
-        norms = check.leaf_norms(other["first_grad"]).values()
-        gaps = check.train_gaps(ref_run, other["losses"],
+        other = check.reference_steps(ref, sz, seed, batches, lr, **kw)
+        norms = check.leaf_norms(ref, other["first_grad"]).values()
+        gaps = check.train_gaps(ref, ref_run, other["losses"],
                                 float(np.sqrt(sum(n * n for n in norms))),
                                 other["end"])
         out[name] = {k: gaps[k] for k in

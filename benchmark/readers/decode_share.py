@@ -2,9 +2,13 @@
 ``flops``) or bytes (``of``: ``bytes``) of the window's mean micro-step,
 from the live lengths the recorder saw, over the mean device time of a
 micro-step in the traced part. Dead slots and rows past a slot's frontier
-are not counted, parameters count once at their stored width."""
-from benchmark import flops
+are not counted, parameters count once at their stored width. The count is
+the family's own (``decode_step_flops``, ``decode_step_bytes``)."""
 from benchmark.readers import decode_blocks, micro_step_seconds
+
+
+def counts_needed(spec):
+    return (f"decode_step_{spec['of']}",)
 
 
 def micro_steps(blocks):
@@ -21,9 +25,8 @@ def read(state, spec):
     seconds = micro_step_seconds(state, spec)
     if not steps or seconds is None:
         return None
-    fn = flops.decode_step_flops if spec["of"] == "flops" \
-        else flops.decode_step_bytes
-    work = sum(fn(state["sz"], lens) for lens in steps) / len(steps)
+    fn = getattr(state["counts"], counts_needed(spec)[0])
+    work = sum(fn(state["sz"], lens, spec) for lens in steps) / len(steps)
     rate = state["peak"]["flops_per_s" if spec["of"] == "flops"
                          else "hbm_bytes_per_s"]
     return 100.0 * work / rate / seconds

@@ -1,7 +1,10 @@
 """Operations of the window's mean prompt at its TRUE length (not its
 bucket) over the mean device time of a prefill program in the traced part
-and the chip's peak."""
-from benchmark import flops
+and the chip's peak. The count is the family's own (``prefill_flops``)."""
+
+
+def counts_needed(spec):
+    return ("prefill_flops",)
 
 
 def read(state, spec):
@@ -11,5 +14,6 @@ def read(state, spec):
     if not mods or not lens:
         return None
     seconds = sum(e - s for s, e, _ in mods) / len(mods) / 1e9
-    work = sum(flops.prefill_flops(state["sz"], n) for n in lens) / len(lens)
+    fn = state["counts"].prefill_flops
+    work = sum(fn(state["sz"], n, spec) for n in lens) / len(lens)
     return 100.0 * work / state["peak"]["flops_per_s"] / seconds

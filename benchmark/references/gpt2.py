@@ -1,4 +1,6 @@
-"""The plain reference: GPT-2 in straightforward ``jax.numpy``, float32.
+"""The plain reference of the ``gpt2`` family (what a configuration with no
+``program.reference`` means): GPT-2 in straightforward ``jax.numpy``,
+float32.
 
 It follows the published description (Radford et al. 2019; the
 ``config.json`` each configuration file names): learned positions,
@@ -13,9 +15,9 @@ embedding and has a bias (this system builds every LM so), and the
 weights are random from a seed.
 
 This module imports nothing of the program and takes nothing the program
-has made. The weights come from :func:`init_params`; the harness calls
-the same function, in one jitted call, to make the weights it hands to
-the program.
+has made. The weights come from :func:`init_params` and the run's key
+(``benchmark.family.seed_key``); the harness calls the same function, in
+one jitted call, to make the weights it hands to the program.
 
 ``mode`` selects the arithmetic of the linear layers and is what the
 CONTROL changes: ``"f32"`` is the reference; ``"bf16"``, ``"int8"`` and
@@ -30,8 +32,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 HI = jax.lax.Precision.HIGHEST
+#: the arithmetic the linear layers can run in: a cell's ``control_mode``
+#: is one of these, and ``f32`` is the reference itself
+MODES = ("f32", "bf16", "int8", "fp8")
 
 #: per-layer leaves: name -> (shape from sizes, init kind)
 LAYER_LEAVES = {
@@ -63,14 +69,6 @@ def leaf_shape(name: str, sz: dict) -> tuple:
     return tuple(sz[k] for k in dims)
 
 
-def seed_key(seed: int):
-    """A key from any whole number up to 2**63: a seed past 31 bits is
-    folded in two halves."""
-    seed = int(seed)
-    key = jax.random.PRNGKey(seed & 0x7FFFFFFF)
-    return jax.random.fold_in(key, (seed >> 31) & 0x7FFFFFFF)
-
-
 def _name_id(name: str) -> int:
     names = sorted(list(LAYER_LEAVES) + list(GLOBAL_LEAVES))
     return names.index(name)
@@ -93,7 +91,7 @@ def make_leaf(key, name: str, sz: dict):
 
 
 def init_params(key, sz: dict) -> dict:
-    """The parameters from :func:`seed_key`'s key: per-layer leaves
+    """The parameters from the run's key: per-layer leaves
     stacked on a leading layer axis (for ``lax.scan``), global leaves as
     they are. Traceable: call it under ``jax.jit``."""
     return {n: make_leaf(key, n, sz)
@@ -205,7 +203,16 @@ def served_gaps(params: dict, seq, first: int, n: int, sz: dict,
     return served, control
 
 
-# -- training: loss, gradients, Adam -----------------------------------------
+def served_gaps_fn(sz: dict, key, mode: str = "f32"):
+    """What ``check.served_gaps`` calls for each ``(seq, first, n)``: the
+    parameters made whole from ``key`` in one jitted call, and one
+    compiled :func:`served_gaps` over them."""
+    params = jax.jit(lambda k: init_params(k, sz))(key)
+    fn = jax.jit(functools.partial(served_gaps, sz=sz, mode=mode))
+    return lambda seq, first, n: fn(params, seq, first, n)
+
+
+# -- training: the loss, and the grain leaves are compared at -----------------
 
 
 def loss_sum(params: dict, x, y, sz: dict, mode: str = "f32"):
@@ -216,15 +223,20 @@ def loss_sum(params: dict, x, y, sz: dict, mode: str = "f32"):
     return jnp.sum(logz - picked)
 
 
-def adam_step(params, m, v, grads, step: int, lr: float,
-              b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8):
-    """One Adam update (Kingma & Ba 2015, with bias correction); ``step``
-    counts from 1."""
-    m = jax.tree_util.tree_map(lambda a, g: b1 * a + (1 - b1) * g, m, grads)
-    v = jax.tree_util.tree_map(
-        lambda a, g: b2 * a + (1 - b2) * g * g, v, grads)
-    c1, c2 = 1 - b1 ** step, 1 - b2 ** step
-    params = jax.tree_util.tree_map(
-        lambda p, a, s: p - lr * (a / c1) / (jnp.sqrt(s / c2) + eps),
-        params, m, v)
-    return params, m, v
+def split_leaves(tree: dict) -> dict:
+    """Leaves at the grain they are compared at: every layer of a stacked
+    leaf apart, and the fused QKV leaves in their three parts (the key
+    bias has no gradient under softmax while the query's and the value's
+    have). Values are 1-D float64 numpy arrays."""
+    out = {}
+    for name, leaf in tree.items():
+        leaf = np.asarray(leaf, np.float64)
+        layers = leaf if name in LAYER_LEAVES else leaf[None]
+        for i, row in enumerate(layers):
+            tag = f"{name}[{i}]" if name in LAYER_LEAVES else name
+            if name.startswith("qkv_"):
+                for part, piece in zip("qkv", np.split(row, 3, axis=-1)):
+                    out[f"{tag}.{part}"] = piece.reshape(-1)
+            else:
+                out[tag] = row.reshape(-1)
+    return out

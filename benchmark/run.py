@@ -4,7 +4,9 @@
 
 (``python -m benchmark.run`` is the same.) Everything that belongs to one
 configuration, one traffic mix, one cell or one metric is a data file that
-this runner finds by name; see ``benchmark/README.md``. It needs a TPU with
+this runner finds by name, and whatever belongs to one model family or one
+kind of reading is a module found the same way, under the same root; see
+``benchmark/README.md``. It needs a TPU with
 as many chips as the cell asks for and exits 2, printing no result,
 without one. The last line of standard output is the result; the line
 before it says how ``setup_s`` divides.
@@ -25,7 +27,7 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:   # started as a file: make ``benchmark`` importable
     sys.path.insert(0, ROOT)
 
-from benchmark import setup_log  # noqa: E402
+from benchmark import family, setup_log  # noqa: E402
 
 T_START = setup_log.process_start()
 SERVING_KINDS = ("backlog", "open-loop")
@@ -62,13 +64,28 @@ def metrics_for(manifest: dict, workload: str, trace: bool) -> list[dict]:
     return [m for m in group if workload in m.get("workloads", [workload])]
 
 
+def readers_for(entries: list[dict], root: str = ROOT) -> list[tuple]:
+    """Each metric with its file and the reader that file names."""
+    out = []
+    for entry in entries:
+        spec = load_json(root, "benchmark", "metrics", f"{entry['name']}.json")
+        out.append((entry, spec, family.load(root, "readers", spec["reader"])))
+    return out
+
+
+def counts_needed(entries: list[dict], root: str = ROOT) -> list[str]:
+    """The counting functions these metrics' readers will ask the family's
+    counts module for."""
+    return sorted({name for _, spec, reader in readers_for(entries, root)
+                   if hasattr(reader, "counts_needed")
+                   for name in reader.counts_needed(spec)})
+
+
 def evaluate(entries: list[dict], state: dict, root: str = ROOT) -> dict:
     """Each metric through the reader its file names. A reader that finds
     nothing to read returns None and the metric is left out."""
     out = {}
-    for entry in entries:
-        spec = load_json(root, "benchmark", "metrics", f"{entry['name']}.json")
-        reader = importlib.import_module(f"benchmark.readers.{spec['reader']}")
+    for entry, spec, reader in readers_for(entries, root):
         value = reader.read(state, spec)
         if value is not None:
             out[entry["name"]] = {"value": float(value), "unit": entry["unit"]}
@@ -112,6 +129,11 @@ def run_cell(manifest: dict, files: dict, workload: str, seed: int,
     trace, the comparison and the metrics. Returns the result line and the
     line that divides ``setup_s``."""
     cell, cfg, mix = files["cell"], files["config"], files["mix"]
+    entries = metrics_for(manifest, workload, trace)
+    # what the family's modules lack is said now, not after the window
+    fam = family.resolve(cfg, mix["kind"], files["control_mode"], root)
+    family.require(fam.counts, counts_needed(
+        metrics_for(manifest, workload, True), root), "the counts module")
     log = setup_log.CompileLog()
     clock = setup_log.SetupClock(T_START)
     clock.mark("import_and_backend")
@@ -120,9 +142,10 @@ def run_cell(manifest: dict, files: dict, workload: str, seed: int,
         shutil.rmtree(trace_dir, ignore_errors=True)
     module = "serving" if mix["kind"] in SERVING_KINDS else "training"
     runner = importlib.import_module(f"benchmark.{module}")
-    state = runner.run(cfg, mix, seed, seconds, trace_dir, clock, log)
+    state = runner.run(fam, mix, seed, seconds, trace_dir, clock, log)
     state.update(peak=peak, compile_log=log, chips=int(cell["chips"]),
-                 setup_s=state["t_open"] - T_START, mix=mix)
+                 setup_s=state["t_open"] - T_START, mix=mix,
+                 sz=fam.sz, counts=fam.counts)
     device = dict(device, memory_peak_bytes=state["peak_bytes"])
     line = {}
     if trace_dir:
@@ -140,7 +163,7 @@ def run_cell(manifest: dict, files: dict, workload: str, seed: int,
     from benchmark import check
 
     correct, compared = check.verdict(state["numbers"], files["limits"])
-    metrics = evaluate(metrics_for(manifest, workload, trace), state, root)
+    metrics = evaluate(entries, state, root)
     result = {
         "correct": correct, "attempted": state["attempted"],
         "failed": state["failed"], "metrics": metrics, "device": device,

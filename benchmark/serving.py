@@ -11,40 +11,41 @@ none from the program's log-bucketed histograms.
 from __future__ import annotations
 
 import gc
-import importlib
 import time
 
 import numpy as np
 
-from benchmark import check, reference, traffic
+from benchmark import check, traffic
+from benchmark.family import seed_key
 
 #: the tail of the window that a ``--trace 1`` run traces
 TRACE_SECONDS = 5.0
 STEP_SPAN = "bench.engine_step"
 
 
-def build_weights(cfg: dict, seed: int):
+def build_weights(fam, seed: int):
     """The program's variables, made on the device in one jitted call
     from the seed (the reference makes the same numbers for itself)."""
     import jax
 
-    sz = reference.sizes(cfg)
-    adapter = importlib.import_module(
-        f"benchmark.adapters.{cfg['program']['adapter']}")
-    make = jax.jit(lambda key: adapter.to_program(
-        reference.init_params(key, sz), sz["layers"]))
-    return jax.block_until_ready(make(reference.seed_key(seed)))
+    make = jax.jit(lambda key: fam.adapter.to_program(
+        fam.reference.init_params(key, fam.sz), fam.sz))
+    return jax.block_until_ready(make(seed_key(seed)))
 
 
-def build_engine(cfg: dict, variables, max_queue: int):
-    from mmlspark_tpu.core.telemetry import FlightRecorder
+def build_graph(fam):
     from mmlspark_tpu.models import build_model
+
+    return build_model(fam.builder, **fam.cfg["program"]["model"])
+
+
+def build_engine(fam, variables, max_queue: int):
+    from mmlspark_tpu.core.telemetry import FlightRecorder
     from mmlspark_tpu.serve.engine import ServeEngine
 
-    graph = build_model(cfg["program"]["adapter"], **cfg["program"]["model"])
     recorder = FlightRecorder(capacity=1 << 21)
-    return ServeEngine(graph, variables, recorder=recorder,
-                       max_queue=max_queue, **cfg["program"]["engine"])
+    return ServeEngine(build_graph(fam), variables, recorder=recorder,
+                       max_queue=max_queue, **fam.cfg["program"]["engine"])
 
 
 def warm_up(engine, mix: dict, vocab: int, slots: int) -> None:
@@ -219,14 +220,14 @@ def request_table(events: list, loop: Loop) -> list[dict]:
     return loop.rows
 
 
-def prepare(cfg: dict, mix: dict, seed: int, max_queue: int, clock):
+def prepare(fam, mix: dict, seed: int, max_queue: int, clock):
     """Weights, engine and warm-up: the engine ready for its traffic."""
-    sz = reference.sizes(cfg)
-    variables = build_weights(cfg, seed)
+    variables = build_weights(fam, seed)
     clock.mark("weights")
-    engine = build_engine(cfg, variables, max_queue)
+    engine = build_engine(fam, variables, max_queue)
     clock.mark("engine")
-    warm_up(engine, mix, sz["v"], int(cfg["program"]["engine"]["slots"]))
+    warm_up(engine, mix, fam.sz["v"],
+            int(fam.cfg["program"]["engine"]["slots"]))
     clock.mark("warmup")
     return engine
 
@@ -251,26 +252,27 @@ def drive(engine, cfg: dict, mix: dict, reqs: list, seconds: float,
     return loop, t_open, t_close
 
 
-def requests_for(cfg: dict, mix: dict, seed: int, seconds: float) -> tuple:
+def requests_for(fam, mix: dict, seed: int, seconds: float) -> tuple:
     """The mix's requests and the queue they need."""
-    vocab = reference.sizes(cfg)["v"]
+    vocab = fam.sz["v"]
     if mix["kind"] == "backlog":
-        slots = int(cfg["program"]["engine"]["slots"])
+        slots = int(fam.cfg["program"]["engine"]["slots"])
         return (traffic.backlog_requests(mix, vocab, seed),
                 slots + int(mix["queued"]) + 1)
     reqs = traffic.open_loop_requests(mix, vocab, seed, seconds)
     return reqs, len(reqs)
 
 
-def run(cfg: dict, mix: dict, seed: int, seconds: float, trace_dir,
+def run(fam, mix: dict, seed: int, seconds: float, trace_dir,
         clock, log, control_modes: tuple = ()) -> dict:
     import jax
 
-    sz = reference.sizes(cfg)
-    reqs, max_queue = requests_for(cfg, mix, seed, seconds)
-    engine = prepare(cfg, mix, seed, max_queue, clock)
+    ref, sz = fam.reference, fam.sz
+    reqs, max_queue = requests_for(fam, mix, seed, seconds)
+    engine = prepare(fam, mix, seed, max_queue, clock)
     tracer = Tracer(trace_dir)
-    loop, t_open, t_close = drive(engine, cfg, mix, reqs, seconds, tracer)
+    loop, t_open, t_close = drive(engine, fam.cfg, mix, reqs, seconds,
+                                  tracer)
     clock.mark("fill", at=t_open)
     events = engine.recorder.events()
     rows = request_table(events, loop)
@@ -295,16 +297,16 @@ def run(cfg: dict, mix: dict, seed: int, seconds: float, trace_dir,
             if r.get("status") == "completed" and r["finished"] > t_open
             and len(r.get("served", ()))]
     samples = check.sample_requests(done, int(mix["check_requests"]), seed)
-    numbers = check.served_gaps(sz, seed, samples, cache_len)
+    numbers = check.served_gaps(ref, sz, seed, samples, cache_len)
     for mode in control_modes:   # only benchmark/limits.py asks for these
         numbers[f"control_gap.{mode}"] = check.served_gaps(
-            sz, seed, samples, cache_len, mode)["control_gap"]
+            ref, sz, seed, samples, cache_len, mode)["control_gap"]
     numbers["unanswered"] = unanswered + failed
     ends = loop.tick_ends
     ticks = {"before_window": sum(1 for t in ends if t <= t_open),
              "ms": [round((b - a) * 1e3, 1) for a, b in zip(ends, ends[1:])
                     if t_open <= a and b <= t_close]}
-    return {"kind": mix["kind"], "sz": sz, "seconds": seconds, "ticks": ticks,
+    return {"kind": mix["kind"], "seconds": seconds, "ticks": ticks,
             "t_open": t_open, "t_close": t_close, "events": events,
             "requests": rows, "peak_bytes": peak, "numbers": numbers,
             "attempted": len(judged), "failed": failed + unanswered,

@@ -23,7 +23,7 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import run, serving, setup_log  # noqa: E402
+from benchmark import family, run, serving, setup_log  # noqa: E402
 from benchmark.readers import quantile  # noqa: E402
 
 
@@ -37,15 +37,16 @@ def main(argv=None) -> int:
     manifest = run.load_json(ROOT, "BENCHMARK.json")
     files = run.cell_files(manifest, args.workload)
     cfg, mix = files["config"], files["mix"]
+    fam = family.resolve(cfg, mix["kind"])
     run.chips_or_exit(1)
     run.compile_cache()
     clock = setup_log.SetupClock(setup_log.process_start())
     top = dict(mix, rate_per_s=max(args.rates))
-    _, max_queue = serving.requests_for(cfg, top, args.seed, args.seconds)
-    engine = serving.prepare(cfg, mix, args.seed, max_queue, clock)
+    _, max_queue = serving.requests_for(fam, top, args.seed, args.seconds)
+    engine = serving.prepare(fam, mix, args.seed, max_queue, clock)
     for rate in args.rates:
         at = dict(mix, rate_per_s=rate)
-        reqs, _ = serving.requests_for(cfg, at, args.seed, args.seconds)
+        reqs, _ = serving.requests_for(fam, at, args.seed, args.seconds)
         loop, t_open, t_close = serving.drive(
             engine, cfg, at, reqs, args.seconds, serving.Tracer(None))
         rows = serving.request_table(engine.recorder.events(), loop)

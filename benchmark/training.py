@@ -17,13 +17,12 @@ and fetch the same executable from the persistent cache.
 from __future__ import annotations
 
 import gc
-import importlib
 import math
 import time
 
 import numpy as np
 
-from benchmark import check, reference, serving, traffic
+from benchmark import check, serving, traffic
 
 
 class WindowClosed(Exception):
@@ -62,24 +61,23 @@ def make_recorder(tracer):
     return WindowRecorder(capacity=1 << 16)
 
 
-def run(cfg: dict, mix: dict, seed: int, seconds: float, trace_dir,
+def run(fam, mix: dict, seed: int, seconds: float, trace_dir,
         clock, log) -> dict:
     import jax
 
-    from mmlspark_tpu.models import build_model
     from mmlspark_tpu.train.trainer import SPMDTrainer, TrainConfig
 
-    sz = reference.sizes(cfg)
-    spec = cfg["program"]["trainer"]
+    ref, sz = fam.reference, fam.sz
+    spec = fam.cfg["program"]["trainer"]
     chips = math.prod(spec["mesh_axes"].values())
     rows, seq = int(mix["rows_per_chip"]) * chips, int(mix["seq"])
     check_steps, warm = int(mix["check_steps"]), int(mix["warm_steps"])
     steps = warm + math.ceil(seconds * float(mix["steps_per_s_ceiling"])) + 1
     x, y = traffic.train_batches(sz["v"], seed, rows * (check_steps + steps),
                                  seq)
-    start = jax.device_get(serving.build_weights(cfg, seed))
+    start = jax.device_get(serving.build_weights(fam, seed))
     clock.mark("weights")
-    graph = build_model(cfg["program"]["adapter"], **cfg["program"]["model"])
+    graph = serving.build_graph(fam)
     tracer = serving.Tracer(trace_dir)
     recorder = make_recorder(tracer)
     trainer = SPMDTrainer(graph, TrainConfig(
@@ -110,17 +108,16 @@ def run(cfg: dict, mix: dict, seed: int, seconds: float, trace_dir,
     del trainer
     gc.collect()
 
-    ref_run = check.reference_steps(
-        sz, seed, [(x[i * rows:(i + 1) * rows], y[i * rows:(i + 1) * rows])
-                   for i in range(check_steps)], spec["learning_rate"])
-    adapter = importlib.import_module(
-        f"benchmark.adapters.{cfg['program']['adapter']}")
+    batches = [(x[i * rows:(i + 1) * rows], y[i * rows:(i + 1) * rows])
+               for i in range(check_steps)]
+    ref_run = check.reference_steps(ref, sz, seed, batches,
+                                    spec["learning_rate"])
     numbers = check.train_gaps(
-        ref_run, [h["loss"] for h in first], first[0]["grad_norm"],
-        adapter.from_program(after, sz["layers"], np.stack))
+        ref, ref_run, [h["loss"] for h in first], first[0]["grad_norm"],
+        fam.adapter.from_program(after, sz, np.stack))
     in_window = [e["t"] for e in events if e["name"] == "step"
                  and recorder.t_open < e["t"] <= recorder.t_close]
-    return {"kind": "train", "sz": sz, "seconds": seconds,
+    return {"kind": "train", "seconds": seconds,
             "t_open": recorder.t_open, "t_close": recorder.t_close,
             "events": events, "peak_bytes": peak, "numbers": numbers,
             "attempted": len(in_window), "failed": 0, "chips": chips,

@@ -18,7 +18,7 @@ import json
 import numpy as np
 import pytest
 
-from benchmark import check, limits, reference, run
+from benchmark import check, family, limits, run
 
 import perfbench_tiny as tiny
 
@@ -40,7 +40,7 @@ def test_the_training_control_fails_and_the_faults_fail(tree):
     root, manifest = tree
     files = run.cell_files(manifest, "tiny.tiny-train", root)
     files["control_mode"] = "fp8"
-    readings = limits.training_seed(files, seed=2)
+    readings = limits.training_seed(files, seed=2, root=root)
     lim = files["limits"]
     ok, _ = check.verdict(readings["control"], lim)
     assert not ok and readings["control"]["loss_gap"] > lim["loss_gap"]
@@ -51,6 +51,7 @@ def test_the_training_control_fails_and_the_faults_fail(tree):
 
 
 def test_the_serving_control_reads_wider_than_the_stated_precision():
+    ref = family.load(run.ROOT, "references", "gpt2")
     sz = {"d": 64, "3d": 192, "f": 256, "v": 512, "p": 64, "layers": 2,
           "heads": 4, "eps": 1e-6}
     rng = np.random.default_rng(0)
@@ -58,9 +59,9 @@ def test_the_serving_control_reads_wider_than_the_stated_precision():
                 rng.integers(0, 512, 40).astype(np.int32)) for _ in range(6)]
     stated, control = [], []
     for seed in (1, 2, 3):
-        stated.append(check.served_gaps(sz, seed, samples, 64,
+        stated.append(check.served_gaps(ref, sz, seed, samples, 64,
                                         "bf16")["control_gap"])
-        control.append(check.served_gaps(sz, seed, samples, 64,
+        control.append(check.served_gaps(ref, sz, seed, samples, 64,
                                          "fp8")["control_gap"])
     assert min(control) > 3 * max(stated) > 0
 
@@ -75,23 +76,24 @@ def test_a_missing_or_infinite_number_is_not_correct():
 
 
 def test_leaves_with_a_dead_gradient_are_left_out_by_rule_not_by_name():
+    ref = family.load(run.ROOT, "references", "gpt2")
     sz = {"d": 32, "3d": 96, "f": 128, "v": 96, "p": 32, "layers": 2,
           "heads": 4, "eps": 1e-6}
     x, y = (np.random.default_rng(1).integers(0, 96, (3, 4, 32))
             .astype(np.int32) for _ in range(2))
-    ref_run = check.reference_steps(sz, 5, list(zip(x, y)), 1e-4)
-    grads = check.leaf_norms(ref_run["first_grad"])
+    ref_run = check.reference_steps(ref, sz, 5, list(zip(x, y)), 1e-4)
+    grads = check.leaf_norms(ref, ref_run["first_grad"])
     median = np.median(list(grads.values()))
     dead = {k for k, g in grads.items()
             if g < check.DEAD_GRADIENT_SHARE * median}
     assert dead == {"qkv_b[0].k", "qkv_b[1].k"}
-    gaps = check.train_gaps(ref_run, ref_run["losses"],
+    gaps = check.train_gaps(ref, ref_run, ref_run["losses"],
                             float(np.sqrt(sum(g * g for g in grads.values()))),
                             ref_run["end"])
     assert gaps["leaves_left_out"] == 2
     assert gaps["loss_gap"] == gaps["grad_gap"] == gaps["delta_gap"] == 0.0
     # a state left unchanged reads 1 on the change, whatever the seed
-    still = check.train_gaps(ref_run, ref_run["losses"], 1.0,
+    still = check.train_gaps(ref, ref_run, ref_run["losses"], 1.0,
                              ref_run["start"])
     assert still["delta_gap"] == pytest.approx(1.0)
 

@@ -1,6 +1,7 @@
 """The reduction from a trace to numbers, on hand-made intervals and on a
-small trace recorded on the chip (``data/``), and ``flops.py`` against hand
-counts. No topology is described and no chip is needed."""
+small trace recorded on the chip (``data/``), and the ``gpt2`` family's
+counts (``counts/gpt2.py``) against hand counts. No topology is described
+and no chip is needed."""
 
 import gzip
 import json
@@ -8,14 +9,22 @@ import os
 
 import pytest
 
-from benchmark import flops, reference, trace_reduce
+from benchmark import family, trace_reduce
+from benchmark.flops import roofline_share
 from benchmark.readers import decode_share, module_gap, module_ms
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 RECORDED = os.path.join(HERE, "data", "decode_ticks.xplane.pb.gz")
-with open(os.path.join(HERE, "..", "..", "benchmark", "configs",
-                       "gpt2-large.json")) as f:
-    LARGE = reference.sizes(json.load(f))
+
+
+def sizes_of(config: str) -> dict:
+    with open(os.path.join(family.ROOT, "benchmark", "configs",
+                           f"{config}.json")) as f:
+        return family.resolve(json.load(f), "backlog").sz
+
+
+flops = family.load(family.ROOT, "counts", "gpt2")
+LARGE = sizes_of("gpt2-large")
 with open(os.path.join(HERE, "..", "..", "benchmark", "peaks.json")) as f:
     V5E = json.load(f)["TPU v5 lite"]
 
@@ -79,7 +88,7 @@ def test_readers_of_programs_read_gaps_and_micro_steps():
               for i in range(2)]
     one_layer = dict(LARGE, layers=1)   # the toy runs the kernel once a step
     state = {"trace": t, "events": events, "t_open": 0.0, "t_close": 9.0,
-             "sz": one_layer, "peak": V5E}
+             "sz": one_layer, "counts": flops, "peak": V5E}
     spec = {"module": "decode_block", "op": r"^attn\.", "per": "micro_step"}
     assert module_gap.read(state, spec) == pytest.approx(6.0)
     # two programs, 4 ms together, two kernel calls: two micro-steps
@@ -143,7 +152,7 @@ def test_one_decode_micro_step_by_hand():
     assert flops.decode_step_bytes(LARGE, lens) < full
     # at the chip's peaks a step that took its byte floor reads 100, not more
     floor = flops.decode_step_bytes(LARGE, lens) / V5E["hbm_bytes_per_s"]
-    share, side = flops.roofline_share(
+    share, side = roofline_share(
         flops.decode_step_flops(LARGE, lens),
         flops.decode_step_bytes(LARGE, lens), floor, V5E)
     assert side == "memory" and share == pytest.approx(100.0)
@@ -157,13 +166,11 @@ def test_one_prefill_of_640_tokens_by_hand():
     assert flops.prefill_flops(LARGE, 640) < 0.7 * flops.prefill_flops(
         LARGE, 1024)
     assert flops.attn_prefill_bytes(LARGE, 640) == 4 * 640 * 1280 * 2
-    assert flops.roofline_share(1.0, 1.0, 0.0, V5E) is None
+    assert roofline_share(1.0, 1.0, 0.0, V5E) is None
 
 
 def test_training_counts_forward_and_backward_once():
-    with open(os.path.join(HERE, "..", "..", "benchmark", "configs",
-                           "gpt2-medium.json")) as f:
-        medium = reference.sizes(json.load(f))
+    medium = sizes_of("gpt2-medium")
     weights = 24 * 12 * 1024 * 1024 + 1024 * 50257
     assert flops.matmul_params(medium) == weights
     attn = 24 * 3 * (4 * 1024 * (1024 * 1025 // 2)) / 1024
