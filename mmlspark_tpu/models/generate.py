@@ -38,30 +38,43 @@ from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.models.graph import _accepts_kwarg
 
 
-def cache_geometry(graph, variables) -> dict:
-    """``{block name: (kv_heads, head_dim)}`` for every block that takes
-    a ``cache`` kwarg, read off the fused qkv kernel so it stays correct
-    for any (heads, kv_heads, head_dim) build. Shared by
-    :func:`init_cache` (per-call decode buffers) and the serving engine's
-    slot pool (:mod:`mmlspark_tpu.serve.cache_pool`), which preallocates
-    the same shapes once per process.
+#: the cache kinds a block can have: ``linear`` (what a block that declares
+#: nothing gets: a row for every position, laid out ``(B, rows, hk, d)``),
+#: and the two a block DECLARES through ``cache_spec()``: ``full`` (a row
+#: for every position) and ``ring`` (the last ``rows`` positions, position
+#: ``p`` in row ``p % rows``), which the serving pool lays out head-major,
+#: ``(S, hk, rows, d)`` (models/hybrid.py, serve/cache_pool.py)
+LINEAR, FULL_ROWS, RING_ROWS = "linear", "full", "ring"
+
+
+def cache_specs(graph, variables) -> dict:
+    """``{block name: (kind, rows, kv_heads, key width, value width)}``
+    for every block that takes a ``cache`` kwarg. A block that has a
+    ``cache_spec()`` DECLARES its geometry (``rows`` None means every
+    position); for any other the geometry is read off its fused qkv
+    kernel, as one ``linear`` kind whose keys and values are equally
+    wide. Shared by :func:`init_cache` and the serving pools.
 
     Raises :class:`FriendlyError` (never a bare KeyError — the decode-API
     fuzz contract) when ``graph.extra`` lacks the ``heads`` metadata or a
     cache-accepting block's variables lack the ``attn/qkv`` param path
     the geometry is read from."""
+    cached = [(name, mod) for name, mod in graph.blocks
+              if _accepts_kwarg(mod, "cache")]
     heads = graph.extra.get("heads")
-    if not heads:
+    if not heads and not (
+            cached and all(hasattr(mod, "cache_spec") for _, mod in cached)):
         raise FriendlyError(
             f"KV-cache decode needs graph.extra['heads'] to size the "
             f"cache buffers; '{graph.name}' does not record it — register "
             "the model builder with heads metadata in extra"
         )
-    hk = graph.extra.get("kv_heads") or heads
-    geometry = {}
-    for name, mod in graph.blocks:
-        if not _accepts_kwarg(mod, "cache"):
+    specs = {}
+    for name, mod in cached:
+        if hasattr(mod, "cache_spec"):
+            specs[name] = tuple(mod.cache_spec())
             continue
+        hk = graph.extra.get("kv_heads") or heads
         try:
             kern = variables[name]["params"]["attn"]["qkv"]["kernel"]
         except (KeyError, TypeError) as e:
@@ -78,23 +91,52 @@ def cache_geometry(graph, variables) -> dict:
             from mmlspark_tpu.ops.quantize import _Q8
 
             kern = kern[_Q8]
-        geometry[name] = (hk, kern.shape[1] // (heads + 2 * hk))
+        d = kern.shape[1] // (heads + 2 * hk)
+        specs[name] = (LINEAR, None, hk, d, d)
+    return specs
+
+
+def declares_cache_kinds(graph) -> bool:
+    """Whether any block of ``graph`` declares its own cache geometry
+    (rings, or keys and values of different widths)."""
+    return any(hasattr(mod, "cache_spec") for _, mod in graph.blocks)
+
+
+def cache_geometry(graph, variables) -> dict:
+    """``{block name: (kv_heads, head_dim)}``: :func:`cache_specs` for
+    the callers that hold linear rows of one width only (the paged pool,
+    the int8 rows). Raises :class:`FriendlyError` for a graph whose
+    blocks declare another geometry."""
+    geometry = {}
+    for name, (kind, _rows, hk, dk, dv) in cache_specs(
+            graph, variables).items():
+        if kind != LINEAR or dk != dv:
+            raise FriendlyError(
+                f"block '{name}' of '{graph.name}' declares a '{kind}' "
+                f"cache with keys {dk} and values {dv} wide; only the "
+                "dense bf16 slot pool (SlotCachePool) holds declared "
+                "geometries — the paged pool, int8 rows, the KV hand-off "
+                "and a mesh keep linear rows of one width"
+            )
+        geometry[name] = (hk, dk)
     return geometry
 
 
 def init_cache(graph, variables, batch: int, total: int) -> dict:
-    """Preallocated per-block K/V decode buffers, ``(B, total, hk, d)``
-    bf16 zeros for every block that takes a ``cache`` kwarg (geometry
-    from :func:`cache_geometry`)."""
+    """Preallocated per-block LINEAR K/V decode buffers, ``(B, total,
+    hk, dk)`` and ``(B, total, hk, dv)`` bf16 zeros for every block that
+    takes a ``cache`` kwarg (geometry from :func:`cache_specs`): what a
+    prefill fills, whatever kind the serving pool then keeps."""
     cache = {}
-    for name, (hk, d) in cache_geometry(graph, variables).items():
-        buf = jnp.zeros((batch, total, hk, d), jnp.bfloat16)
-        cache[name] = (buf, buf)
+    for name, (_kind, _rows, hk, dk, dv) in cache_specs(
+            graph, variables).items():
+        cache[name] = (jnp.zeros((batch, total, hk, dk), jnp.bfloat16),
+                       jnp.zeros((batch, total, hk, dv), jnp.bfloat16))
     return cache
 
 
 def _cached_apply(graph, variables, ids, cache, pos, rolled=False,
-                  step=False, live=None):
+                  step=False, live=None, valid=None, counters=None):
     """One forward over ``ids`` (B, T) starting at absolute position
     ``pos`` (traced ok), reading/writing the K/V cache. Returns
     (logits (B, T, V), new cache). ``rolled`` switches the blocks to
@@ -104,7 +146,11 @@ def _cached_apply(graph, variables, ids, cache, pos, rolled=False,
     one-token PROMPT is still a prefill and must route with scoring
     semantics. ``live`` ((B,) bool, serving's fused decode blocks only)
     zeroes dead rows' flash-decode live lengths so the kernel skips
-    their cache reads; only blocks that declare the kwarg receive it."""
+    their cache reads; only blocks that declare the kwarg receive it.
+    ``valid`` ((B, T) bool) marks the real tokens for blocks that route
+    (a pad or a dead row routes nowhere), and such a block hands back a
+    third value, its counters, which land in ``counters[name]`` when a
+    dict is given."""
     x = ids
     new_cache = dict(cache)
     for name, mod in graph.blocks:
@@ -115,12 +161,34 @@ def _cached_apply(graph, variables, ids, cache, pos, rolled=False,
                 kwargs["decode"] = step
             if live is not None and _accepts_kwarg(mod, "live"):
                 kwargs["live"] = live
-            x, new_cache[name] = mod.apply(v, x, **kwargs)
+            if valid is not None and _accepts_kwarg(mod, "valid"):
+                kwargs["valid"] = valid
+            x, new_cache[name], *counted = mod.apply(v, x, **kwargs)
+            if counted and counters is not None:
+                counters[name] = counted[0]
         elif _accepts_kwarg(mod, "pos"):
             x = mod.apply(v, x, pos=pos)
         else:
             x = mod.apply(v, x)
     return x, new_cache
+
+
+def counts_routing(graph) -> bool:
+    """Whether ``graph`` has blocks that route tokens to experts and
+    count it (``block.routed``): its decode block and its prefill then
+    return their counters beside their tokens."""
+    return any(getattr(mod, "routed", False) for _, mod in graph.blocks)
+
+
+def routing_totals(counters: dict) -> dict:
+    """Per-block routing counters ``{block: {"pairs", "hit"}}`` as two
+    vectors over the routed blocks, in the graph's order:
+    ``expert_pairs`` and ``experts_hit``."""
+    names = sorted(counters, key=lambda n: (len(n), n))
+    return {
+        "expert_pairs": jnp.stack([counters[n]["pairs"] for n in names]),
+        "experts_hit": jnp.stack([counters[n]["hit"] for n in names]),
+    }
 
 
 def greedy_next(logits):
@@ -158,10 +226,14 @@ def make_decode_block(graph, pad_id: int = 0):
 
     Returns ``(tokens (S, t), live (S,), buffers, pos)`` where the
     final ``live`` is the per-slot finished vector (False = the row
-    died inside this block). Parity contract: a row's token stream is
-    bit-identical to single-request greedy ``generate()`` up to and
-    including its EOS / last budgeted token; columns after that are
-    pads the host discards.
+    died inside this block). For a graph that routes tokens to experts
+    (:func:`counts_routing`) a fifth value follows: ``{"expert_pairs",
+    "experts_hit"}``, per routed block the (token, expert) pairs that
+    fell on held experts and the held experts hit, summed over the
+    block's micro-steps; it comes back in the same fetch as the tokens.
+    Parity contract: a row's token stream is bit-identical to
+    single-request greedy ``generate()`` up to and including its EOS /
+    last budgeted token; columns after that are pads the host discards.
 
     The block is GSPMD-cleanly partitionable: every per-slot input
     (``pos``/``live``/``tok``/``rem``/``eos``, the buffers' slot dim)
@@ -174,6 +246,8 @@ def make_decode_block(graph, pad_id: int = 0):
     (docs/SERVING.md "Sharded serving").
     """
 
+    routed = counts_routing(graph)
+
     def decode_block(variables, buffers, pos, live, tok, rem, eos, t):
         def micro(carry, _):
             tok, buffers, pos, live, rem = carry
@@ -181,9 +255,11 @@ def make_decode_block(graph, pad_id: int = 0):
             # Dead rows run too (fixed shapes) but at frozen pos with
             # zeroed flash-decode lengths — their only cost is the
             # repeated, harmless K/V write their next prefill overwrites.
+            counters = {} if routed else None
             logits, buffers = _cached_apply(
                 graph, variables, tok[:, None], buffers, pos,
                 step=True, live=live,
+                valid=live[:, None] if routed else None, counters=counters,
             )
             nxt = greedy_next(logits[:, 0])
             emit = jnp.where(live, nxt, jnp.asarray(pad_id, jnp.int32))
@@ -194,11 +270,18 @@ def make_decode_block(graph, pad_id: int = 0):
             # row just emitted its last allowed token
             live = live & (emit != eos) & (rem > 0)
             tok = jnp.where(live, emit, tok)
+            if routed:
+                return (tok, buffers, pos, live, rem), (
+                    emit, routing_totals(counters))
             return (tok, buffers, pos, live, rem), emit
 
         (tok, buffers, pos, live, rem), toks = jax.lax.scan(
             micro, (tok, buffers, pos, live, rem), None, length=t
         )
+        if routed:
+            toks, stats = toks
+            stats = jax.tree_util.tree_map(lambda a: a.sum(axis=0), stats)
+            return jnp.swapaxes(toks, 0, 1), live, buffers, pos, stats
         return jnp.swapaxes(toks, 0, 1), live, buffers, pos
 
     return decode_block

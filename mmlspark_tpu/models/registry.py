@@ -47,6 +47,7 @@ def registered_models() -> list[str]:
 def _ensure_loaded() -> None:
     # builder modules self-register on import
     import mmlspark_tpu.models.bilstm  # noqa: F401
+    import mmlspark_tpu.models.hybrid  # noqa: F401
     import mmlspark_tpu.models.mlp  # noqa: F401
     import mmlspark_tpu.models.moe  # noqa: F401
     import mmlspark_tpu.models.onnx_import  # noqa: F401

@@ -164,7 +164,22 @@ def _validate_and_expand_gqa(q, k, v):
     return k, v
 
 
-def rolled_window_attention(q, k, v, pos, *, scale=None):
+def sink_denominator(m, denom, sink):
+    """A learned per-head SINK joined to a softmax's denominator only:
+    ``m`` (B, H, Q, 1) is the running maximum of the scores, ``denom``
+    (B, H, Q) the sum of ``exp(s - m)``, ``sink`` (H,) one logit a query
+    head. Returns the factor that rescales the weights to the maximum
+    taken with the sink, and the denominator with the sink's share:
+    ``p_ij = exp(s_ij - m') / (sum_j exp(s_ij - m') + exp(b_h - m'))``,
+    ``m' = max(m, b_h)``. A row with nothing to see keeps weights of
+    nought over a denominator of one."""
+    b = sink.astype(jnp.float32)[None, :, None, None]
+    m_new = jnp.maximum(m, b)
+    corr = jnp.exp(m - m_new)
+    return corr, denom * corr[..., 0] + jnp.exp(b - m_new)[..., 0]
+
+
+def rolled_window_attention(q, k, v, pos, *, scale=None, sink=None):
     """One decode step against a ROLLED sliding-window cache.
 
     ``k``/``v`` are (B, W, Hkv, D) circular buffers where slot ``j``
@@ -179,6 +194,10 @@ def rolled_window_attention(q, k, v, pos, *, scale=None):
     This is what keeps long generations O(window) in memory: the
     framework's sliding-window models never need a (B, P+N, ...) cache
     (models/generate.py picks this path automatically).
+
+    ``pos`` may also be a (B,) vector of per-row positions, the values
+    may be narrower or wider than the keys, and ``sink`` (H,) joins the
+    denominator (:func:`sink_denominator`).
     """
     k, v = _validate_and_expand_gqa(q, k, v)
     if scale is None:
@@ -187,20 +206,25 @@ def rolled_window_attention(q, k, v, pos, *, scale=None):
         "bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale
     w = k.shape[1]
+    pos = jnp.asarray(pos)
+    if pos.ndim:
+        pos = pos[:, None, None, None]
     valid = jnp.arange(w)[None, None, None, :] <= pos  # pos >= W: all on
     s = jnp.where(valid, s, NEG_INF)
     # the slot at pos % W is always valid, so no fully-masked rows exist
     m = s.max(axis=-1, keepdims=True)
     p = jnp.exp(s - m)
+    denom = p.sum(axis=-1)
+    if sink is not None:
+        corr, denom = sink_denominator(m, denom, sink)
+        p = p * corr
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
-    return (out / jnp.moveaxis(p.sum(axis=-1), 1, 2)[..., None]).astype(
-        q.dtype
-    )
+    return (out / jnp.moveaxis(denom, 1, 2)[..., None]).astype(q.dtype)
 
 
 def dense_attention(q, k, v, *, causal: bool = False,
                     window: int | None = None, scale=None,
-                    q_offset: int = 0, kv_offset: int = 0):
+                    q_offset: int = 0, kv_offset: int = 0, sink=None):
     """Reference multi-head attention, (B, S, H, D) layout.
 
     Single fused einsum-softmax-einsum — exactly what XLA fuses well on one
@@ -210,6 +234,10 @@ def dense_attention(q, k, v, *, causal: bool = False,
     kernel: each query sees its W most recent keys; requires causal).
     ``q_offset`` may be a (B,) vector of per-row positions (the serving
     engine's multi-tenant decode step — see ``causal_block_mask``).
+    The values may be narrower or wider than the keys (the output takes
+    the values' width), and ``sink`` (H,), one learned logit a query
+    head, joins the softmax's denominator only
+    (:func:`sink_denominator`).
     """
     if window is not None:
         if not causal:
@@ -231,7 +259,10 @@ def dense_attention(q, k, v, *, causal: bool = False,
     m = s.max(axis=-1, keepdims=True)
     m = jnp.where(jnp.isneginf(m), 0.0, m)
     p = jnp.exp(s - m)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
     denom = p.sum(axis=-1)
+    if sink is not None:
+        corr, denom = sink_denominator(m, denom, sink)
+        p = p * corr
+    out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
     denom = jnp.moveaxis(jnp.where(denom == 0.0, 1.0, denom), 1, 2)[..., None]
     return (out / denom).astype(q.dtype)

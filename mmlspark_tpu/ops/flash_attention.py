@@ -131,9 +131,16 @@ def _live_k_range(qi, *, window: int | None, blk: int):
 # forward
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
                 scale: float, causal: bool, window: int | None, blk: int,
-                seq_len: int, with_lse: bool, masked: bool):
+                seq_len: int, with_lse: bool, masked: bool,
+                has_sink: bool = False):
+    # a learned per-head sink (inference only) rides in as one more
+    # operand, a (1, 1, LANES) lanes-replicated logit of this head
+    sink_ref = None
+    if has_sink:
+        sink_ref, *rest = rest
+    o_ref, *rest = rest
     # the LSE residual exists only on the grad path (with_lse): the
     # inference-only forward skips computing AND writing the
     # lanes-replicated f32 (bh, s, 128) tensor, which would otherwise
@@ -186,6 +193,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
         l = l_scr[:, :1]
+        if has_sink:
+            # the sink joins the denominator only: rescale to the
+            # maximum taken with it (ops/attention.py sink_denominator)
+            o_ref[0] = _with_sink(
+                m_scr[:, :1], l, acc_scr[:], sink_ref[0][:, :1]
+            ).astype(o_ref.dtype)
+            return
         o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)).astype(
             o_ref.dtype
         )
@@ -199,6 +213,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
                     jnp.where(l_scr[:] == 0.0, 1.0, l_scr[:])
                 ),
             )
+
+
+def _with_sink(m, l, acc, sink):
+    """The normalised output with a per-row sink logit joined to the
+    denominator: ``m``, ``l``, ``sink`` broadcast against ``acc``'s
+    rows. A sink of ``NEG_INF`` adds nothing; a row that saw no key
+    comes out as zeros."""
+    m_new = jnp.maximum(m, sink)
+    corr = jnp.exp(m - m_new)
+    denom = l * corr + jnp.exp(sink - m_new)
+    return acc * corr / jnp.where(denom == 0.0, 1.0, denom)
 
 
 def _to_bh(t, s_pad):
@@ -215,8 +240,9 @@ def _from_bh(t, b, h, s):
 
 def _flash_forward(q, k, v, *, causal: bool, window: int | None,
                    scale: float, block: int, interpret: bool,
-                   with_lse: bool = True):
+                   with_lse: bool = True, sink=None):
     b, s, h, d = q.shape
+    dv = v.shape[-1]  # the values (and the output) may differ in width
     # grouped-query attention: K/V may carry fewer heads (h_kv) than Q;
     # the group factor g maps query-head grid index bh -> kv row bh // g
     # in the index maps, so K/V are never materialized per query head
@@ -226,12 +252,12 @@ def _flash_forward(q, k, v, *, causal: bool, window: int | None,
     qb, kb, vb = (_to_bh(t, s_pad) for t in (q, k, v))
     n_blk = s_pad // blk
     grid = (b * h, n_blk, n_blk)
-    tile = lambda im: pl.BlockSpec((1, blk, d), im,
-                                   memory_space=pltpu.VMEM)
+    tile = lambda im, width=d: pl.BlockSpec((1, blk, width), im,
+                                            memory_space=pltpu.VMEM)
     lse_tile = pl.BlockSpec((1, blk, LANES), lambda bh, i, j: (bh, i, 0),
                             memory_space=pltpu.VMEM)
-    out_shape = [jax.ShapeDtypeStruct((b * h, s_pad, d), q.dtype)]
-    out_specs = [tile(lambda bh, i, j: (bh, i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((b * h, s_pad, dv), q.dtype)]
+    out_specs = [tile(lambda bh, i, j: (bh, i, 0), dv)]
     if with_lse:
         out_shape.append(
             jax.ShapeDtypeStruct((b * h, s_pad, LANES), jnp.float32)
@@ -248,26 +274,39 @@ def _flash_forward(q, k, v, *, causal: bool, window: int | None,
             return (bh // g, jnp.clip(j, lo, hi), 0)
     else:
         kv_im = lambda bh, i, j: (bh // g, j, 0)  # noqa: E731
+    in_specs = [
+        tile(lambda bh, i, j: (bh, i, 0)),  # Q: row block
+        tile(kv_im),                        # K: column block
+        tile(kv_im, dv),                    # V: column block
+    ]
+    operands = [qb, kb, vb]
+    extra = {}
+    if sink is not None:
+        # one logit a query head, lanes-replicated, row bh = batch*h + head
+        operands.append(jnp.broadcast_to(
+            jnp.tile(sink.astype(jnp.float32), b)[:, None, None],
+            (b * h, 1, LANES),
+        ))
+        in_specs.append(pl.BlockSpec((1, 1, LANES),
+                                     lambda bh, i, j: (bh, 0, 0),
+                                     memory_space=pltpu.VMEM))
+        extra["has_sink"] = True
     res = pl.pallas_call(
         partial(_fwd_kernel, scale=scale, causal=causal, window=window,
                 blk=blk, seq_len=s, with_lse=with_lse,
-                masked=s_pad != s),
+                masked=s_pad != s, **extra),
         out_shape=tuple(out_shape),
         grid=grid,
-        in_specs=[
-            tile(lambda bh, i, j: (bh, i, 0)),  # Q: row block
-            tile(kv_im),                        # K: column block
-            tile(kv_im),                        # V: column block
-        ],
+        in_specs=in_specs,
         out_specs=tuple(out_specs),
         scratch_shapes=[
             pltpu.VMEM((blk, LANES), jnp.float32),  # running max
             pltpu.VMEM((blk, LANES), jnp.float32),  # running normalizer
-            pltpu.VMEM((blk, d), jnp.float32),      # accumulator
+            pltpu.VMEM((blk, dv), jnp.float32),     # accumulator
         ],
         compiler_params=_GRID_SEMANTICS,
         interpret=interpret,
-    )(qb, kb, vb)
+    )(*operands)
     if with_lse:
         out, lse = res
         return _from_bh(out, b, h, s), lse
@@ -524,7 +563,7 @@ def _build(causal: bool, window: int | None, scale_key, block: int,
 def flash_attention(q, k, v, *, causal: bool = False,
                     window: int | None = None, scale=None,
                     block: int = 128, interpret: bool | None = None,
-                    mesh=None):
+                    mesh=None, sink=None):
     """Blockwise fused attention, (B, S, H, D) layout, exact output AND
     exact gradients — both directions O(S·d) memory.
 
@@ -548,6 +587,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
     ``mesh``: the caller's (data, model) mesh when the operands are
     sharded over one — the kernel then runs per shard (see
     :func:`_kernel_axes`). Leave it None inside a ``shard_map`` body.
+
+    ``sink`` ((H,) float, one learned logit a query head) joins the
+    softmax's denominator only, and ``v`` may be narrower or wider than
+    ``q`` and ``k`` (the output takes its width). Either makes the call
+    INFERENCE ONLY (the forward kernel alone, no VJP, no mesh): these
+    are the serving prefill's variants.
     """
     if not (q.dtype == k.dtype == v.dtype):
         # matmuls feed the MXU native-dtype operands (no f32 upcast),
@@ -576,6 +621,18 @@ def flash_attention(q, k, v, *, causal: bool = False,
         from mmlspark_tpu.core.env import is_tpu
 
         interpret = not is_tpu()
+    if sink is not None or v.shape[-1] != q.shape[-1]:
+        if mesh is not None:
+            raise ValueError(
+                "flash_attention with a sink or with values of another "
+                "width than the keys is not run under a mesh"
+            )
+        out, _ = _flash_forward(
+            q, k, v, causal=causal, window=window,
+            scale=scale if scale else q.shape[-1] ** -0.5, block=block,
+            interpret=bool(interpret), with_lse=False, sink=sink,
+        )
+        return out
     fn = _build(causal, window, scale, block, bool(interpret))
     if mesh is None:
         return fn(q, k, v)
@@ -918,6 +975,248 @@ def flash_decode(q, k, v, lengths, *, scale=None, block: int = 128,
         interpret=bool(interpret),
     )(*operands)
     return _from_bh(out, b, h, 1)
+
+
+# ---------------------------------------------------------------------------
+# grouped flash decode: one KV head's whole group of query heads a grid
+# step, over HEAD-MAJOR slot caches
+#
+# flash_decode walks every KV row once for EACH query head of a group: at
+# 16 query heads a KV head that is 16 walks of the same rows, and its
+# (B, L, Hkv, D) operands have to be laid out anew in the kernel's tiles
+# on every call. This kernel takes the caches as the pool stores them for
+# blocks that declare their geometry, ``(B, Hkv, L, Dk)`` and
+# ``(B, Hkv, L, Dv)``: merging the two leading dimensions is free, the
+# minor two are the kernel's own tiles, and a grid step multiplies the
+# group's (G, Dk) queries with one (blk, Dk) block of keys. The keys and
+# the values may differ in width, and a learned per-head sink joins the
+# denominator when the walk ends. The same kernel reads a full-length
+# cache (``lengths = pos + 1``) and a ring (``lengths = min(pos + 1,
+# W)``: every written slot of a ring is inside the window).
+
+
+def _decode_group_kernel(len_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
+                         m_scr, l_scr, acc_scr, *,
+                         scale: float, blk: int, kv_heads: int):
+    row = pl.program_id(0)   # batch * kv_heads + kv head
+    kb = pl.program_id(1)
+    length = len_ref[row // kv_heads]  # live positions [0, length)
+
+    @pl.when(kb == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(kb * blk < length)
+    def _update():
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # (G, blk) f32
+        kpos = kb * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos >= length, NEG_INF, s)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[:] = jnp.broadcast_to(
+            l_scr[:, :1] * corr + p.sum(axis=-1, keepdims=True),
+            l_scr.shape,
+        )
+        # P.V in f32, as _decode_kernel keeps it: the read is bound by
+        # the K/V stream, not by these few rows of products
+        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _finalize():
+        o_ref[0] = _with_sink(
+            m_scr[:, :1], l_scr[:, :1], acc_scr[:], sink_ref[0][:, :1]
+        ).astype(o_ref.dtype)
+
+
+def _row_write_kernel(at_ref, kn_ref, vn_ref, kc_ref, vc_ref, ko_ref,
+                      vo_ref, *, tile: int):
+    row = at_ref[pl.program_id(0)] % tile
+
+    def put(new_ref, old_ref, out_ref):
+        # through float32: the select then needs no packed-dtype
+        # broadcast along sublanes, and bf16 -> f32 -> bf16 is exact
+        rows = jax.lax.broadcasted_iota(jnp.int32, old_ref.shape, 2)
+        out_ref[...] = jnp.where(
+            rows == row, new_ref[...].astype(jnp.float32),
+            old_ref[...].astype(jnp.float32),
+        ).astype(out_ref.dtype)
+
+    put(kn_ref, kc_ref, ko_ref)
+    put(vn_ref, vc_ref, vo_ref)
+
+
+def cache_row_write(k, v, k_new, v_new, at, *,
+                    interpret: bool | None = None):
+    """Head-major caches ``k`` (B, Hkv, L, Dk) and ``v`` (B, Hkv, L, Dv)
+    with row ``at[b]`` of every head of batch row ``b`` taken from
+    ``k_new`` (B, Hkv, Dk) and ``v_new`` (B, Hkv, Dv): one decode step's
+    cache write, in place on donated caches.
+
+    The XLA scatter that ``k.at[rows, :, at].set(...)`` lowers to wants
+    the caches in a layout of its own, and between it and the decode
+    kernel the whole cache would be copied twice a layer a micro-step.
+    This kernel aliases the caches to its outputs and rewrites only the
+    sublane tile that holds the row: the layout stays the decode
+    kernel's."""
+    b, hk, L, dk = k.shape
+    dv = v.shape[3]
+    if interpret is None:
+        from mmlspark_tpu.core.env import is_tpu
+
+        interpret = not is_tpu()
+    # whole sublanes of the cache's dtype, or the whole of a shorter cache
+    tile = 32 // k.dtype.itemsize
+    if L % tile:
+        tile = L
+    at = jnp.clip(jnp.asarray(at, jnp.int32), 0, L - 1)
+
+    def new_spec(width):
+        return pl.BlockSpec((1, hk, 1, width),
+                            lambda i, at: (i, 0, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    def old_spec(width):
+        return pl.BlockSpec((1, hk, tile, width),
+                            lambda i, at: (i, 0, at[i] // tile, 0),
+                            memory_space=pltpu.VMEM)
+
+    return pl.pallas_call(
+        partial(_row_write_kernel, tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b,),
+            in_specs=[new_spec(dk), new_spec(dv), old_spec(dk),
+                      old_spec(dv)],
+            out_specs=[old_spec(dk), old_spec(dv)],
+        ),
+        out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)),
+        # operands count the scalar-prefetched ``at``: 3 and 4 are k, v
+        input_output_aliases={3: 0, 4: 1},
+        interpret=bool(interpret),
+        name="cache_row_write",
+    )(at, k_new[:, :, None].astype(k.dtype),
+      v_new[:, :, None].astype(v.dtype), k, v)
+
+
+def flash_decode_grouped(q, k, v, lengths, *, sink=None, scale=None,
+                         block: int = 512, interpret: bool | None = None,
+                         name: str | None = None):
+    """Length-aware decode attention for ONE query token per row over
+    HEAD-MAJOR caches, a KV head's whole group of query heads per grid
+    step.
+
+    ``q`` is (B, 1, H, Dk); ``k`` is (B, Hkv, L, Dk) and ``v`` is
+    (B, Hkv, L, Dv), ``Hkv`` dividing ``H`` (query head ``i`` reads KV
+    head ``i // (H // Hkv)``); ``lengths`` is (B,) int32, row ``b``
+    attending cache rows ``[0, lengths[b])`` and nothing else
+    (``lengths[b] == 0`` yields zeros). ``sink`` ((H,) float) joins the
+    softmax's denominator only. Returns (B, 1, H, Dv) in ``q``'s dtype.
+
+    The KV grid dimension streams ``L`` in blocks of ``block`` rows (the
+    whole of a shorter cache) and the index map clamps at each row's
+    last live block, so dead blocks are never fetched. Inference only,
+    one device only. ``name`` names the kernel in a device trace."""
+    if q.ndim != 4 or q.shape[1] != 1:
+        raise ValueError(
+            "flash_decode_grouped takes a SINGLE query token per row: q "
+            f"must be (B, 1, H, Dk), got {q.shape}"
+        )
+    b, _, h, dk = q.shape
+    if (k.ndim != 4 or v.ndim != 4 or k.shape[:3] != v.shape[:3]
+            or k.shape[0] != b or k.shape[3] != dk or h % k.shape[1]):
+        raise ValueError(
+            "flash_decode_grouped needs head-major caches (B, Hkv, L, Dk) "
+            f"and (B, Hkv, L, Dv) with Hkv dividing H={h}, got "
+            f"{k.shape} / {v.shape}"
+        )
+    if not (q.dtype == k.dtype == v.dtype):
+        raise ValueError(
+            "flash_decode_grouped requires q, k, v to share one dtype, "
+            f"got {q.dtype}/{k.dtype}/{v.dtype}"
+        )
+    hk, L, dv = k.shape[1], k.shape[2], v.shape[3]
+    lengths = jnp.asarray(lengths)
+    if lengths.shape != (b,):
+        raise ValueError(
+            f"lengths must be ({b},) — one live length per batch row — "
+            f"got {lengths.shape}"
+        )
+    g = h // hk
+    if scale is None:
+        scale = dk ** -0.5
+    if interpret is None:
+        from mmlspark_tpu.core.env import is_tpu
+
+        interpret = not is_tpu()
+    lengths = jnp.clip(lengths.astype(jnp.int32), 0, L)
+    blk = _decode_block(L, block)
+    if L % blk:
+        raise ValueError(
+            f"flash_decode_grouped streams the cache without a pad copy: "
+            f"its {L} rows need a block of whole sublanes that divides "
+            f"them (tried up to {block})"
+        )
+    n_blk = L // blk
+    # the group's rows padded to whole sublanes; pad rows are sliced off
+    gp = _round_up(g, SUBLANES)
+    qb = q.reshape(b * hk, g, dk)
+    if sink is None:
+        sink = jnp.full((h,), NEG_INF, jnp.float32)
+    sb = sink.astype(jnp.float32).reshape(hk, g)
+    if gp != g:
+        qb = jnp.pad(qb, ((0, 0), (0, gp - g), (0, 0)))
+        sb = jnp.pad(sb, ((0, 0), (0, gp - g)), constant_values=NEG_INF)
+    sb = jnp.broadcast_to(sb[:, :, None], (hk, gp, LANES))
+    kb = k.reshape(b * hk, L, dk)
+    vb = v.reshape(b * hk, L, dv)
+
+    def kv_im(row, j, lens):
+        length = lens[row // hk]
+        last = jnp.maximum((length + blk - 1) // blk - 1, 0)
+        return (row, jnp.minimum(j, last), 0)
+
+    out = pl.pallas_call(
+        partial(_decode_group_kernel, scale=scale, blk=blk, kv_heads=hk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * hk, n_blk),
+            in_specs=[
+                pl.BlockSpec((1, gp, dk), lambda row, j, lens: (row, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, blk, dk), kv_im, memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, blk, dv), kv_im, memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, gp, LANES),
+                             lambda row, j, lens: (row % hk, 0, 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, gp, dv), lambda row, j, lens: (row, 0, 0),
+                memory_space=pltpu.VMEM,
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((gp, LANES), jnp.float32),  # running max
+                pltpu.VMEM((gp, LANES), jnp.float32),  # normalizer
+                pltpu.VMEM((gp, dv), jnp.float32),     # accumulator
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b * hk, gp, dv), q.dtype),
+        compiler_params=_DECODE_SEMANTICS,
+        interpret=bool(interpret),
+        **({"name": name} if name else {}),
+    )(lengths, qb, kb, vb, sb)
+    return out[:, :g].reshape(b, 1, h, dv)
 
 
 # ---------------------------------------------------------------------------

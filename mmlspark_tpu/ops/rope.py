@@ -36,15 +36,30 @@ def rope_tables(positions, head_dim: int, base: float = 10000.0):
     return jnp.cos(ang), jnp.sin(ang)
 
 
-def apply_rope(x, positions=None, *, base: float = 10000.0):
+def apply_rope(x, positions=None, *, base: float = 10000.0,
+               rotary_dim: int | None = None):
     """Rotate ``x`` of shape (B, S, H, D) by position; D must be even.
 
     ``positions`` defaults to 0..S-1; a (B, S) matrix applies per-row
     positions (multi-tenant decode). The rotation is applied in f32 and
     cast back to ``x.dtype`` (bf16 activations keep their dtype through
     the attention stack).
+
+    ``rotary_dim`` (even, at most D) rotates only the FIRST
+    ``rotary_dim`` dimensions of every head, as the half-rotation over
+    those dimensions alone; the others pass unchanged (partial rotary
+    embeddings). ``base`` is the layer's own: a model with layers of
+    several kinds hands each its base.
     """
     b, s, h, d = x.shape
+    if rotary_dim is not None and int(rotary_dim) != d:
+        r = int(rotary_dim)
+        if r % 2 or not 0 < r < d:
+            raise ValueError(
+                f"rotary_dim must be even and in (0, {d}], got {rotary_dim}"
+            )
+        rotated = apply_rope(x[..., :r], positions, base=base)
+        return jnp.concatenate((rotated, x[..., r:]), axis=-1)
     if positions is None:
         positions = jnp.arange(s)
     positions = jnp.asarray(positions)

@@ -29,6 +29,8 @@ the output for the trainer to add.
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 
@@ -168,6 +170,115 @@ def moe_ffn_dropless(x, gate_w, w_in, b_in, w_out, b_out):
     y = y + b_out[expert].astype(x.dtype)
     out = y.astype(jnp.float32) * gate[:, None]
     return out.reshape(b, t, d).astype(x.dtype)
+
+
+def router_topk(x, router_w, select_bias, top_k: int):
+    """Sigmoid scores, the choice by score plus a selection bias, the
+    weights by score alone (the "noaux_tc" router of the DeepSeek-V3
+    line, one group). ``x`` is (N, D); ``router_w`` (D, E);
+    ``select_bias`` (E,). Returns ``(experts (N, top_k) int32, weights
+    (N, top_k) float32)``: the ``top_k`` largest of ``z + bias``, and
+    ``z_e / (sum of the chosen z + 1e-20)``. The bias moves the choice
+    and never a weight. All of it in float32, the product at full
+    precision: a choice is a step, not a rounding."""
+    z = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+    _, experts = jax.lax.top_k(z + select_bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(z, experts, axis=-1)
+    weights = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights
+
+
+def held_tiles(tokens: int, held: int, top_k: int) -> tuple[int, int]:
+    """The row tile of :func:`moe_ffn_held`'s grouped products for
+    ``tokens`` tokens, and the most tiles the held experts' pairs can
+    fill once every expert's rows are padded to whole tiles: a token
+    chooses an expert at most once, so an expert has at most ``tokens``
+    rows, and all of them together at most ``tokens * min(top_k, held)``."""
+    tm = min(512, -(-tokens // 16) * 16)
+    most = min(held * -(-tokens // tm),
+               held + tokens * min(top_k, held) // tm)
+    return tm, most
+
+
+def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, *,
+                 top_k: int, first: int, valid=None,
+                 interpret: bool | None = None):
+    """The part of a routed SwiGLU layer that THIS holder's experts give.
+
+    The router scores all ``E`` experts (``router_w`` (D, E)) and every
+    token takes its ``top_k`` (:func:`router_topk`); this holder has the
+    experts ``first .. first + held - 1`` (``w_gate``/``w_up`` (held, D,
+    F), ``w_down`` (held, F, D)), computes ``w_e * SwiGLU_e(x)`` for the
+    (token, expert) pairs that fell on them and adds nothing for the
+    rest: summed over the holders of all ``E`` experts that is the whole
+    layer (expert parallelism's contract; on one chip there is no
+    exchange to run). ``x`` is (B, T, D); ``valid`` ((B, T) bool) marks
+    the real tokens: a pad or a dead row routes nowhere.
+
+    Dropless at every length and fixed in shape: the held pairs are
+    ranked inside their expert, every expert's rows are padded to whole
+    row tiles, and three grouped products
+    (:func:`mmlspark_tpu.ops.grouped_matmul.grouped_matmul`) run over
+    the tiles that are live. No (tokens, D, F) weight copy is made, and
+    an expert that no token chose is not read.
+
+    Returns ``(out (B, T, D) in x's dtype, {"pairs", "hit"})``: the
+    pairs that fell on held experts and the held experts hit."""
+    from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
+
+    b, t, d = x.shape
+    held = w_gate.shape[0]
+    n = b * t
+    flat = x.reshape(n, d)
+    experts, weights = router_topk(flat, router_w, select_bias, top_k)
+    local = experts - first
+    mine = (local >= 0) & (local < held)
+    if valid is not None:
+        mine = mine & valid.reshape(n)[:, None]
+    pairs = n * top_k
+    group = jnp.where(mine, local, held).reshape(pairs)
+    member = group[:, None] == jnp.arange(held)[None, :]       # (P, held)
+    sizes = member.sum(axis=0).astype(jnp.int32)
+    tm, most = held_tiles(n, held, top_k)
+    padded = -(-sizes // tm) * tm
+    pad_end = jnp.cumsum(padded)
+    # a pair's row: its expert's first row plus its rank inside the expert
+    rank = jnp.take_along_axis(
+        jnp.cumsum(member, axis=0, dtype=jnp.int32) - 1,
+        jnp.minimum(group, held - 1)[:, None], axis=1,
+    )[:, 0]
+    mine_flat = mine.reshape(pairs)
+    row = jnp.where(
+        mine_flat, (pad_end - padded)[jnp.minimum(group, held - 1)] + rank, 0
+    )
+    rows = most * tm
+    token_of_row = jnp.zeros((rows,), jnp.int32).at[
+        jnp.where(mine_flat, row, rows)
+    ].set(jnp.arange(pairs, dtype=jnp.int32) // top_k, mode="drop")
+    tile_group = jnp.searchsorted(
+        pad_end, jnp.arange(most, dtype=jnp.int32) * tm, side="right"
+    )
+    live_tiles = pad_end[-1] // tm
+    gmm = partial(grouped_matmul, tile_group=tile_group,
+                  live_tiles=live_tiles, tm=tm, interpret=interpret)
+    xs = flat[token_of_row].astype(w_gate.dtype)
+    gate = gmm(xs, w_gate, name="moe_gate")
+    up = gmm(xs, w_up, name="moe_up")
+    mid = (jax.nn.silu(gate.astype(jnp.float32))
+           * up.astype(jnp.float32)).astype(w_down.dtype)
+    y = gmm(mid, w_down, name="moe_down")
+    # back to the tokens: each pair reads its own row; what fell
+    # elsewhere reads nothing (a where, not a product with nought: a
+    # dead tile's rows are zeros, but nothing here leans on it)
+    mine_w = jnp.where(mine, weights, 0.0)
+    picked = jnp.where(mine_flat[:, None], y[row].astype(jnp.float32), 0.0)
+    out = (picked.reshape(n, top_k, d) * mine_w[:, :, None]).sum(axis=1)
+    counters = {"pairs": mine.sum().astype(jnp.int32),
+                "hit": (sizes > 0).sum().astype(jnp.int32)}
+    return out.reshape(b, t, d).astype(x.dtype), counters
 
 
 def validate_experts(n_experts: int, mesh=None) -> None:

@@ -36,13 +36,19 @@ identical shardings on every tick.
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from mmlspark_tpu.core.exceptions import FriendlyError
-from mmlspark_tpu.models.generate import cache_geometry
+from mmlspark_tpu.models.generate import (
+    FULL_ROWS,
+    LINEAR,
+    RING_ROWS,
+    cache_specs,
+)
 from mmlspark_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 #: headroom multiplied onto the prefill amax when fixing a slot's int8
@@ -110,8 +116,28 @@ def _put_rows(pool, values, slot, written):
     return jax.lax.dynamic_update_slice(pool, values[None], at)
 
 
+def _head_major_rows(kind: str, filled, rows: int, start, length):
+    """One slot's rows of a head-major pool entry, ``(hk, rows, d)``,
+    taken from a linear prefill cache ``filled`` (rows, hk, d), with the
+    mask of the rows this write fills."""
+    if kind == FULL_ROWS:
+        rows = min(filled.shape[0], rows)
+        at = jnp.arange(rows)
+        taken = filled[:rows]
+    else:
+        # ring row j holds the latest position p < length with p % rows
+        # == j; before the ring has wrapped, rows past the prompt's end
+        # have no such position (p < 0) and stay as they are
+        j = jnp.arange(rows)
+        at = length - 1 - ((length - 1 - j) % rows)
+        taken = jnp.take(filled, jnp.clip(at, 0, filled.shape[0] - 1),
+                         axis=0)
+    written = (at >= start) & (at >= 0) & (at < length)
+    return jnp.moveaxis(taken, 0, 1), written[None, :, None]
+
+
 def _write_slot(buffers, positions, live, prefill_cache, slot, start,
-                length):
+                length, kinds=None):
     """The whole of :meth:`SlotCachePool.write_prefill` as one program:
     rows ``[start, length)`` of ``slot`` take the batch-1
     ``prefill_cache``'s rows, every other row of the pool stays as it
@@ -119,9 +145,23 @@ def _write_slot(buffers, positions, live, prefill_cache, slot, start,
     ``start`` and ``length`` are traced scalars, so the program is keyed
     by the prefill cache's shape alone. A pool entry of four leaves is
     an int8 one, whose per-head scales are fixed here from the rows
-    below ``length``."""
+    below ``length``. ``kinds`` (static) names the blocks whose entries
+    are head-major ``(S, hk, rows, d)`` by their own declaration: a
+    ``full`` one takes the same rows, transposed; a ``ring`` of ``R``
+    rows takes, in row ``j``, the latest position below ``length`` that
+    is congruent to ``j``: the prompt's last ``min(P, R)`` rows at
+    ``pos % R``."""
     new_buffers = {}
     for name, entry in buffers.items():
+        kind = (kinds or {}).get(name, LINEAR)
+        if kind != LINEAR:
+            placed = []
+            for pool, filled in zip(entry, prefill_cache[name]):
+                values, written = _head_major_rows(
+                    kind, filled[0], pool.shape[2], start, length)
+                placed.append(_put_rows(pool, values, slot, written))
+            new_buffers[name] = tuple(placed)
+            continue
         rows = min(prefill_cache[name][0].shape[1], entry[0].shape[1])
         ck, cv = (c[0, :rows] for c in prefill_cache[name])
         row = jnp.arange(rows)[:, None, None]
@@ -161,6 +201,14 @@ class SlotCachePool:
     bookkeeping (which slots are leased); the arrays themselves stay on
     device and are replaced functionally each tick.
 
+    A block that DECLARES its geometry (``cache_spec()``,
+    models/hybrid.py) gets a head-major entry instead, ``(slots, hk,
+    rows, dk)`` and ``(slots, hk, rows, dv)``: ``rows`` is ``cache_len``
+    for a ``full`` block and the window for a ``ring``, which holds
+    position ``p`` in row ``p % rows``. Both kinds live in this one
+    pool, are written by the one jitted ``_write_slot`` and read by the
+    one fused decode block.
+
     ``kv_dtype="int8"`` (docs/PERFORMANCE.md "Quantized decode") stores
     K/V as int8 — HALF the bf16 pool's HBM bytes — and each block's
     entry grows to ``(K, V, k_scale, v_scale)`` with (slots, hk) f32
@@ -182,13 +230,37 @@ class SlotCachePool:
                 f"cache_len must be >= 2 (one prompt token + one "
                 f"generated), got {cache_len}"
             )
-        geometry = cache_geometry(graph, variables)
-        if not geometry:
+        specs = cache_specs(graph, variables)
+        if not specs:
             raise FriendlyError(
                 f"'{graph.name}' has no cache-accepting blocks; the "
                 "serving engine needs the KV-cache decode path "
                 "(transformer_lm family)"
             )
+        #: ``{block: kind}`` for the blocks that declare their geometry
+        #: (head-major entries: full-length rows, or a ring)
+        self.kinds = {name: spec[0] for name, spec in specs.items()
+                      if spec[0] != LINEAR}
+        if self.kinds and kv_dtype != "bf16":
+            raise FriendlyError(
+                f"'{graph.name}' declares its cache geometry (rings, keys "
+                f"and values of different widths); kv_dtype={kv_dtype!r} "
+                "rows are linear rows of one width — serve it with "
+                "kv_dtype='bf16'"
+            )
+        if self.kinds and mesh is not None and mesh.size > 1:
+            msize = int(mesh.shape.get(MODEL_AXIS, 1))
+            uneven = [name for name, spec in specs.items()
+                      if spec[2] % msize]
+            raise FriendlyError(
+                f"'{graph.name}' declares its cache geometry; its "
+                "head-major full-length rows and rings are pooled on one "
+                "device only — a mesh is not served yet"
+                + (f" (and its '{MODEL_AXIS}' axis of {msize} does not "
+                   f"divide the KV heads of {uneven[0]})" if uneven else "")
+            )
+        geometry = {name: (hk, dk)
+                    for name, (_k, _r, hk, dk, _dv) in specs.items()}
         self.mesh = mesh
         if mesh is not None:
             data = int(mesh.shape.get(DATA_AXIS, 1))
@@ -231,13 +303,22 @@ class SlotCachePool:
                 else:
                     self._kv_shardings[name] = (sh, sh)
         self.buffers = {}
-        for name, (hk, d) in geometry.items():
+        for name, (kind, rows, hk, d, dv) in specs.items():
             # K and V must be DISTINCT arrays: the engine's decode step
             # donates the whole buffer pytree (donate_argnums), and a
             # pair aliasing one allocation cannot be donated twice —
             # same for the int8 mode's two scale leaves
-            k = jnp.zeros((slots, cache_len, hk, d), store_dtype)
-            v = jnp.zeros((slots, cache_len, hk, d), store_dtype)
+            if kind == LINEAR:
+                k = jnp.zeros((slots, cache_len, hk, d), store_dtype)
+                v = jnp.zeros((slots, cache_len, hk, d), store_dtype)
+            else:
+                # declared geometry, head-major: the decode kernel
+                # streams (rows, d) tiles of one KV head without a copy.
+                # A ring never needs more rows than the pool's length
+                rows = cache_len if kind == FULL_ROWS else min(
+                    int(rows), cache_len)
+                k = jnp.zeros((slots, hk, rows, d), store_dtype)
+                v = jnp.zeros((slots, hk, rows, dv), store_dtype)
             entry = (k, v)
             if quantized:
                 entry = (
@@ -293,7 +374,9 @@ class SlotCachePool:
         if mesh is not None:
             pinned = (self._kv_shardings, self._slot_sharding,
                       self._slot_sharding)
-        self._write = jax.jit(_write_slot, donate_argnums=(0,),
+        write = (partial(_write_slot, kinds=dict(self.kinds))
+                 if self.kinds else _write_slot)
+        self._write = jax.jit(write, donate_argnums=(0,),
                               out_shardings=pinned)
 
     # -- sharding anchors --------------------------------------------------
@@ -456,18 +539,14 @@ class SlotCachePool:
                 "scales are fixed per lease from the whole prompt "
                 "(use the paged pool for resumable int8 fills)"
             )
-        nbytes = 0
-        for name, entry in self.buffers.items():
+        for name in self.buffers:
             rows = prefill_cache[name][0].shape[1]
             if rows < length:
                 raise FriendlyError(
                     f"prefill cache of block '{name}' holds {rows} "
                     f"rows, fewer than the prefill length {length}"
                 )
-            # K and V alike: (slots, cache_len, hk, d) in the pool's dtype
-            nbytes += 2 * (length - start) * (
-                math.prod(entry[0].shape[2:]) * entry[0].dtype.itemsize
-            )
+        nbytes = sum(self._write_bytes(length, start).values())
         # after the donation the old K/V arrays are gone: the pool's
         # state is rebound from the program's outputs and from nothing
         # else
@@ -476,6 +555,31 @@ class SlotCachePool:
             np.int32(slot), np.int32(start), np.int32(length),
         )
         return 1, nbytes
+
+    def _write_bytes(self, length: int, start: int) -> dict:
+        """K/V bytes a write of rows ``[start, length)`` puts into the
+        pool, by the kind of the entries they land in: a ring takes the
+        last rows it has room for."""
+        out = {LINEAR: 0, FULL_ROWS: 0, RING_ROWS: 0}
+        for name, entry in self.buffers.items():
+            kind = self.kinds.get(name, LINEAR)
+            k, v = entry[0], entry[1]
+            if kind == LINEAR:
+                rows, per_row = length - start, 2 * math.prod(k.shape[2:])
+            else:
+                rows = min(length - start, k.shape[2])
+                per_row = k.shape[1] * (k.shape[3] + v.shape[3])
+            out[kind] += rows * per_row * k.dtype.itemsize
+        return out
+
+    def bytes_by_kind(self, length: int, start: int = 0) -> dict:
+        """``{"bytes_full", "bytes_ring"}`` of a write of rows ``[start,
+        length)``, for a pool whose blocks declare their geometry;
+        nothing for a pool of linear rows."""
+        if not self.kinds:
+            return {}
+        by = self._write_bytes(length, start)
+        return {"bytes_full": by[FULL_ROWS], "bytes_ring": by[RING_ROWS]}
 
     # -- accounting for telemetry ------------------------------------------
 

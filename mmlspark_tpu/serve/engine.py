@@ -72,9 +72,12 @@ from mmlspark_tpu.core.telemetry import (
 )
 from mmlspark_tpu.models.generate import (
     _cached_apply,
+    counts_routing,
+    declares_cache_kinds,
     greedy_next,
     init_cache,
     make_decode_block,
+    routing_totals,
 )
 from mmlspark_tpu.parallel.mesh import make_mesh, parse_mesh_axes
 from mmlspark_tpu.parallel.sharding import (
@@ -92,6 +95,28 @@ from mmlspark_tpu.testing.compile_guard import (
     ProgramCountingJit,
     jit_cache_size,
 )
+
+
+def _routing_drops(graph) -> bool:
+    """Whether ``graph`` routes tokens to experts in a way that can DROP
+    one (capacity dispatch): such routing is not causal over a padded
+    window, so the model prefills at exact length and takes no chunks.
+    A builder says so in ``extra["routing_drops"]``; one that records
+    only ``n_experts`` (``transformer_lm_moe``) drops."""
+    return bool(graph.extra.get("routing_drops",
+                                graph.extra.get("n_experts")))
+
+
+def _routing_attrs(stats, steps: int) -> dict:
+    """The routing counters a decode block or a prefill fetched beside
+    its tokens, as event attributes: per routed layer and per micro-step
+    the pairs that fell on held experts and the held experts hit."""
+    if not stats:
+        return {}
+    return {
+        name: round(float(np.mean(stats[name])) / max(steps, 1), 3)
+        for name in ("expert_pairs", "experts_hit")
+    }
 
 
 def _resolve_mesh(mesh):
@@ -157,9 +182,11 @@ class ServeEngine:
             raise FriendlyError(
                 f"'{graph.name}' uses a sliding window ({window}) "
                 f"smaller than cache_len ({cache_len}); the slot pool "
-                "holds linear per-slot buffers only — rolled circular "
-                "buffers are not pooled yet. Serve with cache_len <= "
-                "window, or build the model without window"
+                "keeps a ring only for a block that declares one "
+                "(cache_spec(), as hybrid_lm's window layers do), and "
+                f"'{graph.name}' has one window for linear rows. Serve "
+                "with cache_len <= window, or build the model without "
+                "window"
             )
         if decode_block < 1:
             raise FriendlyError(
@@ -191,7 +218,7 @@ class ServeEngine:
                     "can never be dispatched — drop the flag or shrink "
                     "the chunk"
                 )
-            if graph.extra.get("n_experts"):
+            if _routing_drops(graph):
                 raise FriendlyError(
                     f"'{graph.name}' is a MoE model, which prefills at "
                     "exact length (expert-capacity routing is not "
@@ -322,6 +349,13 @@ class ServeEngine:
                 f"role must be 'both', 'prefill' or 'decode', got "
                 f"{role!r}"
             )
+        if role != "both" and declares_cache_kinds(graph):
+            raise FriendlyError(
+                f"'{graph.name}' declares its cache geometry (rings, keys "
+                "and values of different widths); the fleet's KV hand-off "
+                "ships linear rows of one width — serve it with "
+                "role='both'"
+            )
         self.role = role
         #: KV hand-off payloads awaiting collection by the fleet
         #: (prefill-role engines fill this; ``take_handoffs`` drains)
@@ -441,11 +475,17 @@ class ServeEngine:
         # makes the pads invisible: pad positions sit AFTER every real
         # token, the real positions' K/V and logits cannot see them, and
         # ``last`` (traced, so no retrace per value) slices the true
-        # last-token logits out of the padded row. MoE models opt out —
-        # their expert-capacity routing is not causal (a pad consumes
-        # capacity that can change a REAL token's expert), so they keep
-        # exact-length prefill.
-        self._bucketed = not graph.extra.get("n_experts")
+        # last-token logits out of the padded row. A model whose routing
+        # can DROP a token opts out — expert-capacity routing is not
+        # causal (a pad consumes capacity that can change a REAL token's
+        # expert), so it keeps exact-length prefill. A family whose
+        # routing is per token and dropless (``routing_drops`` False in
+        # the builder's extra) buckets like any other.
+        self._bucketed = not _routing_drops(graph)
+        #: whether the decode block and the prefill hand back routing
+        #: counters beside their tokens (models/generate.py)
+        self._routed = counts_routing(graph)
+        routed = self._routed
 
         def _prefill(variables, prompt, last):
             # (1, B) padded prompt -> first greedy token (from position
@@ -453,11 +493,19 @@ class ServeEngine:
             # jit retraces per distinct BUCKET
             cache = init_cache(graph, variables, 1, prompt.shape[1])
             variables = _deq(variables)
-            logits, cache = _cached_apply(graph, variables, prompt,
-                                          cache, 0)
+            counters = {} if routed else None
+            logits, cache = _cached_apply(
+                graph, variables, prompt, cache, 0,
+                # the bucket's pads route nowhere
+                valid=(jnp.arange(prompt.shape[1]) <= last)[None, :]
+                if routed else None,
+                counters=counters,
+            )
             cur = jax.lax.dynamic_slice_in_dim(
                 logits, last, 1, axis=1
             )[:, 0]
+            if routed:
+                return greedy_next(cur), cache, routing_totals(counters)
             return greedy_next(cur), cache
 
         # both programs run behind the retrace watchdog: any compile
@@ -1247,7 +1295,7 @@ class ServeEngine:
                         with self._tracer.region(
                             "serve.prefill_dispatch", request=req.id
                         ):
-                            first_d, cache = self._prefill(
+                            first_d, cache, *stats = self._prefill(
                                 self.variables,
                                 jnp.asarray(padded[None]), p - 1,
                             )
@@ -1257,7 +1305,8 @@ class ServeEngine:
                         self._pool_write(req.id, slot, cache, p)
                         if self._prefix_cache:
                             self.pool.prefix_insert(slot, seq)
-                        first = self._first_token(req.id, first_d)
+                        first = self._first_token(req.id, first_d,
+                                                  timed, stats)
                         break
                     except Exception as e:
                         if is_resource_exhausted(e):
@@ -1388,13 +1437,22 @@ class ServeEngine:
             dispatches, nbytes = self.pool.write_prefill(
                 slot, cache, length, start=start
             )
-            r.count(dispatches=dispatches, bytes=nbytes)
+            by_kind = getattr(self.pool, "bytes_by_kind", None)
+            r.count(dispatches=dispatches, bytes=nbytes,
+                    **(by_kind(length, start) if by_kind else {}))
 
-    def _first_token(self, request: int, first_d) -> int:
+    def _first_token(self, request: int, first_d, prefill=None,
+                     stats=()) -> int:
         """The host's wait for a prefill's first token: the admit
-        path's one sync."""
+        path's one sync. A prefill that routed tokens to experts hands
+        its counters over in ``stats``; they come back in the same fetch
+        and are counted on ``prefill``, its ``serve.prefill`` region."""
         with self._tracer.region("serve.first_token", request=request):
-            return int(first_d[0])
+            if not stats:
+                return int(first_d[0])
+            first_h, stats_h = jax.device_get((first_d, stats[0]))
+            prefill.count(**_routing_attrs(stats_h, 1))
+            return int(first_h[0])
 
     # -- chunked prefill (docs/SERVING.md "Chunked prefill") ---------------
 
@@ -1836,7 +1894,7 @@ class ServeEngine:
                     live_in = self.pool.live
                     if prev is not None:
                         live_in = jnp.copy(live_in)
-                    toks, live, buffers, positions = self._decode(
+                    toks, live, buffers, positions, *stats = self._decode(
                         self.variables, self.pool.buffers,
                         self.pool.positions, live_in,
                         tok_d, rem_d, eos_d, t_block,
@@ -1857,7 +1915,8 @@ class ServeEngine:
             self._dispatch_gen += 1
             self.pool.defer_frees(self._dispatch_gen)
             self._inflight = {
-                "toks": toks, "live": live, "states": states,
+                "toks": toks, "live": live, "stats": stats,
+                "states": states,
                 "pre_pos": pre_pos, "t_block": t_block,
                 "family": family, "issued": issue.t0,
                 "gen": self._dispatch_gen, "tick": tick,
@@ -1888,8 +1947,13 @@ class ServeEngine:
                     if self._faults is not None:
                         self._faults.fire("serve.device_get", tick=tick,
                                           replica=self._replica)
-                    toks_h, live_h = jax.device_get(
-                        (block["toks"], block["live"])
+                    # a routed model's counters ride the same fetch
+                    # (an empty list for any other)
+                    toks_h, live_h, stats_h = jax.device_get(
+                        (block["toks"], block["live"], block["stats"])
+                    )
+                    block["routing"] = _routing_attrs(
+                        stats_h and stats_h[0], block["t_block"]
                     )
                     break
                 except Exception as e:
@@ -1995,6 +2059,7 @@ class ServeEngine:
             "dispatch", tick=tick, family=family,
             ms=round(exec_s * 1e3, 3),
             queued_ms=round(queued_s * 1e3, 3), tokens=n_tokens,
+            **inflight.get("routing", {}),
         )
         if __debug__:
             # device/host parity holds row by row for every request
@@ -2097,7 +2162,7 @@ class ServeEngine:
                     if self._faults is not None:
                         self._faults.fire("serve.decode", tick=tick,
                                           replica=self._replica)
-                    toks, live, buffers, positions = self._decode(
+                    toks, live, buffers, positions, *stats = self._decode(
                         self.variables, self.pool.buffers,
                         self.pool.positions, self.pool.live,
                         tok_d, rem_d, eos_d, t_block,
@@ -2129,7 +2194,8 @@ class ServeEngine:
             # the dispatch SUCCEEDED and the pool is rebound: the sync
             # loop pays its block's full device time in the fetch
             block = {
-                "toks": toks, "live": live, "t_block": t_block,
+                "toks": toks, "live": live, "stats": stats,
+                "t_block": t_block,
                 "states": states, "pre_pos": pre_pos,
                 "n_active": n_active, "family": family,
             }
@@ -2211,6 +2277,7 @@ class ServeEngine:
         self.recorder.record(
             "dispatch", tick=tick, family=family,
             ms=round(decode_s * 1e3, 3), tokens=n_tokens,
+            **block.get("routing", {}),
         )
         if __debug__:
             # the device live mask and the host's retirement

@@ -18,10 +18,13 @@ import jax.numpy as jnp
 import pytest
 
 from mmlspark_tpu.ops.flash_attention import (
+    cache_row_write,
     flash_attention,
     flash_decode,
+    flash_decode_grouped,
     paged_flash_decode,
 )
+from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
 
 HEADS, HEAD_DIM, CACHE = 12, 64, 1024
 GPT2_SMALL = dict(vocab_size=50257, d_model=768, heads=HEADS, depth=12,
@@ -144,7 +147,67 @@ def _prefill():
     return prefill, [variables, jax.ShapeDtypeStruct((1, CACHE), jnp.int32)]
 
 
+# -- hybrid_lm's kernels at the benchmark cell's shapes (MiMo-V2-Flash:
+# 64 query heads, q/k 192 and v 128, 4 KV heads over 4,096 rows or 8 over
+# a ring of 128, 64 slots; 16 held experts of 4,096 x 2,048)
+
+
+def _bf16(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+
+def _hybrid_decode(hk, rows, sink):
+    args = [_bf16(64, 1, 64, 192), _bf16(64, hk, rows, 192),
+            _bf16(64, hk, rows, 128),
+            jax.ShapeDtypeStruct((64,), jnp.int32)]
+    if not sink:
+        return (lambda q, k, v, n: flash_decode_grouped(
+            q, k, v, n, interpret=False), args)
+    return (lambda q, k, v, n, s: flash_decode_grouped(
+        q, k, v, n, sink=s, interpret=False),
+        args + [jax.ShapeDtypeStruct((64,), jnp.float32)])
+
+
+def _hybrid_row_write(hk, rows):
+    return (lambda k, v, kn, vn, at: cache_row_write(
+        k, v, kn, vn, at, interpret=False),
+        [_bf16(64, hk, rows, 192), _bf16(64, hk, rows, 128),
+         _bf16(64, hk, 192), _bf16(64, hk, 128),
+         jax.ShapeDtypeStruct((64,), jnp.int32)])
+
+
+def _hybrid_forward(hk, window, block, s=4096):
+    args = [_bf16(1, s, 64, 192), _bf16(1, s, hk, 192),
+            _bf16(1, s, hk, 128)]
+    if window is None:
+        return (lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block=block, interpret=False), args)
+    return (lambda q, k, v, sink: flash_attention(
+        q, k, v, causal=True, window=window, sink=sink, block=block,
+        interpret=False),
+        args + [jax.ShapeDtypeStruct((64,), jnp.float32)])
+
+
+def _grouped(rows, tm, n, k):
+    tiles = rows // tm
+    return (lambda x, w, g, live: grouped_matmul(
+        x, w, g, live, tm=tm, interpret=False),
+        [_bf16(rows, k), _bf16(16, k, n),
+         jax.ShapeDtypeStruct((tiles,), jnp.int32),
+         jax.ShapeDtypeStruct((), jnp.int32)])
+
+
 CASES = {
+    "hybrid_decode_full_4096": lambda: _hybrid_decode(4, 4096, False),
+    "hybrid_decode_ring_128_sink": lambda: _hybrid_decode(8, 128, True),
+    "hybrid_row_write_full": lambda: _hybrid_row_write(4, 4096),
+    "hybrid_row_write_ring": lambda: _hybrid_row_write(8, 128),
+    "hybrid_fwd_full_4096": lambda: _hybrid_forward(4, None, 512),
+    "hybrid_fwd_swa_sink_4096": lambda: _hybrid_forward(8, 128, 512),
+    "hybrid_fwd_swa_sink_256": lambda: _hybrid_forward(8, 128, 128, s=256),
+    "grouped_matmul_decode_up": lambda: _grouped(1024, 64, 2048, 4096),
+    "grouped_matmul_decode_down": lambda: _grouped(1024, 64, 4096, 2048),
+    "grouped_matmul_prefill_up": lambda: _grouped(40960, 512, 2048, 4096),
     "flash_fwd_full": lambda: _attention(),
     "flash_fwd_causal": lambda: _attention(causal=True),
     "flash_fwd_windowed": lambda: _attention(causal=True, window=256),
