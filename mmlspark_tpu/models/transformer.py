@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from mmlspark_tpu.core.exceptions import ParamError
+from mmlspark_tpu.models.generate import HeadMajorKV
 from mmlspark_tpu.models.graph import FINAL_NODE, NamedGraph
 from mmlspark_tpu.models.registry import register_model
 from mmlspark_tpu.ops.attention import dense_attention
@@ -230,6 +231,40 @@ class SelfAttention(nn.Module):
                     v_scale=cscales[1] if cscales else None,
                     mesh=self.mesh,
                 )
+            elif isinstance(cache, HeadMajorKV):
+                # the one-device bf16 slot pool (serve/cache_pool.py):
+                # (S, hk, cache_len, d) rows, which the pool says with
+                # the entry's type. The step's row is written in place
+                # and the kernel streams (rows, d) tiles of each KV
+                # head: no relayout of the pool on either side
+                if not (per_row and decode and t == 1) or (
+                        self.window is not None
+                        and self.window < cache.k.shape[2]):
+                    raise ParamError(
+                        "head-major caches serve per-row single-token "
+                        "full-window decode only (the serve engine's "
+                        "fused decode step); prefill uses the linear "
+                        "cache path"
+                    )
+                from mmlspark_tpu.ops.attention import decode_live_lengths
+                from mmlspark_tpu.ops.flash_attention import (
+                    cache_row_write,
+                    flash_decode_grouped,
+                )
+
+                # (b, hk, d) -> the entry's own heads and width: packed
+                # rows hold adjacent heads side by side (lane_pack)
+                packed = (b, cache.k.shape[1], -1)
+                new_cache = HeadMajorKV(*cache_row_write(
+                    *cache, k[:, 0].reshape(packed), v[:, 0].reshape(packed),
+                    pos))
+                # named as the trace has always shown this module's
+                # decode kernel (``attn.N``), where the decode metrics
+                # look: the kernel is jitted where it stands, so the
+                # module's scope no longer names it
+                o = flash_decode_grouped(
+                    q, *new_cache, decode_live_lengths(pos, b, live=live),
+                    name="attn")
             else:
                 ck, cv, *cscales = cache
                 if cscales and not (

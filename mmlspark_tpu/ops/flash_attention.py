@@ -993,50 +993,94 @@ def flash_decode(q, k, v, lengths, *, scale=None, block: int = 128,
 # denominator when the walk ends. The same kernel reads a full-length
 # cache (``lengths = pos + 1``) and a ring (``lengths = min(pos + 1,
 # W)``: every written slot of a ring is inside the window).
+#
+# A group of fewer than 8 query heads (MHA is a group of one) fills less
+# than one sublane tile, and a grid step that holds one KV head of one
+# slot costs more than the rows it streams. There a step takes SEVERAL
+# KV heads of one slot, blocks ``(1, Hb, blk, D)`` of the 4-D caches, in
+# one batched product: the slot's one live length clamps them all.
+
+#: what a grid step's K and V blocks may take of VMEM, double-buffered and
+#: as VMEM holds them (the minor dimension in whole lanes): a quarter of
+#: the 16 MiB a kernel gets by default, which leaves the float32 copies
+#: of the scores and of V their room
+_DECODE_KV_VMEM = 4 << 20
+
+# grid (slot, head group, kv-block)
+_DECODE_HEADS_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL, pltpu.ARBITRARY),
+)
 
 
 def _decode_group_kernel(len_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
                          m_scr, l_scr, acc_scr, *,
-                         scale: float, blk: int, kv_heads: int):
-    row = pl.program_id(0)   # batch * kv_heads + kv head
-    kb = pl.program_id(1)
+                         scale: float, blk: int, kv_heads: int,
+                         kv_axis: int = 1):
+    # one KV head a step: q (G, Dk), k (blk, Dk), grid row = batch *
+    # kv_heads + kv head. Several a step: q (Hb, G, Dk), k (Hb, blk, Dk),
+    # the leading dimension a batch of the two products, grid row = the
+    # slot (``kv_heads`` 1) and the kv-block on grid axis ``kv_axis`` 2
+    row = pl.program_id(0)
+    kb = pl.program_id(kv_axis)
     length = len_ref[row // kv_heads]  # live positions [0, length)
+    heads = tuple(range(q_ref.ndim - 3))  # the products' batch dimensions
+    n = len(heads)
 
     @pl.when(kb == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
     @pl.when(kb * blk < length)
     def _update():
         s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            q_ref[0], k_ref[0], (((n + 1,), (n + 1,)), (heads, heads)),
             preferred_element_type=jnp.float32,
-        ) * scale  # (G, blk) f32
-        kpos = kb * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        ) * scale  # (..., G, blk) f32
+        kpos = kb * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                   n + 1)
         s = jnp.where(kpos >= length, NEG_INF, s)
-        m_prev = m_scr[:, :1]
+        m_prev = m_scr[..., :1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[:] = jnp.broadcast_to(
-            l_scr[:, :1] * corr + p.sum(axis=-1, keepdims=True),
+        l_scr[...] = jnp.broadcast_to(
+            l_scr[..., :1] * corr + p.sum(axis=-1, keepdims=True),
             l_scr.shape,
         )
         # P.V in f32, as _decode_kernel keeps it: the read is bound by
         # the K/V stream, not by these few rows of products
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p, v_ref[0].astype(jnp.float32),
+            (((n + 1,), (n,)), (heads, heads)),
             preferred_element_type=jnp.float32,
         )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
 
-    @pl.when(kb == pl.num_programs(1) - 1)
+    @pl.when(kb == pl.num_programs(kv_axis) - 1)
     def _finalize():
         o_ref[0] = _with_sink(
-            m_scr[:, :1], l_scr[:, :1], acc_scr[:], sink_ref[0][:, :1]
+            m_scr[..., :1], l_scr[..., :1], acc_scr[...],
+            sink_ref[0][..., :1]
         ).astype(o_ref.dtype)
+
+
+def _heads_and_rows(hk: int, cache_len: int, block: int, dk: int, dv: int,
+                    itemsize: int) -> tuple[int, int]:
+    """``(heads, rows)`` of one grid step when a group is smaller than a
+    sublane tile. Heads before rows: as many rows, 128 at least and
+    ``block`` at most, as let ALL of a slot's heads fit
+    ``_DECODE_KV_VMEM`` double-buffered, then the largest divisor of
+    ``hk`` that fits beside that many rows. (On a v5e at 10 heads of 128
+    lanes x 1,024 rows, live lengths 32-400: 10 heads x 256 rows took
+    70 us a call, 5 x 512 99, 10 x 512 86, 1 x 256 229; all rows live,
+    120 us each: my chip run, PR 30.)"""
+    row = (_round_up(dk, LANES) + _round_up(dv, LANES)) * itemsize
+    most = _DECODE_KV_VMEM // (2 * row)  # a step's rows over all its heads
+    blk = _decode_block(cache_len, max(min(block, most // hk), LANES))
+    return max(hb for hb in range(1, hk + 1)
+               if hk % hb == 0 and (hb * blk <= most or hb == 1)), blk
 
 
 def _row_write_kernel(at_ref, kn_ref, vn_ref, kc_ref, vc_ref, ko_ref,
@@ -1069,12 +1113,21 @@ def cache_row_write(k, v, k_new, v_new, at, *,
     This kernel aliases the caches to its outputs and rewrites only the
     sublane tile that holds the row: the layout stays the decode
     kernel's."""
-    b, hk, L, dk = k.shape
-    dv = v.shape[3]
     if interpret is None:
         from mmlspark_tpu.core.env import is_tpu
 
         interpret = not is_tpu()
+    return _cache_row_write(k, v, k_new, v_new, at, interpret=bool(interpret))
+
+
+# jitted where they stand: a decode block calls each kernel once a layer
+# and the engine builds a block a ladder size, so the kernel's body is
+# traced and lowered ONCE a signature, not 36 x 6 times (a traced kernel
+# cost the host 0.12 s: 27 s of a warm set-up, my chip run, PR 30)
+@partial(jax.jit, static_argnames=("interpret",))
+def _cache_row_write(k, v, k_new, v_new, at, *, interpret: bool):
+    b, hk, L, dk = k.shape
+    dv = v.shape[3]
     # whole sublanes of the cache's dtype, or the whole of a shorter cache
     tile = 32 // k.dtype.itemsize
     if L % tile:
@@ -1124,30 +1177,64 @@ def flash_decode_grouped(q, k, v, lengths, *, sink=None, scale=None,
     (``lengths[b] == 0`` yields zeros). ``sink`` ((H,) float) joins the
     softmax's denominator only. Returns (B, 1, H, Dv) in ``q``'s dtype.
 
+    Caches whose rows are ``f * Dk`` and ``f * Dv`` wide are PACKED
+    (``models.generate.lane_pack``): ``(B, Hkv / f, L, f * Dk)``, ``f``
+    adjacent KV heads side by side in one row. Each query head is then
+    laid, among zeros, over the lanes of its own KV head, the ``f`` heads
+    of a row are read as one head with ``f`` groups, and each query head
+    keeps its own lanes of the result.
+
     The KV grid dimension streams ``L`` in blocks of ``block`` rows (the
     whole of a shorter cache) and the index map clamps at each row's
-    last live block, so dead blocks are never fetched. Inference only,
-    one device only. ``name`` names the kernel in a device trace."""
+    last live block, so dead blocks are never fetched. A group of fewer
+    than 8 query heads takes several KV heads of a slot a grid step
+    (:func:`_heads_and_rows`). Inference only, one device only. ``name``
+    names the kernel in a device trace."""
+    if interpret is None:
+        from mmlspark_tpu.core.env import is_tpu
+
+        interpret = not is_tpu()
+    return _flash_decode_grouped(
+        q, k, v, jnp.asarray(lengths), sink, scale=scale, block=block,
+        interpret=bool(interpret), name=name)
+
+
+@partial(jax.jit, static_argnames=("scale", "block", "interpret", "name"))
+def _flash_decode_grouped(q, k, v, lengths, sink, *, scale, block: int,
+                          interpret: bool, name):
     if q.ndim != 4 or q.shape[1] != 1:
         raise ValueError(
             "flash_decode_grouped takes a SINGLE query token per row: q "
             f"must be (B, 1, H, Dk), got {q.shape}"
         )
     b, _, h, dk = q.shape
+    f = k.shape[3] // dk if k.ndim == 4 else 0
     if (k.ndim != 4 or v.ndim != 4 or k.shape[:3] != v.shape[:3]
-            or k.shape[0] != b or k.shape[3] != dk or h % k.shape[1]):
+            or k.shape[0] != b or k.shape[3] != f * dk or v.shape[3] % f
+            or h % (k.shape[1] * f)):
         raise ValueError(
             "flash_decode_grouped needs head-major caches (B, Hkv, L, Dk) "
-            f"and (B, Hkv, L, Dv) with Hkv dividing H={h}, got "
-            f"{k.shape} / {v.shape}"
+            f"and (B, Hkv, L, Dv) with Hkv dividing H={h}, or f heads "
+            f"packed in a row, got {k.shape} / {v.shape}"
         )
+    if f > 1:
+        # query head i reads KV head i // g, which lies in the lanes
+        # [(i // g) % f * Dk, +Dk) of packed row head i // (g * f)
+        g = h // (k.shape[1] * f)
+        mine = jax.nn.one_hot((jnp.arange(h) // g) % f, f, dtype=q.dtype)
+        wide = (q[..., None, :] * mine[:, :, None]).reshape(b, 1, h, f * dk)
+        out = flash_decode_grouped(
+            wide, k, v, lengths, sink=sink,
+            scale=dk ** -0.5 if scale is None else scale, block=block,
+            interpret=interpret, name=name)
+        out = out.reshape(b, 1, h, f, v.shape[3] // f)
+        return (out * mine[:, :, None]).sum(axis=3)
     if not (q.dtype == k.dtype == v.dtype):
         raise ValueError(
             "flash_decode_grouped requires q, k, v to share one dtype, "
             f"got {q.dtype}/{k.dtype}/{v.dtype}"
         )
     hk, L, dv = k.shape[1], k.shape[2], v.shape[3]
-    lengths = jnp.asarray(lengths)
     if lengths.shape != (b,):
         raise ValueError(
             f"lengths must be ({b},) — one live length per batch row — "
@@ -1156,12 +1243,10 @@ def flash_decode_grouped(q, k, v, lengths, *, sink=None, scale=None,
     g = h // hk
     if scale is None:
         scale = dk ** -0.5
-    if interpret is None:
-        from mmlspark_tpu.core.env import is_tpu
-
-        interpret = not is_tpu()
     lengths = jnp.clip(lengths.astype(jnp.int32), 0, L)
-    blk = _decode_block(L, block)
+    several = g < SUBLANES  # KV heads of a slot a grid step
+    hb, blk = (_heads_and_rows(hk, L, block, dk, dv, k.dtype.itemsize)
+               if several else (1, _decode_block(L, block)))
     if L % blk:
         raise ValueError(
             f"flash_decode_grouped streams the cache without a pad copy: "
@@ -1179,6 +1264,48 @@ def flash_decode_grouped(q, k, v, lengths, *, sink=None, scale=None,
         qb = jnp.pad(qb, ((0, 0), (0, gp - g), (0, 0)))
         sb = jnp.pad(sb, ((0, 0), (0, gp - g)), constant_values=NEG_INF)
     sb = jnp.broadcast_to(sb[:, :, None], (hk, gp, LANES))
+    named = {"name": name} if name else {}
+    if several:
+
+        def heads_im(slot, hg, j, lens):
+            return (slot, hg, 0, 0)
+
+        def kv_heads_im(slot, hg, j, lens):
+            last = jnp.maximum((lens[slot] + blk - 1) // blk - 1, 0)
+            return (slot, hg, jnp.minimum(j, last), 0)
+
+        out = pl.pallas_call(
+            partial(_decode_group_kernel, scale=scale, blk=blk, kv_heads=1,
+                    kv_axis=2),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(b, hk // hb, n_blk),
+                in_specs=[
+                    pl.BlockSpec((1, hb, gp, dk), heads_im,
+                                 memory_space=pltpu.VMEM),
+                    pl.BlockSpec((1, hb, blk, dk), kv_heads_im,
+                                 memory_space=pltpu.VMEM),
+                    pl.BlockSpec((1, hb, blk, dv), kv_heads_im,
+                                 memory_space=pltpu.VMEM),
+                    pl.BlockSpec((1, hb, gp, LANES),
+                                 lambda slot, hg, j, lens: (hg, 0, 0, 0),
+                                 memory_space=pltpu.VMEM),
+                ],
+                out_specs=pl.BlockSpec((1, hb, gp, dv), heads_im,
+                                       memory_space=pltpu.VMEM),
+                scratch_shapes=[
+                    pltpu.VMEM((hb, gp, LANES), jnp.float32),  # running max
+                    pltpu.VMEM((hb, gp, LANES), jnp.float32),  # normalizer
+                    pltpu.VMEM((hb, gp, dv), jnp.float32),     # accumulator
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, hk, gp, dv), q.dtype),
+            compiler_params=_DECODE_HEADS_SEMANTICS,
+            interpret=bool(interpret),
+            **named,
+        )(lengths, qb.reshape(b, hk, gp, dk), k, v,
+          sb.reshape(hk // hb, hb, gp, LANES))
+        return out[:, :, :g].reshape(b, 1, h, dv)
     kb = k.reshape(b * hk, L, dk)
     vb = v.reshape(b * hk, L, dv)
 
@@ -1214,7 +1341,7 @@ def flash_decode_grouped(q, k, v, lengths, *, sink=None, scale=None,
         out_shape=jax.ShapeDtypeStruct((b * hk, gp, dv), q.dtype),
         compiler_params=_DECODE_SEMANTICS,
         interpret=bool(interpret),
-        **({"name": name} if name else {}),
+        **named,
     )(lengths, qb, kb, vb, sb)
     return out[:, :g].reshape(b, 1, h, dv)
 

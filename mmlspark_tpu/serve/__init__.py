@@ -2,7 +2,8 @@
 
 The subsystem that turns ``models/generate.py``'s per-call static-shape
 decode into a multi-tenant engine (docs/SERVING.md): a preallocated
-``(slots, cache_len, hk, d)`` K/V pool (:mod:`cache_pool`), a
+K/V pool of ``slots x cache_len`` rows (:mod:`cache_pool`; head-major
+on one device in bf16, linear ``(slots, cache_len, hk, d)`` otherwise), a
 tick-based continuous-batching scheduler (:mod:`scheduler`), the public
 ``ServeEngine.submit/step/run`` API with admission control and
 per-request deadlines (:mod:`engine`), serving observability as

@@ -3,9 +3,10 @@
 ``models/generate.py`` preallocates one ``(B, total, hk, d)`` K/V buffer
 pair per block PER CALL — correct for offline batch decode, wasteful for
 serving, where requests arrive and retire continuously. The pool flips
-the allocation: ONE ``(S, cache_len, hk, d)`` buffer pair per block for
-the whole process (head geometry from
-:func:`mmlspark_tpu.models.generate.cache_geometry`, the same fused-qkv
+the allocation: ONE buffer pair per block for the whole process —
+head-major ``(S, hk, cache_len, d)`` on one device in bf16, linear
+``(S, cache_len, hk, d)`` in int8 or under a mesh (head geometry from
+:func:`mmlspark_tpu.models.generate.cache_specs`, the same fused-qkv
 readout ``init_cache`` uses), where ``S`` is the number of serving slots.
 A request leases a slot for its lifetime, the prefill writes its
 prompt's K/V into positions ``[0, P)`` of that slot row, decode steps
@@ -46,8 +47,10 @@ from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.models.generate import (
     FULL_ROWS,
     LINEAR,
+    HeadMajorKV,
     RING_ROWS,
     cache_specs,
+    lane_pack,
 )
 from mmlspark_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
@@ -146,8 +149,10 @@ def _write_slot(buffers, positions, live, prefill_cache, slot, start,
     by the prefill cache's shape alone. A pool entry of four leaves is
     an int8 one, whose per-head scales are fixed here from the rows
     below ``length``. ``kinds`` (static) names the blocks whose entries
-    are head-major ``(S, hk, rows, d)`` by their own declaration: a
-    ``full`` one takes the same rows, transposed; a ``ring`` of ``R``
+    are head-major ``(S, hk, rows, d)``, by their own declaration or by
+    the pool's choice: a ``full`` one takes the same rows, transposed
+    (adjacent heads side by side where the entry is packed); a ``ring``
+    of ``R``
     rows takes, in row ``j``, the latest position below ``length`` that
     is congruent to ``j``: the prompt's last ``min(P, R)`` rows at
     ``pos % R``."""
@@ -157,10 +162,16 @@ def _write_slot(buffers, positions, live, prefill_cache, slot, start,
         if kind != LINEAR:
             placed = []
             for pool, filled in zip(entry, prefill_cache[name]):
+                # a packed entry (lane_pack) takes adjacent heads side
+                # by side: the same bytes, the head axis split
+                filled = filled[0].reshape(
+                    filled.shape[1], pool.shape[1], pool.shape[3])
                 values, written = _head_major_rows(
-                    kind, filled[0], pool.shape[2], start, length)
+                    kind, filled, pool.shape[2], start, length)
                 placed.append(_put_rows(pool, values, slot, written))
-            new_buffers[name] = tuple(placed)
+            # the entry's own type: a pair, or a HeadMajorKV
+            new_buffers[name] = jax.tree_util.tree_unflatten(
+                jax.tree_util.tree_structure(entry), placed)
             continue
         rows = min(prefill_cache[name][0].shape[1], entry[0].shape[1])
         ck, cv = (c[0, :rows] for c in prefill_cache[name])
@@ -196,18 +207,25 @@ class SlotCachePool:
     """Preallocated per-block K/V buffers with slot lease/free accounting.
 
     ``buffers`` is the live pytree the scheduler's jitted decode step
-    reads and returns — ``{block: (K, V)}`` with each array
-    ``(slots, cache_len, hk, d)`` bf16. The pool owns the host-side
+    reads and returns — ``{block: (K, V)}``. The pool owns the host-side
     bookkeeping (which slots are leased); the arrays themselves stay on
     device and are replaced functionally each tick.
 
-    A block that DECLARES its geometry (``cache_spec()``,
-    models/hybrid.py) gets a head-major entry instead, ``(slots, hk,
-    rows, dk)`` and ``(slots, hk, rows, dv)``: ``rows`` is ``cache_len``
-    for a ``full`` block and the window for a ``ring``, which holds
-    position ``p`` in row ``p % rows``. Both kinds live in this one
-    pool, are written by the one jitted ``_write_slot`` and read by the
-    one fused decode block.
+    On ONE device in bf16 every entry is HEAD-MAJOR, ``(slots, hk,
+    rows, dk)`` and ``(slots, hk, rows, dv)``: the layout the decode
+    step's row write updates in place and its kernel streams without a
+    copy. A block that DECLARES its geometry (``cache_spec()``,
+    models/hybrid.py) gets what it declared, ``full`` rows or a ``ring``
+    of its window's rows, which holds position ``p`` in row ``p %
+    rows``, as a plain pair. A block that declares nothing
+    (``transformer_lm``) gets kind ``full``, ``rows = cache_len``, as a
+    :class:`HeadMajorKV`, so that it reads the layout off the entry's
+    type, its heads packed to whole lanes where they divide
+    (``lane_pack``: 20 heads of 64 are stored ``(slots, 10, rows,
+    128)``). All live in this one pool, are written by the one jitted
+    ``_write_slot`` and read by the one fused decode block. Under a mesh
+    or in int8 an undeclared block keeps LINEAR rows, plain tuples of
+    ``(slots, cache_len, hk, d)`` arrays.
 
     ``kv_dtype="int8"`` (docs/PERFORMANCE.md "Quantized decode") stores
     K/V as int8 — HALF the bf16 pool's HBM bytes — and each block's
@@ -237,18 +255,16 @@ class SlotCachePool:
                 "serving engine needs the KV-cache decode path "
                 "(transformer_lm family)"
             )
-        #: ``{block: kind}`` for the blocks that declare their geometry
-        #: (head-major entries: full-length rows, or a ring)
-        self.kinds = {name: spec[0] for name, spec in specs.items()
-                      if spec[0] != LINEAR}
-        if self.kinds and kv_dtype != "bf16":
+        declared = {name: spec[0] for name, spec in specs.items()
+                    if spec[0] != LINEAR}
+        if declared and kv_dtype != "bf16":
             raise FriendlyError(
                 f"'{graph.name}' declares its cache geometry (rings, keys "
                 f"and values of different widths); kv_dtype={kv_dtype!r} "
                 "rows are linear rows of one width — serve it with "
                 "kv_dtype='bf16'"
             )
-        if self.kinds and mesh is not None and mesh.size > 1:
+        if declared and mesh is not None and mesh.size > 1:
             msize = int(mesh.shape.get(MODEL_AXIS, 1))
             uneven = [name for name, spec in specs.items()
                       if spec[2] % msize]
@@ -259,6 +275,15 @@ class SlotCachePool:
                 + (f" (and its '{MODEL_AXIS}' axis of {msize} does not "
                    f"divide the KV heads of {uneven[0]})" if uneven else "")
             )
+        #: ``{block: kind}`` of the HEAD-MAJOR entries (full-length
+        #: rows, or a ring): the declared ones, and on one device in
+        #: bf16 every other block's too, as ``full``. The int8 rows and
+        #: a mesh's pinned ``P(data, None, model, None)`` shardings keep
+        #: linear rows
+        self.kinds = dict(declared)
+        if mesh is None and kv_dtype == "bf16":
+            self.kinds = {name: declared.get(name, FULL_ROWS)
+                          for name in specs}
         geometry = {name: (hk, dk)
                     for name, (_k, _r, hk, dk, _dv) in specs.items()}
         self.mesh = mesh
@@ -303,26 +328,31 @@ class SlotCachePool:
                 else:
                     self._kv_shardings[name] = (sh, sh)
         self.buffers = {}
-        for name, (kind, rows, hk, d, dv) in specs.items():
+        for name, (_kind, rows, hk, d, dv) in specs.items():
             # K and V must be DISTINCT arrays: the engine's decode step
             # donates the whole buffer pytree (donate_argnums), and a
             # pair aliasing one allocation cannot be donated twice —
             # same for the int8 mode's two scale leaves
+            kind = self.kinds.get(name, LINEAR)
             if kind == LINEAR:
-                k = jnp.zeros((slots, cache_len, hk, d), store_dtype)
-                v = jnp.zeros((slots, cache_len, hk, d), store_dtype)
+                entry = (jnp.zeros((slots, cache_len, hk, d), store_dtype),
+                         jnp.zeros((slots, cache_len, hk, d), store_dtype))
             else:
-                # declared geometry, head-major: the decode kernel
-                # streams (rows, d) tiles of one KV head without a copy.
-                # A ring never needs more rows than the pool's length
+                # head-major: the decode kernel streams (rows, d) tiles
+                # of a KV head without a copy. A ring never needs more
+                # rows than the pool's length
                 rows = cache_len if kind == FULL_ROWS else min(
                     int(rows), cache_len)
-                k = jnp.zeros((slots, hk, rows, d), store_dtype)
-                v = jnp.zeros((slots, hk, rows, dv), store_dtype)
-            entry = (k, v)
+                # a block that declared nothing learns the layout from
+                # the entry's type, and reads rows packed to whole lanes
+                f, pair = ((1, tuple) if name in declared
+                           else (lane_pack(hk, d, dv), HeadMajorKV._make))
+                entry = pair((
+                    jnp.zeros((slots, hk // f, rows, f * d), store_dtype),
+                    jnp.zeros((slots, hk // f, rows, f * dv), store_dtype)))
             if quantized:
                 entry = (
-                    k, v,
+                    *entry,
                     jnp.ones((slots, hk), jnp.float32),
                     jnp.ones((slots, hk), jnp.float32),
                 )
@@ -574,8 +604,8 @@ class SlotCachePool:
 
     def bytes_by_kind(self, length: int, start: int = 0) -> dict:
         """``{"bytes_full", "bytes_ring"}`` of a write of rows ``[start,
-        length)``, for a pool whose blocks declare their geometry;
-        nothing for a pool of linear rows."""
+        length)``, for a pool that holds head-major entries; nothing for
+        a pool of linear rows."""
         if not self.kinds:
             return {}
         by = self._write_bytes(length, start)

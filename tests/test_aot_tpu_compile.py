@@ -10,6 +10,7 @@ that passes is not a chip run.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -29,6 +30,11 @@ from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
 HEADS, HEAD_DIM, CACHE = 12, 64, 1024
 GPT2_SMALL = dict(vocab_size=50257, d_model=768, heads=HEADS, depth=12,
                   d_ff=3072, max_len=CACHE)
+# the benchmark's serving configuration (gpt2-large.chat-backlog: 20 heads
+# x 64, 16 slots x 1,024); its 36 layers are cut to 2 here for time
+GPT2_LARGE = dict(vocab_size=50257, d_model=1280, heads=20, depth=2,
+                  d_ff=5120, max_len=CACHE)
+LARGE_SLOTS = 16
 
 
 @pytest.fixture(scope="module")
@@ -108,10 +114,10 @@ def _paged(slots, page_size, int8):
         args + [sc, sc])
 
 
-def _gpt2_small():
+def _gpt2(config=GPT2_SMALL):
     from mmlspark_tpu.models import build_model
 
-    graph = build_model("transformer_lm", **GPT2_SMALL)
+    graph = build_model("transformer_lm", **config)
     assert graph.extra["attn_impl"] == "flash"  # is_tpu patched -> auto
     variables = jax.eval_shape(
         graph.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
@@ -119,14 +125,23 @@ def _gpt2_small():
     return graph, variables
 
 
-def _decode_block(slots=8, t=32):
-    from mmlspark_tpu.models.generate import cache_geometry, make_decode_block
+def _pool_shapes(graph, variables, slots, **options):
+    """The entries ``SlotCachePool`` would hold for ``slots`` slots, as
+    shapes: a ONE-slot pool is built and its slot dimension widened, so
+    the layout under test is the pool's own choice."""
+    from mmlspark_tpu.serve.cache_pool import SlotCachePool
 
-    graph, variables = _gpt2_small()
-    buffers = {
-        name: (jax.ShapeDtypeStruct((slots, CACHE, hk, d), jnp.bfloat16),) * 2
-        for name, (hk, d) in cache_geometry(graph, variables).items()
-    }
+    pool = SlotCachePool(graph, variables, 1, CACHE, **options)
+    return pool, jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((slots,) + a.shape[1:], a.dtype),
+        pool.buffers)
+
+
+def _decode_block(slots=8, t=32, config=GPT2_SMALL):
+    from mmlspark_tpu.models.generate import make_decode_block
+
+    graph, variables = _gpt2(config)
+    _pool, buffers = _pool_shapes(graph, variables, slots)
     ints = jax.ShapeDtypeStruct((slots,), jnp.int32)
     live = jax.ShapeDtypeStruct((slots,), jnp.bool_)
     block = make_decode_block(graph)
@@ -138,7 +153,7 @@ def _decode_block(slots=8, t=32):
 def _prefill():
     from mmlspark_tpu.models.generate import _cached_apply, init_cache
 
-    graph, variables = _gpt2_small()
+    graph, variables = _gpt2()
 
     def prefill(v, prompt):
         cache = init_cache(graph, v, 1, prompt.shape[1])
@@ -197,7 +212,28 @@ def _grouped(rows, tm, n, k):
          jax.ShapeDtypeStruct((), jnp.int32)])
 
 
+def _large_decode(slots=LARGE_SLOTS):
+    """gpt2-large's decode read as the dense bf16 pool holds its rows:
+    20 heads x 64 two to a row, all ten row-heads a grid step."""
+    return (lambda q, k, v, n: flash_decode_grouped(
+        q, k, v, n, interpret=False),
+        [_bf16(slots, 1, 20, 64), _bf16(slots, 10, CACHE, 128),
+         _bf16(slots, 10, CACHE, 128),
+         jax.ShapeDtypeStruct((slots,), jnp.int32)])
+
+
+def _large_row_write(slots=LARGE_SLOTS):
+    return (lambda k, v, kn, vn, at: cache_row_write(
+        k, v, kn, vn, at, interpret=False),
+        [_bf16(slots, 10, CACHE, 128), _bf16(slots, 10, CACHE, 128),
+         _bf16(slots, 10, 128), _bf16(slots, 10, 128),
+         jax.ShapeDtypeStruct((slots,), jnp.int32)])
+
+
 CASES = {
+    "gpt2_large_decode_grouped_16": _large_decode,
+    "gpt2_large_decode_grouped_32": lambda: _large_decode(32),
+    "gpt2_large_row_write_16": _large_row_write,
     "hybrid_decode_full_4096": lambda: _hybrid_decode(4, 4096, False),
     "hybrid_decode_ring_128_sink": lambda: _hybrid_decode(8, 128, True),
     "hybrid_row_write_full": lambda: _hybrid_row_write(4, 4096),
@@ -237,55 +273,92 @@ def test_compiles_for_v5e(case, chip, monkeypatch):
     # its dense / interpret branch; the test steers it, not an option
     monkeypatch.setattr("mmlspark_tpu.core.env.is_tpu", lambda: True)
     fn, args = CASES[case]()
-    args = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
-        args,
-    )
-    compiled = jax.jit(fn).lower(*args).compile()
+    compiled = jax.jit(fn).lower(*_on(chip, args)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-def test_pool_write_updates_the_pool_in_place_on_v5e(kv_dtype, chip):
-    """The dense pool's jitted prefill write at GPT-2-small shapes, 8
-    slots, a 256-row bucket: every pool buffer is aliased to its output
-    and the temporaries stay under ONE buffer's size, so the donation
-    took and no copy of the pool exists beside the pool (a failed one
-    would add the pool's bytes to the chip's peak on every admission)."""
+def _on(chip, tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        tree)
+
+
+def _pool_copies(text: str, shape: tuple) -> list:
+    """The ``copy`` and ``transpose`` instructions of an optimised HLO
+    module whose result has a pool array's dimensions, in any order."""
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* (copy|copy-start|transpose)\(",
+                      line)
+        if m and sorted(int(n) for n in m.group(1).split(",")) == sorted(
+                shape):
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("config,slots,kv_dtype", [
+    (GPT2_SMALL, 8, "bf16"), (GPT2_SMALL, 8, "int8"),
+    (GPT2_LARGE, LARGE_SLOTS, "bf16"),
+], ids=["gpt2_small-bf16", "gpt2_small-int8", "gpt2_large-bf16"])
+def test_pool_write_updates_the_pool_in_place_on_v5e(config, slots,
+                                                     kv_dtype, chip,
+                                                     monkeypatch):
+    """The dense pool's jitted prefill write at real shapes, a 256-row
+    bucket, into the layout the pool itself chose: every pool buffer is
+    aliased to its output and the temporaries stay under ONE buffer's
+    size, so the donation took and no copy of the pool exists beside the
+    pool (a failed one would add the pool's bytes to the chip's peak on
+    every admission)."""
     import math
 
-    from mmlspark_tpu.models import build_model
-    from mmlspark_tpu.serve.cache_pool import SlotCachePool
-
-    # the program is the pool's own, and keyed by its arguments' shapes:
-    # a pool of any size lowers it at the real one
-    graph = build_model("transformer_lm", vocab_size=16, d_model=16,
-                        heads=2, depth=1, max_len=8, attn_impl="dense")
-    variables = graph.init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, 8), jnp.int32))
-    write = SlotCachePool(graph, variables, 2, 8, kv_dtype=kv_dtype)._write
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-
-    slots, rows, blocks = 8, 256, GPT2_SMALL["depth"]
-    store = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
-    kv = sds((slots, CACHE, HEADS, HEAD_DIM), store)
-    entry = (kv, kv)
-    if kv_dtype == "int8":
-        entry += (sds((slots, HEADS), jnp.float32),) * 2
-    buffers = {f"block{i}": entry for i in range(blocks)}
-    source = sds((1, rows, HEADS, HEAD_DIM), jnp.bfloat16)
+    monkeypatch.setattr("mmlspark_tpu.core.env.is_tpu", lambda: True)
+    graph, variables = _gpt2(config)
+    pool, buffers = _pool_shapes(graph, variables, slots, kv_dtype=kv_dtype)
+    heads = config["heads"]
+    source = jax.ShapeDtypeStruct((1, 256, heads, HEAD_DIM), jnp.bfloat16)
     cache = {name: (source, source) for name in buffers}
-    scalar = sds((), jnp.int32)
-    compiled = write.lower(
-        buffers, sds((slots,), jnp.int32), sds((slots,), jnp.bool_),
-        cache, scalar, scalar, scalar,
-    ).compile()
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    compiled = pool._write.lower(*_on(chip, (
+        buffers, jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_), cache, scalar, scalar,
+        scalar,
+    ))).compile()
     memory = compiled.memory_analysis()
+    kv = buffers["block0"][0]
     one = math.prod(kv.shape) * kv.dtype.itemsize
-    assert memory.alias_size_in_bytes >= 2 * blocks * one
+    assert one == slots * CACHE * heads * HEAD_DIM * kv.dtype.itemsize
+    assert memory.alias_size_in_bytes >= 2 * config["depth"] * one
     assert memory.temp_size_in_bytes < one
+    assert not _pool_copies(compiled.as_text(), kv.shape)
+
+
+def test_gpt2_large_decode_block_reads_the_pool_where_it_lies(
+        chip, monkeypatch):
+    """The fused decode block at gpt2-large's own shapes (20 heads x 64,
+    16 slots x 1,024; 2 of its 36 layers), over the entries the pool
+    itself lays out: it compiles for the described v5e, its optimised
+    HLO holds no ``copy`` or ``transpose`` of a pool-sized operand (the
+    rows are written and read where they lie: heads of 64 two to a row
+    of 128 lanes, which the chip holds as the kernels read them), the
+    pool is updated in place, and the temporaries stay under 1 GB."""
+    from mmlspark_tpu.models.generate import HeadMajorKV
+
+    monkeypatch.setattr("mmlspark_tpu.core.env.is_tpu", lambda: True)
+    fn, args = _decode_block(slots=LARGE_SLOTS, t=4, config=GPT2_LARGE)
+    buffers = args[1]
+    for entry in buffers.values():
+        assert isinstance(entry, HeadMajorKV)
+        assert entry.k.shape == (LARGE_SLOTS, 10, CACHE, 128)
+    compiled = jax.jit(fn, donate_argnums=(1, 2, 3)).lower(
+        *_on(chip, args)).compile()
+    text = compiled.as_text()
+    one = LARGE_SLOTS * 10 * CACHE * 128 * 2
+    assert not _pool_copies(text, (LARGE_SLOTS, 10, CACHE, 128))
+    # the kernel under the name the decode metrics look for, once a layer
+    assert len(set(re.findall(r"%(attn\.\d+) = ", text))) == 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 4 * one
+    assert memory.temp_size_in_bytes < 1 << 30
 
 
 def test_pool_refuses_a_page_table_the_kernel_cannot_hold():

@@ -24,7 +24,12 @@ from mmlspark_tpu.ops.attention import (
     dense_attention,
     mask_value,
 )
-from mmlspark_tpu.ops.flash_attention import _decode_block, flash_decode
+from mmlspark_tpu.ops.flash_attention import (
+    _decode_block,
+    _heads_and_rows,
+    flash_decode,
+    flash_decode_grouped,
+)
 
 
 def _qkv(b, L, h, hk, d, dtype, seed=0):
@@ -124,6 +129,50 @@ def test_lengths_clip_to_cache_len():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5
     )
+
+
+# -- the grouped kernel, a group smaller than a sublane tile ----------------
+
+
+@pytest.mark.parametrize("hk,g,pack,budget", [
+    (20, 1, 1, None),       # gpt2-large's heads: all 20 a grid step
+    (20, 1, 2, None),       # the same, two heads to a row of 128 lanes
+    (20, 1, 1, 4 * 128 * 512),   # room for 2 head-blocks of 128 rows
+    (3, 1, 1, 4 * 128 * 512),    # a prime: no divisor but 1 fits
+    (3, 2, 1, None),        # a group of 2, every head a step
+    (4, 2, 2, None),        # GQA, packed: a row's two heads, two groups
+    (2, 8, 2, None),        # a packed group of 8: one row-head a step
+], ids=["mha20", "mha20-packed", "mha20-2heads", "prime3-1head", "gqa3",
+        "gqa4-packed", "group8-packed"])
+def test_grouped_kernel_small_groups_match_dense(hk, g, pack, budget,
+                                                 monkeypatch):
+    """``flash_decode_grouped`` over head-major rows, lengths 0, 1, ``L``
+    and ragged, against ``dense_attention`` over the same rows laid
+    linear: several KV heads of a slot a grid step when the group is
+    under 8, as many as the budget holds and ``hk`` divides by."""
+    from mmlspark_tpu.ops import flash_attention as fa
+
+    L, d = 384, 64
+    if budget is not None:
+        monkeypatch.setattr(fa, "_DECODE_KV_VMEM", budget)
+        want_heads = {20: 2, 3: 1}[hk]
+        assert _heads_and_rows(hk, L, 512, d, d, 2) == (want_heads, 128)
+    elif g * pack < 8:
+        assert _heads_and_rows(hk // pack, L, 512, pack * d, pack * d,
+                               2)[0] == hk // pack
+    q, k, v = _qkv(6, L, hk * g, hk, d, jnp.bfloat16)
+    lengths = jnp.asarray([0, 1, L, 129, 37, 256], jnp.int32)
+
+    def head_major(x):      # (B, L, hk, d) -> (B, hk / f, L, f * d)
+        return jnp.moveaxis(x.reshape(6, L, hk // pack, pack * d), 1, 2)
+
+    out = flash_decode_grouped(q, head_major(k), head_major(v), lengths)
+    ref = _dense_ref(q, k, v, lengths)
+    assert out.shape == ref.shape and out.dtype == q.dtype
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32),
+        atol=1e-2, rtol=1e-2)
+    assert not np.asarray(out[0], np.float32).any()
 
 
 def test_decode_block_prefers_exact_divisors():

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.core.metrics_contracts import MetricData
 from mmlspark_tpu.models import build_model, generate
+from mmlspark_tpu.models.generate import HeadMajorKV
 from mmlspark_tpu.serve import ServeEngine, SlotCachePool
 from mmlspark_tpu.serve.cache_pool import KV_SCALE_MARGIN, kv_head_scales
 from mmlspark_tpu.testing.compile_guard import (
@@ -68,9 +69,12 @@ def test_slot_pool_lease_free_accounting():
         pool.free(b)  # double free
     assert pool.lease() == b  # the freed slot is reusable
 
-    # buffer geometry: one (K, V) pair per cache-accepting block, slot-major
-    for ck, cv in pool.buffers.values():
-        assert ck.shape[:2] == (3, 16) and ck.dtype == jnp.bfloat16
+    # buffer geometry: one (K, V) pair per cache-accepting block,
+    # slot-major and, in bf16 on one device, head-major within a slot
+    for entry in pool.buffers.values():
+        ck, cv = entry
+        assert isinstance(entry, HeadMajorKV)
+        assert ck.shape == (3, 2, 16, 16) and ck.dtype == jnp.bfloat16
         assert cv.shape == ck.shape
 
 
@@ -86,11 +90,11 @@ def test_slot_pool_guards():
 # -- the pool's one jitted write -------------------------------------------
 
 
-def _random_pool(kv_dtype, slots, cache_len, seed=0):
+def _random_pool(kv_dtype, slots, cache_len, seed=0, **model):
     """A pool whose every array holds seeded noise, so a row the write
     must leave alone is told from one it never touched, with all but
     one slot leased."""
-    m = _tiny(max_len=64)
+    m = _tiny(max_len=64, **model)
     v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
     pool = SlotCachePool(m, v, slots=slots, cache_len=cache_len,
                          kv_dtype=kv_dtype)
@@ -99,8 +103,9 @@ def _random_pool(kv_dtype, slots, cache_len, seed=0):
     for name, entry in pool.buffers.items():
         kv = [rng.integers(-127, 128, size=a.shape) for a in entry[:2]]
         scales = [rng.uniform(0.5, 2.0, size=a.shape) for a in entry[2:]]
-        noisy[name] = tuple(
-            jnp.asarray(x, a.dtype) for x, a in zip(kv + scales, entry)
+        noisy[name] = jax.tree_util.tree_unflatten(
+            jax.tree_util.tree_structure(entry),
+            [jnp.asarray(x, a.dtype) for x, a in zip(kv + scales, entry)],
         )
     pool.buffers = noisy
     for _ in range(slots - 1):
@@ -108,15 +113,20 @@ def _random_pool(kv_dtype, slots, cache_len, seed=0):
     return pool
 
 
-def _source_cache(pool, rows, length, dtype, seed=1):
-    """A batch-1 prefill cache of ``rows`` rows whose rows from
-    ``length`` on hold a sentinel no prompt row comes near."""
+def _source_cache(pool, rows, length, dtype, seed=1, heads=None):
+    """A batch-1 LINEAR prefill cache of ``rows`` rows whose rows from
+    ``length`` on hold a sentinel no prompt row comes near. ``heads``
+    is the model's ``(hk, d)`` where the pool's entry does not show it
+    (a packed one)."""
     rng = np.random.default_rng(seed)
     cache = {}
     for name, entry in pool.buffers.items():
+        k = entry[0]
+        hk_d = heads or ((k.shape[1], k.shape[3])
+                         if isinstance(entry, HeadMajorKV) else k.shape[2:])
         pair = []
         for _ in range(2):
-            x = rng.normal(size=(1, rows) + entry[0].shape[2:]) * 3.0
+            x = rng.normal(size=(1, rows) + tuple(hk_d)) * 3.0
             x[0, length:] = 1e4
             pair.append(jnp.asarray(x, dtype))
         cache[name] = tuple(pair)
@@ -128,27 +138,34 @@ def _host(tree):
 
 
 @pytest.mark.parametrize(
-    "kv_dtype,src_dtype,rows,slot,start,length",
+    "kv_dtype,src_dtype,rows,slot,start,length,d_model",
     [
-        ("bf16", jnp.bfloat16, 16, 1, 0, 11),
-        ("bf16", jnp.bfloat16, 16, 2, 4, 13),
-        ("bf16", jnp.float32, 8, 2, 0, 5),
+        ("bf16", jnp.bfloat16, 16, 1, 0, 11, 32),
+        ("bf16", jnp.bfloat16, 16, 2, 4, 13, 32),
+        ("bf16", jnp.float32, 8, 2, 0, 5, 32),
         # the chunked fill's carry: as many rows as the pool
-        ("bf16", jnp.bfloat16, 24, 1, 7, 19),
-        ("bf16", jnp.bfloat16, 16, 0, 0, 16),
-        ("int8", jnp.bfloat16, 16, 1, 0, 11),
-        ("int8", jnp.float32, 24, 2, 0, 24),
+        ("bf16", jnp.bfloat16, 24, 1, 7, 19, 32),
+        ("bf16", jnp.bfloat16, 16, 0, 0, 16, 32),
+        # heads of 64: two side by side in a row of 128 lanes
+        ("bf16", jnp.bfloat16, 16, 2, 4, 13, 256),
+        ("int8", jnp.bfloat16, 16, 1, 0, 11, 32),
+        ("int8", jnp.float32, 24, 2, 0, 24, 32),
     ],
     ids=["bf16", "bf16-resume", "bf16-cast", "bf16-carry", "bf16-full",
-         "int8", "int8-carry"],
+         "bf16-packed", "int8", "int8-carry"],
 )
 def test_write_prefill_matches_the_eager_write_bit_for_bit(
-        kv_dtype, src_dtype, rows, slot, start, length):
+        kv_dtype, src_dtype, rows, slot, start, length, d_model):
     """The jitted, donated write against a NumPy oracle of the eager
     one it replaced: rows ``[start, length)`` of one slot change and
-    nothing else does."""
-    pool = _random_pool(kv_dtype, slots=4, cache_len=24)
-    cache = _source_cache(pool, rows, length, src_dtype)
+    nothing else does. The bf16 pool's rows lie head-major, so the
+    oracle writes them transposed, adjacent heads side by side where
+    the pool packs them."""
+    heads = 4 if d_model == 256 else 2
+    pool = _random_pool(kv_dtype, slots=4, cache_len=24, d_model=d_model,
+                        heads=heads)
+    cache = _source_cache(pool, rows, length, src_dtype,
+                          heads=(heads, d_model // heads))
     want = _host(pool.buffers)
     want_pos, want_live = _host((pool.positions, pool.live))
     for name, entry in want.items():
@@ -164,16 +181,24 @@ def test_write_prefill_matches_the_eager_write_bit_for_bit(
                 entry[2 + i][slot] = scale
                 values = np.clip(np.round(f32 / scale[:, None]),
                                  -127, 127)
-            entry[i][slot, start:length] = values.astype(entry[i].dtype)
+            values = values.astype(entry[i].dtype)
+            if kv_dtype == "bf16":
+                assert isinstance(pool.buffers[name], HeadMajorKV)
+                packed = entry[i].shape[1], entry[i].shape[3]
+                assert packed == ((2, 128) if d_model == 256
+                                  else (heads, d_model // heads))
+                entry[i][slot, :, start:length] = np.moveaxis(
+                    values.reshape(len(values), *packed), 0, 1)
+            else:
+                entry[i][slot, start:length] = values
     want_pos[slot], want_live[slot] = length, True
 
     dispatches, nbytes = pool.write_prefill(slot, cache, length,
                                             start=start)
 
     assert dispatches == 1
-    hk_d = 32                       # d_model 32: heads x head_dim
     width = 1 if kv_dtype == "int8" else 2
-    assert nbytes == len(want) * 2 * (length - start) * hk_d * width
+    assert nbytes == len(want) * 2 * (length - start) * d_model * width
     got = _host(pool.buffers)
     for name, entry in want.items():
         assert len(got[name]) == len(entry)
@@ -474,6 +499,100 @@ def test_submit_validation():
         engine.submit(np.asarray([1, -2, 3], np.int32), max_new_tokens=2)
     # nothing above leaked into the accounting
     assert engine.metrics.submitted == 0 and not engine.busy
+
+
+@pytest.mark.parametrize("config,packed", [
+    ({}, 1),                                            # MHA: a group of 1
+    ({"heads": 4, "kv_heads": 2}, 1),                   # GQA, a group of 2
+    ({"d_model": 64, "heads": 8, "kv_heads": 1}, 1),    # a group of 8
+    ({"d_model": 128, "heads": 2}, 2),                  # MHA, heads of 64
+    ({"d_model": 256, "heads": 4, "kv_heads": 2}, 2),   # GQA, heads of 64
+    ({"model": "transformer_lm_moe", "n_experts": 2}, 1),
+], ids=["mha", "gqa2", "group8", "mha-packed", "gqa2-packed", "moe"])
+def test_head_major_pool_serves_generates_tokens(config, packed):
+    """The one-device bf16 pool keeps its rows head-major (heads of 64
+    two to a row of 128 lanes) and the decode step writes and reads them
+    where they lie: five requests over two slots, so slots retire and
+    are leased again mid-run, give ``generate()``'s tokens one for one.
+    Groups under 8 take several KV heads a grid step, a group of 8 one."""
+    config = dict(config)
+    name = config.pop("model", "transformer_lm")
+    cfg = dict(vocab_size=8, d_model=32, heads=2, depth=2, max_len=32)
+    cfg.update(config)
+    m = build_model(name, **cfg)
+    v, ids = _train_lm(m)
+    prompts = [np.asarray(ids[0, :n]) for n in (4, 9, 6, 3, 7)]
+    budgets = (8, 5, 9, 6, 8)
+    want = [np.asarray(generate(m, v, p[None], max_new_tokens=n))[0]
+            for p, n in zip(prompts, budgets)]
+    engine = ServeEngine(m, v, slots=2, cache_len=32, decode_block=4)
+    hk = cfg.get("kv_heads") or cfg["heads"]
+    d = cfg["d_model"] // cfg["heads"]
+    for entry in engine.pool.buffers.values():
+        assert isinstance(entry, HeadMajorKV)
+        assert entry.k.shape == entry.v.shape == (2, hk // packed, 32,
+                                                  packed * d)
+    rids = [engine.submit(p, max_new_tokens=n)
+            for p, n in zip(prompts, budgets)]
+    results = engine.run()
+    for rid, w in zip(rids, want):
+        assert results[rid].status == "completed"
+        np.testing.assert_array_equal(np.asarray(results[rid].tokens), w)
+    leases = [e for e in engine.recorder.events()
+              if e["name"] == "serve.pool_write"]
+    assert len(leases) == 5 > engine.pool.num_slots
+    for e in leases:
+        assert e["attrs"]["bytes_full"] == e["attrs"]["bytes"] > 0
+
+
+@pytest.mark.parametrize("holder", ["bf16", "bf16-heads-of-64", "int8",
+                                    "paged", "mesh"])
+def test_the_pools_layout_is_its_holders(holder):
+    """Which layout a block's rows have is the pool's to decide, by what
+    holds them: bf16 on one device lies head-major (heads of 64 two to
+    a row), and every byte a prefill writes is counted as ``bytes_full``;
+    int8 rows, pages and a pool under a mesh keep the layouts they had
+    and count none."""
+    options = {
+        "int8": {"kv_dtype": "int8"},
+        "paged": {"paged": True, "page_size": 8},
+        "mesh": {"mesh": {"data": 2, "model": 2}},
+    }.get(holder, {})
+    if holder == "mesh" and jax.device_count() < 4:
+        pytest.skip("needs 4 devices")
+    hk, d = (2, 64) if holder == "bf16-heads-of-64" else (2, 16)
+    m = _tiny(d_model=hk * d, heads=hk)
+    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    engine = ServeEngine(m, v, slots=2, cache_len=32, **options)
+    rng = np.random.default_rng(0)
+    for n in (5, 9, 3):
+        engine.submit(rng.integers(0, 8, size=n).astype(np.int32),
+                      max_new_tokens=4)
+    assert all(r.status == "completed" for r in engine.run().values())
+    writes = [e["attrs"] for e in engine.recorder.events()
+              if e["name"] == "serve.pool_write"]
+    assert len(writes) == 3
+    shapes = {
+        "bf16": (2, 2, 32, 16), "bf16-heads-of-64": (2, 1, 32, 128),
+        "int8": (2, 32, 2, 16), "mesh": (2, 32, 2, 16),
+        "paged": (engine.pool.buffers["block0"][0].shape[0], 2, 8, 16),
+    }
+    for entry in engine.pool.buffers.values():
+        assert entry[0].shape == entry[1].shape == shapes[holder]
+        assert isinstance(entry, HeadMajorKV) == holder.startswith("bf16")
+        assert len(entry) == {"int8": 4, "paged": 3}.get(holder, 2)
+    if holder.startswith("bf16"):
+        assert engine.pool.kinds == {"block0": "full", "block1": "full"}
+        assert all(w["bytes_full"] == w["bytes"] > 0 for w in writes)
+        assert all(w["bytes_ring"] == 0 for w in writes)
+    else:
+        assert not getattr(engine.pool, "kinds", None)
+        assert not any("bytes_full" in w for w in writes)
+    if holder == "mesh":
+        from jax.sharding import PartitionSpec as P
+
+        for entry in engine.pool.buffers.values():
+            assert entry[0].sharding.spec == P("data", None, "model", None)
 
 
 def test_engine_build_guards():
