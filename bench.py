@@ -1285,7 +1285,7 @@ def bench_serve_int8(jax) -> dict:
     from mmlspark_tpu.models import build_model
     from mmlspark_tpu.ops.flash_attention import flash_decode
     from mmlspark_tpu.serve import ServeEngine
-    from mmlspark_tpu.serve.cache_pool import kv_head_scales, quantize_kv
+    from mmlspark_tpu.ops.kv_cache import kv_head_scales, quantize_kv
 
     full = _full_scale(jax)
     vocab, d_model, heads, depth = (

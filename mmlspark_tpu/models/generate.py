@@ -31,59 +31,12 @@ Two decode strategies, both fixed-shape and single-jit:
 
 from __future__ import annotations
 
-from typing import Any, NamedTuple
-
 import jax
 import jax.numpy as jnp
 
 from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.models.graph import _accepts_kwarg
-
-
-#: the cache kinds a block can have: ``linear`` (what a block that declares
-#: nothing gets: a row for every position, laid out ``(B, rows, hk, d)``),
-#: and the two a block DECLARES through ``cache_spec()``: ``full`` (a row
-#: for every position) and ``ring`` (the last ``rows`` positions, position
-#: ``p`` in row ``p % rows``), which the serving pool lays out head-major,
-#: ``(S, hk, rows, d)`` (models/hybrid.py, serve/cache_pool.py)
-LINEAR, FULL_ROWS, RING_ROWS = "linear", "full", "ring"
-
-
-class HeadMajorKV(NamedTuple):
-    """A serving pool entry whose rows lie HEAD-MAJOR: ``k`` is ``(S, hk,
-    rows, dk)`` and ``v`` ``(S, hk, rows, dv)``, the layout that
-    ``cache_row_write`` updates in place and ``flash_decode_grouped``
-    streams without a copy. Shapes cannot tell ``(S, hk, L, d)`` from a
-    linear ``(S, L, hk, d)``, so the pool says it with the entry's TYPE
-    and a block reads the layout off what it is handed. A pytree of two
-    leaves that unpacks like the ``(k, v)`` pair it replaces.
-
-    Rows narrower than the TPU's 128 lanes are PACKED where the heads
-    allow it (:func:`lane_pack`): ``f`` adjacent KV heads lie side by
-    side in one row, ``(S, hk / f, rows, f * d)``, which in memory is
-    the prefill cache's own ``(rows, hk, d)`` order with the head axis
-    split. The row write and the pool's prefill write see ``hk / f``
-    heads of width ``f * d``; ``flash_decode_grouped`` reads ``f`` off
-    the widths."""
-
-    k: Any
-    v: Any
-
-
-def lane_pack(hk: int, dk: int, dv: int) -> int:
-    """How many adjacent KV heads one row of a head-major pool entry
-    holds side by side: as many as fill 128 lanes, where the widths and
-    the head count divide, else 1. An array whose minor dimension is
-    under 128 lives on the TPU with a LARGER dimension in its lanes
-    (bf16[16, 20, 1024, 64] is held ``{2,3,1,0}``, rows in the lanes:
-    sandbox compile, PR 30), so a kernel that wants rows of 64 would
-    have the whole pool copied into its layout and back around every
-    decode block; rows of 128 are held as the kernel reads them."""
-    lanes = 128
-    if dk != dv or not 0 < dk < lanes or lanes % dk:
-        return 1
-    f = lanes // dk
-    return f if hk % f == 0 else 1
+from mmlspark_tpu.ops.kv_cache import LINEAR
 
 
 def cache_specs(graph, variables) -> dict:

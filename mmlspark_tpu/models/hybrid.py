@@ -18,7 +18,7 @@ in ``param_dtype``.
 Every block DECLARES the geometry of its KV cache (:meth:`HybridBlock.
 cache_spec`): a full block keeps a row for every position, a window block
 a ring of ``window`` rows. The serving pool
-(:mod:`mmlspark_tpu.serve.cache_pool`) allocates by that declaration,
+(``serve/cache_pool.py``, ``SlotCachePool``) allocates by that declaration,
 head-major, which is the layout the decode kernel
 (:func:`mmlspark_tpu.ops.flash_attention.flash_decode_grouped`) streams
 without a copy.
@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 
 from mmlspark_tpu.core.exceptions import ParamError
-from mmlspark_tpu.models.generate import FULL_ROWS, RING_ROWS
 from mmlspark_tpu.models.graph import FINAL_NODE, NamedGraph
 from mmlspark_tpu.models.registry import register_model
 from mmlspark_tpu.models.transformer import (
@@ -45,6 +44,7 @@ from mmlspark_tpu.models.transformer import (
     FLASH,
     resolve_attn_impl,
 )
+from mmlspark_tpu.ops import kv_cache
 from mmlspark_tpu.ops.attention import dense_attention
 
 FULL, SWA = "full", "swa"
@@ -131,46 +131,21 @@ class HybridAttention(nn.Module):
         new_cache = None
         if cache is None:
             o = self._prompt_attention(q, k, v, sink, kind)
-        elif jnp.ndim(pos):
-            # the serving pool's slot caches, HEAD-MAJOR as the pool
-            # allocates them by cache_spec: (S, hk, rows, dk) and
-            # (S, hk, rows, dv); a window block's rows are a ring
-            if not (decode and t == 1):
-                raise ParamError(
-                    "per-row cache positions (the serve engine's fused "
-                    "decode step) are single-token"
-                )
-            from mmlspark_tpu.ops.attention import decode_live_lengths
-            from mmlspark_tpu.ops.flash_attention import (
-                cache_row_write,
-                flash_decode_grouped,
-            )
-
-            ck, cv = cache
-            rows = ck.shape[2]
-            at = pos % rows if self.window is not None else pos
-            ck, cv = cache_row_write(ck, cv, k[:, 0], v[:, 0], at)
-            new_cache = (ck, cv)
-            lengths = decode_live_lengths(pos, b, live=live)
-            if self.window is not None:
-                # every written slot of a ring lies inside the window
-                lengths = jnp.minimum(lengths, rows)
-            o = flash_decode_grouped(q, ck, cv, lengths, sink=sink,
-                                     name=f"attn_{kind}_decode")
+        elif decode and t == 1 and not kv_cache.is_linear(cache):
+            # the serving pool's entry, as the pool allocates it by
+            # cache_spec: a window block's rows are a ring
+            o, new_cache = kv_cache.decode_step(
+                cache, q, k, v, pos, live, window=self.window, sink=sink,
+                name=f"attn_{kind}_decode")
         else:
             # a linear (B, total, hk, d) cache: prefill, a chunk or a
             # resume against a live prefix, generate()'s decode steps
-            ck, cv = cache
-            ck = jax.lax.dynamic_update_slice(
-                ck, k.astype(ck.dtype), (0, pos, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cv, v.astype(cv.dtype), (0, pos, 0, 0))
-            new_cache = (ck, cv)
+            new_cache = kv_cache.write_rows(cache, k, v, pos)
             if isinstance(pos, int) and pos == 0:
                 # a prefill from position 0 sees this call's own K/V only
                 o = self._prompt_attention(q, k, v, sink, kind)
             else:
-                o = dense_attention(q, ck, cv, causal=True,
+                o = dense_attention(q, *new_cache, causal=True,
                                     window=self.window, q_offset=pos,
                                     sink=sink)
         out = proj("attn_out", d_model)(o.reshape(b, t, h * dv))
@@ -263,11 +238,9 @@ class HybridBlock(nn.Module):
         pool holds for this block. A full block keeps every position
         (``rows`` None: the pool's ``cache_len``), a window block a ring
         of ``window`` rows."""
-        if self.window is None:
-            return (FULL_ROWS, None, self.kv_heads, self.head_dim,
-                    self.v_head_dim)
-        return (RING_ROWS, int(self.window), self.kv_heads, self.head_dim,
-                self.v_head_dim)
+        kind, rows = ((kv_cache.FULL_ROWS, None) if self.window is None
+                      else (kv_cache.RING_ROWS, int(self.window)))
+        return (kind, rows, self.kv_heads, self.head_dim, self.v_head_dim)
 
     @property
     def routed(self) -> bool:

@@ -26,9 +26,9 @@ import jax
 import jax.numpy as jnp
 
 from mmlspark_tpu.core.exceptions import ParamError
-from mmlspark_tpu.models.generate import HeadMajorKV
 from mmlspark_tpu.models.graph import FINAL_NODE, NamedGraph
 from mmlspark_tpu.models.registry import register_model
+from mmlspark_tpu.ops import kv_cache
 from mmlspark_tpu.ops.attention import dense_attention
 
 DENSE = "dense"
@@ -125,17 +125,12 @@ class SelfAttention(nn.Module):
         impl = resolve_attn_impl(self.attn_impl)
         new_cache = None
         if cache is not None:
-            # KV-cache decode (models/generate.py): the preallocated
-            # (B, total, hk, d) buffers take this step's K/V at ``pos``;
-            # unwritten future positions are invisible either way —
-            # causal mask (q_offset=pos) on the dense read, live-length
-            # mask in the decode kernel — so one static-shape program
-            # serves both prefill (t = prompt len, pos = 0) and decode
-            # (t = 1). The impl dispatch above is a *training/scoring*
-            # choice; decode reads are bandwidth-bound, which is exactly
-            # why single-token steps route to the length-aware split-KV
-            # kernel below: it skips the HBM traffic for dead cache
-            # blocks instead of reorganizing compute.
+            # KV-cache decode (models/generate.py): the cache takes this
+            # call's K/V at ``pos``; unwritten future positions are
+            # invisible either way (causal mask on the dense read,
+            # live-length mask in the decode kernels), so one static-shape
+            # program serves prefill and decode. What a cache entry is,
+            # and how a step writes and reads it: ops/kv_cache.py
             if not self.causal:
                 raise ParamError("cache decode requires causal=True")
             if rolled and t != 1:
@@ -143,213 +138,19 @@ class SelfAttention(nn.Module):
                     "rolled cache decode is single-token (t=1); "
                     "prefill uses the linear cache path"
                 )
-            per_row = bool(jnp.ndim(pos))
-            if per_row and (rolled or t != 1):
+            if jnp.ndim(pos) and (rolled or t != 1):
                 raise ParamError(
                     "per-row cache positions (the serve engine's fused "
                     "decode step) are single-token and linear-cache only"
                 )
-            if len(cache) in (3, 5):
-                # PAGED slot cache (mmlspark_tpu/serve/paging.py): K/V
-                # are physical page stores (num_pages, hk, page_size, d)
-                # shared by all rows, plus a (B, max_pages) page table
-                # mapping each row's logical positions through its pages.
-                # The 5-tuple is the int8 page store: two extra
-                # (num_pages, hk) f32 per-page scale leaves. This is
-                # strictly the serve engine's fused decode-block
-                # format — prefill runs on a linear batch-1 cache and
-                # the pool scatters it into pages host-side.
-                if not (per_row and decode and t == 1):
-                    raise ParamError(
-                        "paged caches serve per-row single-token decode "
-                        "only (the serve engine's fused decode step); "
-                        "prefill uses the linear cache path"
-                    )
-                ck, cv, ptab, *cscales = cache
-                ps = ck.shape[2]
-                virt = ptab.shape[1] * ps
-                if self.window is not None and self.window < virt:
-                    raise ParamError(
-                        f"paged decode has no windowed read: window "
-                        f"({self.window}) must cover the virtual cache "
-                        f"({virt})"
-                    )
-                # scatter this step's K/V through the table: row b's
-                # position pos[b] lands in physical page
-                # ptab[b, pos // ps] at offset pos % ps. Dead rows hold
-                # a frozen pos whose page the pool keeps pointed at a
-                # trash page, so their writes never touch live data.
-                rows = jnp.arange(b)
-                pages = ptab[rows, pos // ps]
-                offs = pos % ps
-                hidx = jnp.arange(ck.shape[1])
-                if cscales:
-                    # int8 page store: a page's scale is FIXED at its
-                    # first write — offs == 0 means this token opens a
-                    # fresh page (ensure_decode_pages pre-mapped it),
-                    # so its amax (+ headroom) becomes the page's
-                    # scale; later tokens into the page quantize
-                    # against it and saturate into the error budget.
-                    # Dead rows re-stamp their trash page's scale,
-                    # which nothing ever reads (live length 0).
-                    from mmlspark_tpu.serve.cache_pool import (
-                        kv_head_scales, quantize_kv,
-                    )
-
-                    ks, vs = cscales
-                    tk = k[:, 0].astype(jnp.float32)
-                    tv = v[:, 0].astype(jnp.float32)
-                    first = (offs == 0)[:, None]
-                    row_ks = jnp.where(
-                        first, kv_head_scales(tk, axes=(2,)), ks[pages]
-                    )
-                    row_vs = jnp.where(
-                        first, kv_head_scales(tv, axes=(2,)), vs[pages]
-                    )
-                    ks = ks.at[pages].set(row_ks)
-                    vs = vs.at[pages].set(row_vs)
-                    cscales = [ks, vs]
-                    wk = quantize_kv(tk, row_ks)
-                    wv = quantize_kv(tv, row_vs)
-                else:
-                    wk = k[:, 0].astype(ck.dtype)
-                    wv = v[:, 0].astype(cv.dtype)
-                ck = ck.at[pages[:, None], hidx[None, :], offs[:, None]
-                           ].set(wk)
-                cv = cv.at[pages[:, None], hidx[None, :], offs[:, None]
-                           ].set(wv)
-                new_cache = (ck, cv, ptab, *cscales)
-                from mmlspark_tpu.ops.attention import decode_live_lengths
-                from mmlspark_tpu.ops.flash_attention import (
-                    paged_flash_decode,
-                )
-
-                o = paged_flash_decode(
-                    q, ck, cv, decode_live_lengths(pos, b, live=live),
-                    ptab,
-                    k_scale=cscales[0] if cscales else None,
-                    v_scale=cscales[1] if cscales else None,
-                    mesh=self.mesh,
-                )
-            elif isinstance(cache, HeadMajorKV):
-                # the one-device bf16 slot pool (serve/cache_pool.py):
-                # (S, hk, cache_len, d) rows, which the pool says with
-                # the entry's type. The step's row is written in place
-                # and the kernel streams (rows, d) tiles of each KV
-                # head: no relayout of the pool on either side
-                if not (per_row and decode and t == 1) or (
-                        self.window is not None
-                        and self.window < cache.k.shape[2]):
-                    raise ParamError(
-                        "head-major caches serve per-row single-token "
-                        "full-window decode only (the serve engine's "
-                        "fused decode step); prefill uses the linear "
-                        "cache path"
-                    )
-                from mmlspark_tpu.ops.attention import decode_live_lengths
-                from mmlspark_tpu.ops.flash_attention import (
-                    cache_row_write,
-                    flash_decode_grouped,
-                )
-
-                # (b, hk, d) -> the entry's own heads and width: packed
-                # rows hold adjacent heads side by side (lane_pack)
-                packed = (b, cache.k.shape[1], -1)
-                new_cache = HeadMajorKV(*cache_row_write(
-                    *cache, k[:, 0].reshape(packed), v[:, 0].reshape(packed),
-                    pos))
-                # named as the trace has always shown this module's
-                # decode kernel (``attn.N``), where the decode metrics
-                # look: the kernel is jitted where it stands, so the
-                # module's scope no longer names it
-                o = flash_decode_grouped(
-                    q, *new_cache, decode_live_lengths(pos, b, live=live),
-                    name="attn")
+            if t == 1 and (decode or rolled):
+                # ``attn``: as the trace has always named this decode kernel
+                o, new_cache = kv_cache.decode_step(
+                    cache, q, k, v, pos, live, window=self.window,
+                    name="attn", mesh=self.mesh, rolled=rolled)
             else:
-                ck, cv, *cscales = cache
-                if cscales and not (
-                    per_row and decode and t == 1
-                    and (self.window is None
-                         or self.window >= ck.shape[1])
-                ):
-                    # the 4-tuple is the slot pool's int8 mode; only
-                    # the flash-decode read below can dequantize it
-                    raise ParamError(
-                        "int8 dense caches serve the engine's per-row "
-                        "single-token full-window decode only; prefill "
-                        "and single-request generate use bf16 linear "
-                        "caches"
-                    )
-                if per_row:
-                    # multi-tenant decode (mmlspark_tpu.serve): every
-                    # batch row is a different request writing its own
-                    # absolute position in its own slot buffer
-                    rows = jnp.arange(b)
-                    if cscales:
-                        # quantize the step's K/V against the slots'
-                        # prefill-fixed scales (out-of-range values
-                        # saturate — priced into the parity budget)
-                        from mmlspark_tpu.serve.cache_pool import (
-                            quantize_kv,
-                        )
-
-                        wk = quantize_kv(k[:, 0], cscales[0])
-                        wv = quantize_kv(v[:, 0], cscales[1])
-                    else:
-                        wk = k[:, 0].astype(ck.dtype)
-                        wv = v[:, 0].astype(cv.dtype)
-                    ck = ck.at[rows, pos].set(wk)
-                    cv = cv.at[rows, pos].set(wv)
-                else:
-                    # rolled (O(window) circular, sliding-window models
-                    # on long generations): this step's K/V land at slot
-                    # pos % W — every written slot is inside the window
-                    # by construction (ops/attention.py
-                    # rolled_window_attention). Linear: the write index
-                    # IS the absolute position.
-                    idx = pos % ck.shape[1] if rolled else pos
-                    ck = jax.lax.dynamic_update_slice(
-                        ck, k.astype(ck.dtype), (0, idx, 0, 0)
-                    )
-                    cv = jax.lax.dynamic_update_slice(
-                        cv, v.astype(cv.dtype), (0, idx, 0, 0)
-                    )
-                new_cache = (ck, cv, *cscales)
-                if rolled:
-                    from mmlspark_tpu.ops.attention import (
-                        rolled_window_attention,
-                    )
-
-                    o = rolled_window_attention(q, ck, cv, pos)
-                elif decode and t == 1 and (
-                    self.window is None or self.window >= ck.shape[1]
-                ):
-                    # single-token DECODE step over a linear cache: the
-                    # length-aware split-KV kernel reads only each row's
-                    # LIVE positions [0, pos+1) — per-row work O(pos),
-                    # not O(cache_len) — instead of a dense read of the
-                    # whole buffer. Window models reach here only when
-                    # the window covers the buffer (masking would be a
-                    # no-op); a tighter window uses the rolled path or
-                    # dense fallback.
-                    from mmlspark_tpu.ops.attention import (
-                        decode_live_lengths,
-                    )
-                    from mmlspark_tpu.ops.flash_attention import (
-                        flash_decode,
-                    )
-
-                    # ``live`` (the serve engine's fused decode-block
-                    # carry) zeroes dead rows' lengths, so the kernel's
-                    # early-out skips their cache traffic mid-block
-                    o = flash_decode(
-                        q, ck, cv,
-                        decode_live_lengths(pos, b, live=live),
-                        k_scale=cscales[0] if cscales else None,
-                        v_scale=cscales[1] if cscales else None,
-                        mesh=self.mesh,
-                    )
-                elif impl == FLASH and isinstance(pos, int) and pos == 0:
+                new_cache = kv_cache.write_rows(cache, k, v, pos)
+                if impl == FLASH and isinstance(pos, int) and pos == 0:
                     # a PREFILL from position 0 (static, so this is
                     # decided at trace time) sees exactly this call's
                     # own K/V — the cache beyond t is unwritten and
@@ -365,7 +166,7 @@ class SelfAttention(nn.Module):
                     o = flash_attention(q, k, v, causal=True,
                                         window=self.window, mesh=self.mesh)
                 else:
-                    o = dense_attention(q, ck, cv, causal=True,
+                    o = dense_attention(q, *new_cache, causal=True,
                                         window=self.window, q_offset=pos)
         elif impl == FLASH:
             from mmlspark_tpu.ops.flash_attention import flash_attention

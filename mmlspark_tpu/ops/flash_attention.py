@@ -645,7 +645,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
 # ---------------------------------------------------------------------------
 # flash decode: split-KV single-token attention over slot caches
 #
-# The serving hot path (mmlspark_tpu/serve) decodes ONE query token per
+# The serving hot path (the ``serve`` package) decodes ONE query token per
 # slot per tick against a preallocated (B, cache_len, hk, d) cache, but a
 # dense read does cache_len worth of work per row no matter how little of
 # the buffer is live. This kernel streams K/V in blocks with the online-
@@ -1178,7 +1178,7 @@ def flash_decode_grouped(q, k, v, lengths, *, sink=None, scale=None,
     softmax's denominator only. Returns (B, 1, H, Dv) in ``q``'s dtype.
 
     Caches whose rows are ``f * Dk`` and ``f * Dv`` wide are PACKED
-    (``models.generate.lane_pack``): ``(B, Hkv / f, L, f * Dk)``, ``f``
+    (``ops.kv_cache.lane_pack``): ``(B, Hkv / f, L, f * Dk)``, ``f``
     adjacent KV heads side by side in one row. Each query head is then
     laid, among zeros, over the lanes of its own KV head, the ``f`` heads
     of a row are read as one head with ``f`` groups, and each query head
@@ -1348,7 +1348,7 @@ def _flash_decode_grouped(q, k, v, lengths, sink, *, scale, block: int,
 
 # ---------------------------------------------------------------------------
 # paged flash decode: the same split-KV walk through a page-table
-# indirection. The paged cache pool (mmlspark_tpu/serve/paging.py) stores
+# indirection. The paged cache pool (``serve/paging.py``) stores
 # K/V as (num_pages, hk, page_size, d) physical pages and maps each
 # slot's logical positions through a (slots, max_pages) int32 page table.
 # flash_decode already walks the KV stream block-by-block with the block

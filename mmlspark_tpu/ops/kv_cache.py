@@ -1,0 +1,357 @@
+"""The KV cache's entry formats, and the two things a program does with
+an entry: a prefill writes rows into it, a decode step appends one row a
+slot and attends over the live rows.
+
+One home for what the models (``models/``) and the pools (``serve/``)
+both have to know, below both. An ENTRY is what one block's cache is, in
+``generate()``'s cache dict and in a pool's ``buffers`` alike, a pytree
+of arrays whose layout is said by its TYPE: :class:`Int8Rows`,
+:class:`HeadMajorKV`, :class:`PagedKV`, :class:`PagedInt8KV`. Linear
+bfloat16 rows ``(B, rows, hk, d)`` are the ONE untyped default, a plain
+``(k, v)`` pair: it is what :func:`mmlspark_tpu.models.generate.
+init_cache`, ``generate()``, every prefill program, the snapshots and
+the fleet's hand-off exchange, so it keeps their format (a pool under a
+mesh serves from it too), and every other layout is told from it by
+type. :func:`decode_step` is the one call of a decode step, whatever the
+entry; :func:`write_rows` the linear entry's write. A new cache kind is a
+type and a step here, plus what a pool allocates.
+"""
+
+from __future__ import annotations
+
+from typing import Any, NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from mmlspark_tpu.core.exceptions import FriendlyError, ParamError
+from mmlspark_tpu.ops.attention import (
+    decode_live_lengths,
+    dense_attention,
+    rolled_window_attention,
+)
+
+#: the cache kinds a block can have: ``linear`` (what a block that declares
+#: nothing gets: a row for every position, laid out ``(B, rows, hk, d)``),
+#: and the two a block DECLARES through ``cache_spec()``: ``full`` (a row
+#: for every position) and ``ring`` (the last ``rows`` positions, position
+#: ``p`` in row ``p % rows``), which the serving pool lays out head-major
+#: (models/hybrid.py, serve/cache_pool.py)
+LINEAR, FULL_ROWS, RING_ROWS = "linear", "full", "ring"
+
+#: headroom multiplied onto the prefill amax when fixing a slot's int8
+#: quantization scale: decode steps quantize with the SAME scale
+#: in-graph (a per-step rescale would invalidate already-written int8
+#: rows), so the margin absorbs decode K/V drifting above the prompt's
+#: range; values beyond it saturate at ±127 — graceful, and part of the
+#: declared error budget (docs/PERFORMANCE.md "Quantized decode")
+KV_SCALE_MARGIN = 1.5
+
+VALID_KV_DTYPES = ("bf16", "int8")
+
+
+class Int8Rows(NamedTuple):
+    """The dense pool's int8 mode: linear ``(S, rows, hk, d)`` int8 rows
+    and ``(S, hk)`` float32 scales, fixed for a lease by its prefill."""
+
+    k: Any
+    v: Any
+    k_scale: Any
+    v_scale: Any
+
+
+class HeadMajorKV(NamedTuple):
+    """A serving pool entry whose rows lie HEAD-MAJOR: ``k`` is ``(S, hk,
+    rows, dk)`` and ``v`` ``(S, hk, rows, dv)``, the layout that
+    ``cache_row_write`` updates in place and ``flash_decode_grouped``
+    streams without a copy. Shapes cannot tell ``(S, hk, L, d)`` from a
+    linear ``(S, L, hk, d)``, so the pool says it with the entry's TYPE.
+    ``rows`` is every position, or a window block's ring (position ``p``
+    in row ``p % rows``).
+
+    Rows narrower than the TPU's 128 lanes are PACKED where the heads
+    allow it (:func:`lane_pack`): ``f`` adjacent KV heads lie side by
+    side in one row, ``(S, hk / f, rows, f * d)``, which in memory is
+    the prefill cache's own ``(rows, hk, d)`` order with the head axis
+    split. The row write and the pool's prefill write see ``hk / f``
+    heads of width ``f * d``; ``flash_decode_grouped`` reads ``f`` off
+    the widths."""
+
+    k: Any
+    v: Any
+
+
+class PagedKV(NamedTuple):
+    """The paged pool's entry (serve/paging.py): the page stores and the
+    block's own copy of the page table (donation forbids shared leaves)."""
+
+    k: Any
+    v: Any
+    page_table: Any
+
+
+class PagedInt8KV(NamedTuple):
+    """The paged pool's int8 mode: ``(num_pages, hk)`` float32 scales, a
+    page's fixed at its first write."""
+
+    k: Any
+    v: Any
+    page_table: Any
+    k_scale: Any
+    v_scale: Any
+
+
+def lane_pack(hk: int, dk: int, dv: int) -> int:
+    """How many adjacent KV heads one row of a head-major pool entry
+    holds side by side: as many as fill 128 lanes, where the widths and
+    the head count divide, else 1. An array whose minor dimension is
+    under 128 lives on the TPU with a LARGER dimension in its lanes
+    (bf16[16, 20, 1024, 64] is held ``{2,3,1,0}``, rows in the lanes:
+    sandbox compile, PR 30), so a kernel that wants rows of 64 would
+    have the whole pool copied into its layout and back around every
+    decode block; rows of 128 are held as the kernel reads them."""
+    lanes = 128
+    if dk != dv or not 0 < dk < lanes or lanes % dk:
+        return 1
+    f = lanes // dk
+    return f if hk % f == 0 else 1
+
+
+def validate_kv_dtype(kv_dtype: str, geometry: dict) -> None:
+    """Shared pool-level contract for ``kv_dtype`` (dense and paged
+    pools): the flag must name a supported dtype, and int8 requires an
+    even head_dim — the decode kernels' int8 VREG tile packs lanes
+    pairwise and rejects odd D (the CLI surfaces this as the
+    FriendlyError, not a kernel shape crash mid-serve)."""
+    if kv_dtype not in VALID_KV_DTYPES:
+        raise FriendlyError(
+            f"kv_dtype must be one of {VALID_KV_DTYPES}, got "
+            f"{kv_dtype!r}"
+        )
+    if kv_dtype == "int8":
+        for name, (hk, d) in geometry.items():
+            if d % 2:
+                raise FriendlyError(
+                    f"kv_dtype='int8' requires an even head_dim (the "
+                    f"int8 decode-kernel tile packs lanes pairwise), "
+                    f"but block '{name}' has head_dim {d}. Use "
+                    f"kv_dtype='bf16' or an even d_model/heads split"
+                )
+
+
+def quantize_kv(values, scales):
+    """Symmetric int8 quantization of K/V ``values`` (..., hk, d) with
+    per-kv-head ``scales`` broadcastable over (..., hk); out-of-range
+    values saturate at ±127. ONE definition shared by the pools'
+    prefill writes and the in-graph decode-step writes, so both paths
+    land bit-identical int8 for identical inputs."""
+    q = jnp.round(values.astype(jnp.float32) / scales[..., None])
+    return jnp.clip(q, -127, 127).astype(jnp.int8)
+
+
+def kv_head_scales(values, axes) -> jnp.ndarray:
+    """Per-kv-head f32 quantization scales from the amax of ``values``
+    over ``axes`` (every dim but the kv-head dim), with the
+    ``KV_SCALE_MARGIN`` headroom and a 1.0 floor substituted for
+    all-zero heads (a zero scale would divide by zero; scale 1.0 maps
+    zeros to zeros exactly)."""
+    amax = jnp.abs(values.astype(jnp.float32)).max(axis=axes)
+    scale = amax * (KV_SCALE_MARGIN / 127.0)
+    return jnp.where(scale == 0.0, 1.0, scale)
+
+
+#: what each pool-only layout says when it is handed anything but the
+#: engine's fused decode step
+_REFUSALS = {
+    PagedKV: (
+        "paged caches serve per-row single-token decode only (the serve "
+        "engine's fused decode step); prefill uses the linear cache path"
+    ),
+    HeadMajorKV: (
+        "head-major caches serve per-row single-token full-window decode "
+        "only (the serve engine's fused decode step); prefill uses the "
+        "linear cache path"
+    ),
+    Int8Rows: (
+        "int8 dense caches serve the engine's per-row single-token "
+        "full-window decode only; prefill and single-request generate "
+        "use bf16 linear caches"
+    ),
+}
+_REFUSALS[PagedInt8KV] = _REFUSALS[PagedKV]
+
+
+def is_linear(entry) -> bool:
+    """Whether ``entry`` is the untyped default, linear ``(k, v)`` rows."""
+    return type(entry) not in _REFUSALS
+
+
+def write_rows(entry, k, v, pos, *, rolled: bool = False):
+    """The LINEAR entry with this call's ``k``/``v`` (B, T, hk, d)
+    written from ``pos`` on: a prefill, a chunk or a resume against a
+    live prefix, ``generate()``'s decode steps. ``pos`` (B,) is the
+    engine's multi-tenant step: every batch row is a different request
+    writing its own absolute position in its own slot buffer.
+    ``rolled`` (O(window) circular, sliding-window models on long
+    generations): the step's K/V land at slot ``pos % W`` — every
+    written slot is inside the window by construction
+    (ops/attention.py rolled_window_attention)."""
+    if not is_linear(entry):
+        raise ParamError(_REFUSALS[type(entry)])
+    ck, cv = entry
+    if jnp.ndim(pos):
+        if k.shape[1] != 1:
+            raise ParamError("per-row cache positions (the serve engine's "
+                             "fused decode step) are single-token")
+        rows = jnp.arange(ck.shape[0])
+        return (ck.at[rows, pos].set(k[:, 0].astype(ck.dtype)),
+                cv.at[rows, pos].set(v[:, 0].astype(cv.dtype)))
+    at = (0, pos % ck.shape[1] if rolled else pos, 0, 0)
+    return (jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), at),
+            jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), at))
+
+
+def _fused_step_only(entry, q, pos, window, rows: int | None = None):
+    """Refuse a pool-only layout anything but the engine's fused decode
+    step: one token a row at per-row positions, and where ``rows`` is
+    given a window that covers them."""
+    if q.shape[1] != 1 or not jnp.ndim(pos) or (
+            rows is not None and window is not None and window < rows):
+        raise ParamError(_REFUSALS[type(entry)])
+
+
+def _linear_step(entry, q, k, v, pos, live, *, window, sink, name, mesh,
+                 rolled):
+    from mmlspark_tpu.ops import flash_attention as kernels  # lazy: Pallas
+
+    ck, cv = new = write_rows(entry, k, v, pos, rolled=rolled)
+    if rolled:
+        return rolled_window_attention(q, ck, cv, pos), new
+    if window is not None and window < ck.shape[1]:
+        # a window tighter than the buffer: dense read with the mask
+        return dense_attention(q, ck, cv, causal=True, window=window,
+                               q_offset=pos), new
+    # the length-aware split-KV kernel reads only each row's LIVE
+    # positions [0, pos+1) — per-row work O(pos), not O(cache_len) — and
+    # ``live`` zeroes dead rows' lengths, so it skips their cache traffic
+    lengths = decode_live_lengths(pos, q.shape[0], live=live)
+    return kernels.flash_decode(q, ck, cv, lengths, mesh=mesh), new
+
+
+def _int8_rows_step(entry, q, k, v, pos, live, *, window, sink, name, mesh,
+                    rolled):
+    from mmlspark_tpu.ops import flash_attention as kernels
+
+    # only the flash-decode read below can dequantize the rows
+    _fused_step_only(entry, q, pos, window, rows=entry.k.shape[1])
+    rows = jnp.arange(q.shape[0])
+    # quantize the step's K/V against the slots' prefill-fixed scales
+    # (out-of-range values saturate — priced into the parity budget)
+    wk = quantize_kv(k[:, 0], entry.k_scale)
+    wv = quantize_kv(v[:, 0], entry.v_scale)
+    new = entry._replace(k=entry.k.at[rows, pos].set(wk),
+                         v=entry.v.at[rows, pos].set(wv))
+    o = kernels.flash_decode(
+        q, new.k, new.v, decode_live_lengths(pos, q.shape[0], live=live),
+        k_scale=new.k_scale, v_scale=new.v_scale, mesh=mesh)
+    return o, new
+
+
+def _head_major_step(entry, q, k, v, pos, live, *, window, sink, name, mesh,
+                     rolled):
+    from mmlspark_tpu.ops import flash_attention as kernels
+
+    b, rows = q.shape[0], entry.k.shape[2]
+    _fused_step_only(entry, q, pos, window, rows=rows)
+    # a ring: position p lies in row p % rows
+    at = pos if window is None else pos % rows
+    # (b, hk, d) -> the entry's own heads and width (lane_pack). The row
+    # is written in place and the kernel streams (rows, d) tiles of each
+    # KV head: no relayout of the pool on either side
+    packed = (b, entry.k.shape[1], -1)
+    new = HeadMajorKV(*kernels.cache_row_write(
+        *entry, k[:, 0].reshape(packed), v[:, 0].reshape(packed), at))
+    lengths = decode_live_lengths(pos, b, live=live)
+    if window is not None:
+        # every written row of a ring lies inside the window
+        lengths = jnp.minimum(lengths, rows)
+    # the kernel is jitted where it stands, so no module's scope names
+    # it: ``name`` is what the trace shows, where the decode metrics look
+    return kernels.flash_decode_grouped(q, *new, lengths, sink=sink,
+                                        name=name), new
+
+
+def _paged_step(entry, q, k, v, pos, live, *, window, sink, name, mesh,
+                rolled):
+    from mmlspark_tpu.ops import flash_attention as kernels
+
+    # strictly the serve engine's fused decode-block format — prefill
+    # runs on a linear batch-1 cache and the pool scatters it into pages
+    _fused_step_only(entry, q, pos, window)
+    ck, cv, ptab = entry.k, entry.v, entry.page_table
+    b, ps, virt = q.shape[0], ck.shape[2], ptab.shape[1] * ck.shape[2]
+    if window is not None and window < virt:
+        raise ParamError(
+            f"paged decode has no windowed read: window ({window}) must "
+            f"cover the virtual cache ({virt})"
+        )
+    # scatter this step's K/V through the table: row b's position pos[b]
+    # lands in physical page ptab[b, pos // ps] at offset pos % ps. Dead
+    # rows hold a frozen pos whose page the pool keeps pointed at a
+    # trash page, so their writes never touch live data.
+    pages = ptab[jnp.arange(b), pos // ps]
+    offs = pos % ps
+    at = (pages[:, None], jnp.arange(ck.shape[1])[None, :], offs[:, None])
+    scales = {}
+    if isinstance(entry, PagedInt8KV):
+        # int8 page store: a page's scale is FIXED at its first write —
+        # offs == 0 means this token opens a fresh page
+        # (ensure_decode_pages pre-mapped it), so its amax (+ headroom)
+        # becomes the page's scale; later tokens into the page quantize
+        # against it and saturate into the error budget. Dead rows
+        # re-stamp their trash page's scale, which nothing ever reads
+        # (live length 0).
+        tk = k[:, 0].astype(jnp.float32)
+        tv = v[:, 0].astype(jnp.float32)
+        first = (offs == 0)[:, None]
+        row_ks = jnp.where(first, kv_head_scales(tk, axes=(2,)),
+                           entry.k_scale[pages])
+        row_vs = jnp.where(first, kv_head_scales(tv, axes=(2,)),
+                           entry.v_scale[pages])
+        scales = {"k_scale": entry.k_scale.at[pages].set(row_ks),
+                  "v_scale": entry.v_scale.at[pages].set(row_vs)}
+        wk, wv = quantize_kv(tk, row_ks), quantize_kv(tv, row_vs)
+    else:
+        wk, wv = k[:, 0].astype(ck.dtype), v[:, 0].astype(cv.dtype)
+    new = entry._replace(k=ck.at[at].set(wk), v=cv.at[at].set(wv), **scales)
+    o = kernels.paged_flash_decode(
+        q, new.k, new.v, decode_live_lengths(pos, b, live=live), ptab,
+        mesh=mesh, **scales)
+    return o, new
+
+
+_STEPS = {Int8Rows: _int8_rows_step, HeadMajorKV: _head_major_step,
+          PagedKV: _paged_step, PagedInt8KV: _paged_step}
+
+
+def decode_step(entry, q, k, v, pos, live=None, *, window=None, sink=None,
+                name=None, mesh=None, rolled: bool = False):
+    """One decode step over ``entry``, whatever its layout: append this
+    step's K/V row for every slot at ``pos`` and attend ``q`` over the
+    live rows. ``q`` is (B, 1, H, dk), ``k``/``v`` (B, 1, hk, d); ``pos``
+    is (B,) per-row positions (the serve engine's fused decode step,
+    which every pool-only layout requires) or, for linear rows, a scalar
+    (``generate()``, ``rolled`` where its buffers are circular). ``live``
+    ((B,) bool) zeroes dead rows' lengths, so the length-aware kernels
+    skip their cache traffic. The block's static facts: its ``window``
+    (None: full attention), its learned ``sink`` (head-major entries
+    only), the ``name`` its decode kernel has in a trace, its ``mesh``.
+    Returns ``(o, new entry)``: ``o`` (B, 1, H, dv), the entry of the
+    same type and leaves."""
+    if sink is not None and not isinstance(entry, HeadMajorKV):
+        raise ParamError(
+            "only head-major entries are read with a learned sink; got a "
+            f"{type(entry).__name__} entry")
+    step = _STEPS.get(type(entry), _linear_step)
+    return step(entry, q, k, v, pos, live, window=window, sink=sink,
+                name=name, mesh=mesh, rolled=rolled)

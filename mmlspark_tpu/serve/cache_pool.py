@@ -44,68 +44,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from mmlspark_tpu.core.exceptions import FriendlyError
-from mmlspark_tpu.models.generate import (
+from mmlspark_tpu.models.generate import cache_specs
+from mmlspark_tpu.ops.kv_cache import (
     FULL_ROWS,
     LINEAR,
-    HeadMajorKV,
     RING_ROWS,
-    cache_specs,
+    HeadMajorKV,
+    Int8Rows,
+    kv_head_scales,
     lane_pack,
+    quantize_kv,
+    validate_kv_dtype,
 )
 from mmlspark_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
-
-#: headroom multiplied onto the prefill amax when fixing a slot's int8
-#: quantization scale: decode steps quantize with the SAME scale
-#: in-graph (a per-step rescale would invalidate already-written int8
-#: rows), so the margin absorbs decode K/V drifting above the prompt's
-#: range; values beyond it saturate at ±127 — graceful, and part of the
-#: declared error budget (docs/PERFORMANCE.md "Quantized decode")
-KV_SCALE_MARGIN = 1.5
-
-VALID_KV_DTYPES = ("bf16", "int8")
-
-
-def validate_kv_dtype(kv_dtype: str, geometry: dict) -> None:
-    """Shared pool-level contract for ``kv_dtype`` (dense and paged
-    pools): the flag must name a supported dtype, and int8 requires an
-    even head_dim — the decode kernels' int8 VREG tile packs lanes
-    pairwise and rejects odd D (the CLI surfaces this as the
-    FriendlyError, not a kernel shape crash mid-serve)."""
-    if kv_dtype not in VALID_KV_DTYPES:
-        raise FriendlyError(
-            f"kv_dtype must be one of {VALID_KV_DTYPES}, got "
-            f"{kv_dtype!r}"
-        )
-    if kv_dtype == "int8":
-        for name, (hk, d) in geometry.items():
-            if d % 2:
-                raise FriendlyError(
-                    f"kv_dtype='int8' requires an even head_dim (the "
-                    f"int8 decode-kernel tile packs lanes pairwise), "
-                    f"but block '{name}' has head_dim {d}. Use "
-                    f"kv_dtype='bf16' or an even d_model/heads split"
-                )
-
-
-def quantize_kv(values, scales):
-    """Symmetric int8 quantization of K/V ``values`` (..., hk, d) with
-    per-kv-head ``scales`` broadcastable over (..., hk); out-of-range
-    values saturate at ±127. ONE definition shared by the pools'
-    prefill writes and the transformer's in-graph decode-step writes,
-    so both paths land bit-identical int8 for identical inputs."""
-    q = jnp.round(values.astype(jnp.float32) / scales[..., None])
-    return jnp.clip(q, -127, 127).astype(jnp.int8)
-
-
-def kv_head_scales(values, axes) -> jnp.ndarray:
-    """Per-kv-head f32 quantization scales from the amax of ``values``
-    over ``axes`` (every dim but the kv-head dim), with the
-    ``KV_SCALE_MARGIN`` headroom and a 1.0 floor substituted for
-    all-zero heads (a zero scale would divide by zero; scale 1.0 maps
-    zeros to zeros exactly)."""
-    amax = jnp.abs(values.astype(jnp.float32)).max(axis=axes)
-    scale = amax * (KV_SCALE_MARGIN / 127.0)
-    return jnp.where(scale == 0.0, 1.0, scale)
 
 
 def _put_rows(pool, values, slot, written):
@@ -140,26 +91,23 @@ def _head_major_rows(kind: str, filled, rows: int, start, length):
 
 
 def _write_slot(buffers, positions, live, prefill_cache, slot, start,
-                length, kinds=None):
+                length, *, kinds):
     """The whole of :meth:`SlotCachePool.write_prefill` as one program:
     rows ``[start, length)`` of ``slot`` take the batch-1
     ``prefill_cache``'s rows, every other row of the pool stays as it
     is, and the slot goes live at position ``length``. ``slot``,
     ``start`` and ``length`` are traced scalars, so the program is keyed
-    by the prefill cache's shape alone. A pool entry of four leaves is
-    an int8 one, whose per-head scales are fixed here from the rows
-    below ``length``. ``kinds`` (static) names the blocks whose entries
-    are head-major ``(S, hk, rows, d)``, by their own declaration or by
-    the pool's choice: a ``full`` one takes the same rows, transposed
-    (adjacent heads side by side where the entry is packed); a ``ring``
-    of ``R``
-    rows takes, in row ``j``, the latest position below ``length`` that
-    is congruent to ``j``: the prompt's last ``min(P, R)`` rows at
-    ``pos % R``."""
+    by the prefill cache's shape alone. What an entry is, its type says
+    (ops/kv_cache.py). An :class:`Int8Rows` entry's per-head scales are
+    fixed here from the rows below ``length``. A :class:`HeadMajorKV`
+    entry is ``full`` or a ``ring`` by ``kinds`` (static): a ``full`` one
+    takes the same rows, transposed (adjacent heads side by side where
+    the entry is packed); a ``ring`` of ``R`` rows takes, in row ``j``,
+    the latest position below ``length`` that is congruent to ``j``: the
+    prompt's last ``min(P, R)`` rows at ``pos % R``."""
     new_buffers = {}
     for name, entry in buffers.items():
-        kind = (kinds or {}).get(name, LINEAR)
-        if kind != LINEAR:
+        if isinstance(entry, HeadMajorKV):
             placed = []
             for pool, filled in zip(entry, prefill_cache[name]):
                 # a packed entry (lane_pack) takes adjacent heads side
@@ -167,24 +115,18 @@ def _write_slot(buffers, positions, live, prefill_cache, slot, start,
                 filled = filled[0].reshape(
                     filled.shape[1], pool.shape[1], pool.shape[3])
                 values, written = _head_major_rows(
-                    kind, filled, pool.shape[2], start, length)
+                    kinds[name], filled, pool.shape[2], start, length)
                 placed.append(_put_rows(pool, values, slot, written))
-            # the entry's own type: a pair, or a HeadMajorKV
-            new_buffers[name] = jax.tree_util.tree_unflatten(
-                jax.tree_util.tree_structure(entry), placed)
+            new_buffers[name] = HeadMajorKV(*placed)
             continue
         rows = min(prefill_cache[name][0].shape[1], entry[0].shape[1])
         ck, cv = (c[0, :rows] for c in prefill_cache[name])
         row = jnp.arange(rows)[:, None, None]
         written = (row >= start) & (row < length)
-        if len(entry) == 2:
-            pk, pv = entry
-            new_buffers[name] = (
-                _put_rows(pk, ck, slot, written),
-                _put_rows(pv, cv, slot, written),
-            )
+        if not isinstance(entry, Int8Rows):
+            new_buffers[name] = (_put_rows(entry[0], ck, slot, written),
+                                 _put_rows(entry[1], cv, slot, written))
             continue
-        pk, pv, pks, pvs = entry
         # the prompt amax (+ margin) FIXES this lease's scales: decode
         # steps quantize against them in-graph, so they must be set
         # before the first block dispatch. The bucket's pad rows are
@@ -192,10 +134,11 @@ def _write_slot(buffers, positions, live, prefill_cache, slot, start,
         ck, cv = (jnp.where(row < length, c, 0) for c in (ck, cv))
         k_scl = kv_head_scales(ck, axes=(0, 2))  # (hk,)
         v_scl = kv_head_scales(cv, axes=(0, 2))
-        new_buffers[name] = (
-            _put_rows(pk, quantize_kv(ck, k_scl), slot, written),
-            _put_rows(pv, quantize_kv(cv, v_scl), slot, written),
-            pks.at[slot].set(k_scl), pvs.at[slot].set(v_scl),
+        new_buffers[name] = Int8Rows(
+            _put_rows(entry.k, quantize_kv(ck, k_scl), slot, written),
+            _put_rows(entry.v, quantize_kv(cv, v_scl), slot, written),
+            entry.k_scale.at[slot].set(k_scl),
+            entry.v_scale.at[slot].set(v_scl),
         )
     # the slot's first decode step writes its first generated token's
     # K/V at position ``length`` (the prompt fills [0, P))
@@ -207,35 +150,27 @@ class SlotCachePool:
     """Preallocated per-block K/V buffers with slot lease/free accounting.
 
     ``buffers`` is the live pytree the scheduler's jitted decode step
-    reads and returns — ``{block: (K, V)}``. The pool owns the host-side
-    bookkeeping (which slots are leased); the arrays themselves stay on
-    device and are replaced functionally each tick.
+    reads and returns, ``{block: entry}``, each entry one of
+    :mod:`mmlspark_tpu.ops.kv_cache`'s layouts (docs/SERVING.md "Cache
+    entries"). The pool owns the host-side bookkeeping (which slots are
+    leased); the arrays themselves stay on device and are replaced
+    functionally each tick.
 
-    On ONE device in bf16 every entry is HEAD-MAJOR, ``(slots, hk,
-    rows, dk)`` and ``(slots, hk, rows, dv)``: the layout the decode
-    step's row write updates in place and its kernel streams without a
-    copy. A block that DECLARES its geometry (``cache_spec()``,
-    models/hybrid.py) gets what it declared, ``full`` rows or a ``ring``
-    of its window's rows, which holds position ``p`` in row ``p %
-    rows``, as a plain pair. A block that declares nothing
-    (``transformer_lm``) gets kind ``full``, ``rows = cache_len``, as a
-    :class:`HeadMajorKV`, so that it reads the layout off the entry's
-    type, its heads packed to whole lanes where they divide
-    (``lane_pack``: 20 heads of 64 are stored ``(slots, 10, rows,
-    128)``). All live in this one pool, are written by the one jitted
-    ``_write_slot`` and read by the one fused decode block. Under a mesh
-    or in int8 an undeclared block keeps LINEAR rows, plain tuples of
-    ``(slots, cache_len, hk, d)`` arrays.
+    On ONE device in bf16 every entry is a ``HeadMajorKV``: a block that
+    DECLARES its geometry (``cache_spec()``, models/hybrid.py) gets what
+    it declared, ``full`` rows or a ``ring`` of its window's rows; a
+    block that declares nothing (``transformer_lm``) gets kind ``full``,
+    ``rows = cache_len``. All live in this one pool, are written by the
+    one jitted ``_write_slot`` and read by the one fused decode block.
+    Under a mesh an undeclared block keeps LINEAR rows, a plain pair.
 
     ``kv_dtype="int8"`` (docs/PERFORMANCE.md "Quantized decode") stores
-    K/V as int8 — HALF the bf16 pool's HBM bytes — and each block's
-    entry grows to ``(K, V, k_scale, v_scale)`` with (slots, hk) f32
-    per-(slot, kv-head) scales as extra cache-pytree leaves: prefill
-    fixes a slot's scales from its prompt amax (+ headroom), decode
-    steps quantize in-graph against them, and the flash-decode kernel
-    dequantizes in-VMEM. All four leaves are DISTINCT arrays (donation)
-    and all four carry pinned shardings under a mesh. The bf16 mode is
-    unchanged — it remains the accuracy oracle the int8 parity suite
+    K/V as int8 — HALF the bf16 pool's HBM bytes — in ``Int8Rows``
+    entries: prefill fixes a slot's scales from its prompt amax (+
+    headroom), decode steps quantize in-graph against them, and the
+    flash-decode kernel dequantizes in-VMEM. All four leaves are
+    DISTINCT arrays (donation) and carry pinned shardings under a mesh.
+    The bf16 mode remains the accuracy oracle the int8 parity suite
     measures against.
     """
 
@@ -312,21 +247,6 @@ class SlotCachePool:
             self._slot_sharding = NamedSharding(mesh, P(DATA_AXIS))
             msize = int(mesh.shape.get(MODEL_AXIS, 1))
             self._kv_shardings = {}
-            for name, (hk, d) in geometry.items():
-                # shard KV heads over the model axis only when they tile
-                # evenly (GQA/MQA models with hk < model size replicate
-                # the head dim, mirroring build_param_shardings' degrade)
-                head = (
-                    MODEL_AXIS if msize > 1 and hk % msize == 0 else None
-                )
-                sh = NamedSharding(mesh, P(DATA_AXIS, None, head, None))
-                if quantized:
-                    # (slots, hk) scale leaves shard exactly like the
-                    # dims they index: slots over data, heads over model
-                    ssc = NamedSharding(mesh, P(DATA_AXIS, head))
-                    self._kv_shardings[name] = (sh, sh, ssc, ssc)
-                else:
-                    self._kv_shardings[name] = (sh, sh)
         self.buffers = {}
         for name, (_kind, rows, hk, d, dv) in specs.items():
             # K and V must be DISTINCT arrays: the engine's decode step
@@ -337,29 +257,33 @@ class SlotCachePool:
             if kind == LINEAR:
                 entry = (jnp.zeros((slots, cache_len, hk, d), store_dtype),
                          jnp.zeros((slots, cache_len, hk, d), store_dtype))
+                if quantized:
+                    entry = Int8Rows(*entry,
+                                     jnp.ones((slots, hk), jnp.float32),
+                                     jnp.ones((slots, hk), jnp.float32))
             else:
                 # head-major: the decode kernel streams (rows, d) tiles
-                # of a KV head without a copy. A ring never needs more
-                # rows than the pool's length
+                # of a KV head without a copy, rows packed to whole
+                # lanes. A ring never needs more rows than the pool's
+                # length
                 rows = cache_len if kind == FULL_ROWS else min(
                     int(rows), cache_len)
-                # a block that declared nothing learns the layout from
-                # the entry's type, and reads rows packed to whole lanes
-                f, pair = ((1, tuple) if name in declared
-                           else (lane_pack(hk, d, dv), HeadMajorKV._make))
-                entry = pair((
+                f = lane_pack(hk, d, dv)
+                entry = HeadMajorKV(
                     jnp.zeros((slots, hk // f, rows, f * d), store_dtype),
-                    jnp.zeros((slots, hk // f, rows, f * dv), store_dtype)))
-            if quantized:
-                entry = (
-                    *entry,
-                    jnp.ones((slots, hk), jnp.float32),
-                    jnp.ones((slots, hk), jnp.float32),
-                )
-            if self._kv_shardings is not None:
-                entry = tuple(jax.device_put(
-                    entry, self._kv_shardings[name]
-                ))
+                    jnp.zeros((slots, hk // f, rows, f * dv), store_dtype))
+            if mesh is not None:
+                # shard KV heads over the model axis only when they tile
+                # evenly (GQA/MQA models with hk < model size replicate
+                # the head dim, mirroring build_param_shardings' degrade);
+                # the (slots, hk) scale leaves shard like the dims they
+                # index. The entry's own type, so the trees match
+                head = MODEL_AXIS if msize > 1 and hk % msize == 0 else None
+                by_rank = {4: P(DATA_AXIS, None, head, None),
+                           2: P(DATA_AXIS, head)}
+                self._kv_shardings[name] = jax.tree_util.tree_map(
+                    lambda a: NamedSharding(mesh, by_rank[a.ndim]), entry)
+                entry = jax.device_put(entry, self._kv_shardings[name])
             self.buffers[name] = entry
         # LIFO free list popping the lowest id first keeps slot
         # assignment deterministic for the parity tests
@@ -404,10 +328,8 @@ class SlotCachePool:
         if mesh is not None:
             pinned = (self._kv_shardings, self._slot_sharding,
                       self._slot_sharding)
-        write = (partial(_write_slot, kinds=dict(self.kinds))
-                 if self.kinds else _write_slot)
-        self._write = jax.jit(write, donate_argnums=(0,),
-                              out_shardings=pinned)
+        self._write = jax.jit(partial(_write_slot, kinds=dict(self.kinds)),
+                              donate_argnums=(0,), out_shardings=pinned)
 
     # -- sharding anchors --------------------------------------------------
 
@@ -509,11 +431,12 @@ class SlotCachePool:
             # 1.0 init: a freed (quarantined/preempted/retired) lease
             # must not leak its calibration into the next tenant, and
             # the parity tests assert the reset
-            new_buffers = {}
-            for name, (k, v, ks, vs) in self.buffers.items():
-                new_buffers[name] = (
-                    k, v, ks.at[slot].set(1.0), vs.at[slot].set(1.0),
-                )
+            new_buffers = {
+                name: entry._replace(
+                    k_scale=entry.k_scale.at[slot].set(1.0),
+                    v_scale=entry.v_scale.at[slot].set(1.0))
+                for name, entry in self.buffers.items()
+            }
             if self._kv_shardings is not None:
                 new_buffers = jax.device_put(
                     new_buffers, self._kv_shardings
@@ -593,7 +516,7 @@ class SlotCachePool:
         out = {LINEAR: 0, FULL_ROWS: 0, RING_ROWS: 0}
         for name, entry in self.buffers.items():
             kind = self.kinds.get(name, LINEAR)
-            k, v = entry[0], entry[1]
+            k, v = entry[:2]
             if kind == LINEAR:
                 rows, per_row = length - start, 2 * math.prod(k.shape[2:])
             else:

@@ -78,12 +78,14 @@ import jax.numpy as jnp
 from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.core.faults import ResourceExhausted
 from mmlspark_tpu.models.generate import cache_geometry
-from mmlspark_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
-from mmlspark_tpu.serve.cache_pool import (
+from mmlspark_tpu.ops.kv_cache import (
+    PagedInt8KV,
+    PagedKV,
     kv_head_scales,
     quantize_kv,
     validate_kv_dtype,
 )
+from mmlspark_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 #: smallest page: the TPU sublane tile — a page's (page_size, d) face is
 #: the paged decode kernel's KV block, and blocks under 8 rows cannot
@@ -139,17 +141,17 @@ class PagedCachePool:
     (:meth:`prefix_lookup` / :meth:`map_prefix` + :meth:`gather_prefix`
     / :meth:`prefix_insert`), :meth:`paging_stats`, :meth:`snapshot`.
 
-    ``buffers`` is ``{block: (K, V, PT)}`` — the engine's decode jit
-    donates and returns the whole pytree unchanged in structure, and
-    ``models/transformer.py`` recognizes the 3-tuple as the paged
-    cache.
+    ``buffers`` is ``{block: PagedKV(k, v, page_table)}`` — the engine's
+    decode jit donates and returns the whole pytree unchanged in
+    structure, and ``ops/kv_cache.py`` reads the paged layout off the
+    entry's type.
 
     ``kv_dtype="int8"`` (docs/PERFORMANCE.md "Quantized decode") stores
     the page faces as int8 — half the bf16 page store's HBM bytes, so a
-    fixed page budget holds 2x the tokens — and each block's entry
-    grows to ``(K, V, PT, k_scale, v_scale)`` with (num_pages, hk) f32
-    PER-PAGE scales as extra cache-pytree leaves: a page's scale is
-    fixed at its FIRST write (prefill slice amax, or the first decode
+    fixed page budget holds 2x the tokens — and each block's entry is a
+    ``PagedInt8KV(k, v, page_table, k_scale, v_scale)`` with (num_pages,
+    hk) f32 PER-PAGE scales as extra cache-pytree leaves: a page's scale
+    is fixed at its FIRST write (prefill slice amax, or the first decode
     token's amax, + headroom), later writes into the page quantize
     against it, copy-on-extend copies it with the page, and
     ``paged_flash_decode`` dequantizes each fetched page in-VMEM.
@@ -273,11 +275,12 @@ class PagedCachePool:
                     # (num_pages, hk) scale leaves shard like the dims
                     # they index: pages over data, heads over model
                     ssc = NamedSharding(mesh, P(DATA_AXIS, head))
-                    self._kv_shardings[name] = (
+                    self._kv_shardings[name] = PagedInt8KV(
                         sh, sh, self._pt_sharding, ssc, ssc,
                     )
                 else:
-                    self._kv_shardings[name] = (sh, sh, self._pt_sharding)
+                    self._kv_shardings[name] = PagedKV(
+                        sh, sh, self._pt_sharding)
 
         # -- host allocator state --------------------------------------
         # page table mirror: every entry starts at the owning shard's
@@ -322,17 +325,15 @@ class PagedCachePool:
             k = jnp.zeros((num_pages, hk, page_size, d), store_dtype)
             v = jnp.zeros((num_pages, hk, page_size, d), store_dtype)
             pt = jnp.asarray(self._pt_host)
-            entry = (k, v, pt)
+            entry = PagedKV(k, v, pt)
             if quantized:
-                entry = (
+                entry = PagedInt8KV(
                     k, v, pt,
                     jnp.ones((num_pages, hk), jnp.float32),
                     jnp.ones((num_pages, hk), jnp.float32),
                 )
             if self._kv_shardings is not None:
-                entry = tuple(jax.device_put(
-                    entry, self._kv_shardings[name]
-                ))
+                entry = jax.device_put(entry, self._kv_shardings[name])
             self.buffers[name] = entry
         self._free = list(range(slots - 1, -1, -1))
         self._leased: set[int] = set()
@@ -472,18 +473,15 @@ class PagedCachePool:
         return changed_kv
 
     def _copy_page(self, src: int, dst: int) -> None:
-        for name, (pk, pv, pt, *scales) in self.buffers.items():
-            nk = pk.at[dst].set(pk[src])
-            nv = pv.at[dst].set(pv[src])
-            if scales:
-                # int8 mode: a page copy is only faithful WITH its
-                # quantization scales — the copied int8 values decode
-                # through the same multipliers as the original's
-                ks, vs = scales
-                scales = [
-                    ks.at[dst].set(ks[src]), vs.at[dst].set(vs[src]),
-                ]
-            self.buffers[name] = (nk, nv, pt, *scales)
+        for name, entry in self.buffers.items():
+            # int8 mode: a page copy is only faithful WITH its
+            # quantization scales — the copied int8 values decode
+            # through the same multipliers as the original's. Every
+            # leaf but the table is indexed by page
+            self.buffers[name] = entry._replace(**{
+                field: leaf.at[dst].set(leaf[src])
+                for field, leaf in entry._asdict().items()
+                if field != "page_table"})
 
     # -- device-state commits ----------------------------------------------
 
@@ -493,11 +491,11 @@ class PagedCachePool:
         committed to the table's canonical sharding under a mesh."""
         if not self._pt_dirty:
             return
-        for name, (pk, pv, _old, *scales) in self.buffers.items():
+        for name, entry in self.buffers.items():
             pt = jnp.asarray(self._pt_host)
             if self._kv_shardings is not None:
-                pt = jax.device_put(pt, self._kv_shardings[name][2])
-            self.buffers[name] = (pk, pv, pt, *scales)
+                pt = jax.device_put(pt, self._pt_sharding)
+            self.buffers[name] = entry._replace(page_table=pt)
         self._pt_dirty = False
 
     def _commit_kv(self) -> None:
@@ -509,18 +507,9 @@ class PagedCachePool:
         if self._kv_shardings is None:
             return
         # int8 mode: the (num_pages, hk) scale leaves ride the same
-        # commit — eager page copies touch them too, and their pinned
-        # shardings sit at the same tuple positions in _kv_shardings
-        kv = {
-            name: (e[0], e[1], *e[3:]) for name, e in self.buffers.items()
-        }
-        sh = {
-            name: (s[0], s[1], *s[3:])
-            for name, s in self._kv_shardings.items()
-        }
-        kv = jax.device_put(kv, sh)
-        for name, (k, v, *scales) in kv.items():
-            self.buffers[name] = (k, v, self.buffers[name][2], *scales)
+        # commit — eager page copies touch them too. A page table is
+        # committed already (_commit_pt) and passes through untouched
+        self.buffers = jax.device_put(self.buffers, self._kv_shardings)
 
     def _commit_slot_pair(self, positions, live) -> None:
         """Rebind positions+live behind ONE pinned update (two
@@ -682,8 +671,9 @@ class PagedCachePool:
         offs = jnp.asarray(pos % self.page_size)
         dispatches, nbytes = 2, 0
         quantized = self.kv_dtype == "int8"
-        for name, (pk, pv, pt, *scales) in self.buffers.items():
-            ck, cv = prefill_cache[name][0], prefill_cache[name][1]
+        for name, entry in self.buffers.items():
+            pk, pv = entry.k, entry.v
+            ck, cv = prefill_cache[name]
             hidx = jnp.arange(pk.shape[1])
             dispatches += 1
             # K and V alike: (num_pages, hk, page_size, d)
@@ -691,7 +681,7 @@ class PagedCachePool:
                 pk.shape[1] * pk.shape[3] * pk.dtype.itemsize
             )
             if quantized:
-                ks, vs = scales
+                ks, vs = entry.k_scale, entry.v_scale
                 # Per-page scales are fixed at each page's FIRST write:
                 # a page is fresh here iff its first logical position
                 # is at or past ``start`` — the prefix-resume path's
@@ -728,7 +718,8 @@ class PagedCachePool:
                 nv = pv.at[
                     pages[:, None], hidx[None, :], offs[:, None]
                 ].set(qv)
-                self.buffers[name] = (nk, nv, pt, ks, vs)
+                self.buffers[name] = entry._replace(
+                    k=nk, v=nv, k_scale=ks, v_scale=vs)
                 dispatches += 4   # two concatenations, two scatters
             else:
                 nk = pk.at[
@@ -737,7 +728,7 @@ class PagedCachePool:
                 nv = pv.at[
                     pages[:, None], hidx[None, :], offs[:, None]
                 ].set(cv[0, start:length].astype(pv.dtype))
-                self.buffers[name] = (nk, nv, pt)
+                self.buffers[name] = entry._replace(k=nk, v=nv)
                 # a slice and a scatter each, and a cast where it is one
                 dispatches += 4 + (ck.dtype != pk.dtype) \
                     + (cv.dtype != pv.dtype)
@@ -879,10 +870,12 @@ class PagedCachePool:
 
             rep = NamedSharding(self.mesh, P())
         out = {}
-        for name, (pk, pv, _pt, *scales) in self.buffers.items():
-            hk, d = pk.shape[1], pk.shape[3]
+        for name, entry in self.buffers.items():
+            hk, d = entry.k.shape[1], entry.k.shape[3]
+            scales = ((entry.k_scale, entry.v_scale)
+                      if isinstance(entry, PagedInt8KV) else (None, None))
             lin = []
-            for store, scl in zip((pk, pv), scales or (None, None)):
+            for store, scl in zip((entry.k, entry.v), scales):
                 g = store[idx]  # (n, hk, ps, d)
                 dtype = store.dtype
                 if scl is not None:

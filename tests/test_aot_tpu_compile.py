@@ -341,7 +341,7 @@ def test_gpt2_large_decode_block_reads_the_pool_where_it_lies(
     rows are written and read where they lie: heads of 64 two to a row
     of 128 lanes, which the chip holds as the kernels read them), the
     pool is updated in place, and the temporaries stay under 1 GB."""
-    from mmlspark_tpu.models.generate import HeadMajorKV
+    from mmlspark_tpu.ops.kv_cache import HeadMajorKV
 
     monkeypatch.setattr("mmlspark_tpu.core.env.is_tpu", lambda: True)
     fn, args = _decode_block(slots=LARGE_SLOTS, t=4, config=GPT2_LARGE)

@@ -25,14 +25,14 @@ import jax.numpy as jnp
 from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.models import build_model
 from mmlspark_tpu.ops.flash_attention import flash_decode, paged_flash_decode
-from mmlspark_tpu.ops.quantize import kv_cache_bytes
-from mmlspark_tpu.serve import ServeEngine
-from mmlspark_tpu.serve.cache_pool import (
-    SlotCachePool,
+from mmlspark_tpu.ops.kv_cache import (
     kv_head_scales,
     quantize_kv,
     validate_kv_dtype,
 )
+from mmlspark_tpu.ops.quantize import kv_cache_bytes
+from mmlspark_tpu.serve import ServeEngine
+from mmlspark_tpu.serve.cache_pool import SlotCachePool
 from mmlspark_tpu.serve.paging import PagedCachePool
 from mmlspark_tpu.testing.compile_guard import serve_compile_guard
 
