@@ -49,9 +49,6 @@ from mmlspark_tpu.ops.attention import dense_attention
 
 FULL, SWA = "full", "swa"
 DENSE_FFN, ROUTED_FFN = "dense", "routed"
-#: rows of the flash forward's blocks once a prompt is long enough: at
-#: 128 the kernel's grid steps, not its products, take the time
-PREFILL_BLOCK = 512
 
 
 class RMSNorm(nn.Module):
@@ -157,11 +154,10 @@ class HybridAttention(nn.Module):
                                    sink=sink)
         from mmlspark_tpu.ops.flash_attention import flash_attention
 
-        block = PREFILL_BLOCK if q.shape[1] >= PREFILL_BLOCK else 128
         # named apart from ``attn``: the trace tells the kinds apart
         with jax.named_scope(f"attn_{kind}_prefill"):
             return flash_attention(q, k, v, causal=True, window=self.window,
-                                   sink=sink, block=block)
+                                   sink=sink)
 
 
 class _Experts(nn.Module):

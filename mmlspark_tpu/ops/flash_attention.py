@@ -23,6 +23,33 @@ with ``dS = P ⊙ (dO·Vᵀ − D)`` and ``D = rowsum(dO ⊙ O)`` precomputed in
 XLA. Memory stays O(S·d) end to end — nothing (S, S) is ever materialized
 in either direction.
 
+Block size. One grid step of the three kernels takes ``blk`` rows of Q
+and ``blk`` rows of K of one (batch, head). ``_flash_block`` chooses
+``blk`` from the sequence length, the head widths and the VMEM the tiles
+need; ``block=`` overrides it (the tests do, to span several blocks). It
+takes the most rows it may, the whole sequence up to 1,024: a step costs
+0.4 us before it does anything, and rescales its accumulators once for
+every block of K, so at 128 rows the steps took the time, not the
+products. On a v5e, device time of one call at train-dp4's shape,
+``(8, 1024, 16, 64)`` bfloat16, causal (``tools/flash_block_timing.py``,
+chip run of PR 32; us):
+
+    block    forward    dK/dV      dQ     all three
+      128      3,306    3,089   2,995      9,391
+      256      1,758    1,650   1,228      4,636
+      512        973      827     714      2,514
+    1,024        474      821     585      1,881
+
+At 1,024 a call is one step a head and computes the masked upper
+triangle too, yet is the fastest of the four; XLA's own attention
+(``dense_attention``), forward and backward in one program, took 6,841.
+Two heads a step at 1,024 were slower (forward 568), four at 512 no
+faster (2,081 for the three) than one at 1,024. The forward kernel alone
+at mimo-v2-flash's prefill (64 query heads, q/k 192, v 128) over 1,024,
+2,048 and 4,096 rows: full layers 518, 1,676, 6,093 at a block of 512 and
+277, 1,089, 3,634 at 1,024; window layers (128 rows, a sink) 536, 1,272,
+3,160 and 285, 1,156, 2,781.
+
 Off-TPU (the unit-test CPU mesh) the kernels run in interpreter mode, so
 the same code path is tested everywhere.
 """
@@ -46,16 +73,61 @@ from mmlspark_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 LANES = 128
 SUBLANES = 8  # min f32 sublane tile; single-row decode broadcasts to it
 
+#: what one grid step of the forward and backward kernels may take of
+#: VMEM (a v5e core has 128 MiB): the budget ``_flash_block`` holds a
+#: block to, and the kernels' ``vmem_limit_bytes``, so that the compiler
+#: does not refuse at its own default what the budget allows
+_FLASH_VMEM = 48 << 20
+
 # all three kernels share a (batch·heads, outer-block, streamed-block)
 # grid: the first two dims own disjoint outputs/scratch, only the last
 # carries accumulator state across iterations
 _GRID_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL, pltpu.ARBITRARY),
+    vmem_limit_bytes=_FLASH_VMEM,
 )
 
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
+
+
+#: the most rows of Q and of K one grid step takes: train-dp4's whole
+#: sequence (the timings are in the module's docstring)
+_FLASH_BLOCK_MOST = 1024
+
+
+def _flash_vmem_bytes(blk: int, d: int, dv: int, itemsize: int) -> int:
+    """VMEM one grid step holds at ``blk`` rows of Q and of K, by the
+    widest of the three kernels (dK/dV), as VMEM holds the tiles: the
+    minor dimension in whole lanes, every operand double-buffered."""
+    wide = _round_up(max(d, dv), LANES)
+    row = 6 * 2 * wide * itemsize   # Q, dO, K, V in; dK, dV out
+    row += 2 * 2 * LANES * 4        # LSE and D, lanes-replicated float32
+    row += 2 * wide * 4             # the two float32 accumulators
+    # the (blk, blk) tiles a step makes: scores, P, dP, dS in float32,
+    # P and dS again in the operands' dtype, the mask's two iotas
+    return blk * row + blk * blk * (6 * 4 + 2 * itemsize)
+
+
+def _flash_block(s: int, d: int, dv: int, itemsize: int) -> int:
+    """Rows of Q and of K a grid step of the forward and both backward
+    kernels takes, for ``s`` rows of ``d``-wide keys and ``dv``-wide
+    values: the one place that chooses it (callers pass ``block=`` only
+    to override).
+
+    A sequence that fits one lane tile is one block, padded to whole
+    sublanes. A longer one is padded to whole lane tiles and takes the
+    most lane tiles, ``_FLASH_BLOCK_MOST`` rows at most, that divide the
+    padded length, so that a larger block never adds padded rows, and
+    that ``_flash_vmem_bytes`` puts inside ``_FLASH_VMEM``."""
+    if s <= LANES:
+        return _round_up(s, SUBLANES)
+    tiles = _round_up(s, LANES) // LANES
+    return LANES * max(
+        n for n in range(1, min(tiles, _FLASH_BLOCK_MOST // LANES) + 1)
+        if tiles % n == 0 and (n == 1 or _flash_vmem_bytes(
+            n * LANES, d, dv, itemsize) <= _FLASH_VMEM))
 
 
 def _kernel_axes(mesh, batch: int, kv_heads: int):
@@ -238,8 +310,17 @@ def _from_bh(t, b, h, s):
     return jnp.moveaxis(t[:, :s].reshape(b, h, s, -1), 1, 2)
 
 
+def _block_rows(block: int | None, q, v) -> int:
+    """The block of one call: the caller's ``block`` (at most the
+    sequence in whole sublanes), or the module's choice."""
+    s = q.shape[1]
+    if block is None:
+        return _flash_block(s, q.shape[-1], v.shape[-1], q.dtype.itemsize)
+    return min(block, _round_up(s, SUBLANES))
+
+
 def _flash_forward(q, k, v, *, causal: bool, window: int | None,
-                   scale: float, block: int, interpret: bool,
+                   scale: float, block: int | None, interpret: bool,
                    with_lse: bool = True, sink=None):
     b, s, h, d = q.shape
     dv = v.shape[-1]  # the values (and the output) may differ in width
@@ -247,7 +328,7 @@ def _flash_forward(q, k, v, *, causal: bool, window: int | None,
     # the group factor g maps query-head grid index bh -> kv row bh // g
     # in the index maps, so K/V are never materialized per query head
     g = h // k.shape[2]
-    blk = min(block, _round_up(s, 8))
+    blk = _block_rows(block, q, v)
     s_pad = _round_up(s, blk)
     qb, kb, vb = (_to_bh(t, s_pad) for t in (q, k, v))
     n_blk = s_pad // blk
@@ -412,7 +493,7 @@ def _bwd_q_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dd_ref,
 
 
 def _flash_backward(q, k, v, out, lse, g, *, causal: bool,
-                    window: int | None, scale: float, block: int,
+                    window: int | None, scale: float, block: int | None,
                     interpret: bool):
     b, s, h, d = q.shape
     # GQA: the dK/dV kernel runs per QUERY head (accumulating across the
@@ -422,7 +503,7 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool,
     # materialized per query head
     h_kv = k.shape[2]
     grp = h // h_kv
-    blk = min(block, _round_up(s, 8))
+    blk = _block_rows(block, q, v)
     s_pad = _round_up(s, blk)
     qb, kb, vb, dob = (_to_bh(t, s_pad) for t in (q, k, v, g))
     # D = rowsum(dO ⊙ O): (bh, s_pad), lanes-replicated like the LSE
@@ -530,7 +611,7 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool,
 
 
 @lru_cache(maxsize=None)
-def _build(causal: bool, window: int | None, scale_key, block: int,
+def _build(causal: bool, window: int | None, scale_key, block: int | None,
            interpret: bool):
     @jax.custom_vjp
     def f(q, k, v):
@@ -562,8 +643,8 @@ def _build(causal: bool, window: int | None, scale_key, block: int,
 
 def flash_attention(q, k, v, *, causal: bool = False,
                     window: int | None = None, scale=None,
-                    block: int = 128, interpret: bool | None = None,
-                    mesh=None, sink=None):
+                    block: int | None = None,
+                    interpret: bool | None = None, mesh=None, sink=None):
     """Blockwise fused attention, (B, S, H, D) layout, exact output AND
     exact gradients — both directions O(S·d) memory.
 
@@ -581,8 +662,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
     in forward and both backward kernels.
 
     ``interpret=None`` auto-selects: compiled kernel on TPU, interpreter
-    elsewhere (tests). Sequences are padded to the block size internally;
-    padded keys are masked, padded query rows are sliced away.
+    elsewhere (tests). ``block=None`` takes the rows of a grid step from
+    the shapes (:func:`_flash_block`); a number overrides that.
+    Sequences are padded to the block size internally; padded keys are
+    masked, padded query rows are sliced away.
 
     ``mesh``: the caller's (data, model) mesh when the operands are
     sharded over one — the kernel then runs per shard (see

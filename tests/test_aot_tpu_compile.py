@@ -69,12 +69,14 @@ def _attention(**kw):
             _qkv(2, CACHE))
 
 
-def _attention_bwd():
+def _attention_bwd(shape=(2, CACHE, HEADS, HEAD_DIM)):
+    """Forward and both backward kernels at the block the module chooses."""
     def loss(q, k, v):
         out = flash_attention(q, k, v, causal=True, interpret=False)
         return out.astype(jnp.float32).sum()
 
-    return jax.grad(loss, argnums=(0, 1, 2)), _qkv(2, CACHE)
+    return (jax.grad(loss, argnums=(0, 1, 2)),
+            (jax.ShapeDtypeStruct(shape, jnp.bfloat16),) * 3)
 
 
 def _decode(slots, int8, cache=CACHE):
@@ -241,6 +243,11 @@ CASES = {
     "hybrid_fwd_full_4096": lambda: _hybrid_forward(4, None, 512),
     "hybrid_fwd_swa_sink_4096": lambda: _hybrid_forward(8, 128, 512),
     "hybrid_fwd_swa_sink_256": lambda: _hybrid_forward(8, 128, 128, s=256),
+    # the cell's widest prompt, at the block the module chooses
+    "hybrid_fwd_full_3072_chosen": lambda: _hybrid_forward(
+        4, None, None, s=3072),
+    "hybrid_fwd_swa_sink_3072_chosen": lambda: _hybrid_forward(
+        8, 128, None, s=3072),
     "grouped_matmul_decode_up": lambda: _grouped(1024, 64, 2048, 4096),
     "grouped_matmul_decode_down": lambda: _grouped(1024, 64, 4096, 2048),
     "grouped_matmul_prefill_up": lambda: _grouped(40960, 512, 2048, 4096),
@@ -248,6 +255,8 @@ CASES = {
     "flash_fwd_causal": lambda: _attention(causal=True),
     "flash_fwd_windowed": lambda: _attention(causal=True, window=256),
     "flash_bwd_causal": _attention_bwd,
+    # gpt2-medium.train-dp4's call on one chip: 8 sequences x 1,024, 16 x 64
+    "flash_train_dp4_grad_chosen": lambda: _attention_bwd((8, 1024, 16, 64)),
     "flash_decode_bf16_8": lambda: _decode(8, False),
     "flash_decode_bf16_64": lambda: _decode(64, False),
     # generate()'s cache is prompt + new tokens long: 700 + 48 has no
@@ -275,6 +284,40 @@ def test_compiles_for_v5e(case, chip, monkeypatch):
     fn, args = CASES[case]()
     compiled = jax.jit(fn).lower(*_on(chip, args)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _kernel_grids(jaxpr, found=None) -> list:
+    """The grid of every ``pallas_call`` of a traced program, calls
+    inside ``custom_vjp`` and ``jit`` bodies too, in program order."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(tuple(eqn.params["grid_mapping"].grid))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _kernel_grids(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("case,calls,bh,s,d,dv", [
+    ("flash_train_dp4_grad_chosen", 3, 8 * 16, 1024, 64, 64),
+    ("hybrid_fwd_full_3072_chosen", 1, 64, 3072, 192, 128),
+    ("hybrid_fwd_swa_sink_3072_chosen", 1, 64, 3072, 192, 128),
+])
+def test_flash_grid_is_the_choosers(case, calls, bh, s, d, dv, monkeypatch):
+    """The kernels of a call that passes no ``block`` take the grid that
+    ``_flash_block`` gives their shapes: each of train-dp4's three
+    calls one step a head, 128, where a block of 128 took 8,192."""
+    from mmlspark_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr("mmlspark_tpu.core.env.is_tpu", lambda: True)
+    fn, args = CASES[case]()
+    blocks = -(-s // fa._flash_block(s, d, dv, 2))
+    grids = _kernel_grids(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert blocks == -(-s // 1024)
+    assert grids == [(bh, blocks, blocks)] * calls
 
 
 def _on(chip, tree):

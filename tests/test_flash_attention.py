@@ -380,3 +380,66 @@ def test_transformer_lm_rope():
     with pytest.raises(ParamError, match="pos_embedding"):
         build_model("transformer_lm", vocab_size=32, d_model=16, heads=2,
                     depth=1, max_len=16, pos_embedding="alibi")
+
+
+# -- the block chooser: pure Python, no kernel runs ---------------------------
+
+
+@pytest.mark.parametrize("name,s,d,dv,want", [
+    # gpt2-medium.train-dp4's call on one chip: (8, 1024, 16, 64)
+    ("train_dp4", 1024, 64, 64, 1024),
+    # gpt2-large's prefill buckets: a bucket is one block a head
+    ("gpt2_large_32", 32, 64, 64, 32),
+    ("gpt2_large_64", 64, 64, 64, 64),
+    ("gpt2_large_128", 128, 64, 64, 128),
+    ("gpt2_large_256", 256, 64, 64, 256),
+    # mimo-v2-flash's prefill buckets, full and window layers alike
+    # (q/k 192, v 128)
+    ("mimo_512", 512, 192, 128, 512),
+    ("mimo_1024", 1024, 192, 128, 1024),
+    ("mimo_2048", 2048, 192, 128, 1024),
+    ("mimo_4096", 4096, 192, 128, 1024),
+    # no whole number of the largest block: padded to whole lane tiles
+    # and no further, the block a divisor of those
+    ("odd_1000", 1000, 64, 64, 1024),
+    ("odd_700", 700, 64, 64, 768),
+    ("odd_1152", 1152, 64, 64, 384),
+    ("odd_5000", 5000, 64, 64, 1024),
+    # shorter than a lane tile: one block of whole sublanes
+    ("short_20", 20, 64, 64, 24),
+    ("short_100", 100, 128, 128, 104),
+])
+def test_block_chooser(name, s, d, dv, want):
+    from mmlspark_tpu.ops import flash_attention as fa
+
+    blk = fa._flash_block(s, d, dv, 2)
+    assert blk == want
+    assert blk % fa.SUBLANES == 0
+    # the block divides the length padded to whole lane tiles (whole
+    # sublanes under one tile): a larger block adds no padded rows
+    assert fa._round_up(s, blk) == fa._round_up(
+        s, fa.LANES if s > fa.LANES else fa.SUBLANES)
+    assert blk <= fa._FLASH_BLOCK_MOST
+    assert fa._flash_vmem_bytes(blk, d, dv, 2) <= fa._FLASH_VMEM
+    # float32 operands are held to the same budget
+    assert fa._flash_vmem_bytes(
+        fa._flash_block(s, d, dv, 4), d, dv, 4) <= fa._FLASH_VMEM
+
+
+@pytest.mark.parametrize("window", [None, 40])
+def test_chosen_block_pads_and_matches_dense(rng, window):
+    # no block= : 136 rows are padded to two lane tiles and taken as ONE
+    # block of 256, so padded keys AND padded query rows are masked
+    q, k, v = _qkv(rng, b=1, s=136, h=2, d=8)
+    w = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
+    kw = dict(causal=True, window=window)
+    got, grads = jax.value_and_grad(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v, **kw) * w),
+        argnums=(0, 1, 2))(q, k, v)
+    want, want_grads = jax.value_and_grad(
+        lambda q, k, v: jnp.sum(dense_attention(q, k, v, **kw) * w),
+        argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    for a, b in zip(grads, want_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-4, rtol=1e-4)

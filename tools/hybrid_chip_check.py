@@ -78,7 +78,8 @@ def kernels() -> None:
         wv = v.at[rows_i, :, at].set(vn)
         report(f"cache_row_write.{kind}", max(gap(k2, wk), gap(v2, wv)), 0.0)
         # the forward kernel
-        for t, block in ((512, 512), (1024, 512), (384, 128)):
+        # None: the block the module chooses, as the model's prefill runs
+        for t, block in ((512, None), (1024, None), (2048, None), (384, 128)):
             q = jax.random.normal(kq, (1, t, h, dk), jnp.bfloat16)
             k = jax.random.normal(kk, (1, t, hk, dk), jnp.bfloat16)
             v = jax.random.normal(kv, (1, t, hk, dv), jnp.bfloat16)
