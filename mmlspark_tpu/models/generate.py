@@ -36,7 +36,12 @@ import jax.numpy as jnp
 
 from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.models.graph import _accepts_kwarg
-from mmlspark_tpu.ops.kv_cache import LINEAR
+from mmlspark_tpu.ops.kv_cache import (
+    LATENT_ROWS,
+    LINEAR,
+    LatentRows,
+    latent_width,
+)
 
 
 def cache_specs(graph, variables) -> dict:
@@ -90,7 +95,7 @@ def cache_specs(graph, variables) -> dict:
 
 def declares_cache_kinds(graph) -> bool:
     """Whether any block of ``graph`` declares its own cache geometry
-    (rings, or keys and values of different widths)."""
+    (rings, latent rows, or keys and values of different widths)."""
     return any(hasattr(mod, "cache_spec") for _, mod in graph.blocks)
 
 
@@ -118,10 +123,16 @@ def init_cache(graph, variables, batch: int, total: int) -> dict:
     """Preallocated per-block LINEAR K/V decode buffers, ``(B, total,
     hk, dk)`` and ``(B, total, hk, dv)`` bf16 zeros for every block that
     takes a ``cache`` kwarg (geometry from :func:`cache_specs`): what a
-    prefill fills, whatever kind the serving pool then keeps."""
+    prefill fills, whatever kind the serving pool then keeps. A block
+    that declares ``latent`` rows gets its one array, ``(B, total, W)``
+    (:class:`~mmlspark_tpu.ops.kv_cache.LatentRows`)."""
     cache = {}
-    for name, (_kind, _rows, hk, dk, dv) in cache_specs(
+    for name, (kind, _rows, hk, dk, dv) in cache_specs(
             graph, variables).items():
+        if kind == LATENT_ROWS:
+            cache[name] = LatentRows(jnp.zeros(
+                (batch, total, latent_width(dk)), jnp.bfloat16))
+            continue
         cache[name] = (jnp.zeros((batch, total, hk, dk), jnp.bfloat16),
                        jnp.zeros((batch, total, hk, dv), jnp.bfloat16))
     return cache

@@ -6,18 +6,23 @@ one fused ``qkv``, one window for all layers). The open models of 2025
 mix kinds inside one stack, and this builder takes the mix as data: for
 every layer an ATTENTION kind (``full``: causal over the whole context;
 ``swa``: a causal sliding window with, optionally, a learned per-head
-sink in the softmax's denominator) and an FFN kind (``dense``: SwiGLU;
-``routed``: sigmoid top-k routing over ``n_experts`` experts of which this
-holder has a stated range, :func:`mmlspark_tpu.parallel.expert.
-moe_ffn_held`). Around them: RMSNorm, projections without biases, query
-and key heads of one width and value heads of another, rotary positions
-on the first ``rotary_dim`` dimensions of a head at a base per attention
-kind, KV heads per attention kind, an untied head, and parameters stored
-in ``param_dtype``.
+sink in the softmax's denominator; ``mla``: multi-head latent attention,
+causal over the whole context, whose keys and values are up-projections
+of ONE compressed row a position, :class:`LatentAttention`) and an FFN
+kind (``dense``: SwiGLU; ``routed``: sigmoid top-k routing over
+``n_experts`` experts of which this holder has a stated range,
+:func:`mmlspark_tpu.parallel.expert.moe_ffn_held`, beside an always-on
+shared SwiGLU where ``shared_d_ff`` gives one). Around them: RMSNorm,
+projections without biases, query and key heads of one width and value
+heads of another, rotary positions on the first ``rotary_dim`` dimensions
+of a head (a latent layer's: on its rotary part alone) at a base per
+attention kind, KV heads per attention kind, an untied head, and
+parameters stored in ``param_dtype``.
 
 Every block DECLARES the geometry of its KV cache (:meth:`HybridBlock.
 cache_spec`): a full block keeps a row for every position, a window block
-a ring of ``window`` rows. The serving pool
+a ring of ``window`` rows, a latent block ONE row ``[c ; k_rope]`` for
+every position and all heads. The serving pool
 (``serve/cache_pool.py``, ``SlotCachePool``) allocates by that declaration,
 head-major, which is the layout the decode kernel
 (:func:`mmlspark_tpu.ops.flash_attention.flash_decode_grouped`) streams
@@ -29,6 +34,7 @@ the logits in float32; the residual stream is float32.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Any
 
 import flax.linen as nn
@@ -47,7 +53,7 @@ from mmlspark_tpu.models.transformer import (
 from mmlspark_tpu.ops import kv_cache
 from mmlspark_tpu.ops.attention import dense_attention
 
-FULL, SWA = "full", "swa"
+FULL, SWA, MLA = "full", "swa", "mla"
 DENSE_FFN, ROUTED_FFN = "dense", "routed"
 
 
@@ -91,6 +97,7 @@ class HybridAttention(nn.Module):
     attn_impl: str = "dense"
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    rope_interleave: bool = False
 
     @nn.compact
     def __call__(self, x, cache=None, pos=None, decode=False, live=None):
@@ -114,16 +121,13 @@ class HybridAttention(nn.Module):
         if self.sink:
             sink = self.param("sink", nn.initializers.zeros, (h,),
                               self.param_dtype).astype(jnp.float32)
-        if cache is None:
-            positions = None
-        elif jnp.ndim(pos):  # the engine's per-row decode step
-            positions = jnp.asarray(pos)[:, None] + jnp.arange(t)
-        else:
-            positions = pos + jnp.arange(t)
+        positions = _positions(cache, pos, t)
         q = apply_rope(q, positions, base=self.rope_base,
-                       rotary_dim=self.rotary_dim)
+                       rotary_dim=self.rotary_dim,
+                       interleave=self.rope_interleave)
         k = apply_rope(k, positions, base=self.rope_base,
-                       rotary_dim=self.rotary_dim)
+                       rotary_dim=self.rotary_dim,
+                       interleave=self.rope_interleave)
         kind = FULL if self.window is None else SWA
         new_cache = None
         if cache is None:
@@ -149,15 +153,124 @@ class HybridAttention(nn.Module):
         return out if new_cache is None else (out, new_cache)
 
     def _prompt_attention(self, q, k, v, sink, kind: str):
-        if resolve_attn_impl(self.attn_impl) != FLASH:
-            return dense_attention(q, k, v, causal=True, window=self.window,
-                                   sink=sink)
-        from mmlspark_tpu.ops.flash_attention import flash_attention
+        return _prompt_attention(self.attn_impl, q, k, v, kind,
+                                 window=self.window, sink=sink)
 
-        # named apart from ``attn``: the trace tells the kinds apart
-        with jax.named_scope(f"attn_{kind}_prefill"):
-            return flash_attention(q, k, v, causal=True, window=self.window,
-                                   sink=sink)
+
+def _positions(cache, pos, t: int):
+    """The positions of a call's ``t`` tokens: 0.. without a cache, from
+    ``pos`` with one ((B,) per-row: the engine's fused decode step)."""
+    if cache is None:
+        return None
+    if jnp.ndim(pos):
+        return jnp.asarray(pos)[:, None] + jnp.arange(t)
+    return pos + jnp.arange(t)
+
+
+def _prompt_attention(attn_impl: str, q, k, v, kind: str, *, window=None,
+                      sink=None):
+    """A prompt's causal attention over its own K/V, by the flash forward
+    kernel where the block runs it."""
+    if resolve_attn_impl(attn_impl) != FLASH:
+        return dense_attention(q, k, v, causal=True, window=window,
+                               sink=sink)
+    from mmlspark_tpu.ops.flash_attention import flash_attention
+
+    # named apart from ``attn``: the trace tells the kinds apart
+    with jax.named_scope(f"attn_{kind}_prefill"):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               sink=sink)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2/V3's MLA, without the
+    query's low rank). ``q = x W_q`` as ``heads`` of ``[nope ; rope]``;
+    ``[c_raw ; k_rope] = x W_kva``, ``c = RMSNorm(c_raw)``, the one
+    ``k_rope`` shared by all heads; ``[k_nope_h ; v_h] = c W_kvb`` a
+    head. Rotary positions on ``q_rope`` and ``k_rope`` alone.
+
+    What is CACHED is the latent row ``[c ; k_rope]`` (after the norm and
+    the rotation): ``kv_lora_rank + rope_dim`` numbers a position for all
+    heads (:class:`mmlspark_tpu.ops.kv_cache.LatentRows`). A PREFILL
+    from position 0 EXPANDS: per-head keys ``[k_nope_h ; k_rope]`` and
+    values through the flash forward kernel, and hands the latent rows
+    to the cache. Every other call ABSORBS: ``W_kvb``'s key half is
+    folded into the query (``q'_h = [q_nope_h W_UK_h^T ; q_rope_h]``),
+    the scores and the weighted sum run over the cached rows themselves
+    (values = the rows' first ``kv_lora_rank`` columns), and the value
+    half is applied after the sum (``o_h = o_lat_h W_UV_h``): the same
+    attention, and no per-head key or value is ever made for a cached
+    position."""
+
+    heads: int
+    nope_dim: int
+    rope_dim: int
+    v_head_dim: int
+    kv_lora_rank: int
+    rope_base: float
+    rope_interleave: bool = False
+    eps: float = 1e-5
+    attn_impl: str = "dense"
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, cache=None, pos=None, decode=False, live=None):
+        from mmlspark_tpu.ops.rope import apply_rope
+
+        b, t, d_model = x.shape
+        h, dn, dr, dv, rank = (self.heads, self.nope_dim, self.rope_dim,
+                               self.v_head_dim, self.kv_lora_rank)
+        x = x.astype(self.dtype)
+
+        def proj(name, width):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype,
+                            param_dtype=self.param_dtype, name=name)
+
+        def product(spec, a, w):
+            return jnp.einsum(spec, a, w,
+                              preferred_element_type=jnp.float32
+                              ).astype(self.dtype)
+
+        q = proj("q", h * (dn + dr))(x).reshape(b, t, h, dn + dr)
+        kv_a = proj("kv_a", rank + dr)(x)
+        c = RMSNorm(self.eps, self.param_dtype, name="kv_norm")(
+            kv_a[..., :rank]).astype(self.dtype)
+        w_kvb = self.param(
+            "kv_b", nn.initializers.normal(0.02), (rank, h * (dn + dv)),
+            self.param_dtype).astype(self.dtype).reshape(rank, h, dn + dv)
+        positions = _positions(cache, pos, t)
+        rotate = partial(apply_rope, positions=positions,
+                         base=self.rope_base,
+                         interleave=self.rope_interleave)
+        q_nope, q_rope = q[..., :dn], rotate(q[..., dn:])
+        k_rope = rotate(kv_a[..., None, rank:])            # (b, t, 1, dr)
+        # the cached row: what a later step's scores and sums read
+        rows = jnp.concatenate((c, k_rope[:, :, 0]), axis=-1)[:, :, None]
+        new_cache = None
+        if cache is None or (isinstance(pos, int) and pos == 0):
+            # expanded, over this call's own rows only
+            kv = product("btc,chn->bthn", c, w_kvb)
+            k = jnp.concatenate(
+                (kv[..., :dn], jnp.broadcast_to(k_rope, (b, t, h, dr))),
+                axis=-1)
+            o = _prompt_attention(
+                self.attn_impl, jnp.concatenate((q_nope, q_rope), axis=-1),
+                k, kv[..., dn:], MLA)
+            if cache is not None:
+                new_cache = kv_cache.write_latent_rows(cache, rows[:, :, 0],
+                                                       pos)
+        else:
+            # absorbed: the engine's fused step over the pool's rows, or
+            # generate()'s steps, a chunk, a resume over linear ones
+            q_lat = product("bthn,chn->bthc", q_nope, w_kvb[..., :dn])
+            o_lat, new_cache = kv_cache.decode_step(
+                cache, jnp.concatenate((q_lat, q_rope), axis=-1), rows,
+                rows[..., :rank], pos, live, name=f"attn_{MLA}_decode",
+                scale=(dn + dr) ** -0.5)
+            o = product("bthc,chv->bthv", o_lat, w_kvb[..., dn:])
+        out = proj("attn_out", d_model)(o.reshape(b, t, h * dv))
+        return out if new_cache is None else (out, new_cache)
 
 
 class _Experts(nn.Module):
@@ -188,6 +301,8 @@ class RoutedFFN(nn.Module):
     held: int
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    shared_d_ff: int = 0    # an always-on, unweighted SwiGLU beside the sum
+    scale: float = 1.0      # on every routing weight, after normalising
 
     @nn.compact
     def __call__(self, x, valid=None):
@@ -202,11 +317,30 @@ class RoutedFFN(nn.Module):
                                         self.param_dtype, name="experts")()
         # the router reads the normed stream in float32, the experts in
         # the compute dtype
-        return moe_ffn_held(
+        out, counters = moe_ffn_held(
             x, router, bias, w_gate.astype(self.dtype),
             w_up.astype(self.dtype), w_down.astype(self.dtype),
             top_k=self.top_k, first=self.first, valid=valid,
+            scale=self.scale,
         )
+        if self.shared_d_ff:
+            # every holder has the shared expert whole, for its own
+            # tokens: it is no part of what expert parallelism divides
+            out = out + _swiglu(x.astype(self.dtype), self.shared_d_ff,
+                                "shared", self.dtype, self.param_dtype)
+        return out, counters
+
+
+def _swiglu(y, d_ff: int, prefix: str, dtype, param_dtype):
+    """``(silu(y W_gate) * (y W_up)) W_out`` without biases, its three
+    matrices named ``<prefix>_gate``, ``_up`` and ``_out`` in the calling
+    module."""
+    def dense(name, width):
+        return nn.Dense(width, use_bias=False, dtype=dtype,
+                        param_dtype=param_dtype, name=f"{prefix}_{name}")
+
+    return dense("out", y.shape[-1])(
+        nn.silu(dense("gate", d_ff)(y)) * dense("up", d_ff)(y))
 
 
 class HybridBlock(nn.Module):
@@ -228,12 +362,23 @@ class HybridBlock(nn.Module):
     attn_impl: str = "dense"
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    rope_interleave: bool = False
+    # > 0: latent attention, ``rotary_dim`` the width of its one rotary
+    # key and ``head_dim - rotary_dim`` that of a head's unrotated part
+    kv_lora_rank: int = 0
+    shared_d_ff: int = 0
+    routed_scale: float = 1.0
 
     def cache_spec(self) -> tuple:
         """``(kind, rows, kv_heads, key width, value width)``: what the
         pool holds for this block. A full block keeps every position
         (``rows`` None: the pool's ``cache_len``), a window block a ring
-        of ``window`` rows."""
+        of ``window`` rows, a latent block every position's ONE row
+        ``[c ; k_rope]``, whose first ``kv_lora_rank`` columns are the
+        values."""
+        if self.kv_lora_rank:
+            return (kv_cache.LATENT_ROWS, None, 1,
+                    self.kv_lora_rank + self.rotary_dim, self.kv_lora_rank)
         kind, rows = ((kv_cache.FULL_ROWS, None) if self.window is None
                       else (kv_cache.RING_ROWS, int(self.window)))
         return (kind, rows, self.kv_heads, self.head_dim, self.v_head_dim)
@@ -251,12 +396,19 @@ class HybridBlock(nn.Module):
                 "keeps it linear, the serving pool keeps the ring"
             )
         y = RMSNorm(self.eps, self.param_dtype, name="ln1")(x)
-        attn = HybridAttention(
-            self.heads, self.kv_heads, self.head_dim, self.v_head_dim,
-            self.window, self.rope_base, self.rotary_dim, self.value_scale,
-            self.sink, self.attn_impl, self.dtype, self.param_dtype,
-            name="attn",
-        )(y, cache=cache, pos=pos, decode=decode, live=live)
+        if self.kv_lora_rank:
+            attend = LatentAttention(
+                self.heads, self.head_dim - self.rotary_dim,
+                self.rotary_dim, self.v_head_dim, self.kv_lora_rank,
+                self.rope_base, self.rope_interleave, self.eps,
+                self.attn_impl, self.dtype, self.param_dtype, name="attn")
+        else:
+            attend = HybridAttention(
+                self.heads, self.kv_heads, self.head_dim, self.v_head_dim,
+                self.window, self.rope_base, self.rotary_dim,
+                self.value_scale, self.sink, self.attn_impl, self.dtype,
+                self.param_dtype, self.rope_interleave, name="attn")
+        attn = attend(y, cache=cache, pos=pos, decode=decode, live=live)
         new_cache = None
         if cache is not None:
             attn, new_cache = attn
@@ -266,18 +418,12 @@ class HybridBlock(nn.Module):
         if self.routed:
             y, counters = RoutedFFN(
                 self.n_experts, self.top_k, self.d_ff, self.held[0],
-                self.held[1], self.dtype, self.param_dtype, name="moe",
+                self.held[1], self.dtype, self.param_dtype,
+                self.shared_d_ff, self.routed_scale, name="moe",
             )(y, valid)
         else:
-            def dense(name, width):
-                return nn.Dense(width, use_bias=False, dtype=self.dtype,
-                                param_dtype=self.param_dtype, name=name)
-
-            y = y.astype(self.dtype)
-            y = dense("mlp_out", x.shape[-1])(
-                nn.silu(dense("mlp_gate", self.d_ff)(y))
-                * dense("mlp_up", self.d_ff)(y)
-            )
+            y = _swiglu(y.astype(self.dtype), self.d_ff, "mlp", self.dtype,
+                        self.param_dtype)
         out = x + y
         if new_cache is None:
             return out
@@ -339,14 +485,27 @@ def hybrid_lm(
     max_len: int = 512,
     attn_impl: str = AUTO,
     param_dtype: Any = "float32",
+    rope_interleave: bool = False,
+    kv_lora_rank: int = 0,
+    qk_nope_head_dim: int = 0,
+    qk_rope_head_dim: int = 0,
+    shared_d_ff: int = 0,
+    routed_scale: float = 1.0,
 ) -> NamedGraph:
     """Causal decoder LM with a per-layer pattern: ``attention[i]`` in
-    (``"full"``, ``"swa"``) and ``ffn[i]`` in (``"dense"``, ``"routed"``)
-    give layer ``i`` its kinds. ``kv_heads``, ``rope_base`` and
-    ``full_sink`` are the full layers', ``swa_kv_heads``,
+    (``"full"``, ``"swa"``, ``"mla"``) and ``ffn[i]`` in (``"dense"``,
+    ``"routed"``) give layer ``i`` its kinds. ``kv_heads``, ``rope_base``
+    and ``full_sink`` are the full layers', ``swa_kv_heads``,
     ``swa_rope_base``, ``swa_sink`` and ``window`` the window layers'.
-    ``held_experts = (first, count)`` says which of the router's
-    ``n_experts`` experts this holder has (default: all)."""
+    The latent layers' are ``kv_lora_rank`` (the compressed row's width),
+    ``qk_nope_head_dim`` and ``qk_rope_head_dim`` (a query head's
+    unrotated and rotated parts, which add up to ``head_dim``) and
+    ``rope_base``; ``rope_interleave`` pairs adjacent dimensions in every
+    layer's rotation. ``held_experts = (first, count)`` says which of
+    the router's ``n_experts`` experts this holder has (default: all);
+    ``shared_d_ff`` > 0 gives every routed layer an always-on shared
+    SwiGLU of that width, ``routed_scale`` multiplies the routing
+    weights."""
     attention, ffn = tuple(attention), tuple(ffn)
     if not attention or len(attention) != len(ffn):
         raise ParamError(
@@ -354,9 +513,20 @@ def hybrid_lm(
             "give every layer its kinds: same length, at least one"
         )
     for kind in attention:
-        if kind not in (FULL, SWA):
+        if kind not in (FULL, SWA, MLA):
             raise ParamError(
-                f"attention kinds are '{FULL}' and '{SWA}', got {kind!r}")
+                f"attention kinds are '{FULL}', '{SWA}' and '{MLA}', got "
+                f"{kind!r}")
+    if MLA in attention and not (
+            kv_lora_rank > 0 and qk_nope_head_dim > 0
+            and qk_rope_head_dim > 0 and qk_rope_head_dim % 2 == 0
+            and qk_nope_head_dim + qk_rope_head_dim == head_dim):
+        raise ParamError(
+            f"'{MLA}' layers need kv_lora_rank ({kv_lora_rank}) > 0 and a "
+            f"query head of qk_nope_head_dim ({qk_nope_head_dim}) + an even "
+            f"qk_rope_head_dim ({qk_rope_head_dim}) = head_dim ({head_dim})")
+    if shared_d_ff < 0:
+        raise ParamError(f"shared_d_ff must be >= 0, got {shared_d_ff}")
     for kind in ffn:
         if kind not in (DENSE_FFN, ROUTED_FFN):
             raise ParamError(
@@ -395,7 +565,7 @@ def hybrid_lm(
         ("embed", TokenEmbed(vocab_size, d_model, dtype))
     ]
     for i, (a_kind, f_kind) in enumerate(zip(attention, ffn)):
-        swa = a_kind == SWA
+        swa, latent = a_kind == SWA, a_kind == MLA
         routed = f_kind == ROUTED_FFN
         blocks.append((f"block{i}", HybridBlock(
             heads=heads, kv_heads=swa_kv_heads if swa else kv_heads,
@@ -403,13 +573,19 @@ def hybrid_lm(
             window=int(window) if swa else None,
             rope_base=float(
                 (swa_rope_base or rope_base) if swa else rope_base),
-            rotary_dim=rotary_dim, value_scale=float(value_scale),
-            sink=bool(swa_sink if swa else full_sink), ffn=f_kind,
+            rotary_dim=int(qk_rope_head_dim) if latent else rotary_dim,
+            value_scale=float(value_scale),
+            sink=bool(swa_sink if swa else full_sink and not latent),
+            ffn=f_kind,
             d_ff=expert_d_ff if routed else d_ff,
             n_experts=n_experts if routed else 0,
             top_k=top_k if routed else 0,
             held=(first, count) if routed else (0, 0),
             eps=norm_eps, attn_impl=attn_impl, param_dtype=dtype,
+            rope_interleave=bool(rope_interleave),
+            kv_lora_rank=int(kv_lora_rank) if latent else 0,
+            shared_d_ff=int(shared_d_ff) if routed else 0,
+            routed_scale=float(routed_scale) if routed else 1.0,
         )))
     blocks.append((FINAL_NODE, HybridHead(vocab_size, norm_eps,
                                           param_dtype=dtype)))

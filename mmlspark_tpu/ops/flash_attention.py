@@ -1095,14 +1095,21 @@ _DECODE_HEADS_SEMANTICS = pltpu.CompilerParams(
 )
 
 
-def _decode_group_kernel(len_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *,
+def _decode_group_kernel(len_ref, q_ref, k_ref, *rest,
                          scale: float, blk: int, kv_heads: int,
-                         kv_axis: int = 1):
+                         kv_axis: int = 1, values_in_keys: int = 0):
     # one KV head a step: q (G, Dk), k (blk, Dk), grid row = batch *
     # kv_heads + kv head. Several a step: q (Hb, G, Dk), k (Hb, blk, Dk),
     # the leading dimension a batch of the two products, grid row = the
-    # slot (``kv_heads`` 1) and the kv-block on grid axis ``kv_axis`` 2
+    # slot (``kv_heads`` 1) and the kv-block on grid axis ``kv_axis`` 2.
+    # A LATENT cache (``values_in_keys`` > 0) has no value operand: the
+    # values are the first ``values_in_keys`` columns of the key rows,
+    # which are fetched once and serve both products
+    if values_in_keys:
+        v_ref = None
+        sink_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        v_ref, sink_ref, o_ref, m_scr, l_scr, acc_scr = rest
     row = pl.program_id(0)
     kb = pl.program_id(kv_axis)
     length = len_ref[row // kv_heads]  # live positions [0, length)
@@ -1117,8 +1124,9 @@ def _decode_group_kernel(len_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
 
     @pl.when(kb * blk < length)
     def _update():
+        k = k_ref[0]
         s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((n + 1,), (n + 1,)), (heads, heads)),
+            q_ref[0], k, (((n + 1,), (n + 1,)), (heads, heads)),
             preferred_element_type=jnp.float32,
         ) * scale  # (..., G, blk) f32
         kpos = kb * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape,
@@ -1132,11 +1140,19 @@ def _decode_group_kernel(len_ref, q_ref, k_ref, v_ref, sink_ref, o_ref,
             l_scr[..., :1] * corr + p.sum(axis=-1, keepdims=True),
             l_scr.shape,
         )
-        # P.V in f32, as _decode_kernel keeps it: the read is bound by
-        # the K/V stream, not by these few rows of products
+        if values_in_keys:
+            # a whole group of query heads on ONE stream of rows is no
+            # longer bound by the stream alone (32 heads x 512 columns:
+            # 60 FLOP a byte), so P.V feeds the MXU the rows' own dtype
+            # with a float32 accumulator, as the prefill kernel does
+            v = k[..., :values_in_keys]
+            p = p.astype(v.dtype)
+        else:
+            # P.V in f32, as _decode_kernel keeps it: the read is bound
+            # by the K/V stream, not by these few rows of products
+            v = v_ref[0].astype(jnp.float32)
         acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32),
-            (((n + 1,), (n,)), (heads, heads)),
+            p, v, (((n + 1,), (n,)), (heads, heads)),
             preferred_element_type=jnp.float32,
         )
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
@@ -1166,11 +1182,12 @@ def _heads_and_rows(hk: int, cache_len: int, block: int, dk: int, dv: int,
                if hk % hb == 0 and (hb * blk <= most or hb == 1)), blk
 
 
-def _row_write_kernel(at_ref, kn_ref, vn_ref, kc_ref, vc_ref, ko_ref,
-                      vo_ref, *, tile: int):
+def _row_write_kernel(at_ref, *refs, tile: int):
+    # refs: the new rows, the caches and the outputs, one of each an array
     row = at_ref[pl.program_id(0)] % tile
-
-    def put(new_ref, old_ref, out_ref):
+    n = len(refs) // 3
+    for new_ref, old_ref, out_ref in zip(refs[:n], refs[n:2 * n],
+                                         refs[2 * n:]):
         # through float32: the select then needs no packed-dtype
         # broadcast along sublanes, and bf16 -> f32 -> bf16 is exact
         rows = jax.lax.broadcasted_iota(jnp.int32, old_ref.shape, 2)
@@ -1179,8 +1196,13 @@ def _row_write_kernel(at_ref, kn_ref, vn_ref, kc_ref, vc_ref, ko_ref,
             old_ref[...].astype(jnp.float32),
         ).astype(out_ref.dtype)
 
-    put(kn_ref, kc_ref, ko_ref)
-    put(vn_ref, vc_ref, vo_ref)
+
+def _interpret(interpret: bool | None) -> bool:
+    if interpret is None:
+        from mmlspark_tpu.core.env import is_tpu
+
+        interpret = not is_tpu()
+    return bool(interpret)
 
 
 def cache_row_write(k, v, k_new, v_new, at, *,
@@ -1196,11 +1218,17 @@ def cache_row_write(k, v, k_new, v_new, at, *,
     This kernel aliases the caches to its outputs and rewrites only the
     sublane tile that holds the row: the layout stays the decode
     kernel's."""
-    if interpret is None:
-        from mmlspark_tpu.core.env import is_tpu
+    return _cache_row_write((k, v), (k_new, v_new), at,
+                            interpret=_interpret(interpret))
 
-        interpret = not is_tpu()
-    return _cache_row_write(k, v, k_new, v_new, at, interpret=bool(interpret))
+
+def latent_row_write(rows, new, at, *, interpret: bool | None = None):
+    """A latent cache ``rows`` (B, L, W) with row ``at[b]`` of batch row
+    ``b`` taken from ``new`` (B, W): :func:`cache_row_write` for the one
+    array of an ``ops.kv_cache.LatentRows`` entry, one KV head."""
+    (out,) = _cache_row_write((rows[:, None],), (new[:, None],), at,
+                              interpret=_interpret(interpret))
+    return out[:, 0]
 
 
 # jitted where they stand: a decode block calls each kernel once a layer
@@ -1208,47 +1236,47 @@ def cache_row_write(k, v, k_new, v_new, at, *,
 # traced and lowered ONCE a signature, not 36 x 6 times (a traced kernel
 # cost the host 0.12 s: 27 s of a warm set-up, my chip run, PR 30)
 @partial(jax.jit, static_argnames=("interpret",))
-def _cache_row_write(k, v, k_new, v_new, at, *, interpret: bool):
-    b, hk, L, dk = k.shape
-    dv = v.shape[3]
+def _cache_row_write(caches: tuple, news: tuple, at, *, interpret: bool):
+    b, hk, L, _ = caches[0].shape
     # whole sublanes of the cache's dtype, or the whole of a shorter cache
-    tile = 32 // k.dtype.itemsize
+    tile = 32 // caches[0].dtype.itemsize
     if L % tile:
         tile = L
     at = jnp.clip(jnp.asarray(at, jnp.int32), 0, L - 1)
 
-    def new_spec(width):
-        return pl.BlockSpec((1, hk, 1, width),
+    def new_spec(cache):
+        return pl.BlockSpec((1, hk, 1, cache.shape[3]),
                             lambda i, at: (i, 0, 0, 0),
                             memory_space=pltpu.VMEM)
 
-    def old_spec(width):
-        return pl.BlockSpec((1, hk, tile, width),
+    def old_spec(cache):
+        return pl.BlockSpec((1, hk, tile, cache.shape[3]),
                             lambda i, at: (i, 0, at[i] // tile, 0),
                             memory_space=pltpu.VMEM)
 
+    n = len(caches)
     return pl.pallas_call(
         partial(_row_write_kernel, tile=tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b,),
-            in_specs=[new_spec(dk), new_spec(dv), old_spec(dk),
-                      old_spec(dv)],
-            out_specs=[old_spec(dk), old_spec(dv)],
+            in_specs=[*map(new_spec, caches), *map(old_spec, caches)],
+            out_specs=[*map(old_spec, caches)],
         ),
-        out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)),
-        # operands count the scalar-prefetched ``at``: 3 and 4 are k, v
-        input_output_aliases={3: 0, 4: 1},
+        out_shape=tuple(jax.ShapeDtypeStruct(c.shape, c.dtype)
+                        for c in caches),
+        # operands count the scalar-prefetched ``at`` and then the new
+        # rows: the caches follow
+        input_output_aliases={1 + n + i: i for i in range(n)},
         interpret=bool(interpret),
         name="cache_row_write",
-    )(at, k_new[:, :, None].astype(k.dtype),
-      v_new[:, :, None].astype(v.dtype), k, v)
+    )(at, *(new[:, :, None].astype(c.dtype)
+            for new, c in zip(news, caches)), *caches)
 
 
 def flash_decode_grouped(q, k, v, lengths, *, sink=None, scale=None,
                          block: int = 512, interpret: bool | None = None,
-                         name: str | None = None):
+                         name: str | None = None, values_in_keys: int = 0):
     """Length-aware decode attention for ONE query token per row over
     HEAD-MAJOR caches, a KV head's whole group of query heads per grid
     step.
@@ -1272,25 +1300,43 @@ def flash_decode_grouped(q, k, v, lengths, *, sink=None, scale=None,
     last live block, so dead blocks are never fetched. A group of fewer
     than 8 query heads takes several KV heads of a slot a grid step
     (:func:`_heads_and_rows`). Inference only, one device only. ``name``
-    names the kernel in a device trace."""
-    if interpret is None:
-        from mmlspark_tpu.core.env import is_tpu
+    names the kernel in a device trace.
 
-        interpret = not is_tpu()
+    A LATENT cache (``ops.kv_cache.LatentRows``) is read with ``v`` None
+    and ``values_in_keys = Dv``: the values are the first ``Dv`` columns
+    of the key rows, so each live row is fetched ONCE and serves the
+    scores and the weighted sum. ``Hkv`` is then 1 (a group of at least
+    8 query heads), ``Dv`` whole lanes, and ``scale`` the caller's (the
+    rows' width is not the width the scores were trained at)."""
     return _flash_decode_grouped(
         q, k, v, jnp.asarray(lengths), sink, scale=scale, block=block,
-        interpret=bool(interpret), name=name)
+        interpret=_interpret(interpret), name=name,
+        values_in_keys=int(values_in_keys))
 
 
-@partial(jax.jit, static_argnames=("scale", "block", "interpret", "name"))
+@partial(jax.jit, static_argnames=("scale", "block", "interpret", "name",
+                                   "values_in_keys"))
 def _flash_decode_grouped(q, k, v, lengths, sink, *, scale, block: int,
-                          interpret: bool, name):
+                          interpret: bool, name, values_in_keys: int = 0):
     if q.ndim != 4 or q.shape[1] != 1:
         raise ValueError(
             "flash_decode_grouped takes a SINGLE query token per row: q "
             f"must be (B, 1, H, Dk), got {q.shape}"
         )
     b, _, h, dk = q.shape
+    if values_in_keys:
+        if (v is not None or scale is None or k.ndim != 4
+                or k.shape[:2] != (b, 1) or k.shape[3] != dk
+                or not 0 < values_in_keys <= dk or values_in_keys % LANES
+                or h < SUBLANES):
+            raise ValueError(
+                "a latent read takes v=None, a scale, one KV head of rows "
+                f"(B, 1, L, Dk={dk}) for at least {SUBLANES} query heads "
+                f"and values of whole lanes inside them, got k {k.shape}, "
+                f"values_in_keys {values_in_keys}, H={h}, scale {scale}"
+            )
+        # the validation below reads the values' shape only
+        v = jax.ShapeDtypeStruct(k.shape[:3] + (values_in_keys,), k.dtype)
     f = k.shape[3] // dk if k.ndim == 4 else 0
     if (k.ndim != 4 or v.ndim != 4 or k.shape[:3] != v.shape[:3]
             or k.shape[0] != b or k.shape[3] != f * dk or v.shape[3] % f
@@ -1390,7 +1436,8 @@ def _flash_decode_grouped(q, k, v, lengths, sink, *, scale, block: int,
           sb.reshape(hk // hb, hb, gp, LANES))
         return out[:, :, :g].reshape(b, 1, h, dv)
     kb = k.reshape(b * hk, L, dk)
-    vb = v.reshape(b * hk, L, dv)
+    # a latent read has no value operand and no block of one
+    vb = () if values_in_keys else (v.reshape(b * hk, L, dv),)
 
     def kv_im(row, j, lens):
         length = lens[row // hk]
@@ -1398,7 +1445,8 @@ def _flash_decode_grouped(q, k, v, lengths, sink, *, scale, block: int,
         return (row, jnp.minimum(j, last), 0)
 
     out = pl.pallas_call(
-        partial(_decode_group_kernel, scale=scale, blk=blk, kv_heads=hk),
+        partial(_decode_group_kernel, scale=scale, blk=blk, kv_heads=hk,
+                values_in_keys=values_in_keys),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b * hk, n_blk),
@@ -1406,7 +1454,8 @@ def _flash_decode_grouped(q, k, v, lengths, sink, *, scale, block: int,
                 pl.BlockSpec((1, gp, dk), lambda row, j, lens: (row, 0, 0),
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, blk, dk), kv_im, memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, blk, dv), kv_im, memory_space=pltpu.VMEM),
+                *(pl.BlockSpec((1, blk, dv), kv_im,
+                               memory_space=pltpu.VMEM) for _ in vb),
                 pl.BlockSpec((1, gp, LANES),
                              lambda row, j, lens: (row % hk, 0, 0),
                              memory_space=pltpu.VMEM),
@@ -1425,7 +1474,7 @@ def _flash_decode_grouped(q, k, v, lengths, sink, *, scale, block: int,
         compiler_params=_DECODE_SEMANTICS,
         interpret=bool(interpret),
         **named,
-    )(lengths, qb, kb, vb, sb)
+    )(lengths, qb, kb, *vb, sb)
     return out[:, :g].reshape(b, 1, h, dv)
 
 

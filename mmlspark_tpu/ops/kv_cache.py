@@ -6,7 +6,8 @@ One home for what the models (``models/``) and the pools (``serve/``)
 both have to know, below both. An ENTRY is what one block's cache is, in
 ``generate()``'s cache dict and in a pool's ``buffers`` alike, a pytree
 of arrays whose layout is said by its TYPE: :class:`Int8Rows`,
-:class:`HeadMajorKV`, :class:`PagedKV`, :class:`PagedInt8KV`. Linear
+:class:`HeadMajorKV`, :class:`LatentRows`, :class:`PagedKV`,
+:class:`PagedInt8KV`. Linear
 bfloat16 rows ``(B, rows, hk, d)`` are the ONE untyped default, a plain
 ``(k, v)`` pair: it is what :func:`mmlspark_tpu.models.generate.
 init_cache`, ``generate()``, every prefill program, the snapshots and
@@ -36,8 +37,10 @@ from mmlspark_tpu.ops.attention import (
 #: and the two a block DECLARES through ``cache_spec()``: ``full`` (a row
 #: for every position) and ``ring`` (the last ``rows`` positions, position
 #: ``p`` in row ``p % rows``), which the serving pool lays out head-major
-#: (models/hybrid.py, serve/cache_pool.py)
+#: (models/hybrid.py, serve/cache_pool.py), and ``latent`` (a row for every
+#: position that is no K/V pair: :class:`LatentRows`)
 LINEAR, FULL_ROWS, RING_ROWS = "linear", "full", "ring"
+LATENT_ROWS = "latent"
 
 #: headroom multiplied onto the prefill amax when fixing a slot's int8
 #: quantization scale: decode steps quantize with the SAME scale
@@ -81,6 +84,28 @@ class HeadMajorKV(NamedTuple):
     v: Any
 
 
+class LatentRows(NamedTuple):
+    """A LATENT entry (multi-head latent attention): ONE array ``rows``
+    ``(B, rows, W)``, a position's row ``[c ; k_rope]`` the compressed
+    KV and the one rotary key that ALL query heads share, so there is no
+    head axis and no value array: the values are the rows' first columns
+    (``c``), read in the same fetch as the keys. ``generate()``, a
+    prefill's cache and the serving pool hold it alike (with one KV head
+    head-major and linear are the same bytes); what differs is the step:
+    per-row positions take the engine's fused step (one row written in
+    place, :func:`~mmlspark_tpu.ops.flash_attention.flash_decode_grouped`
+    with ``values_in_keys``), anything else a plain write and a dense
+    read.
+
+    ``W`` is the rows' width in whole lanes (:func:`latent_width`), the
+    pad columns nought: a v5e holds ``bf16[S, L, 576]`` with the ROWS in
+    its lanes (``{1,2,0}``: sandbox compile, PR 33), so a kernel that
+    streams rows would have the pool copied into its layout around every
+    decode block; ``bf16[S, L, 640]`` is held as the kernel reads it."""
+
+    rows: Any
+
+
 class PagedKV(NamedTuple):
     """The paged pool's entry (serve/paging.py): the page stores and the
     block's own copy of the page table (donation forbids shared leaves)."""
@@ -115,6 +140,12 @@ def lane_pack(hk: int, dk: int, dv: int) -> int:
         return 1
     f = lanes // dk
     return f if hk % f == 0 else 1
+
+
+def latent_width(dk: int) -> int:
+    """The width a :class:`LatentRows` entry stores rows of ``dk``
+    numbers at: whole lanes of 128."""
+    return -(-int(dk) // 128) * 128
 
 
 def validate_kv_dtype(kv_dtype: str, geometry: dict) -> None:
@@ -330,12 +361,71 @@ def _paged_step(entry, q, k, v, pos, live, *, window, sink, name, mesh,
     return o, new
 
 
+#: rows of a latent entry a grid step of the decode read streams. On a v5e
+#: at the kanana-2-30b-a3b cell's shapes (64 slots x 8,192 rows of 640
+#: lanes, 32 query heads, live lengths of 3.5k in the mean: my chip run,
+#: PR 33) a call took 850 us at 256, 616 at 512, 557 at 1,024, 627 at
+#: 2,048, 730 at 4,096 (its bytes alone: 361 us); with every row live 1,530,
+#: 1,079, 894, 893, 895 (92% of the HBM peak). Shorter blocks pay more
+#: grid steps, longer ones read further past a slot's last live row
+_LATENT_BLOCK = 1024
+
+
+def _lanes(x, entry):
+    """``x`` widened with noughts to a latent entry's whole lanes, in its
+    dtype."""
+    pad = entry.rows.shape[-1] - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]).astype(
+        entry.rows.dtype)
+
+
+def write_latent_rows(entry, rows, pos):
+    """The :class:`LatentRows` entry with this call's ``rows`` (B, T, dk)
+    written from the scalar ``pos`` on: a prefill, a chunk or a resume,
+    ``generate()``'s steps."""
+    return LatentRows(jax.lax.dynamic_update_slice(
+        entry.rows, _lanes(rows, entry), (0, pos, 0)))
+
+
+def _latent_step(entry, q, k, v, pos, live, *, window, sink, name, mesh,
+                 rolled, scale=None):
+    from mmlspark_tpu.ops import flash_attention as kernels
+
+    if window is not None or rolled or scale is None:
+        raise ParamError(
+            "latent rows are read over every position, at the scale the "
+            "block gives (their width is not the scores'); got window "
+            f"{window}, rolled {rolled}, scale {scale}")
+    b, t = q.shape[:2]
+    dv = v.shape[-1]
+    q = _lanes(q, entry)
+    if jnp.ndim(pos) and t == 1:
+        # the engine's fused step: the row written in place, each live
+        # row fetched once for its keys and its values
+        rows = kernels.latent_row_write(
+            entry.rows, _lanes(k[:, 0, 0], entry), pos)
+        o = kernels.flash_decode_grouped(
+            q, rows[:, None], None, decode_live_lengths(pos, b, live=live),
+            scale=scale, name=name, values_in_keys=dv, block=_LATENT_BLOCK)
+        return o, LatentRows(rows)
+    if jnp.ndim(pos):
+        raise ParamError("per-row cache positions (the serve engine's "
+                         "fused decode step) are single-token")
+    # generate()'s steps, a chunk or a resume against a live prefix: a
+    # plain write and a dense read, the values a slice of the keys
+    new = write_latent_rows(entry, k[:, :, 0], pos)
+    keys = new.rows[:, :, None]
+    return dense_attention(q, keys, keys[..., :dv], causal=True,
+                           q_offset=pos, scale=scale), new
+
+
 _STEPS = {Int8Rows: _int8_rows_step, HeadMajorKV: _head_major_step,
-          PagedKV: _paged_step, PagedInt8KV: _paged_step}
+          LatentRows: _latent_step, PagedKV: _paged_step,
+          PagedInt8KV: _paged_step}
 
 
 def decode_step(entry, q, k, v, pos, live=None, *, window=None, sink=None,
-                name=None, mesh=None, rolled: bool = False):
+                name=None, mesh=None, rolled: bool = False, scale=None):
     """One decode step over ``entry``, whatever its layout: append this
     step's K/V row for every slot at ``pos`` and attend ``q`` over the
     live rows. ``q`` is (B, 1, H, dk), ``k``/``v`` (B, 1, hk, d); ``pos``
@@ -347,11 +437,23 @@ def decode_step(entry, q, k, v, pos, live=None, *, window=None, sink=None,
     (None: full attention), its learned ``sink`` (head-major entries
     only), the ``name`` its decode kernel has in a trace, its ``mesh``.
     Returns ``(o, new entry)``: ``o`` (B, 1, H, dv), the entry of the
-    same type and leaves."""
+    same type and leaves.
+
+    A :class:`LatentRows` entry takes ``k`` (B, T, 1, dk), this call's
+    rows ``[c ; k_rope]``, and ``v`` their first ``dv`` columns (only its
+    width is read: nothing is stored twice); ``q`` (B, T, H, dk) is the
+    ABSORBED query and ``scale`` the scores' own, which the rows' width
+    does not give. ``T`` > 1 at a scalar ``pos`` is a chunk or a resume;
+    ``o`` is (B, T, H, dv) in the latent space."""
     if sink is not None and not isinstance(entry, HeadMajorKV):
         raise ParamError(
             "only head-major entries are read with a learned sink; got a "
             f"{type(entry).__name__} entry")
+    if scale is not None and not isinstance(entry, LatentRows):
+        raise ParamError(
+            "only latent entries are read at a given scale; got a "
+            f"{type(entry).__name__} entry")
     step = _STEPS.get(type(entry), _linear_step)
     return step(entry, q, k, v, pos, live, window=window, sink=sink,
-                name=name, mesh=mesh, rolled=rolled)
+                name=name, mesh=mesh, rolled=rolled,
+                **({} if scale is None else {"scale": scale}))

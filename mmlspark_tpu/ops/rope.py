@@ -37,7 +37,7 @@ def rope_tables(positions, head_dim: int, base: float = 10000.0):
 
 
 def apply_rope(x, positions=None, *, base: float = 10000.0,
-               rotary_dim: int | None = None):
+               rotary_dim: int | None = None, interleave: bool = False):
     """Rotate ``x`` of shape (B, S, H, D) by position; D must be even.
 
     ``positions`` defaults to 0..S-1; a (B, S) matrix applies per-row
@@ -58,7 +58,8 @@ def apply_rope(x, positions=None, *, base: float = 10000.0,
             raise ValueError(
                 f"rotary_dim must be even and in (0, {d}], got {rotary_dim}"
             )
-        rotated = apply_rope(x[..., :r], positions, base=base)
+        rotated = apply_rope(x[..., :r], positions, base=base,
+                             interleave=interleave)
         return jnp.concatenate((rotated, x[..., r:]), axis=-1)
     if positions is None:
         positions = jnp.arange(s)
@@ -70,6 +71,12 @@ def apply_rope(x, positions=None, *, base: float = 10000.0,
     else:  # (S, D/2): broadcast over (B, H)
         cos = cos[None, :, None, :]
         sin = sin[None, :, None, :]
+    if interleave:
+        pairs = x.astype(jnp.float32).reshape(b, s, h, d // 2, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        return jnp.stack(
+            (x1 * cos - x2 * sin, x1 * sin + x2 * cos), axis=-1
+        ).reshape(b, s, h, d).astype(x.dtype)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate(
         (x1 * cos - x2 * sin, x1 * sin + x2 * cos), axis=-1

@@ -172,15 +172,16 @@ def moe_ffn_dropless(x, gate_w, w_in, b_in, w_out, b_out):
     return out.reshape(b, t, d).astype(x.dtype)
 
 
-def router_topk(x, router_w, select_bias, top_k: int):
+def router_topk(x, router_w, select_bias, top_k: int, scale: float = 1.0):
     """Sigmoid scores, the choice by score plus a selection bias, the
     weights by score alone (the "noaux_tc" router of the DeepSeek-V3
     line, one group). ``x`` is (N, D); ``router_w`` (D, E);
     ``select_bias`` (E,). Returns ``(experts (N, top_k) int32, weights
     (N, top_k) float32)``: the ``top_k`` largest of ``z + bias``, and
-    ``z_e / (sum of the chosen z + 1e-20)``. The bias moves the choice
-    and never a weight. All of it in float32, the product at full
-    precision: a choice is a step, not a rounding."""
+    ``scale * z_e / (sum of the chosen z + 1e-20)`` (``scale`` is the
+    line's ``routed_scaling_factor``, applied after the normalisation).
+    The bias moves the choice and never a weight. All of it in float32,
+    the product at full precision: a choice is a step, not a rounding."""
     z = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
@@ -188,6 +189,8 @@ def router_topk(x, router_w, select_bias, top_k: int):
     _, experts = jax.lax.top_k(z + select_bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(z, experts, axis=-1)
     weights = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        weights = weights * jnp.float32(scale)
     return experts.astype(jnp.int32), weights
 
 
@@ -204,7 +207,7 @@ def held_tiles(tokens: int, held: int, top_k: int) -> tuple[int, int]:
 
 
 def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, *,
-                 top_k: int, first: int, valid=None,
+                 top_k: int, first: int, valid=None, scale: float = 1.0,
                  interpret: bool | None = None):
     """The part of a routed SwiGLU layer that THIS holder's experts give.
 
@@ -216,7 +219,8 @@ def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, *,
     rest: summed over the holders of all ``E`` experts that is the whole
     layer (expert parallelism's contract; on one chip there is no
     exchange to run). ``x`` is (B, T, D); ``valid`` ((B, T) bool) marks
-    the real tokens: a pad or a dead row routes nowhere.
+    the real tokens: a pad or a dead row routes nowhere. ``scale``
+    multiplies every routing weight (:func:`router_topk`).
 
     Dropless at every length and fixed in shape: the held pairs are
     ranked inside their expert, every expert's rows are padded to whole
@@ -233,7 +237,8 @@ def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, *,
     held = w_gate.shape[0]
     n = b * t
     flat = x.reshape(n, d)
-    experts, weights = router_topk(flat, router_w, select_bias, top_k)
+    experts, weights = router_topk(flat, router_w, select_bias, top_k,
+                                   scale)
     local = experts - first
     mine = (local >= 0) & (local < held)
     if valid is not None:

@@ -47,12 +47,15 @@ from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.models.generate import cache_specs
 from mmlspark_tpu.ops.kv_cache import (
     FULL_ROWS,
+    LATENT_ROWS,
     LINEAR,
     RING_ROWS,
     HeadMajorKV,
     Int8Rows,
+    LatentRows,
     kv_head_scales,
     lane_pack,
+    latent_width,
     quantize_kv,
     validate_kv_dtype,
 )
@@ -61,10 +64,11 @@ from mmlspark_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 def _put_rows(pool, values, slot, written):
     """``pool`` with the rows of ``slot`` that ``written`` marks taken
-    from ``values`` (rows, hk, d), cast to the pool's dtype: a read of
-    the slot's first rows, a select and one dynamic-update-slice, which
-    XLA does in place on a donated pool."""
-    at = (slot, 0, 0, 0)
+    from ``values`` (rows, hk, d), or (rows, W) of a latent pool, cast
+    to the pool's dtype: a read of the slot's first rows, a select and
+    one dynamic-update-slice, which XLA does in place on a donated
+    pool."""
+    at = (slot,) + (0,) * values.ndim
     old = jax.lax.dynamic_slice(pool, at, (1, *values.shape))
     values = jnp.where(written, values.astype(pool.dtype), old[0])
     return jax.lax.dynamic_update_slice(pool, values[None], at)
@@ -104,9 +108,16 @@ def _write_slot(buffers, positions, live, prefill_cache, slot, start,
     takes the same rows, transposed (adjacent heads side by side where
     the entry is packed); a ``ring`` of ``R`` rows takes, in row ``j``,
     the latest position below ``length`` that is congruent to ``j``: the
-    prompt's last ``min(P, R)`` rows at ``pos % R``."""
+    prompt's last ``min(P, R)`` rows at ``pos % R``. A
+    :class:`LatentRows` entry takes its one array's rows as they are."""
     new_buffers = {}
     for name, entry in buffers.items():
+        if isinstance(entry, LatentRows):
+            filled = prefill_cache[name].rows[0, :entry.rows.shape[1]]
+            row = jnp.arange(filled.shape[0])[:, None]
+            new_buffers[name] = LatentRows(_put_rows(
+                entry.rows, filled, slot, (row >= start) & (row < length)))
+            continue
         if isinstance(entry, HeadMajorKV):
             placed = []
             for pool, filled in zip(entry, prefill_cache[name]):
@@ -156,11 +167,13 @@ class SlotCachePool:
     leased); the arrays themselves stay on device and are replaced
     functionally each tick.
 
-    On ONE device in bf16 every entry is a ``HeadMajorKV``: a block that
-    DECLARES its geometry (``cache_spec()``, models/hybrid.py) gets what
-    it declared, ``full`` rows or a ``ring`` of its window's rows; a
-    block that declares nothing (``transformer_lm``) gets kind ``full``,
-    ``rows = cache_len``. All live in this one pool, are written by the
+    On ONE device in bf16 every entry is typed: a block that DECLARES
+    its geometry (``cache_spec()``, models/hybrid.py) gets what it
+    declared, a ``HeadMajorKV`` of ``full`` rows or of a ``ring`` of its
+    window's rows, or ``latent`` rows (``LatentRows``: ONE array a
+    block, ``(S, cache_len, W)``); a block that declares nothing
+    (``transformer_lm``) gets a ``HeadMajorKV`` of kind ``full``, ``rows
+    = cache_len``. All live in this one pool, are written by the
     one jitted ``_write_slot`` and read by the one fused decode block.
     Under a mesh an undeclared block keeps LINEAR rows, a plain pair.
 
@@ -194,10 +207,10 @@ class SlotCachePool:
                     if spec[0] != LINEAR}
         if declared and kv_dtype != "bf16":
             raise FriendlyError(
-                f"'{graph.name}' declares its cache geometry (rings, keys "
-                f"and values of different widths); kv_dtype={kv_dtype!r} "
-                "rows are linear rows of one width — serve it with "
-                "kv_dtype='bf16'"
+                f"'{graph.name}' declares its cache geometry (rings, latent "
+                f"rows, keys and values of different widths); kv_dtype="
+                f"{kv_dtype!r} rows are linear K/V rows of one width — "
+                "serve it with kv_dtype='bf16'"
             )
         if declared and mesh is not None and mesh.size > 1:
             msize = int(mesh.shape.get(MODEL_AXIS, 1))
@@ -205,8 +218,8 @@ class SlotCachePool:
                       if spec[2] % msize]
             raise FriendlyError(
                 f"'{graph.name}' declares its cache geometry; its "
-                "head-major full-length rows and rings are pooled on one "
-                "device only — a mesh is not served yet"
+                "head-major full-length rows, rings and latent rows are "
+                "pooled on one device only — a mesh is not served yet"
                 + (f" (and its '{MODEL_AXIS}' axis of {msize} does not "
                    f"divide the KV heads of {uneven[0]})" if uneven else "")
             )
@@ -254,7 +267,12 @@ class SlotCachePool:
             # pair aliasing one allocation cannot be donated twice —
             # same for the int8 mode's two scale leaves
             kind = self.kinds.get(name, LINEAR)
-            if kind == LINEAR:
+            if kind == LATENT_ROWS:
+                # one array a block, no head axis and no value array: the
+                # decode kernel streams (rows, W) tiles of a slot
+                entry = LatentRows(jnp.zeros(
+                    (slots, cache_len, latent_width(d)), store_dtype))
+            elif kind == LINEAR:
                 entry = (jnp.zeros((slots, cache_len, hk, d), store_dtype),
                          jnp.zeros((slots, cache_len, hk, d), store_dtype))
                 if quantized:
@@ -493,6 +511,7 @@ class SlotCachePool:
                 "(use the paged pool for resumable int8 fills)"
             )
         for name in self.buffers:
+            # the first leaf of any entry: (1, rows, ...)
             rows = prefill_cache[name][0].shape[1]
             if rows < length:
                 raise FriendlyError(
@@ -513,9 +532,14 @@ class SlotCachePool:
         """K/V bytes a write of rows ``[start, length)`` puts into the
         pool, by the kind of the entries they land in: a ring takes the
         last rows it has room for."""
-        out = {LINEAR: 0, FULL_ROWS: 0, RING_ROWS: 0}
+        out = {LINEAR: 0, FULL_ROWS: 0, RING_ROWS: 0, LATENT_ROWS: 0}
         for name, entry in self.buffers.items():
             kind = self.kinds.get(name, LINEAR)
+            if kind == LATENT_ROWS:
+                # the stored width, pad lanes and all: what the write moves
+                out[kind] += ((length - start) * entry.rows.shape[2]
+                              * entry.rows.dtype.itemsize)
+                continue
             k, v = entry[:2]
             if kind == LINEAR:
                 rows, per_row = length - start, 2 * math.prod(k.shape[2:])
@@ -526,13 +550,14 @@ class SlotCachePool:
         return out
 
     def bytes_by_kind(self, length: int, start: int = 0) -> dict:
-        """``{"bytes_full", "bytes_ring"}`` of a write of rows ``[start,
-        length)``, for a pool that holds head-major entries; nothing for
-        a pool of linear rows."""
+        """``{"bytes_full", "bytes_ring", "bytes_latent"}`` of a write of
+        rows ``[start, length)``, for a pool that holds typed entries;
+        nothing for a pool of linear rows."""
         if not self.kinds:
             return {}
         by = self._write_bytes(length, start)
-        return {"bytes_full": by[FULL_ROWS], "bytes_ring": by[RING_ROWS]}
+        return {"bytes_full": by[FULL_ROWS], "bytes_ring": by[RING_ROWS],
+                "bytes_latent": by[LATENT_ROWS]}
 
     # -- accounting for telemetry ------------------------------------------
 

@@ -23,6 +23,7 @@ from mmlspark_tpu.ops.flash_attention import (
     flash_attention,
     flash_decode,
     flash_decode_grouped,
+    latent_row_write,
     paged_flash_decode,
 )
 from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
@@ -205,6 +206,35 @@ def _hybrid_forward(hk, window, block, s=4096):
         args + [jax.ShapeDtypeStruct((64,), jnp.float32)])
 
 
+# kanana-2-30b-a3b.report-backlog: 64 slots x 8,192 latent rows of 576
+# numbers held in 640 lanes, 32 query heads on the one stream
+LATENT = dict(slots=64, rows=8192, heads=32, wide=640, values=512)
+
+
+def _latent_decode(slots=LATENT["slots"]):
+    return (lambda q, rows, n: flash_decode_grouped(
+        q, rows, None, n, scale=192 ** -0.5,
+        values_in_keys=LATENT["values"], interpret=False),
+        [_bf16(slots, 1, LATENT["heads"], LATENT["wide"]),
+         _bf16(slots, 1, LATENT["rows"], LATENT["wide"]),
+         jax.ShapeDtypeStruct((slots,), jnp.int32)])
+
+
+def _latent_row_write(slots=LATENT["slots"]):
+    return (lambda rows, new, at: latent_row_write(
+        rows, new, at, interpret=False),
+        [_bf16(slots, LATENT["rows"], LATENT["wide"]),
+         _bf16(slots, LATENT["wide"]),
+         jax.ShapeDtypeStruct((slots,), jnp.int32)])
+
+
+def _latent_forward(s=4096):
+    """The expanded prefill: 32 heads of 192 against 32 of 192 / 128."""
+    return (lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=False),
+        [_bf16(1, s, 32, 192), _bf16(1, s, 32, 192), _bf16(1, s, 32, 128)])
+
+
 def _grouped(rows, tm, n, k):
     tiles = rows // tm
     return (lambda x, w, g, live: grouped_matmul(
@@ -248,6 +278,9 @@ CASES = {
         4, None, None, s=3072),
     "hybrid_fwd_swa_sink_3072_chosen": lambda: _hybrid_forward(
         8, 128, None, s=3072),
+    "latent_decode_8192": _latent_decode,
+    "latent_row_write_8192": _latent_row_write,
+    "latent_fwd_4096_chosen": _latent_forward,
     "grouped_matmul_decode_up": lambda: _grouped(1024, 64, 2048, 4096),
     "grouped_matmul_decode_down": lambda: _grouped(1024, 64, 4096, 2048),
     "grouped_matmul_prefill_up": lambda: _grouped(40960, 512, 2048, 4096),
@@ -419,3 +452,86 @@ def test_pool_refuses_a_page_table_the_kernel_cannot_hold():
     )
     with pytest.raises(FriendlyError, match="scalar memory"):
         PagedCachePool(graph, variables, 64, 32768, page_size=8)
+
+
+# -- kanana-2-30b-a3b.report-backlog: the whole decode block and the widest
+# prefill at the cell's own size, from the configuration's own file
+
+
+def _kanana():
+    import json
+    from pathlib import Path
+
+    from mmlspark_tpu.models import build_model
+
+    cfg = json.loads((Path(__file__).resolve().parent.parent / "benchmark"
+                      / "configs" / "kanana-2-30b-a3b.json").read_text())
+    graph = build_model("hybrid_lm", **cfg["program"]["model"])
+    variables = jax.eval_shape(
+        graph.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    return cfg, graph, variables
+
+
+def test_kanana_decode_block_and_prefill_fit_one_v5e(chip, monkeypatch):
+    """The cell's fused decode block (12 layers, 64 slots x 8,192 latent
+    rows) and its 4,096-row prefill compile for the described v5e inside
+    15.75 GB. The pool is ONE array a block, ``(64, 8192, 640)``
+    bfloat16; the block's optimised HLO holds no ``copy`` or
+    ``transpose`` of a pool-sized operand (the row is written and the
+    rows are read where they lie), the pool is updated in place, the
+    latent kernel stands under its name once a layer, and the
+    temporaries stay under one block's rows."""
+    from mmlspark_tpu.models.generate import (
+        _cached_apply,
+        init_cache,
+        make_decode_block,
+    )
+    from mmlspark_tpu.ops.kv_cache import LatentRows
+    from mmlspark_tpu.serve.cache_pool import SlotCachePool
+
+    monkeypatch.setattr("mmlspark_tpu.core.env.is_tpu", lambda: True)
+    cfg, graph, variables = _kanana()
+    slots, rows = (cfg["program"]["engine"][k] for k in ("slots",
+                                                          "cache_len"))
+    pool = SlotCachePool(graph, variables, 1, rows)
+    buffers = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((slots,) + a.shape[1:], a.dtype),
+        pool.buffers)
+    layers = len(cfg["program"]["model"]["attention"])
+    assert len(buffers) == layers == 12
+    for entry in buffers.values():
+        assert isinstance(entry, LatentRows)
+        assert entry.rows.shape == (slots, rows, 640)
+    one = slots * rows * 640 * 2
+    limit = int(15.75 * 2 ** 30)
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_)
+    block = make_decode_block(graph)
+    compiled = jax.jit(
+        lambda v, b, pos, lv, tok, rem, eos: block(
+            v, b, pos, lv, tok, rem, eos, 4),
+        donate_argnums=(1, 2, 3),
+    ).lower(*_on(chip, [variables, buffers, ints, live, ints, ints, ints])
+            ).compile()
+    text = compiled.as_text()
+    assert not _pool_copies(text, (slots, rows, 640))
+    assert not _pool_copies(text, (slots, 1, rows, 640))
+    assert len(set(re.findall(r"%(attn_mla_decode\.\d+) = ", text))) == layers
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= layers * one
+    assert memory.temp_size_in_bytes < one
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < limit
+
+    def prefill(v, prompt):
+        cache = init_cache(graph, v, 1, prompt.shape[1])
+        return _cached_apply(graph, v, prompt, cache, 0)
+
+    compiled = jax.jit(prefill).lower(*_on(chip, [
+        variables, jax.ShapeDtypeStruct((1, 4096), jnp.int32)])).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    # the prefill runs beside the pool, which it does not hold
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes + layers * one) < limit

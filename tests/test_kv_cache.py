@@ -22,6 +22,7 @@ from mmlspark_tpu.ops.attention import dense_attention
 from mmlspark_tpu.ops.kv_cache import (
     HeadMajorKV,
     Int8Rows,
+    LatentRows,
     PagedInt8KV,
     PagedKV,
     kv_head_scales,
@@ -145,6 +146,51 @@ def test_decode_steps_match_plain_attention_over_linear_rows(case):
         # a dead row reads nothing
         assert not np.asarray(got[~LIVE], np.float32).any()
         pos = jnp.where(live, pos + 1, pos)
+
+
+@pytest.mark.parametrize("per_row", [True, False],
+                         ids=["fused-step", "scalar-position"])
+def test_latent_steps_match_plain_attention_over_linear_rows(per_row):
+    """A latent entry has ONE array and one KV head: a step's row is its
+    key, the row's first 128 columns its value. Rows of 136 numbers lie
+    in 256 lanes; 8 query heads share them, at the block's own scale."""
+    dk, dv, wide, heads, scale = 136, 128, 256, 8, 24 ** -0.5
+    keys = iter(jax.random.split(jax.random.PRNGKey(9), 1 + 2 * STEPS))
+
+    def draw(*shape):
+        return jax.random.normal(next(keys), shape, jnp.bfloat16)
+
+    start = START if per_row else np.full_like(START, 6)
+    live = jnp.asarray(LIVE) if per_row else None
+    written = jnp.arange(ROWS)[None, :, None, None] < start[:, None, None,
+                                                           None]
+    lin = jnp.where(written, draw(SLOTS, ROWS, 1, dk), 0)
+    entry = LatentRows(jnp.pad(lin[:, :, 0], ((0, 0), (0, 0),
+                                              (0, wide - dk))))
+    step = jax.jit(partial(kv_cache.decode_step, name="attn_mla_decode",
+                           scale=scale))
+    pos = jnp.asarray(start, jnp.int32) if per_row else 6
+    slots = jnp.arange(SLOTS)
+    for _ in range(STEPS):
+        q, k = draw(SLOTS, 1, heads, dk), draw(SLOTS, 1, 1, dk)
+        got, new = step(entry, q, k, k[..., :dv], pos, live)
+        assert type(new) is LatentRows
+        assert (new.rows.shape, new.rows.dtype) == (entry.rows.shape,
+                                                    entry.rows.dtype)
+        assert not np.asarray(new.rows[..., dk:], np.float32).any()
+        entry = new
+        lin = lin.at[slots, pos].set(k[:, 0])
+        want = dense_attention(q, lin, lin[..., :dv], causal=True,
+                               q_offset=pos, scale=scale)
+        gap = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))
+        kept = LIVE if per_row else slice(None)
+        assert got.shape == (SLOTS, 1, heads, dv)
+        assert float(gap[kept].max()) <= BF16_BUDGET, float(gap.max())
+        if per_row:
+            assert not np.asarray(got[~LIVE], np.float32).any()
+            pos = jnp.where(live, pos + 1, pos)
+        else:
+            pos += 1
 
 
 @pytest.mark.parametrize("case", [c for c in CASES if c != "linear"])
