@@ -39,7 +39,9 @@ from mmlspark_tpu.models.graph import _accepts_kwarg
 from mmlspark_tpu.ops.kv_cache import (
     LATENT_ROWS,
     LINEAR,
+    STATE_ROWS,
     LatentRows,
+    StateRows,
     latent_width,
 )
 
@@ -93,10 +95,12 @@ def cache_specs(graph, variables) -> dict:
     return specs
 
 
-def declares_cache_kinds(graph) -> bool:
-    """Whether any block of ``graph`` declares its own cache geometry
-    (rings, latent rows, or keys and values of different widths)."""
-    return any(hasattr(mod, "cache_spec") for _, mod in graph.blocks)
+def declares_cache_kinds(graph) -> tuple:
+    """The cache kinds that blocks of ``graph`` declare for themselves
+    (``full``, ``ring``, ``latent``, ``state``), sorted; empty, so false,
+    for a graph whose blocks declare nothing."""
+    return tuple(sorted({mod.cache_spec()[0] for _, mod in graph.blocks
+                         if hasattr(mod, "cache_spec")}))
 
 
 def cache_geometry(graph, variables) -> dict:
@@ -125,13 +129,20 @@ def init_cache(graph, variables, batch: int, total: int) -> dict:
     takes a ``cache`` kwarg (geometry from :func:`cache_specs`): what a
     prefill fills, whatever kind the serving pool then keeps. A block
     that declares ``latent`` rows gets its one array, ``(B, total, W)``
-    (:class:`~mmlspark_tpu.ops.kv_cache.LatentRows`)."""
+    (:class:`~mmlspark_tpu.ops.kv_cache.LatentRows`); one that declares a
+    ``state`` gets its convolution's input at every position, ``(B,
+    total, W)`` (:class:`~mmlspark_tpu.ops.kv_cache.StateRows`), of which
+    the pool keeps the last rows below a prompt's true length."""
     cache = {}
     for name, (kind, _rows, hk, dk, dv) in cache_specs(
             graph, variables).items():
         if kind == LATENT_ROWS:
             cache[name] = LatentRows(jnp.zeros(
                 (batch, total, latent_width(dk)), jnp.bfloat16))
+            continue
+        if kind == STATE_ROWS:
+            cache[name] = StateRows(jnp.zeros((batch, total, dk),
+                                              jnp.bfloat16))
             continue
         cache[name] = (jnp.zeros((batch, total, hk, dk), jnp.bfloat16),
                        jnp.zeros((batch, total, hk, dv), jnp.bfloat16))

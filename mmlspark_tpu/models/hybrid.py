@@ -4,25 +4,30 @@ per-layer pattern.
 ``transformer_lm`` builds every layer alike (LayerNorm, biased GELU MLP,
 one fused ``qkv``, one window for all layers). The open models of 2025
 mix kinds inside one stack, and this builder takes the mix as data: for
-every layer an ATTENTION kind (``full``: causal over the whole context;
-``swa``: a causal sliding window with, optionally, a learned per-head
-sink in the softmax's denominator; ``mla``: multi-head latent attention,
-causal over the whole context, whose keys and values are up-projections
-of ONE compressed row a position, :class:`LatentAttention`) and an FFN
-kind (``dense``: SwiGLU; ``routed``: sigmoid top-k routing over
-``n_experts`` experts of which this holder has a stated range,
+every layer an OPERATOR kind (``full``: attention, causal over the whole
+context; ``swa``: a causal sliding window with, optionally, a learned
+per-head sink in the softmax's denominator; ``mla``: multi-head latent
+attention, causal over the whole context, whose keys and values are
+up-projections of ONE compressed row a position,
+:class:`LatentAttention`; ``conv``: no attention at all, a gated short
+convolution over the last ``conv_kernel`` positions,
+:class:`ShortConv`) and an FFN kind (``dense``: SwiGLU; ``routed``:
+sigmoid top-k routing over ``n_experts`` experts of which this holder has
+a stated range,
 :func:`mmlspark_tpu.parallel.expert.moe_ffn_held`, beside an always-on
 shared SwiGLU where ``shared_d_ff`` gives one). Around them: RMSNorm,
 projections without biases, query and key heads of one width and value
 heads of another, rotary positions on the first ``rotary_dim`` dimensions
 of a head (a latent layer's: on its rotary part alone) at a base per
-attention kind, KV heads per attention kind, an untied head, and
-parameters stored in ``param_dtype``.
+attention kind, KV heads per attention kind, optionally an RMSNorm over
+every query and key head before the rotation (``qk_norm``), an untied
+head, and parameters stored in ``param_dtype``.
 
 Every block DECLARES the geometry of its KV cache (:meth:`HybridBlock.
 cache_spec`): a full block keeps a row for every position, a window block
 a ring of ``window`` rows, a latent block ONE row ``[c ; k_rope]`` for
-every position and all heads. The serving pool
+every position and all heads, a convolution block a STATE of
+``conv_kernel - 1`` rows a slot whatever its length. The serving pool
 (``serve/cache_pool.py``, ``SlotCachePool``) allocates by that declaration,
 head-major, which is the layout the decode kernel
 (:func:`mmlspark_tpu.ops.flash_attention.flash_decode_grouped`) streams
@@ -53,7 +58,7 @@ from mmlspark_tpu.models.transformer import (
 from mmlspark_tpu.ops import kv_cache
 from mmlspark_tpu.ops.attention import dense_attention
 
-FULL, SWA, MLA = "full", "swa", "mla"
+FULL, SWA, MLA, CONV = "full", "swa", "mla", "conv"
 DENSE_FFN, ROUTED_FFN = "dense", "routed"
 
 
@@ -98,6 +103,10 @@ class HybridAttention(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     rope_interleave: bool = False
+    # an RMSNorm over each query and each key head's ``head_dim`` numbers
+    # (one gain for all heads of a kind), before the rotation
+    qk_norm: bool = False
+    eps: float = 1e-5
 
     @nn.compact
     def __call__(self, x, cache=None, pos=None, decode=False, live=None):
@@ -117,6 +126,11 @@ class HybridAttention(nn.Module):
         v = proj("v", hk * dv)(x).reshape(b, t, hk, dv)
         if self.value_scale != 1.0:
             v = v * jnp.asarray(self.value_scale, v.dtype)
+        if self.qk_norm:
+            q = RMSNorm(self.eps, self.param_dtype, name="q_norm")(
+                q).astype(self.dtype)
+            k = RMSNorm(self.eps, self.param_dtype, name="k_norm")(
+                k).astype(self.dtype)
         sink = None
         if self.sink:
             sink = self.param("sink", nn.initializers.zeros, (h,),
@@ -273,6 +287,46 @@ class LatentAttention(nn.Module):
         return out if new_cache is None else (out, new_cache)
 
 
+class ShortConv(nn.Module):
+    """A gated short convolution (the LFM2 line's ``conv`` operator), in
+    an attention's place: ``[B ; C ; u] = x W_in`` (``d -> 3d``), ``g = B
+    * u``, a causal depthwise filter of ``kernel`` taps over ``g``
+    (``conv_t = sum_j w_j g_{t - (kernel - 1) + j}``, ``g`` nought before
+    position 0, the last tap on the current position), the gate ``y = C *
+    conv``, ``y W_out``. No heads, no positions, no softmax, no bias, no
+    activation.
+
+    What a later position needs of the earlier ones is the last ``kernel
+    - 1`` inputs ``g``: the block's cache, whose step is
+    :func:`mmlspark_tpu.ops.kv_cache.state_step` (linear rows for a
+    prefill, a chunk and ``generate()``; the serving pool's constant-size
+    state under the ``conv_decode`` kernel)."""
+
+    kernel: int
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, cache=None, pos=None, decode=False, live=None):
+        b, t, d = x.shape
+        proj = nn.Dense(3 * d, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype, name="in_proj")(
+            x.astype(self.dtype))
+        # (kernel, d): a tap's weights for every channel lie in the lanes
+        taps = self.param("taps", nn.initializers.normal(0.02),
+                          (self.kernel, d), self.param_dtype)
+        entry = cache
+        if cache is None:
+            # no cache to fill: the same sum over rows that start empty
+            entry, pos = kv_cache.StateRows(jnp.zeros((b, t, d),
+                                                      self.dtype)), 0
+        y, new_cache = kv_cache.state_step(entry, proj, taps, pos, live,
+                                           name="conv_decode")
+        out = nn.Dense(d, use_bias=False, dtype=self.dtype,
+                       param_dtype=self.param_dtype, name="out_proj")(y)
+        return out if cache is None else (out, new_cache)
+
+
 class _Experts(nn.Module):
     """The held experts' stacked matrices, under a module named
     ``experts`` as EXPERT_RULES' path expects."""
@@ -368,6 +422,11 @@ class HybridBlock(nn.Module):
     kv_lora_rank: int = 0
     shared_d_ff: int = 0
     routed_scale: float = 1.0
+    qk_norm: bool = False
+    # > 0: no attention but a gated short convolution of that many taps
+    # over a stream ``conv_width`` wide (what the state's rows are)
+    conv_kernel: int = 0
+    conv_width: int = 0
 
     def cache_spec(self) -> tuple:
         """``(kind, rows, kv_heads, key width, value width)``: what the
@@ -375,7 +434,11 @@ class HybridBlock(nn.Module):
         (``rows`` None: the pool's ``cache_len``), a window block a ring
         of ``window`` rows, a latent block every position's ONE row
         ``[c ; k_rope]``, whose first ``kv_lora_rank`` columns are the
-        values."""
+        values, a convolution block a state of ``conv_kernel - 1`` rows
+        the stream's width: no heads, and nothing for values."""
+        if self.conv_kernel:
+            return (kv_cache.STATE_ROWS, self.conv_kernel - 1, 1,
+                    self.conv_width, 0)
         if self.kv_lora_rank:
             return (kv_cache.LATENT_ROWS, None, 1,
                     self.kv_lora_rank + self.rotary_dim, self.kv_lora_rank)
@@ -396,7 +459,10 @@ class HybridBlock(nn.Module):
                 "keeps it linear, the serving pool keeps the ring"
             )
         y = RMSNorm(self.eps, self.param_dtype, name="ln1")(x)
-        if self.kv_lora_rank:
+        if self.conv_kernel:
+            attend = ShortConv(self.conv_kernel, self.dtype,
+                               self.param_dtype, name="conv")
+        elif self.kv_lora_rank:
             attend = LatentAttention(
                 self.heads, self.head_dim - self.rotary_dim,
                 self.rotary_dim, self.v_head_dim, self.kv_lora_rank,
@@ -407,7 +473,8 @@ class HybridBlock(nn.Module):
                 self.heads, self.kv_heads, self.head_dim, self.v_head_dim,
                 self.window, self.rope_base, self.rotary_dim,
                 self.value_scale, self.sink, self.attn_impl, self.dtype,
-                self.param_dtype, self.rope_interleave, name="attn")
+                self.param_dtype, self.rope_interleave, self.qk_norm,
+                self.eps, name="attn")
         attn = attend(y, cache=cache, pos=pos, decode=decode, live=live)
         new_cache = None
         if cache is not None:
@@ -491,10 +558,16 @@ def hybrid_lm(
     qk_rope_head_dim: int = 0,
     shared_d_ff: int = 0,
     routed_scale: float = 1.0,
+    qk_norm: bool = False,
+    conv_kernel: int = 3,
 ) -> NamedGraph:
     """Causal decoder LM with a per-layer pattern: ``attention[i]`` in
-    (``"full"``, ``"swa"``, ``"mla"``) and ``ffn[i]`` in (``"dense"``,
-    ``"routed"``) give layer ``i`` its kinds. ``kv_heads``, ``rope_base``
+    (``"full"``, ``"swa"``, ``"mla"``, ``"conv"``) and ``ffn[i]`` in
+    (``"dense"``, ``"routed"``) give layer ``i`` its kinds. A ``conv``
+    layer has no attention: a gated short convolution of ``conv_kernel``
+    taps (:class:`ShortConv`) stands in its place. ``qk_norm`` puts an
+    RMSNorm over every query and key head of the ``full`` and ``swa``
+    layers, before the rotation. ``kv_heads``, ``rope_base``
     and ``full_sink`` are the full layers', ``swa_kv_heads``,
     ``swa_rope_base``, ``swa_sink`` and ``window`` the window layers'.
     The latent layers' are ``kv_lora_rank`` (the compressed row's width),
@@ -513,10 +586,14 @@ def hybrid_lm(
             "give every layer its kinds: same length, at least one"
         )
     for kind in attention:
-        if kind not in (FULL, SWA, MLA):
+        if kind not in (FULL, SWA, MLA, CONV):
             raise ParamError(
-                f"attention kinds are '{FULL}', '{SWA}' and '{MLA}', got "
-                f"{kind!r}")
+                f"attention kinds are '{FULL}', '{SWA}', '{MLA}' and "
+                f"'{CONV}', got {kind!r}")
+    if CONV in attention and int(conv_kernel) < 2:
+        raise ParamError(
+            f"'{CONV}' layers need conv_kernel >= 2 (the current position "
+            f"and at least one before it), got {conv_kernel}")
     if MLA in attention and not (
             kv_lora_rank > 0 and qk_nope_head_dim > 0
             and qk_rope_head_dim > 0 and qk_rope_head_dim % 2 == 0
@@ -575,7 +652,7 @@ def hybrid_lm(
                 (swa_rope_base or rope_base) if swa else rope_base),
             rotary_dim=int(qk_rope_head_dim) if latent else rotary_dim,
             value_scale=float(value_scale),
-            sink=bool(swa_sink if swa else full_sink and not latent),
+            sink=bool(swa_sink if swa else full_sink and a_kind == FULL),
             ffn=f_kind,
             d_ff=expert_d_ff if routed else d_ff,
             n_experts=n_experts if routed else 0,
@@ -586,6 +663,9 @@ def hybrid_lm(
             kv_lora_rank=int(kv_lora_rank) if latent else 0,
             shared_d_ff=int(shared_d_ff) if routed else 0,
             routed_scale=float(routed_scale) if routed else 1.0,
+            qk_norm=bool(qk_norm) and a_kind in (FULL, SWA),
+            conv_kernel=int(conv_kernel) if a_kind == CONV else 0,
+            conv_width=int(d_model) if a_kind == CONV else 0,
         )))
     blocks.append((FINAL_NODE, HybridHead(vocab_size, norm_eps,
                                           param_dtype=dtype)))

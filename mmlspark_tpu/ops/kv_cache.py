@@ -7,19 +7,24 @@ both have to know, below both. An ENTRY is what one block's cache is, in
 ``generate()``'s cache dict and in a pool's ``buffers`` alike, a pytree
 of arrays whose layout is said by its TYPE: :class:`Int8Rows`,
 :class:`HeadMajorKV`, :class:`LatentRows`, :class:`PagedKV`,
-:class:`PagedInt8KV`. Linear
+:class:`PagedInt8KV`, and the two that are no attention's:
+:class:`StateRows` and :class:`SlotState`, a short convolution's inputs
+(:func:`state_step`). Linear
 bfloat16 rows ``(B, rows, hk, d)`` are the ONE untyped default, a plain
 ``(k, v)`` pair: it is what :func:`mmlspark_tpu.models.generate.
 init_cache`, ``generate()``, every prefill program, the snapshots and
 the fleet's hand-off exchange, so it keeps their format (a pool under a
 mesh serves from it too), and every other layout is told from it by
-type. :func:`decode_step` is the one call of a decode step, whatever the
-entry; :func:`write_rows` the linear entry's write. A new cache kind is a
-type and a step here, plus what a pool allocates.
+type. :func:`decode_step` is the one call of an ATTENTION's decode step,
+whatever the entry; :func:`write_rows` the linear entry's write;
+:func:`state_step` the one call of a short convolution over its state,
+which takes no query and reads no position. A new cache kind is a type
+and a step here, plus what a pool allocates.
 """
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, NamedTuple
 
 import jax
@@ -38,9 +43,12 @@ from mmlspark_tpu.ops.attention import (
 #: for every position) and ``ring`` (the last ``rows`` positions, position
 #: ``p`` in row ``p % rows``), which the serving pool lays out head-major
 #: (models/hybrid.py, serve/cache_pool.py), and ``latent`` (a row for every
-#: position that is no K/V pair: :class:`LatentRows`)
+#: position that is no K/V pair: :class:`LatentRows`) and ``state`` (a
+#: CONSTANT number of rows a slot, whatever its length: the inputs a causal
+#: short convolution still needs, :class:`SlotState`)
 LINEAR, FULL_ROWS, RING_ROWS = "linear", "full", "ring"
 LATENT_ROWS = "latent"
+STATE_ROWS = "state"
 
 #: headroom multiplied onto the prefill amax when fixing a slot's int8
 #: quantization scale: decode steps quantize with the SAME scale
@@ -102,6 +110,28 @@ class LatentRows(NamedTuple):
     its lanes (``{1,2,0}``: sandbox compile, PR 33), so a kernel that
     streams rows would have the pool copied into its layout around every
     decode block; ``bf16[S, L, 640]`` is held as the kernel reads it."""
+
+    rows: Any
+
+
+class StateRows(NamedTuple):
+    """The LINEAR form of a gated short convolution's state: ``rows`` (B,
+    total, W), the convolution's input ``g_t = B_t * u_t`` at EVERY
+    position. What a prefill fills, a chunked fill carries and
+    ``generate()`` decodes on: any later call finds the ``K - 1`` inputs
+    before its first position by a slice, and the pool takes the last
+    ``K - 1`` below a prompt's TRUE length, wherever its bucket ends."""
+
+    rows: Any
+
+
+class SlotState(NamedTuple):
+    """The serving pool's form of the same state: ``rows`` (S, (K - 1) *
+    W), a slot's last ``K - 1`` inputs side by side in ONE row, oldest
+    first (``slots x (K - 1) x W`` in memory; two rows of 2,048 make one of
+    4,096 lanes, which a v5e holds as the kernel reads it). Constant in
+    size whatever the slot's length: no head axis, no position, nothing an
+    attention could read. :func:`state_step` shifts it in place."""
 
     rows: Any
 
@@ -210,6 +240,11 @@ _REFUSALS = {
     ),
 }
 _REFUSALS[PagedInt8KV] = _REFUSALS[PagedKV]
+_REFUSALS[SlotState] = (
+    "a slot's convolution state serves per-row single-token steps only "
+    "(the serve engine's fused decode step); prefill uses the linear "
+    "state rows"
+)
 
 
 def is_linear(entry) -> bool:
@@ -407,6 +442,67 @@ def _latent_step(entry, q, k, v, pos, live, *, window, sink, name, mesh,
     keys = new.rows[:, :, None]
     return dense_attention(q, keys, keys[..., :dv], causal=True,
                            q_offset=pos, scale=scale), new
+
+
+def _causal_taps(g, before, taps):
+    """``c_t = sum_j taps[j] * g_{t - (K - 1) + j}`` over ``g`` (B, T, W)
+    with ``before`` (B, K - 1, W) the inputs ahead of its first position:
+    an explicit sum over shifted copies, in float32."""
+    k, t = taps.shape[0], g.shape[1]
+    padded = jnp.concatenate((before, g), axis=1).astype(jnp.float32)
+    taps = taps.astype(jnp.float32)
+    return sum(taps[j] * padded[:, j:j + t] for j in range(k))
+
+
+def state_step(entry, proj, taps, pos, live=None, *,
+               name: str | None = None):
+    """A gated short convolution over this call's positions and the state
+    ``entry`` holds. ``proj`` (B, T, 3 * W) is the layer's input
+    projection ``[b ; c ; u]``, ``taps`` (K, W) with ``taps[K - 1]`` on
+    the current position: ``g = b * u``, ``conv_t = sum_j taps[j] * g_{t -
+    (K - 1) + j}``, ``y = c * conv``. Returns ``(y (B, T, W) in proj's
+    dtype, new entry)``. ``g`` is rounded to the entry's dtype BEFORE the
+    sum, so a position's output is the same whether its predecessors come
+    from this call or from the state.
+
+    A :class:`StateRows` entry (linear, ``g`` at every position) takes a
+    scalar ``pos``: a prefill, a chunk or a resume against a live prefix,
+    ``generate()``'s steps; the ``K - 1`` rows before ``pos`` are read
+    (nought before position 0) and this call's written from ``pos`` on,
+    under ``jax.named_scope("conv_prefill")`` where ``T`` > 1. A
+    :class:`SlotState` entry takes the engine's fused step: one token a
+    slot, the slot's rows shifted in place by the ``conv_decode`` kernel
+    (``name`` is what a trace shows), dead slots (``live`` False)
+    untouched."""
+    k, w = taps.shape
+    if isinstance(entry, SlotState):
+        from mmlspark_tpu.ops.conv_decode import conv_decode
+
+        if proj.shape[1] != 1 or not jnp.ndim(pos):
+            raise ParamError(_REFUSALS[SlotState])
+        if live is None:
+            live = jnp.ones((proj.shape[0],), bool)
+        y, rows = conv_decode(proj[:, 0], entry.rows, taps, live, name=name)
+        return y[:, None], SlotState(rows)
+    if not isinstance(entry, StateRows) or jnp.ndim(pos):
+        raise ParamError(
+            "a short convolution steps over StateRows at a scalar position "
+            "or over a pool's SlotState at per-row positions; got a "
+            f"{type(entry).__name__} entry, pos of rank {jnp.ndim(pos)}")
+    f32 = jnp.float32
+    b_gate, c_gate, u = (proj[..., j * w:(j + 1) * w].astype(f32)
+                         for j in range(3))
+    g = (b_gate * u).astype(entry.rows.dtype)
+    # the K - 1 inputs before ``pos``, row by row: nought before position 0
+    before = jnp.concatenate([
+        jnp.where(at >= 0, jax.lax.dynamic_slice_in_dim(
+            entry.rows, jnp.maximum(at, 0), 1, axis=1), 0)
+        for at in (pos - (k - 1) + j for j in range(k - 1))], axis=1)
+    new = StateRows(jax.lax.dynamic_update_slice(entry.rows, g, (0, pos, 0)))
+    with (jax.named_scope("conv_prefill") if proj.shape[1] > 1
+          else contextlib.nullcontext()):
+        conv = _causal_taps(g, before, taps)
+    return (c_gate * conv).astype(proj.dtype), new
 
 
 _STEPS = {Int8Rows: _int8_rows_step, HeadMajorKV: _head_major_step,

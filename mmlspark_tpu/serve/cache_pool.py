@@ -50,9 +50,11 @@ from mmlspark_tpu.ops.kv_cache import (
     LATENT_ROWS,
     LINEAR,
     RING_ROWS,
+    STATE_ROWS,
     HeadMajorKV,
     Int8Rows,
     LatentRows,
+    SlotState,
     kv_head_scales,
     lane_pack,
     latent_width,
@@ -94,6 +96,19 @@ def _head_major_rows(kind: str, filled, rows: int, start, length):
     return jnp.moveaxis(taken, 0, 1), written[None, :, None]
 
 
+def _state_rows(filled, rows: int, length):
+    """One slot's row of a :class:`SlotState` entry, ``(1, rows * W)``: the
+    last ``rows`` inputs below ``length`` of a linear state ``filled``
+    (total, W), oldest first. A row that no position falls on (a prompt
+    shorter than the filter's reach) is NOUGHT, whatever the slot held: a
+    re-leased slot otherwise convolves its first tokens with the last
+    occupant's inputs, and no length masks that as it masks an attention's
+    stale rows."""
+    at = length - rows + jnp.arange(rows)
+    taken = jnp.take(filled, jnp.clip(at, 0, filled.shape[0] - 1), axis=0)
+    return jnp.where((at >= 0)[:, None], taken, 0).reshape(1, -1)
+
+
 def _write_slot(buffers, positions, live, prefill_cache, slot, start,
                 length, *, kinds):
     """The whole of :meth:`SlotCachePool.write_prefill` as one program:
@@ -109,9 +124,19 @@ def _write_slot(buffers, positions, live, prefill_cache, slot, start,
     the entry is packed); a ``ring`` of ``R`` rows takes, in row ``j``,
     the latest position below ``length`` that is congruent to ``j``: the
     prompt's last ``min(P, R)`` rows at ``pos % R``. A
-    :class:`LatentRows` entry takes its one array's rows as they are."""
+    :class:`LatentRows` entry takes its one array's rows as they are. A
+    :class:`SlotState` entry takes the last inputs below ``length``, the
+    prompt's TRUE end and not its bucket's, whatever ``start`` is: the
+    whole of a slot's state is rewritten at every admission."""
     new_buffers = {}
     for name, entry in buffers.items():
+        if isinstance(entry, SlotState):
+            filled = prefill_cache[name].rows[0]
+            row = _state_rows(
+                filled, entry.rows.shape[1] // filled.shape[1], length)
+            new_buffers[name] = SlotState(jax.lax.dynamic_update_slice(
+                entry.rows, row.astype(entry.rows.dtype), (slot, 0)))
+            continue
         if isinstance(entry, LatentRows):
             filled = prefill_cache[name].rows[0, :entry.rows.shape[1]]
             row = jnp.arange(filled.shape[0])[:, None]
@@ -170,8 +195,10 @@ class SlotCachePool:
     On ONE device in bf16 every entry is typed: a block that DECLARES
     its geometry (``cache_spec()``, models/hybrid.py) gets what it
     declared, a ``HeadMajorKV`` of ``full`` rows or of a ``ring`` of its
-    window's rows, or ``latent`` rows (``LatentRows``: ONE array a
-    block, ``(S, cache_len, W)``); a block that declares nothing
+    window's rows, ``latent`` rows (``LatentRows``: ONE array a
+    block, ``(S, cache_len, W)``) or a convolution's ``state``
+    (``SlotState``: ``(S, rows * W)``, constant in size whatever
+    ``cache_len`` is); a block that declares nothing
     (``transformer_lm``) gets a ``HeadMajorKV`` of kind ``full``, ``rows
     = cache_len``. All live in this one pool, are written by the
     one jitted ``_write_slot`` and read by the one fused decode block.
@@ -205,20 +232,23 @@ class SlotCachePool:
             )
         declared = {name: spec[0] for name, spec in specs.items()
                     if spec[0] != LINEAR}
+        kinds = ", ".join(f"'{k}'" for k in sorted(set(declared.values())))
         if declared and kv_dtype != "bf16":
             raise FriendlyError(
-                f"'{graph.name}' declares its cache geometry (rings, latent "
-                f"rows, keys and values of different widths); kv_dtype="
-                f"{kv_dtype!r} rows are linear K/V rows of one width — "
-                "serve it with kv_dtype='bf16'"
+                f"'{graph.name}' declares its cache geometry (kinds {kinds}: "
+                f"rings, latent rows, a convolution's state, keys and "
+                f"values of different widths); kv_dtype={kv_dtype!r} rows "
+                "are linear K/V rows of one width — serve it with "
+                "kv_dtype='bf16'"
             )
         if declared and mesh is not None and mesh.size > 1:
             msize = int(mesh.shape.get(MODEL_AXIS, 1))
             uneven = [name for name, spec in specs.items()
                       if spec[2] % msize]
             raise FriendlyError(
-                f"'{graph.name}' declares its cache geometry; its "
-                "head-major full-length rows, rings and latent rows are "
+                f"'{graph.name}' declares its cache geometry (kinds "
+                f"{kinds}); its head-major full-length rows, rings, latent "
+                "rows and convolution state are "
                 "pooled on one device only — a mesh is not served yet"
                 + (f" (and its '{MODEL_AXIS}' axis of {msize} does not "
                    f"divide the KV heads of {uneven[0]})" if uneven else "")
@@ -272,6 +302,11 @@ class SlotCachePool:
                 # decode kernel streams (rows, W) tiles of a slot
                 entry = LatentRows(jnp.zeros(
                     (slots, cache_len, latent_width(d)), store_dtype))
+            elif kind == STATE_ROWS:
+                # a slot's ``rows`` inputs side by side, whatever
+                # cache_len is: what the conv_decode kernel shifts in place
+                entry = SlotState(jnp.zeros((slots, int(rows) * d),
+                                            store_dtype))
             elif kind == LINEAR:
                 entry = (jnp.zeros((slots, cache_len, hk, d), store_dtype),
                          jnp.zeros((slots, cache_len, hk, d), store_dtype))
@@ -532,9 +567,15 @@ class SlotCachePool:
         """K/V bytes a write of rows ``[start, length)`` puts into the
         pool, by the kind of the entries they land in: a ring takes the
         last rows it has room for."""
-        out = {LINEAR: 0, FULL_ROWS: 0, RING_ROWS: 0, LATENT_ROWS: 0}
+        out = {LINEAR: 0, FULL_ROWS: 0, RING_ROWS: 0, LATENT_ROWS: 0,
+               STATE_ROWS: 0}
         for name, entry in self.buffers.items():
             kind = self.kinds.get(name, LINEAR)
+            if kind == STATE_ROWS:
+                # the slot's whole state, a row nought where the prompt is
+                # shorter: the same bytes at every admission
+                out[kind] += entry.rows.shape[1] * entry.rows.dtype.itemsize
+                continue
             if kind == LATENT_ROWS:
                 # the stored width, pad lanes and all: what the write moves
                 out[kind] += ((length - start) * entry.rows.shape[2]
@@ -550,14 +591,15 @@ class SlotCachePool:
         return out
 
     def bytes_by_kind(self, length: int, start: int = 0) -> dict:
-        """``{"bytes_full", "bytes_ring", "bytes_latent"}`` of a write of
-        rows ``[start, length)``, for a pool that holds typed entries;
-        nothing for a pool of linear rows."""
+        """``{"bytes_full", "bytes_ring", "bytes_latent", "bytes_state"}``
+        of a write of rows ``[start, length)``, for a pool that holds typed
+        entries; nothing for a pool of linear rows."""
         if not self.kinds:
             return {}
         by = self._write_bytes(length, start)
         return {"bytes_full": by[FULL_ROWS], "bytes_ring": by[RING_ROWS],
-                "bytes_latent": by[LATENT_ROWS]}
+                "bytes_latent": by[LATENT_ROWS],
+                "bytes_state": by[STATE_ROWS]}
 
     # -- accounting for telemetry ------------------------------------------
 

@@ -351,10 +351,11 @@ class ServeEngine:
             )
         if role != "both" and declares_cache_kinds(graph):
             raise FriendlyError(
-                f"'{graph.name}' declares its cache geometry (rings, keys "
-                "and values of different widths); the fleet's KV hand-off "
-                "ships linear rows of one width — serve it with "
-                "role='both'"
+                f"'{graph.name}' declares its cache geometry (kinds "
+                f"{', '.join(map(repr, declares_cache_kinds(graph)))}: "
+                "rings, latent rows, a convolution's state, keys and values "
+                "of different widths); the fleet's KV hand-off ships linear "
+                "rows of one width — serve it with role='both'"
             )
         self.role = role
         #: KV hand-off payloads awaiting collection by the fleet

@@ -546,3 +546,115 @@ def test_kanana_decode_block_and_prefill_fit_one_v5e(chip, monkeypatch):
     # the prefill runs beside the pool, which it does not hold
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             + memory.output_size_in_bytes + layers * one) < limit
+
+
+# -- lfm2-8b-a1b.synth-backlog: the whole decode block, the widest prefill
+# and the pool's write at the cell's own size, from the configuration's file
+
+
+def _lfm2():
+    import json
+    from pathlib import Path
+
+    from mmlspark_tpu.models import build_model
+
+    cfg = json.loads((Path(__file__).resolve().parent.parent / "benchmark"
+                      / "configs" / "lfm2-8b-a1b.json").read_text())
+    graph = build_model("hybrid_lm", **cfg["program"]["model"])
+    variables = jax.eval_shape(
+        graph.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    return cfg, graph, variables
+
+
+def test_lfm2_decode_block_prefill_and_pool_write_fit_one_v5e(
+        chip, monkeypatch, capsys):
+    """The cell's fused decode block (12 layers: 9 convolution states of
+    ``(128, 4096)`` and 3 K/V pairs of ``(128, 4, 4096, 128)``, two heads
+    of 64 a row), its 1,024-row prefill and the pool's write compile for
+    the described v5e inside 15.75 GB. The block's optimised HLO holds no
+    ``copy`` or ``transpose`` of a pool-sized operand, every pool entry is
+    updated in place (the state by ``conv_decode``'s own alias), each
+    kernel stands under its name once a layer, and the temporaries stay
+    under one layer's K/V."""
+    from mmlspark_tpu.models.generate import (
+        _cached_apply,
+        init_cache,
+        make_decode_block,
+    )
+    from mmlspark_tpu.ops.kv_cache import HeadMajorKV, SlotState
+    from mmlspark_tpu.serve.cache_pool import SlotCachePool
+
+    monkeypatch.setattr("mmlspark_tpu.core.env.is_tpu", lambda: True)
+    cfg, graph, variables = _lfm2()
+    slots, rows = (cfg["program"]["engine"][k] for k in ("slots",
+                                                          "cache_len"))
+    pool = SlotCachePool(graph, variables, 1, rows)
+    buffers = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((slots,) + a.shape[1:], a.dtype),
+        pool.buffers)
+    kinds = cfg["program"]["model"]["attention"]
+    assert len(buffers) == len(kinds) == 12
+    for i, kind in enumerate(kinds):
+        entry = buffers[f"block{i}"]
+        if kind == "conv":
+            assert isinstance(entry, SlotState)
+            assert entry.rows.shape == (slots, 2 * 2048)
+        else:
+            assert isinstance(entry, HeadMajorKV)
+            assert entry.k.shape == entry.v.shape == (slots, 4, rows, 128)
+    one = slots * 4 * rows * 128 * 2
+    state = slots * 4096 * 2
+    pooled = 6 * one + 9 * state
+    limit = int(15.75 * 2 ** 30)
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_)
+    block = make_decode_block(graph)
+    compiled = jax.jit(
+        lambda v, b, pos, lv, tok, rem, eos: block(
+            v, b, pos, lv, tok, rem, eos, 4),
+        donate_argnums=(1, 2, 3),
+    ).lower(*_on(chip, [variables, buffers, ints, live, ints, ints, ints])
+            ).compile()
+    text = compiled.as_text()
+    assert not _pool_copies(text, (slots, 4, rows, 128))
+    assert len(set(re.findall(r"%(conv_decode\.\d+) = ", text))) == 9
+    assert len(set(re.findall(r"%(attn_full_decode\.\d+) = ", text))) == 3
+    memory = compiled.memory_analysis()
+    print("lfm2 decode block: arguments", memory.argument_size_in_bytes,
+          "aliased", memory.alias_size_in_bytes, "temporaries",
+          memory.temp_size_in_bytes)
+    assert memory.alias_size_in_bytes >= pooled
+    assert memory.temp_size_in_bytes < 2 * one
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < limit
+
+    def prefill(v, prompt):
+        cache = init_cache(graph, v, 1, prompt.shape[1])
+        return _cached_apply(graph, v, prompt, cache, 0)
+
+    compiled = jax.jit(prefill).lower(*_on(chip, [
+        variables, jax.ShapeDtypeStruct((1, 1024), jnp.int32)])).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert "conv_prefill" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    print("lfm2 prefill 1024: arguments", memory.argument_size_in_bytes,
+          "temporaries", memory.temp_size_in_bytes, "outputs",
+          memory.output_size_in_bytes)
+    # the prefill runs beside the pool, which it does not hold
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes + pooled) < limit
+
+    # the pool's one write: every entry aliased, no second pool
+    cache = jax.eval_shape(lambda: init_cache(graph, variables, 1, 1024))
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    compiled = pool._write.lower(*_on(chip, (
+        buffers, ints, live, cache, scalar, scalar, scalar))).compile()
+    memory = compiled.memory_analysis()
+    print("lfm2 pool write: aliased", memory.alias_size_in_bytes,
+          "temporaries", memory.temp_size_in_bytes)
+    assert memory.alias_size_in_bytes >= pooled
+    assert memory.temp_size_in_bytes < one
+    assert not _pool_copies(compiled.as_text(), (slots, 4, rows, 128))
+    with capsys.disabled():
+        print(capsys.readouterr().out, end="")

@@ -79,3 +79,17 @@ def test_routing_rows_name_the_swapped_expert_and_its_margin():
         pytest.approx(0.03)
     same = latent_witness.routing_rows(sz, [ref], {"block0": ref})[0]
     assert same["same_choice"] and same["swapped"] == []
+
+
+def test_the_state_checks_pure_parts_hold_at_a_tiny_size():
+    """``tools/state_chip_check.py``: the kernel against its oracle, in
+    the interpreter at 8 slots of 128 channels (the chip's run compiles it
+    at 128 x 2,048), and the cell's live lengths as the tool draws them:
+    inside the cache, a mean between the prompts' and the answers'."""
+    from tools import state_chip_check
+
+    assert state_chip_check.conv_check(8, 128, interpret=True) == 0.0
+    lengths = state_chip_check.cell_lengths(128)
+    assert lengths.shape == (128,) and lengths.min() >= 256
+    assert lengths.max() <= state_chip_check.ROWS - 64
+    assert 1000 < lengths.mean() < 2000

@@ -224,7 +224,8 @@ def test_one_donated_write_puts_a_prompts_rows_into_its_slot(tiny):
     dispatches, nbytes = pool.write_prefill(1, cache, 11)
     assert dispatches == 1 and nbytes == 2 * 11 * 256 * 2
     assert pool.bytes_by_kind(11) == {
-        "bytes_full": 0, "bytes_ring": 0, "bytes_latent": nbytes}
+        "bytes_full": 0, "bytes_ring": 0, "bytes_latent": nbytes,
+        "bytes_state": 0}
     got = np.asarray(pool.buffers["block1"].rows[:, :, 0], np.float32)
     np.testing.assert_array_equal(got[1, :11], np.arange(1, 12))
     assert not got[1, 11:].any() and not got[0].any()
