@@ -195,13 +195,15 @@ def counts_routing(graph) -> bool:
 
 
 def routing_totals(counters: dict) -> dict:
-    """Per-block routing counters ``{block: {"pairs", "hit"}}`` as two
-    vectors over the routed blocks, in the graph's order:
-    ``expert_pairs`` and ``experts_hit``."""
+    """Per-block routing counters ``{block: {"pairs", "hit", "rows"}}``
+    as three vectors over the routed blocks, in the graph's order:
+    ``expert_pairs``, ``experts_hit`` and ``expert_rows``."""
     names = sorted(counters, key=lambda n: (len(n), n))
     return {
-        "expert_pairs": jnp.stack([counters[n]["pairs"] for n in names]),
-        "experts_hit": jnp.stack([counters[n]["hit"] for n in names]),
+        total: jnp.stack([counters[n][counter] for n in names])
+        for total, counter in (("expert_pairs", "pairs"),
+                               ("experts_hit", "hit"),
+                               ("expert_rows", "rows"))
     }
 
 
@@ -242,9 +244,10 @@ def make_decode_block(graph, pad_id: int = 0):
     final ``live`` is the per-slot finished vector (False = the row
     died inside this block). For a graph that routes tokens to experts
     (:func:`counts_routing`) a fifth value follows: ``{"expert_pairs",
-    "experts_hit"}``, per routed block the (token, expert) pairs that
-    fell on held experts and the held experts hit, summed over the
-    block's micro-steps; it comes back in the same fetch as the tokens.
+    "experts_hit", "expert_rows"}``, per routed block the (token,
+    expert) pairs that fell on held experts, the held experts hit and
+    the rows their products multiplied, summed over the block's
+    micro-steps; it comes back in the same fetch as the tokens.
     Parity contract: a row's token stream is bit-identical to
     single-request greedy ``generate()`` up to and including its EOS /
     last budgeted token; columns after that are pads the host discards.

@@ -194,13 +194,59 @@ def router_topk(x, router_w, select_bias, top_k: int, scale: float = 1.0):
     return experts.astype(jnp.int32), weights
 
 
-def held_tiles(tokens: int, held: int, top_k: int) -> tuple[int, int]:
+#: an expert's row tile is the smallest power of two, 16 (a bfloat16
+#: sublane tile) to 512, that holds this many times the MEAN of what an
+#: expert receives, ``tokens * top_k / E``. On a v5e, us for a layer's
+#: three products under an even routing (``tools/hybrid_chip_check.py
+#: time``, my chip run, PR 36; the mean rows an expert, then the tile in
+#: brackets):
+#:
+#:     step                          mean   half     CHOSEN      twice   4 times
+#:     lfm2-8b-a1b decode, 128         16   1,344     966 (32)     986    1,027
+#:     lfm2-8b-a1b prefill, 512        64   1,415   1,027 (128)  1,159    2,089
+#:     kanana-2-30b-a3b decode, 64      3       -     196 (16)     200      210
+#:     kanana-2-30b-a3b prefill, 2,048 96     198     291 (256)    464        -
+#:     mimo-v2-flash decode, 64         2       -     818 (16)     826      841
+#:     mimo-v2-flash prefill, 2,048    64   1,703   1,194 (128)  1,385    2,387
+#:
+#: A tile AT the mean sends half the experts to a second tile, whose
+#: weights are read again; a wider one multiplies pad rows, which costs
+#: little at decode (the weights' bytes bound it) and much at a prefill.
+#: (kanana's prefill at half: 2.7 times the mean rounds to 1.3 there,
+#: and an even draw of 2,048 tokens still fits it: not a rule to lean on.)
+_TILE_OVER_MEAN = 2
+
+
+def held_tiles(tokens: int, held: int, top_k: int,
+               experts: int) -> tuple[int, int]:
     """The row tile of :func:`moe_ffn_held`'s grouped products for
-    ``tokens`` tokens, and the most tiles the held experts' pairs can
-    fill once every expert's rows are padded to whole tiles: a token
-    chooses an expert at most once, so an expert has at most ``tokens``
-    rows, and all of them together at most ``tokens * min(top_k, held)``."""
-    tm = min(512, -(-tokens // 16) * 16)
+    ``tokens`` tokens that each choose ``top_k`` of ``experts`` experts,
+    and the most tiles the ``held`` experts' pairs can fill once every
+    expert's rows are padded to whole tiles.
+
+    The tile follows what ONE expert is expected to receive, not the
+    step's tokens: a product's time is its weights read once a tile and
+    the tile's rows multiplied, pad rows too, so a tile far over an
+    expert's share multiplies (and gathers, and gates) mostly padding.
+    ``_TILE_OVER_MEAN`` times the mean leaves an evenly routed expert in
+    one tile. SKEW costs weights: an expert with more rows than a tile
+    takes a second tile and its weight blocks are read again (where the
+    block is the whole matrix they are found in place), so a hot expert
+    at ``r`` times the tile costs ``ceil(r)`` reads of its matrices, as
+    much as that many experts hit: with every token's first choice ONE
+    expert, +9% at ``lfm2-8b-a1b``'s decode step (35 tiles for 32) and
+    +93% at ``mimo-v2-flash``'s prefill of 2,048 (31 for 16; my chip
+    run, PR 36). It costs no correctness: the layer is dropless at
+    every routing.
+
+    ``most`` is by two bounds: a token chooses an expert at most once,
+    so an expert has at most ``tokens`` rows, and all of them together
+    at most ``tokens * min(top_k, held)``, which leaves at most one
+    partly filled tile an expert."""
+    mean = tokens * top_k / experts
+    tm = 16
+    while tm < 512 and tm < _TILE_OVER_MEAN * mean:
+        tm *= 2
     most = min(held * -(-tokens // tm),
                held + tokens * min(top_k, held) // tm)
     return tm, most
@@ -229,8 +275,10 @@ def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, *,
     the tiles that are live. No (tokens, D, F) weight copy is made, and
     an expert that no token chose is not read.
 
-    Returns ``(out (B, T, D) in x's dtype, {"pairs", "hit"})``: the
-    pairs that fell on held experts and the held experts hit."""
+    Returns ``(out (B, T, D) in x's dtype, {"pairs", "hit", "rows"})``:
+    the pairs that fell on held experts, the held experts hit, and the
+    rows the products multiplied (live tiles x the row tile of
+    :func:`held_tiles`): pairs over rows is how full the tiles were."""
     from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
 
     b, t, d = x.shape
@@ -247,7 +295,7 @@ def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, *,
     group = jnp.where(mine, local, held).reshape(pairs)
     member = group[:, None] == jnp.arange(held)[None, :]       # (P, held)
     sizes = member.sum(axis=0).astype(jnp.int32)
-    tm, most = held_tiles(n, held, top_k)
+    tm, most = held_tiles(n, held, top_k, router_w.shape[1])
     padded = -(-sizes // tm) * tm
     pad_end = jnp.cumsum(padded)
     # a pair's row: its expert's first row plus its rank inside the expert
@@ -277,12 +325,13 @@ def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, *,
     y = gmm(mid, w_down, name="moe_down")
     # back to the tokens: each pair reads its own row; what fell
     # elsewhere reads nothing (a where, not a product with nought: a
-    # dead tile's rows are zeros, but nothing here leans on it)
+    # dead tile's rows were never written and may hold anything)
     mine_w = jnp.where(mine, weights, 0.0)
     picked = jnp.where(mine_flat[:, None], y[row].astype(jnp.float32), 0.0)
     out = (picked.reshape(n, top_k, d) * mine_w[:, :, None]).sum(axis=1)
     counters = {"pairs": mine.sum().astype(jnp.int32),
-                "hit": (sizes > 0).sum().astype(jnp.int32)}
+                "hit": (sizes > 0).sum().astype(jnp.int32),
+                "rows": (live_tiles * tm).astype(jnp.int32)}
     return out.reshape(b, t, d).astype(x.dtype), counters
 
 

@@ -110,13 +110,12 @@ def _routing_drops(graph) -> bool:
 def _routing_attrs(stats, steps: int) -> dict:
     """The routing counters a decode block or a prefill fetched beside
     its tokens, as event attributes: per routed layer and per micro-step
-    the pairs that fell on held experts and the held experts hit."""
+    the pairs that fell on held experts, the held experts hit and the
+    rows the expert products multiplied, pad rows of their tiles too."""
     if not stats:
         return {}
-    return {
-        name: round(float(np.mean(stats[name])) / max(steps, 1), 3)
-        for name in ("expert_pairs", "experts_hit")
-    }
+    return {name: round(float(np.mean(total)) / max(steps, 1), 3)
+            for name, total in stats.items()}
 
 
 def _resolve_mesh(mesh):

@@ -27,6 +27,7 @@ from mmlspark_tpu.ops.flash_attention import (
     paged_flash_decode,
 )
 from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
+from mmlspark_tpu.parallel.expert import held_tiles
 from mmlspark_tpu.testing.compile_guard import kernel_grids
 
 HEADS, HEAD_DIM, CACHE = 12, 64, 1024
@@ -236,11 +237,14 @@ def _latent_forward(s=4096):
         [_bf16(1, s, 32, 192), _bf16(1, s, 32, 192), _bf16(1, s, 32, 128)])
 
 
-def _grouped(rows, tm, n, k):
-    tiles = rows // tm
+def _grouped(tokens, held, top_k, experts, k, n):
+    """One grouped product of an expert layer at the row tile
+    ``held_tiles`` chooses for a step of ``tokens`` tokens and the weight
+    block ``grouped_matmul`` chooses for that tile."""
+    tm, tiles = held_tiles(tokens, held, top_k, experts)
     return (lambda x, w, g, live: grouped_matmul(
         x, w, g, live, tm=tm, interpret=False),
-        [_bf16(rows, k), _bf16(16, k, n),
+        [_bf16(tiles * tm, k), _bf16(held, k, n),
          jax.ShapeDtypeStruct((tiles,), jnp.int32),
          jax.ShapeDtypeStruct((), jnp.int32)])
 
@@ -282,9 +286,30 @@ CASES = {
     "latent_decode_8192": _latent_decode,
     "latent_row_write_8192": _latent_row_write,
     "latent_fwd_4096_chosen": _latent_forward,
-    "grouped_matmul_decode_up": lambda: _grouped(1024, 64, 2048, 4096),
-    "grouped_matmul_decode_down": lambda: _grouped(1024, 64, 4096, 2048),
-    "grouped_matmul_prefill_up": lambda: _grouped(40960, 512, 2048, 4096),
+    # reason-backlog: 64 slots and a bucket of 2,048, 8 of 256, 16 held
+    "grouped_matmul_decode_up": lambda: _grouped(
+        64, 16, 8, 256, 4096, 2048),
+    "grouped_matmul_decode_down": lambda: _grouped(
+        64, 16, 8, 256, 2048, 4096),
+    "grouped_matmul_prefill_up": lambda: _grouped(
+        2048, 16, 8, 256, 4096, 2048),
+    # synth-backlog: 128 slots and a bucket of 512, 4 of 32, all held
+    "grouped_matmul_synth_decode_up": lambda: _grouped(
+        128, 32, 4, 32, 2048, 1792),
+    "grouped_matmul_synth_decode_down": lambda: _grouped(
+        128, 32, 4, 32, 1792, 2048),
+    "grouped_matmul_synth_prefill_up": lambda: _grouped(
+        512, 32, 4, 32, 2048, 1792),
+    # report-backlog: 64 slots and a chunk of 2,048, 6 of 128, 16 held
+    "grouped_matmul_report_decode_up": lambda: _grouped(
+        64, 16, 6, 128, 2048, 768),
+    "grouped_matmul_report_decode_down": lambda: _grouped(
+        64, 16, 6, 128, 768, 2048),
+    "grouped_matmul_report_prefill_down": lambda: _grouped(
+        2048, 16, 6, 128, 768, 2048),
+    # the widest row tile beside the widest contraction
+    "grouped_matmul_tile_512_up": lambda: _grouped(
+        8192, 16, 8, 256, 4096, 2048),
     "flash_fwd_full": lambda: _attention(),
     "flash_fwd_causal": lambda: _attention(causal=True),
     "flash_fwd_windowed": lambda: _attention(causal=True, window=256),
@@ -362,6 +387,25 @@ def test_decode_grid_is_a_work_list_where_blocks_can_be_dead(case, static,
     assert len(grid) == 1 and not isinstance(grid[0], int)
     text = jax.jit(fn).lower(*_on(chip, args)).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("case,blocks", [
+    # (N blocks, contraction blocks) of the weight block chosen by bytes
+    ("grouped_matmul_synth_decode_up", (2, 1)),      # 2,048 x 896: 3.5 MiB
+    ("grouped_matmul_synth_decode_down", (2, 1)),    # 1,792 x 1,024
+    ("grouped_matmul_report_decode_up", (1, 1)),     # an expert whole: 3 MiB
+    ("grouped_matmul_report_decode_down", (1, 1)),
+    ("grouped_matmul_decode_up", (4, 1)),            # 4,096 x 512: 4 MiB
+    ("grouped_matmul_decode_down", (4, 1)),          # 2,048 x 1,024
+])
+def test_grouped_grid_is_bounded_by_the_live_tiles(case, blocks):
+    """The expert products at the three routed cells' decode shapes: the
+    row-tile axis of the grid is TRACED (the live tiles: a dead tile
+    takes no step), the contraction is one block, and the matrix is cut
+    along N into blocks of 3 to 4 MiB."""
+    fn, args = CASES[case]()
+    (grid,) = kernel_grids(fn, *args)
+    assert not isinstance(grid[0], int) and tuple(grid[1:]) == blocks
 
 
 def _on(chip, tree):
@@ -528,6 +572,14 @@ def test_kanana_decode_block_and_prefill_fit_one_v5e(chip, monkeypatch):
     assert not _pool_copies(text, (slots, rows, 640))
     assert not _pool_copies(text, (slots, 1, rows, 640))
     assert len(set(re.findall(r"%(attn_mla_decode\.\d+) = ", text))) == layers
+    # every expert product reads its matrices where they lie, in HBM: XLA
+    # stages none of them in VMEM ahead of the call (a 48 MiB operand it
+    # staged before the kernel stated its scope: ``_compiler_params``)
+    products = re.findall(r"%moe_(?:gate|up|down)\.\d+ = \S+ custom-call\("
+                          r"([^)]*)\)", text)
+    assert len(products) == 3 * (layers - 1)
+    assert all(args.split(", ")[-1].startswith("%get-tuple-element")
+               for args in products)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= layers * one
     assert memory.temp_size_in_bytes < one

@@ -48,6 +48,26 @@ def test_a_long_ticks_time_is_laid_under_the_regions_inside_it():
     assert row["tick_ms_longest_other"] == 100.0
 
 
+def test_the_windows_tile_fill_is_pairs_over_rows():
+    """Pairs over rows of the window's ``dispatch`` events that carry the
+    counters: a block outside the window and one that counts no routing
+    stay out; a run with none gives no line."""
+    def block(t, **attrs):
+        return {"name": "dispatch", "t": t, "attrs": attrs}
+
+    events = [block(10.0, expert_pairs=512.0, expert_rows=1024.0),
+              block(11.0, expert_pairs=500.0, expert_rows=1056.0),
+              block(11.5, family="decode[T=4]"),
+              block(20.0, expert_pairs=1.0, expert_rows=512.0)]
+    state = {"events": events, "t_open": 9.5, "t_close": 13.0}
+    (row,) = long_ticks.tile_fill(state)
+    assert row["dispatches"] == 2
+    assert row["expert_pairs_mean"] == 506.0
+    assert row["expert_rows_mean"] == 1040.0
+    assert row["expert_tile_fill_pct"] == pytest.approx(100 * 506 / 1040)
+    assert long_ticks.tile_fill(dict(state, events=events[2:3])) == []
+
+
 @pytest.mark.parametrize("first,takes", [
     (3, "the served token's side"), (5, "the reference's side"),
     (1, "neither side")])

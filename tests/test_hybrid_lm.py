@@ -24,7 +24,11 @@ from mmlspark_tpu.ops.flash_attention import (
     flash_decode_grouped,
 )
 from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
-from mmlspark_tpu.parallel.expert import moe_ffn_held, router_topk
+from mmlspark_tpu.parallel.expert import (
+    held_tiles,
+    moe_ffn_held,
+    router_topk,
+)
 from mmlspark_tpu.serve.engine import ServeEngine
 from mmlspark_tpu.testing.compile_guard import serve_compile_guard
 
@@ -281,8 +285,10 @@ def test_a_window_for_linear_rows_is_still_refused():
 
 
 def test_the_blocks_fetch_carries_the_routing_counters(tiny):
-    """``dispatch`` carries ``expert_pairs`` and ``experts_hit`` (per
-    routed layer and micro-step), ``serve.prefill`` the prompt's, and
+    """``dispatch`` carries ``expert_pairs``, ``experts_hit`` and
+    ``expert_rows`` (per routed layer and micro-step: the pairs on held
+    experts, the held experts hit, the rows their products multiplied,
+    pad rows too), ``serve.prefill`` the prompt's, and
     ``serve.pool_write`` the bytes of each cache kind: all from fetches
     and counts the engine makes anyway."""
     from mmlspark_tpu.core.telemetry import FlightRecorder
@@ -301,15 +307,19 @@ def test_the_blocks_fetch_carries_the_routing_counters(tiny):
         by_name.setdefault(e["name"], []).append(e["attrs"])
     blocks = [a for a in by_name["dispatch"]
               if a["family"].startswith("decode")]
-    assert blocks and all(
-        {"expert_pairs", "experts_hit"} <= set(a) for a in blocks)
+    counters = {"expert_pairs", "experts_hit", "expert_rows"}
+    assert blocks and all(counters <= set(a) for a in blocks)
     for a in blocks:
         # two live tokens choose 2 of 8 experts each; 4 are held
         assert 0 <= a["expert_pairs"] <= 2 * 2
         assert 0 <= a["experts_hit"] <= min(4, a["expert_pairs"])
+        # an expert has two rows at the most: one tile of 16 an expert hit
+        assert a["expert_rows"] == pytest.approx(16 * a["experts_hit"],
+                                                 abs=0.02)
     assert any(a["expert_pairs"] > 0 for a in blocks)
     prefills = by_name["serve.prefill"]
-    assert all({"expert_pairs", "experts_hit"} <= set(a) for a in prefills)
+    assert all(counters <= set(a) for a in prefills)
+    assert all(a["expert_rows"] >= a["expert_pairs"] for a in prefills)
     # a prompt's pads route nowhere: no more pairs than real tokens give
     assert max(a["expert_pairs"] for a in prefills) <= 17 * 2
     writes = by_name["serve.pool_write"]
@@ -418,7 +428,83 @@ def test_grouped_matmul_multiplies_each_tile_with_its_groups_matrix():
     want = jnp.concatenate([x[i * 16:(i + 1) * 16] @ w[g]
                             for i, g in enumerate((2, 0, 0))])
     np.testing.assert_allclose(got[:48], want, rtol=1e-5, atol=1e-5)
-    assert not np.asarray(got[48:]).any()   # the dead tile
+    # the dead tile took no grid step: its rows were never written (the
+    # interpreter leaves NaN there, a chip whatever the buffer held)
+    assert got[48:].shape == (16, 24)
+
+
+@pytest.mark.parametrize("k, n, tm", [
+    (1792, 256, 16), (256, 1792, 32), (768, 256, 32), (256, 768, 16),
+])
+@pytest.mark.parametrize("live", [0, 3, 5])
+def test_grouped_matmul_at_the_awkward_widths(k, n, tm, live):
+    """The cells' expert widths that no power of two divides (1,792 and
+    768), the smallest row tiles, a group over two tiles (1), a group
+    with none (2), none live and all live: every live tile is the plain
+    product of its rows with its group's matrix, and a contraction in
+    one block gives what a split one gives, to float32 rounding."""
+    rng = np.random.default_rng(k + n + live)
+    groups = (0, 1, 1, 3, 4)
+    x = jnp.asarray(rng.normal(size=(len(groups) * tm, k)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(5, k, n)) * k ** -0.5, jnp.bfloat16)
+    whole = grouped_matmul(x, w, jnp.asarray(groups, jnp.int32), live, tm=tm,
+                           interpret=True)
+    split = grouped_matmul(x, w, jnp.asarray(groups, jnp.int32), live, tm=tm,
+                           tk=128, tn=128, interpret=True)
+    assert whole.shape == split.shape == (len(groups) * tm, n)
+    rows = max(live, 1) * tm   # one tile runs where none is live
+    want = jnp.concatenate([
+        jnp.dot(x[i * tm:(i + 1) * tm], w[g],
+                preferred_element_type=jnp.float32)
+        for i, g in enumerate(groups[:max(live, 1)])])
+    for got in (whole, split):
+        # one rounding to bfloat16 of sums near 1
+        np.testing.assert_allclose(got[:rows].astype(jnp.float32), want,
+                                   atol=2e-2)
+    # the two orders of one float32 sum, each rounded once to bfloat16
+    np.testing.assert_allclose(np.asarray(whole[:rows], np.float32),
+                               np.asarray(split[:rows], np.float32),
+                               rtol=2 ** -7, atol=1e-6)
+
+
+@pytest.mark.parametrize("tm, k, n, tk, tn", [
+    # synth-backlog, report-backlog and reason-backlog: a decode step's
+    # gate/up and down products, then a prefill's
+    (32, 2048, 1792, 2048, 896), (32, 1792, 2048, 1792, 1024),
+    (16, 2048, 768, 2048, 768), (16, 768, 2048, 768, 2048),
+    (16, 4096, 2048, 4096, 512), (16, 2048, 4096, 2048, 1024),
+    (128, 2048, 1792, 2048, 896), (256, 768, 2048, 768, 2048),
+    (128, 4096, 2048, 4096, 512),
+    # widths that no lane tile divides are taken whole
+    (16, 32, 24, 32, 24),
+])
+def test_the_weight_block_is_chosen_by_bytes(tm, k, n, tk, tn):
+    from mmlspark_tpu.ops import grouped_matmul as gm
+
+    assert gm._blocks(tm, k, n, 2) == (tk, tn)
+    assert k % tk == 0 and n % tn == 0
+    block = tk * tn * 2
+    assert block >= min(1 << 20, k * n * 2)
+    assert (2 * (tm * tk + block // 2 + tm * tn) * 2 + 4 * tm * tn
+            <= gm._GMM_VMEM)
+
+
+@pytest.mark.parametrize("weight_mib, scope_mib", [
+    (48, 96),      # kanana-2-30b-a3b: 16 experts of 2,048 x 768
+    (100, 78),     # room for half the weights at the most
+    (224, 16),     # lfm2-8b-a1b's 32 of 2,048 x 1,792 and wider: the least
+    (256, 16),     # mimo-v2-flash: 16 of 4,096 x 2,048
+    (1, 96),       # never over three quarters of VMEM
+])
+def test_the_kernels_scope_leaves_no_room_to_stage_the_weights(weight_mib,
+                                                               scope_mib):
+    from mmlspark_tpu.ops import grouped_matmul as gm
+
+    params = gm._compiler_params(weight_mib << 20)
+    assert params.vmem_limit_bytes == scope_mib << 20
+    assert params.vmem_limit_bytes >= gm._GMM_VMEM // 3 * 4
+    assert gm._VMEM - params.vmem_limit_bytes < max(weight_mib << 20,
+                                                    gm._VMEM // 4 + 1)
 
 
 # -- the router and the expert layer -------------------------------------------
@@ -505,3 +591,71 @@ def test_a_pad_routes_nowhere():
     np.testing.assert_allclose(out[:, :7], short, atol=1e-6)
     assert not np.asarray(out[:, 7:]).any()
     assert int(counters["pairs"]) == int(fewer["pairs"])
+
+
+
+@pytest.mark.parametrize("tokens, held, top_k, experts, tm, most", [
+    # a decode step and a prefill bucket of synth-backlog (128 slots, 4
+    # of 32, all held), report-backlog (64 slots, 6 of 128, 16 held) and
+    # reason-backlog (64 slots, 8 of 256, 16 held)
+    (128, 32, 4, 32, 32, 48), (512, 32, 4, 32, 128, 48),
+    (64, 16, 6, 128, 16, 40), (2048, 16, 6, 128, 256, 64),
+    (64, 16, 8, 256, 16, 48), (2048, 16, 8, 256, 128, 144),
+    (1, 4, 2, 8, 16, 4), (8192, 32, 4, 32, 512, 96),
+])
+def test_the_row_tile_follows_an_experts_share(tokens, held, top_k, experts,
+                                               tm, most):
+    assert held_tiles(tokens, held, top_k, experts) == (tm, most)
+    assert tm in (16, 32, 64, 128, 256, 512)
+    mean = tokens * top_k / experts
+    assert tm >= min(2 * mean, 512) and (tm == 16 or tm / 2 < 2 * mean)
+
+    def tiles(sizes):
+        return sum(-(-size // tm) for size in sizes)
+
+    # the worst routings fit: every token on ONE held expert (and its
+    # other choices spread one a tile), and the even one
+    mine = min(top_k, held)
+    assert tiles([tokens] * mine) <= most
+    assert tiles([tokens] + [1] * (held - 1)) <= most or (
+        tokens + held - 1 > tokens * mine)
+    even, over = divmod(tokens * mine, held)
+    assert tiles([even + (i < over) for i in range(held)]) <= most
+
+
+@pytest.mark.parametrize("top_k, bias0, live_rows", [
+    (1, 9.0, 72),    # SKEW: all 72 tokens on expert 0, three tiles of 32
+    (1, 9.0, 50),    # the same under a mask: two tiles
+    (2, 9.0, 72),    # expert 0 and each token's own second choice
+    (2, 0.0, 41),    # the router's own spread, masked
+])
+def test_the_expert_layer_is_dropless_under_skew(top_k, bias0, live_rows):
+    """An expert that receives more than two tiles' rows takes a third
+    tile; the layer still gives what the dense per-token reference
+    gives, and ``rows`` counts the rows multiplied: live tiles x the
+    row tile."""
+    sz = dict(ref.sizes(CFG), top_k=top_k)
+    key = jax.random.PRNGKey(3)
+    p = ref.init_layer(key, sz, 1)
+    p = dict(p, select_bias=p["select_bias"].at[0].set(bias0))
+    tokens = 72
+    h = jax.random.normal(key, (1, tokens, sz["d"]), jnp.float32)
+    valid = (jnp.arange(tokens) < live_rows)[None]
+    out, counters = moe_ffn_held(
+        h, p["router_w"], p["select_bias"], p["e_gate_w"], p["e_up_w"],
+        p["e_down_w"], top_k=top_k, first=0, valid=valid, interpret=True)
+    want, _ = ref.routed_ffn(h, p, sz, "f32")
+    np.testing.assert_allclose(out[:, :live_rows], want[:, :live_rows],
+                               atol=1e-5)
+    assert not np.asarray(out[:, live_rows:]).any()
+    assert float(jnp.abs(want).max()) > 10 * 1e-5
+    experts, _ = router_topk(h[0], p["router_w"], p["select_bias"], top_k)
+    chosen = np.asarray(experts)[:live_rows]
+    sizes = [(chosen == e).sum() for e in range(4)]   # experts 0-3 held
+    tm, _ = held_tiles(tokens, 4, top_k, 8)
+    assert tm == (32 if top_k == 1 else 64)
+    if bias0:
+        assert sizes[0] == live_rows
+    assert int(counters["pairs"]) == sum(sizes)
+    assert int(counters["hit"]) == sum(size > 0 for size in sizes)
+    assert int(counters["rows"]) == sum(-(-size // tm) for size in sizes) * tm

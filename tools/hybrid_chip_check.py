@@ -3,7 +3,21 @@ its plain-XLA oracle at the benchmark cell's shapes, then the model's own
 forward and its served tokens against the family's reference at the
 published widths. One JSON line a check; exits 1 if any is off.
 
-    chiprun -- python tools/hybrid_chip_check.py
+    chiprun -- python tools/hybrid_chip_check.py [kernels] [model] [time]
+
+(``grouped`` is the grouped product's share of ``kernels`` alone.)
+
+``time`` (asked for by name; about 4 minutes) times an expert layer's
+three grouped products ALONE at the three routed cells' shapes, a decode
+micro-step and one prefill bucket each, under an even routing drawn once:
+us a call of ``moe_gate``, ``moe_up`` and ``moe_down`` and of what XLA
+fuses around them, at the row tile and the weight block the rules choose
+(``parallel/expert.held_tiles``, ``ops/grouped_matmul._blocks``), at
+other tiles and blocks, and, where the parent commit's tree lies in
+``.parent_tree/`` (``git archive``), through the PARENT'S kernel at the
+parent's tile. A line a variant, to stdout and
+``chiprun_out/grouped_matmul_timing.jsonl``: what ``_TILE_OVER_MEAN`` and
+``_GMM_VMEM`` rest on; run it again before changing either or the kernel.
 """
 
 from __future__ import annotations
@@ -42,7 +56,6 @@ def kernels() -> None:
         flash_attention,
         flash_decode_grouped,
     )
-    from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
 
     key = jax.random.PRNGKey(0)
     h, dk, dv = 64, 192, 128
@@ -89,9 +102,16 @@ def kernels() -> None:
             want = dense_attention(q, k, v, causal=True, window=window,
                                    sink=sink)
             report(f"flash_forward.{kind}.{t}", gap(got, want), 3e-2)
-    # grouped products at the decode step's and a prefill's shapes
+    grouped()
+
+
+def grouped() -> None:
+    """The grouped product at the decode step's and a prefill's shapes."""
+    from mmlspark_tpu.ops.grouped_matmul import grouped_matmul
+
     rng = np.random.default_rng(0)
-    for m, tm, live in ((1024, 64, 11), (40960, 512, 9)):
+    # reason-backlog's tiles: 64 tokens and a bucket of 2,048, 8 of 256
+    for m, tm, live in ((48 * 16, 16, 11), (144 * 128, 128, 9)):
         tiles = m // tm
         x = jnp.asarray(rng.normal(size=(m, 4096)), jnp.bfloat16)
         w = jnp.asarray(rng.normal(size=(16, 4096, 2048)) * 0.02,
@@ -103,8 +123,8 @@ def kernels() -> None:
             jnp.dot(x[i * tm:(i + 1) * tm], w[int(group[i])],
                     preferred_element_type=jnp.float32)
             for i in range(live)])
-        report(f"grouped_matmul.{m}", gap(got[:live * tm], want), 2e-2,
-               dead_is_zero=not bool(jnp.abs(got[live * tm:]).max()))
+        # a dead tile takes no grid step: its rows are never written
+        report(f"grouped_matmul.{m}", gap(got[:live * tm], want), 2e-2)
 
 
 def model(seed: int = 5) -> None:
@@ -146,11 +166,180 @@ def model(seed: int = 5) -> None:
            tokens=numbers["tokens_compared"])
 
 
+# -- the expert layer's three products, timed alone ----------------------------
+
+#: (cell, slots, a prefill bucket, top_k, experts, held, d_model, expert_d_ff)
+ROUTED_CELLS = (
+    ("synth-backlog", 128, 512, 4, 32, 32, 2048, 1792),
+    ("report-backlog", 64, 2048, 6, 128, 16, 2048, 768),
+    ("reason-backlog", 64, 2048, 8, 256, 16, 4096, 2048),
+)
+
+
+def parent_tiles(tokens: int, held: int, top_k: int) -> tuple[int, int]:
+    """The row tile before PR 36: by the step's tokens."""
+    tm = min(512, -(-tokens // 16) * 16)
+    return tm, min(held * -(-tokens // tm),
+                   held + tokens * min(top_k, held) // tm)
+
+
+def parent_kernel():
+    """The parent commit's ``grouped_matmul`` out of ``.parent_tree/``, or
+    None where no such tree lies there."""
+    import importlib.util
+
+    path = os.path.join(ROOT, ".parent_tree", "mmlspark_tpu", "ops",
+                        "grouped_matmul.py")
+    if not os.path.exists(path):
+        return None
+    spec = importlib.util.spec_from_file_location("parent_gmm", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.grouped_matmul
+
+
+def time_products(cells=()) -> None:
+    from flash_block_timing import traced_ops
+    from mmlspark_tpu.ops import grouped_matmul as gm
+    from mmlspark_tpu.parallel.expert import held_tiles
+
+    out = os.path.join(ROOT, "chiprun_out", "grouped_matmul_timing.jsonl")
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+    with open(os.path.join(ROOT, "benchmark", "peaks.json")) as f:
+        peak = json.load(f)[jax.devices()[0].device_kind]
+    parent = parent_kernel()
+    interpret = jax.devices()[0].platform != "tpu"   # a rehearsal
+    rng = np.random.default_rng(0)
+
+    def emit(**row):
+        line = json.dumps(row)
+        print(line, flush=True)
+        with open(out, "a") as f:
+            f.write(line + "\n")
+
+    def layer(kernel, tm, most, sizes, d, blocks):
+        """The three products as ``moe_ffn_held`` calls them, over the
+        tiles that ``sizes`` rows an expert fill; ``blocks`` is ``{"up":
+        (tk, tn), "down": ...}``, or None for the kernel's own choice."""
+        tiles = -(-sizes // tm)
+        live = int(tiles.sum())
+        assert live <= most, (live, most)
+        group = np.repeat(np.arange(sizes.size), tiles)
+        group = np.concatenate([group, np.full(most - live, sizes.size - 1)])
+
+        def products(xs, w_gate, w_up, w_down, group, live):
+            def one(x, w, name, kind):
+                more = dict(zip(("tk", "tn"), blocks[kind])) if blocks else {}
+                return kernel(x, w, group, live, tm=tm, name=name,
+                              interpret=interpret, **more)
+
+            gate = one(xs, w_gate, "moe_gate", "up")
+            up = one(xs, w_up, "moe_up", "up")
+            mid = (jax.nn.silu(gate.astype(jnp.float32))
+                   * up.astype(jnp.float32)).astype(xs.dtype)
+            return one(mid, w_down, "moe_down", "down")
+
+        xs = jnp.asarray(rng.normal(size=(most * tm, d)), jnp.bfloat16)
+        return (jax.jit(products), xs, jnp.asarray(group, jnp.int32),
+                jnp.asarray(live, jnp.int32))
+
+    for cell, slots, bucket, top_k, experts, held, d, f in ROUTED_CELLS:
+        if cells and cell not in cells:
+            continue
+        key = jax.random.PRNGKey(held + d)
+        w_gate, w_up = (
+            jax.random.normal(k, (held, d, f), jnp.bfloat16) * 0.02
+            for k in jax.random.split(key))
+        w_down = jax.random.normal(key, (held, f, d), jnp.bfloat16) * 0.02
+        shapes = {"up": (d, f), "down": (f, d)}
+        for phase, tokens in (("decode", slots), ("prefill", bucket)):
+            # an even routing: each token's top_k distinct experts
+            chosen = np.stack([rng.permutation(experts)[:top_k]
+                               for _ in range(tokens)])
+            even = np.bincount(chosen[chosen < held], minlength=held)
+            # and a skewed one: every token's first choice is expert 0
+            hot = np.where(chosen == 0, chosen[:, :1], chosen)
+            hot[:, 0] = 0
+            skew = np.bincount(hot[hot < held], minlength=held)
+
+            def tiles_at(tm):
+                return tm, min(held * -(-tokens // tm),
+                               held + tokens * min(top_k, held) // tm)
+
+            tile = held_tiles(tokens, held, top_k, experts)
+            assert tile == tiles_at(tile[0])
+            # the contraction whole and a block of 2 MiB at the most
+            small = {kind: (k, next((t for t in gm._cuts(n)
+                                     if k * t * 2 <= 2 << 20), None))
+                     for kind, (k, n) in shapes.items()}
+            ladder = {kind: (gm_ladder(k, 1024), gm_ladder(n, 512))
+                      for kind, (k, n) in shapes.items()}
+            variants = [("chosen", gm.grouped_matmul, tile, even, None)]
+            if parent is not None:
+                variants.append(("parent", parent,
+                                 parent_tiles(tokens, held, top_k), even,
+                                 ladder))
+            variants.append(("skew", gm.grouped_matmul, tile, skew, None))
+            if all(tn for _, tn in small.values()):
+                variants.append(("blocks_2MiB", gm.grouped_matmul, tile,
+                                 even, small))
+            variants.append(("parent_blocks", gm.grouped_matmul, tile, even,
+                             ladder))
+            variants += [(f"tm_{tm}", gm.grouped_matmul, tiles_at(tm), even,
+                          None)
+                         for tm in (tile[0] // 2, tile[0] * 2, tile[0] * 4)
+                         if 16 <= tm <= 512]
+            for name, kernel, (tm, most), sizes, blocks in variants:
+                fn, xs, group, live = layer(kernel, tm, most, sizes, d,
+                                            blocks)
+                row = dict(time=cell, phase=phase, variant=name,
+                           tokens=tokens, tm=tm, most=most,
+                           live_tiles=int(live), pairs=int(sizes.sum()),
+                           rows=int(live) * tm, hit=int((sizes > 0).sum()),
+                           blocks=blocks or {
+                               kind: gm._blocks(tm, k, n, 2)
+                               for kind, (k, n) in shapes.items()})
+                try:
+                    ops, call_us = traced_ops(
+                        fn, (xs, w_gate, w_up, w_down, group, live))
+                except Exception as e:  # noqa: BLE001 - VMEM refuses a block
+                    emit(**row, error=str(e)[:200])
+                    continue
+                # a product's least: its hit experts' weights once and its
+                # pairs' rows in and out, or its pairs' multiplications
+                least = max((row["hit"] * d * f + row["pairs"] * (d + f)) * 2
+                            / peak["hbm_bytes_per_s"],
+                            2.0 * row["pairs"] * d * f
+                            / peak["flops_per_s"]) * 1e6
+                kernels_us = {k.split(".")[0]: v for k, v in ops.items()
+                              if k.startswith("moe_")}
+                three = sum(kernels_us.values())
+                emit(**row, **kernels_us, three_us=round(three, 1),
+                     call_us=call_us, least_us=round(least, 1),
+                     roofline_pct=round(300 * least / max(three, 1e-9), 1))
+
+
+def gm_ladder(n: int, most: int) -> int:
+    """The block before PR 36: the largest of ``most, most/2, ... 128``
+    that divides ``n``, else ``n`` whole."""
+    t = most
+    while t >= 128:
+        if n % t == 0:
+            return t
+        t //= 2
+    return n
+
+
 if __name__ == "__main__":
     what = sys.argv[1:] or ["kernels", "model"]
+    sys.path.insert(0, os.path.join(ROOT, "tools"))
     if "kernels" in what:
         kernels()
+    elif "grouped" in what:
+        grouped()
     if "model" in what:
         model()
+    if "time" in what:
+        time_products([a for a in what if a.endswith("-backlog")])
     print(json.dumps({"ok": not FAILED, "failed": FAILED}))
     sys.exit(1 if FAILED else 0)

@@ -13,7 +13,10 @@ An untraced run of a serving cell now and then holds a tick of seconds
 where its like take a few hundred milliseconds (``PERF.md`` section 7); the
 result line's ``ticks`` say THAT it happened, this says in which region.
 The result line is printed as ``run.py`` prints it; one JSON line a long
-tick follows on standard error and in ``chiprun_out/long_ticks.jsonl``.
+tick follows on standard error and in ``chiprun_out/long_ticks.jsonl``,
+and for a cell that routes tokens to experts one line of the window's
+``dispatch`` counters: pairs over rows is how full the expert products'
+row tiles were (no metric reads ``expert_rows`` yet: ``PERF.md`` section 7).
 """
 
 from __future__ import annotations
@@ -84,10 +87,26 @@ def report(state: dict, over_ms: float) -> list[dict]:
     return rows
 
 
+def tile_fill(state: dict) -> list[dict]:
+    """The window's mean ``expert_pairs`` and ``expert_rows`` (a routed
+    layer and micro-step) over its ``dispatch`` events, and their ratio."""
+    from benchmark.readers import in_window
+
+    blocks = [e["attrs"] for e in in_window(state)
+              if e["name"] == "dispatch" and "expert_rows" in e["attrs"]]
+    if not blocks:
+        return []
+    pairs, rows = (sum(float(a[name]) for a in blocks) / len(blocks)
+                   for name in ("expert_pairs", "expert_rows"))
+    return [{"dispatches": len(blocks), "expert_pairs_mean": pairs,
+             "expert_rows_mean": rows,
+             "expert_tile_fill_pct": 100.0 * pairs / max(rows, 1e-9)}]
+
+
 def emit(rows: list[dict], **head) -> None:
     OUT.parent.mkdir(exist_ok=True)
     with OUT.open("a") as f:
-        for row in rows or [{"long_ticks": 0}]:
+        for row in rows:
             line = json.dumps({**head, **row})
             print(line, file=sys.stderr, flush=True)
             f.write(line + "\n")
@@ -110,7 +129,8 @@ def main(argv=None) -> int:
     finally:
         serving.run = serving_run
     if "state" in seen:
-        emit(report(seen["state"], OVER_MS), argv=" ".join(argv))
+        rows = report(seen["state"], OVER_MS) or [{"long_ticks": 0}]
+        emit(rows + tile_fill(seen["state"]), argv=" ".join(argv))
     return rc
 
 
