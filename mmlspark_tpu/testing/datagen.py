@@ -199,7 +199,8 @@ def overfit_periodic_lm(graph, *, steps: int = 60, seq: int = 16,
     import optax
 
     ids = jnp.asarray((np.arange(seq)[None] % period) + 1, jnp.int32)
-    variables = graph.init(jax.random.PRNGKey(0), ids)
+    # under jit: eager, init compiles a program an operation
+    variables = jax.jit(graph.init)(jax.random.PRNGKey(0), ids)
     opt = optax.adam(lr)
     state = opt.init(variables)
 

@@ -31,15 +31,19 @@ def _ids(rng, b, t, vocab=31):
     return rng.integers(0, vocab, size=(b, t)).astype(np.int32)
 
 
+def _seq_parallel(graph, ids, mesh):
+    """The chunked chain under jit: eager, a ``shard_map`` dispatches every
+    step of the chain as a program of its own."""
+    return jax.jit(lambda v: bilstm_seq_parallel_apply(graph, v, ids, mesh))
+
+
 def test_seq_parallel_matches_dense(tagger):
     graph, variables = tagger
     rng = np.random.default_rng(0)
     ids = _ids(rng, 3, 16)
     mesh = make_mesh({"seq": 8})
     dense = np.asarray(graph.apply(variables, jnp.asarray(ids)))
-    par = np.asarray(
-        bilstm_seq_parallel_apply(graph, variables, ids, mesh)
-    )
+    par = np.asarray(_seq_parallel(graph, ids, mesh)(variables))
     np.testing.assert_allclose(par, dense, atol=1e-5, rtol=1e-5)
 
 
@@ -50,9 +54,7 @@ def test_seq_parallel_data_seq_mesh(tagger):
     ids = _ids(rng, 4, 12)
     mesh = make_mesh({"data": 2, "seq": 4})
     dense = np.asarray(graph.apply(variables, jnp.asarray(ids)))
-    par = np.asarray(
-        bilstm_seq_parallel_apply(graph, variables, ids, mesh)
-    )
+    par = np.asarray(_seq_parallel(graph, ids, mesh)(variables))
     np.testing.assert_allclose(par, dense, atol=1e-5, rtol=1e-5)
 
 
@@ -89,8 +91,10 @@ def test_seq_parallel_grads_match_dense(tagger):
 
     from jax.flatten_util import ravel_pytree
 
-    gd = jax.grad(loss_dense)(variables)
-    gp = jax.grad(loss_par)(variables)
+    # under jit: an eager shard_map backward dispatches every step of
+    # the chain op by op
+    gd = jax.jit(jax.grad(loss_dense))(variables)
+    gp = jax.jit(jax.grad(loss_par))(variables)
     flat_d, _ = ravel_pytree(gd)
     flat_p, _ = ravel_pytree(gp)
     # tolerance: the bf16 head matmul backward accumulates in a

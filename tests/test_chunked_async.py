@@ -23,37 +23,16 @@ import jax.numpy as jnp
 
 from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.core.faults import EngineKilled, Fault, FaultInjector
-from mmlspark_tpu.models import build_model, generate
+from mmlspark_tpu.models import build_model
 from mmlspark_tpu.serve import ServeEngine
 from mmlspark_tpu.serve.metrics import ServeMetrics
 from mmlspark_tpu.testing.compile_guard import serve_compile_guard
-
-PERIOD = 4
-
-
-def _train_lm(m, steps=30, seq=16):
-    from mmlspark_tpu.testing.datagen import overfit_periodic_lm
-
-    return overfit_periodic_lm(m, steps=steps, seq=seq, period=PERIOD)
-
-
-def _tiny(**kw):
-    cfg = dict(vocab_size=8, d_model=32, heads=2, depth=2, max_len=32)
-    cfg.update(kw)
-    return build_model("transformer_lm", **cfg)
+from tests.serve_helpers import ref_tokens, trained_lm
 
 
 @pytest.fixture(scope="module")
 def lm():
-    m = _tiny()
-    v, ids = _train_lm(m)
-    return m, v, ids
-
-
-def _ref(m, v, prompt, max_new, eos_id=None):
-    out = generate(m, v, np.asarray(prompt, np.int32)[None], max_new,
-                   eos_id=eos_id)
-    return np.asarray(out)[0]
+    return trained_lm()
 
 
 # -- config validation -----------------------------------------------------
@@ -123,7 +102,7 @@ def test_chunked_parity_ragged_prompts_and_mid_fill_joins(lm):
 
     for rid, p, n in zip(rids, prompts, budgets):
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, n),
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, n),
             err_msg=f"chunked fill diverged: request={rid}",
         )
     # the tentpole pin: one program per chunk bucket, ceiling included
@@ -137,7 +116,7 @@ def test_chunked_parity_mid_fill_eos_and_tiny_budget(lm):
     match generate()'s trim."""
     m, v, ids = lm
     prompt = np.asarray(ids[0, :9])  # 2 chunks at chunk=8
-    free = _ref(m, v, prompt, 4)
+    free = ref_tokens(m, v, prompt, 4)
     eos = int(free[len(prompt)])  # the first generated token
 
     engine = ServeEngine(m, v, slots=2, cache_len=32, prefill_chunk=8)
@@ -224,7 +203,7 @@ def test_async_parity_and_at_most_one_sync_per_block(lm, monkeypatch):
     monkeypatch.undo()
 
     np.testing.assert_array_equal(
-        np.asarray(res.tokens), _ref(m, v, prompt, 17)
+        np.asarray(res.tokens), ref_tokens(m, v, prompt, 17)
     )
     assert syncs["n"] <= 2, f"host syncs: {syncs['n']} (> 1 per block)"
     d = engine.metrics.to_dict()
@@ -259,7 +238,7 @@ def test_async_parity_ragged_with_joins_and_overlap(lm):
 
     for rid, p, n in zip(rids, prompts, budgets):
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, n),
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, n),
             err_msg=f"async stream diverged: request={rid}",
         )
     assert engine.metrics.overlapped_dispatches_total > 0
@@ -287,7 +266,7 @@ def test_chunked_async_parity_2x2_mesh(lm):
             results.update({r.id: r for r in engine.step()})
     for rid, p in zip(rids, prompts):
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, 6),
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, 6),
             err_msg=f"mesh chunked+async diverged: request={rid}",
         )
     assert engine.prefill_compile_count <= engine.num_chunk_buckets
@@ -333,7 +312,7 @@ def test_kill_mid_chunk_restore_is_bit_identical(lm):
     for rid, p in zip(rids, prompts):
         assert results[rid].status == "completed"
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, 8),
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, 8),
             err_msg=f"request {rid} diverged across the mid-chunk kill",
         )
 
@@ -359,7 +338,7 @@ def test_disagg_chunked_handoff(lm):
     results = fleet.run()
     for gid, p in zip(gids, prompts):
         np.testing.assert_array_equal(
-            np.asarray(results[gid].tokens), _ref(m, v, p, 6),
+            np.asarray(results[gid].tokens), ref_tokens(m, v, p, 6),
             err_msg=f"disagg chunked hand-off diverged: {p}",
         )
     assert fleet.engine(1).prefill_compile_count == 0

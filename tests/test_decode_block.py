@@ -19,38 +19,16 @@ import jax
 import jax.numpy as jnp
 
 from mmlspark_tpu.core.exceptions import FriendlyError
-from mmlspark_tpu.models import build_model, generate
 from mmlspark_tpu.models.generate import make_decode_block
 from mmlspark_tpu.serve import ServeEngine
 from mmlspark_tpu.serve.metrics import ServeMetrics
 from mmlspark_tpu.testing.compile_guard import serve_compile_guard
-
-PERIOD = 4
-
-
-def _train_lm(m, steps=30, seq=16):
-    from mmlspark_tpu.testing.datagen import overfit_periodic_lm
-
-    return overfit_periodic_lm(m, steps=steps, seq=seq, period=PERIOD)
-
-
-def _tiny(**kw):
-    cfg = dict(vocab_size=8, d_model=32, heads=2, depth=2, max_len=32)
-    cfg.update(kw)
-    return build_model("transformer_lm", **cfg)
+from tests.serve_helpers import ref_tokens, trained_lm
 
 
 @pytest.fixture(scope="module")
 def lm():
-    m = _tiny()
-    v, ids = _train_lm(m)
-    return m, v, ids
-
-
-def _ref(m, v, prompt, max_new, eos_id=None):
-    out = generate(m, v, np.asarray(prompt, np.int32)[None], max_new,
-                   eos_id=eos_id)
-    return np.asarray(out)[0]
+    return trained_lm()
 
 
 # -- parity: fused blocks vs generate() ------------------------------------
@@ -91,7 +69,7 @@ def test_block_parity_ragged_prompts_and_budgets(lm, block):
 
     for rid, p, n in zip(rids, prompts, budgets):
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, n),
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, n),
             err_msg=f"block={block} request={rid}",
         )
         assert results[rid].generated == n
@@ -110,11 +88,11 @@ def test_block_parity_mid_block_eos(lm, block):
     prompt = np.asarray(ids[0, :3])
     # pick an eos the trained model actually emits a few tokens in, so
     # the stop lands strictly inside a T>1 block
-    free_run = _ref(m, v, prompt, 12)
+    free_run = ref_tokens(m, v, prompt, 12)
     eos = int(free_run[len(prompt) + 2])
     # generate() keeps the padded full-length array; the engine returns
     # prompt + tokens up to and including EOS — trim the ref to match
-    full = _ref(m, v, prompt, 12, eos_id=eos)
+    full = ref_tokens(m, v, prompt, 12, eos_id=eos)
     stop = len(prompt) + int(np.argmax(full[len(prompt):] == eos))
     want = full[:stop + 1]
 
@@ -139,7 +117,7 @@ def test_mid_block_budget_exhaustion_direct_program(lm):
     prompt = np.asarray(ids[0, :5])
     budget = 3  # vs scan length 8: exhausts strictly inside the block
     t = 8
-    want = _ref(m, v, prompt, budget + 1)  # +1: first token via prefill
+    want = ref_tokens(m, v, prompt, budget + 1)  # +1: first token via prefill
 
     cache = init_cache(m, v, 1, 32)
     logits, cache = _cached_apply(m, v, jnp.asarray(prompt)[None], cache, 0)
@@ -171,10 +149,9 @@ def test_mid_block_budget_exhaustion_direct_program(lm):
 def test_true_32_scan_with_rope(lm):
     """A genuine T=32 scan (not a ladder shrink): a RoPE model's
     cache_len can exceed max_len, leaving room for a 32-token block."""
-    m = _tiny(pos_embedding="rope")
-    v, ids = _train_lm(m)
+    m, v, ids = trained_lm(pos_embedding="rope")
     prompt = np.asarray(ids[0, :3])
-    want = _ref(m, v, prompt, 40)
+    want = ref_tokens(m, v, prompt, 40)
 
     engine = ServeEngine(m, v, slots=2, cache_len=64, decode_block=32)
     rid = engine.submit(prompt, max_new_tokens=40)
@@ -218,7 +195,7 @@ def test_at_most_one_host_sync_per_block(lm, monkeypatch):
     monkeypatch.undo()
 
     np.testing.assert_array_equal(
-        np.asarray(res.tokens), _ref(m, v, prompt, 17)
+        np.asarray(res.tokens), ref_tokens(m, v, prompt, 17)
     )
     # 16 decode tokens / blocks of 8 = 2 blocks -> at most 2 synced
     # fetches (1 per block), where the T=1 engine would have paid 16

@@ -13,8 +13,16 @@ sys.path.insert(
 )
 import harness  # noqa: E402
 
+#: the two long-context examples, a third of this tier's time, run from
+#: test_examples_long_context.py: under ``--dist loadfile`` a file is one
+#: worker's, and this one alone was over a third of the suite's wall
+LONG_CONTEXT = ("e306", "e307")
+
 # ignore PROC_SHARD here: the pytest tier always covers every example
-EXAMPLES = harness.discover([], use_shard=False)
+EXAMPLES = [
+    path for path in harness.discover([], use_shard=False)
+    if not os.path.basename(path).startswith(LONG_CONTEXT)
+]
 
 
 def test_examples_discovered():

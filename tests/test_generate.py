@@ -7,6 +7,8 @@ must be deterministic, and every attention configuration (window, GQA,
 RoPE) must decode through the same utility.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -15,14 +17,10 @@ import jax.numpy as jnp
 
 from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.models import beam_search, build_model, generate
+from tests.serve_helpers import PERIOD, init_lm, ref_tokens, trained_lm
 
-PERIOD = 4  # token stream cycles 1,2,3,4,1,2,...
-
-
-def _train_lm(m, steps=60, seq=16):
-    from mmlspark_tpu.testing.datagen import overfit_periodic_lm
-
-    return overfit_periodic_lm(m, steps=steps, seq=seq, period=PERIOD)
+#: this file's models take 60 steps (the serving files' 30), as they did
+trained = functools.partial(trained_lm, steps=60)
 
 
 @pytest.mark.parametrize("config", [
@@ -31,9 +29,7 @@ def _train_lm(m, steps=60, seq=16):
     {"pos_embedding": "rope", "kv_heads": 1},      # RoPE + MQA
 ])
 def test_overfit_lm_continues_the_period(config):
-    m = build_model("transformer_lm", vocab_size=8, d_model=32, heads=2,
-                    depth=2, max_len=32, **config)
-    v, ids = _train_lm(m)
+    m, v, ids = trained(**config)
     prompt = ids[:, :8]
     out = np.asarray(generate(m, v, prompt, max_new_tokens=8))
     want = (np.arange(16) % PERIOD) + 1
@@ -51,9 +47,7 @@ def test_kv_cache_matches_recompute_oracle(config):
     buffers) must produce the same tokens as the O(T²) full-recompute
     path — per config, since window masking, GQA buffer geometry, and
     RoPE offset tables are each their own cached code path."""
-    m = build_model("transformer_lm", vocab_size=8, d_model=32, heads=2,
-                    depth=2, max_len=32, **config)
-    v, ids = _train_lm(m, steps=30)
+    m, v, ids = trained(**config)
     prompt = ids[:, :5]
     kv = np.asarray(generate(m, v, prompt, max_new_tokens=9))
     rc = np.asarray(generate(m, v, prompt, max_new_tokens=9,
@@ -68,27 +62,40 @@ def test_kv_cache_matches_recompute_oracle(config):
     np.testing.assert_array_equal(skv, src)
 
 
+@pytest.mark.parametrize("config", [
+    {},
+    {"pos_embedding": "rope", "kv_heads": 1},
+])
+def test_the_suites_jitted_reference_is_eager_generate(config):
+    """The serving tests hold served streams to ``ref_tokens``, which is
+    ``generate()`` under ``jax.jit``: the same tokens as the eager call,
+    with and without an eos."""
+    m, v, ids = trained(**config)
+    prompt = np.asarray(ids[0, :8])
+    for eos in (None, 3):
+        eager = np.asarray(generate(m, v, prompt[None], 8, eos_id=eos))[0]
+        np.testing.assert_array_equal(ref_tokens(m, v, prompt, 8, eos), eager)
+
+
 def test_greedy_is_deterministic_and_sampling_needs_rng():
-    m = build_model("transformer_lm", vocab_size=8, d_model=16, heads=2,
-                    depth=1, max_len=24)
-    v, ids = _train_lm(m, steps=5)
-    prompt = ids[:, :4]
-    a = np.asarray(generate(m, v, prompt, max_new_tokens=6))
-    b = np.asarray(generate(m, v, prompt, max_new_tokens=6))
+    m, v, ids = trained()
+    prompt = ids[:, :8]
+    a = np.asarray(generate(m, v, prompt, max_new_tokens=8))
+    b = np.asarray(generate(m, v, prompt, max_new_tokens=8))
     np.testing.assert_array_equal(a, b)
     with pytest.raises(FriendlyError, match="rng"):
         generate(m, v, prompt, max_new_tokens=2, temperature=0.7)
     # sampling path runs and keeps the prompt intact
-    s = np.asarray(generate(m, v, prompt, max_new_tokens=6,
+    s = np.asarray(generate(m, v, prompt, max_new_tokens=8,
                             temperature=0.7,
                             rng=jax.random.PRNGKey(3)))
-    np.testing.assert_array_equal(s[:, :4], np.asarray(prompt))
+    np.testing.assert_array_equal(s[:, :8], np.asarray(prompt))
 
 
 def test_generate_guards():
     m = build_model("transformer_lm", vocab_size=8, d_model=16, heads=2,
                     depth=1, max_len=8)
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     prompt = jnp.zeros((1, 6), jnp.int32)
     with pytest.raises(FriendlyError, match="position table"):
         generate(m, v, prompt, max_new_tokens=4)  # 10 > max_len 8
@@ -96,7 +103,7 @@ def test_generate_guards():
         generate(m, v, prompt, max_new_tokens=0)
     bidir = build_model("transformer_lm", vocab_size=8, d_model=16,
                         heads=2, depth=1, max_len=8, causal=False)
-    bv = bidir.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    bv = init_lm(bidir)
     with pytest.raises(FriendlyError, match="causal"):
         generate(bidir, bv, prompt, max_new_tokens=1)
 
@@ -104,9 +111,7 @@ def test_generate_guards():
 def test_rope_generates_past_trained_max_len():
     """RoPE has no position table: generation may run past max_len (the
     structural-extrapolation property, impossible with learned pos)."""
-    m = build_model("transformer_lm", vocab_size=8, d_model=32, heads=2,
-                    depth=2, max_len=16, pos_embedding="rope")
-    v, ids = _train_lm(m, seq=16)
+    m, v, ids = trained(max_len=16, pos_embedding="rope")
     out = np.asarray(generate(m, v, ids, max_new_tokens=8))  # 24 > 16
     want = (np.arange(24) % PERIOD) + 1
     np.testing.assert_array_equal(out[0], want)
@@ -116,9 +121,7 @@ def test_eos_stops_rows_and_pads_the_tail():
     """eos_id: the trained model walks the period 1,2,3,4,...; stopping
     at eos_id=3 must keep tokens up to AND including the first 3, then
     pad — identically on the cache path and the recompute oracle."""
-    m = build_model("transformer_lm", vocab_size=8, d_model=32, heads=2,
-                    depth=2, max_len=32)
-    v, ids = _train_lm(m)
+    m, v, ids = trained()
     prompt = ids[:, :8]  # ends ...3,4 → continuation 1,2,3,4,...
     kv = np.asarray(generate(m, v, prompt, max_new_tokens=8, eos_id=3))
     want = np.concatenate([
@@ -142,9 +145,7 @@ def test_rolled_window_cache_long_generation():
     its trained max_len: the decode carry holds O(window) K/V (the
     rolled circular buffers), RoPE extrapolates structurally, and the
     learned period must continue across many buffer wrap-arounds."""
-    m = build_model("transformer_lm", vocab_size=8, d_model=32, heads=2,
-                    depth=2, max_len=16, window=8, pos_embedding="rope")
-    v, ids = _train_lm(m, seq=16)
+    m, v, ids = trained(max_len=16, window=8, pos_embedding="rope")
     out = np.asarray(generate(m, v, ids, max_new_tokens=32))  # 48 >> W=8
     want = (np.arange(48) % PERIOD) + 1
     np.testing.assert_array_equal(out[0], want)
@@ -154,9 +155,7 @@ def test_top_k_and_top_p_sampling():
     """top_k=1 collapses sampling to greedy; a tight nucleus on a
     peaked (trained) model does too; loose filters reproduce the
     unfiltered stream rng-for-rng; guards reject meaningless configs."""
-    m = build_model("transformer_lm", vocab_size=8, d_model=32, heads=2,
-                    depth=2, max_len=32)
-    v, ids = _train_lm(m)
+    m, v, ids = trained()
     prompt = ids[:, :8]
     greedy = np.asarray(generate(m, v, prompt, max_new_tokens=8))
     k1 = np.asarray(generate(m, v, prompt, max_new_tokens=8,
@@ -190,7 +189,7 @@ def test_top_k_and_top_p_sampling():
 def test_generate_rejects_moe_recompute_and_negative_temperature():
     m = build_model("transformer_lm", vocab_size=8, d_model=16, heads=2,
                     depth=1, max_len=16)
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     with pytest.raises(FriendlyError, match="temperature"):
         generate(m, v, jnp.zeros((1, 4), jnp.int32), max_new_tokens=2,
                  temperature=-0.5, rng=jax.random.PRNGKey(0))
@@ -199,7 +198,7 @@ def test_generate_rejects_moe_recompute_and_negative_temperature():
     # not causal). Full MoE generation semantics: tests/test_moe.py.
     moe = build_model("transformer_lm_moe", vocab_size=8, d_model=16,
                       heads=2, depth=1, max_len=16, n_experts=2)
-    mv = moe.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    mv = init_lm(moe)
     out = generate(moe, mv, jnp.zeros((1, 4), jnp.int32), max_new_tokens=2)
     assert out.shape == (1, 6)
     with pytest.raises(FriendlyError, match="kv_cache"):
@@ -211,9 +210,7 @@ def test_generate_rejects_moe_recompute_and_negative_temperature():
 
 
 def test_beam_one_equals_greedy():
-    m = build_model("transformer_lm", vocab_size=8, d_model=32, heads=2,
-                    depth=2, max_len=32, window=6)
-    v, ids = _train_lm(m, steps=30)
+    m, v, ids = trained(window=6)
     prompt = ids[:, :5]
     greedy = np.asarray(generate(m, v, prompt, max_new_tokens=9))
     beam1 = np.asarray(beam_search(m, v, prompt, max_new_tokens=9,
@@ -256,9 +253,7 @@ def test_beam_full_width_is_exhaustive_at_two_steps():
 
 
 def test_beam_eos_and_return_all():
-    m = build_model("transformer_lm", vocab_size=8, d_model=32, heads=2,
-                    depth=2, max_len=32)
-    v, ids = _train_lm(m)
+    m, v, ids = trained()
     prompt = ids[:, :8]
     out = np.asarray(beam_search(m, v, prompt, max_new_tokens=8,
                                  beams=3, eos_id=3))
@@ -277,7 +272,7 @@ def test_beam_eos_and_return_all():
 def test_beam_guards_and_moe():
     m = build_model("transformer_lm", vocab_size=8, d_model=16, heads=2,
                     depth=1, max_len=16)
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     prompt = jnp.zeros((1, 4), jnp.int32)
     with pytest.raises(FriendlyError, match="beams"):
         beam_search(m, v, prompt, max_new_tokens=2, beams=0)
@@ -285,7 +280,7 @@ def test_beam_guards_and_moe():
         beam_search(m, v, prompt, max_new_tokens=2, beams=9)
     moe = build_model("transformer_lm_moe", vocab_size=8, d_model=16,
                       heads=2, depth=1, max_len=16, n_experts=2)
-    mv = moe.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    mv = init_lm(moe)
     out = beam_search(moe, mv, prompt, max_new_tokens=3, beams=2)
     assert out.shape == (1, 7)
 
@@ -298,7 +293,7 @@ def test_init_cache_friendly_errors():
 
     m = build_model("transformer_lm", vocab_size=8, d_model=16, heads=2,
                     depth=1, max_len=8)
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     init_cache(m, v, 1, 8)  # healthy baseline
 
     del m.extra["heads"]  # build_model returns a fresh graph per call

@@ -38,36 +38,15 @@ from mmlspark_tpu.core.integrity import (
     IntegrityError,
     SnapshotCorruption,
 )
-from mmlspark_tpu.models import build_model, generate
 from mmlspark_tpu.serve import DisaggFleet, ReplicaSet, ServeEngine
 from mmlspark_tpu.testing.compile_guard import serve_compile_guard
 from mmlspark_tpu.train.demo import run_train_demo
-
-PERIOD = 4
-
-
-def _train_lm(m, steps=30, seq=16):
-    from mmlspark_tpu.testing.datagen import overfit_periodic_lm
-
-    return overfit_periodic_lm(m, steps=steps, seq=seq, period=PERIOD)
-
-
-def _tiny(**kw):
-    cfg = dict(vocab_size=8, d_model=32, heads=2, depth=2, max_len=32)
-    cfg.update(kw)
-    return build_model("transformer_lm", **cfg)
+from tests.serve_helpers import ref_tokens, trained_lm
 
 
 @pytest.fixture(scope="module")
 def lm():
-    m = _tiny()
-    v, ids = _train_lm(m)
-    return m, v, ids
-
-
-def _ref(m, v, prompt, max_new):
-    out = generate(m, v, np.asarray(prompt, np.int32)[None], max_new)
-    return np.asarray(out)[0]
+    return trained_lm()
 
 
 def _assert_parity(m, v, results, gids, prompts, max_new):
@@ -76,7 +55,7 @@ def _assert_parity(m, v, results, gids, prompts, max_new):
         res = results[gid]
         assert res.status == "completed", f"gid={gid}: {res.status}"
         np.testing.assert_array_equal(
-            np.asarray(res.tokens), _ref(m, v, p, max_new),
+            np.asarray(res.tokens), ref_tokens(m, v, p, max_new),
             err_msg=f"gid={gid}",
         )
 
@@ -391,6 +370,6 @@ def test_decode_sync_contract_holds_after_verified_restore(lm, monkeypatch):
     monkeypatch.undo()
 
     np.testing.assert_array_equal(
-        np.asarray(res.tokens), _ref(m, v, prompt, 17)
+        np.asarray(res.tokens), ref_tokens(m, v, prompt, 17)
     )
     assert syncs["n"] <= 2, f"host syncs: {syncs['n']} (> 1 per block)"

@@ -277,19 +277,19 @@ def test_moe_generate_kv_cache_matches_unpadded_oracle():
     tokens must equal the growing-unpadded-buffer oracle — a plain
     scoring forward per step, the semantics a user scores with."""
     from mmlspark_tpu.core.exceptions import FriendlyError
-    from mmlspark_tpu.models import build_model, generate
-    from mmlspark_tpu.testing.datagen import overfit_periodic_lm
+    from mmlspark_tpu.models import generate
+    from tests.serve_helpers import trained_lm
 
-    m = build_model(
-        "transformer_lm_moe", vocab_size=8, d_model=32, heads=2, depth=2,
-        max_len=32, n_experts=2, capacity_factor=2.0,  # capacity = tokens
+    m, v, ids = trained_lm(
+        "transformer_lm_moe", steps=40, n_experts=2,
+        capacity_factor=2.0,  # capacity = tokens
     )
-    v, ids = overfit_periodic_lm(m, steps=40)
     prompt = ids[:, :6]
     out = np.asarray(generate(m, v, prompt, max_new_tokens=8))
     buf = np.asarray(prompt)
+    score = jax.jit(m.apply)  # a program a length, not one an operation
     for _ in range(8):
-        lg = np.asarray(m.apply(v, jnp.asarray(buf)))
+        lg = np.asarray(score(v, jnp.asarray(buf)))
         nxt = lg[:, -1].argmax(-1).astype(np.int32)
         buf = np.concatenate([buf, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(out, buf)

@@ -41,16 +41,7 @@ from mmlspark_tpu.testing.compile_guard import (
     compile_guard,
     serve_compile_guard,
 )
-
-
-def _tiny_lm(**kw):
-    cfg = dict(vocab_size=8, d_model=32, heads=2, depth=2, max_len=32)
-    cfg.update(kw)
-    return build_model("transformer_lm", **cfg)
-
-
-def _lm_vars(m, seed=0):
-    return m.init(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
+from tests.serve_helpers import init_lm, tiny_lm
 
 
 def _mlp(num_outputs=3, hidden=(16,)):
@@ -76,9 +67,9 @@ def _prompts(n, vocab=8, seed=0):
 
 
 def test_batch_deployment_rejects_causal_graph():
-    m = _tiny_lm()
+    m = tiny_lm()
     with pytest.raises(FriendlyError, match="causal"):
-        BatchDeployment(m, _lm_vars(m))
+        BatchDeployment(m, init_lm(m))
 
 
 def test_batch_bucket_ladder():
@@ -165,8 +156,8 @@ def test_multimodel_concurrent_bit_identical(tmp_path):
     pin."""
     from mmlspark_tpu.models.onnx_export import save_onnx
 
-    lm = _tiny_lm()
-    lmv = _lm_vars(lm)
+    lm = tiny_lm()
+    lmv = init_lm(lm)
     clf, clfv = _mlp()
     onnx_path = str(tmp_path / "clf.onnx")
     save_onnx(clf, clfv, (1, 8), onnx_path)
@@ -268,10 +259,10 @@ def test_onnx_roundtrip_deployment_bit_equal(tmp_path):
 
 
 def test_submit_routing_errors():
-    lm = _tiny_lm()
+    lm = tiny_lm()
     clf, clfv = _mlp()
     eng = MultiModelEngine()
-    eng.add_lm("lm", lm, _lm_vars(lm), slots=2, cache_len=32)
+    eng.add_lm("lm", lm, init_lm(lm), slots=2, cache_len=32)
     eng.add_batch("classifier", clf, clfv, max_batch=4)
 
     # several deployments: model= is required
@@ -306,10 +297,10 @@ def test_fairness_under_saturating_lm_stream():
     """Satellite: with device_budget=1 and a saturating LM stream, the
     round-robin cursor still admits the classifier within ceil(D/B)=2
     ticks — no deployment starves behind a hot neighbour."""
-    lm = _tiny_lm()
+    lm = tiny_lm()
     clf, clfv = _mlp()
     eng = MultiModelEngine(device_budget=1)
-    eng.add_lm("lm", lm, _lm_vars(lm), slots=2, cache_len=32,
+    eng.add_lm("lm", lm, init_lm(lm), slots=2, cache_len=32,
                max_queue=32, decode_block=4)
     eng.add_batch("clf", clf, clfv, max_batch=4)
 
@@ -574,9 +565,9 @@ def test_replica_set_model_routing():
     """The supervisor's routing key grows a model dimension: replicas
     partition over the models round-robin, submit requires model= and
     routes within that model's replicas only."""
-    lm_a = _tiny_lm(depth=1)
-    lm_b = _tiny_lm(depth=2)
-    va, vb = _lm_vars(lm_a), _lm_vars(lm_b, seed=1)
+    lm_a = tiny_lm(depth=1)
+    lm_b = tiny_lm(depth=2)
+    va, vb = init_lm(lm_a), init_lm(lm_b, seed=1)
     rs = ReplicaSet(
         lm_a, va, replicas=2, slots=2, cache_len=32,
         models={"small": (lm_a, va), "big": (lm_b, vb)},

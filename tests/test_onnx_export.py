@@ -77,7 +77,7 @@ def test_save_onnx_writes_file(tmp_path, rng):
 
 def test_unsupported_family_errors():
     g = build_model("resnet20_cifar10", width=8)
-    v = g.init(jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)))
+    v = jax.jit(g.init)(jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)))
     with pytest.raises(FriendlyError, match="no ONNX exporter"):
         export_onnx(g, v, (1, 32, 32, 3))
 
@@ -91,12 +91,12 @@ def test_transformer_lm_round_trip(rng):
         "transformer_lm", vocab_size=32, d_model=16, heads=4, depth=2,
         max_len=T, attn_impl="dense",
     )
-    v = g.init(jax.random.PRNGKey(1), jnp.zeros((1, T), jnp.int32))
+    v = jax.jit(g.init)(jax.random.PRNGKey(1), jnp.zeros((1, T), jnp.int32))
     ids = rng.integers(0, 32, size=(B, T)).astype(np.int32)
-    want = np.asarray(g.apply(v, jnp.asarray(ids)))
+    want = np.asarray(jax.jit(g.apply)(v, jnp.asarray(ids)))
 
     g2 = load_onnx(export_onnx(g, v, (B, T)))
-    got = np.asarray(g2.apply(g2.init(), jnp.asarray(ids)))
+    got = np.asarray(jax.jit(g2.apply)(g2.init(), jnp.asarray(ids)))
     assert got.shape == want.shape
     np.testing.assert_allclose(got, want, rtol=5e-2, atol=5e-2)
     assert (got.argmax(-1) == want.argmax(-1)).mean() > 0.95
@@ -119,11 +119,11 @@ def test_transformer_lm_non_causal_round_trip(rng):
         "transformer_lm", vocab_size=16, d_model=8, heads=2, depth=1,
         max_len=T, causal=False, attn_impl="dense",
     )
-    v = g.init(jax.random.PRNGKey(2), jnp.zeros((1, T), jnp.int32))
+    v = jax.jit(g.init)(jax.random.PRNGKey(2), jnp.zeros((1, T), jnp.int32))
     ids = rng.integers(0, 16, size=(B, T)).astype(np.int32)
-    want = np.asarray(g.apply(v, jnp.asarray(ids)))
+    want = np.asarray(jax.jit(g.apply)(v, jnp.asarray(ids)))
     g2 = load_onnx(export_onnx(g, v, (B, T)))
-    got = np.asarray(g2.apply(g2.init(), jnp.asarray(ids)))
+    got = np.asarray(jax.jit(g2.apply)(g2.init(), jnp.asarray(ids)))
     np.testing.assert_allclose(got, want, rtol=5e-2, atol=5e-2)
 
 
@@ -137,11 +137,11 @@ def test_transformer_lm_rope_round_trip(rng):
         "transformer_lm", vocab_size=32, d_model=16, heads=4, depth=2,
         max_len=T, attn_impl="dense", pos_embedding="rope",
     )
-    v = g.init(jax.random.PRNGKey(3), jnp.zeros((1, T), jnp.int32))
+    v = jax.jit(g.init)(jax.random.PRNGKey(3), jnp.zeros((1, T), jnp.int32))
     ids = rng.integers(0, 32, size=(B, T)).astype(np.int32)
-    want = np.asarray(g.apply(v, jnp.asarray(ids)))
+    want = np.asarray(jax.jit(g.apply)(v, jnp.asarray(ids)))
     g2 = load_onnx(export_onnx(g, v, (B, T)))
-    got = np.asarray(g2.apply(g2.init(), jnp.asarray(ids)))
+    got = np.asarray(jax.jit(g2.apply)(g2.init(), jnp.asarray(ids)))
     np.testing.assert_allclose(got, want, rtol=5e-2, atol=5e-2)
     assert (got.argmax(-1) == want.argmax(-1)).mean() > 0.95
 
@@ -158,11 +158,11 @@ def test_transformer_lm_window_round_trip(rng):
             depth=1, max_len=T, attn_impl="dense", window=W,
             pos_embedding=pos_mode,
         )
-        v = g.init(jax.random.PRNGKey(5), jnp.zeros((1, T), jnp.int32))
+        v = jax.jit(g.init)(jax.random.PRNGKey(5), jnp.zeros((1, T), jnp.int32))
         ids = rng.integers(0, 32, size=(B, T)).astype(np.int32)
-        want = np.asarray(g.apply(v, jnp.asarray(ids)))
+        want = np.asarray(jax.jit(g.apply)(v, jnp.asarray(ids)))
         g2 = load_onnx(export_onnx(g, v, (B, T)))
-        got = np.asarray(g2.apply(g2.init(), jnp.asarray(ids)))
+        got = np.asarray(jax.jit(g2.apply)(g2.init(), jnp.asarray(ids)))
         np.testing.assert_allclose(got, want, rtol=5e-2, atol=5e-2,
                                    err_msg=pos_mode)
         # allclose above is the mask-correctness gate (a dropped window
@@ -182,10 +182,10 @@ def test_transformer_lm_gqa_round_trip(rng):
         "transformer_lm", vocab_size=32, d_model=16, heads=4, depth=2,
         max_len=T, attn_impl="dense", kv_heads=2, pos_embedding="rope",
     )
-    v = g.init(jax.random.PRNGKey(6), jnp.zeros((1, T), jnp.int32))
+    v = jax.jit(g.init)(jax.random.PRNGKey(6), jnp.zeros((1, T), jnp.int32))
     ids = rng.integers(0, 32, size=(B, T)).astype(np.int32)
-    want = np.asarray(g.apply(v, jnp.asarray(ids)))
+    want = np.asarray(jax.jit(g.apply)(v, jnp.asarray(ids)))
     g2 = load_onnx(export_onnx(g, v, (B, T)))
-    got = np.asarray(g2.apply(g2.init(), jnp.asarray(ids)))
+    got = np.asarray(jax.jit(g2.apply)(g2.init(), jnp.asarray(ids)))
     np.testing.assert_allclose(got, want, rtol=5e-2, atol=5e-2)
     assert (got.argmax(-1) == want.argmax(-1)).mean() > 0.85

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 
 from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.core.faults import Fault, FaultInjector, ResourceExhausted
-from mmlspark_tpu.models import build_model, generate
 from mmlspark_tpu.parallel.mesh import make_mesh
 from mmlspark_tpu.serve import ServeEngine
 from mmlspark_tpu.serve.paging import (
@@ -32,35 +31,14 @@ from mmlspark_tpu.serve.paging import (
     default_page_size,
 )
 from mmlspark_tpu.testing.compile_guard import serve_compile_guard
-
-PERIOD = 4
+from tests.serve_helpers import ref_tokens, trained_lm
 
 TERMINAL = {"completed", "expired", "failed", "stalled"}
 
 
-def _train_lm(m, steps=30, seq=16):
-    from mmlspark_tpu.testing.datagen import overfit_periodic_lm
-
-    return overfit_periodic_lm(m, steps=steps, seq=seq, period=PERIOD)
-
-
-def _tiny(**kw):
-    cfg = dict(vocab_size=8, d_model=32, heads=2, depth=2, max_len=32)
-    cfg.update(kw)
-    return build_model("transformer_lm", **cfg)
-
-
 @pytest.fixture(scope="module")
 def lm():
-    m = _tiny()
-    v, ids = _train_lm(m)
-    return m, v, ids
-
-
-def _ref(m, v, prompt, max_new, eos_id=None):
-    out = generate(m, v, np.asarray(prompt, np.int32)[None], max_new,
-                   eos_id=eos_id)
-    return np.asarray(out)[0]
+    return trained_lm()
 
 
 def _pool(m, v, **kw):
@@ -348,7 +326,7 @@ def test_paged_parity_ragged_prompts_and_joins(lm):
         results.update(engine.run())
     for rid, p in zip(rids, prompts):
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, 4),
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, 4),
             err_msg=f"request={rid}")
     assert engine.decode_compile_count <= engine.num_decode_blocks
     assert engine.prefill_compile_count <= engine.num_prefill_buckets
@@ -365,9 +343,9 @@ def test_mid_block_eos_paged(lm):
     ``generate()`` with the same eos_id byte for byte."""
     m, v, ids = lm
     prompt = np.asarray(ids[0, :3])
-    free_run = _ref(m, v, prompt, 12)
+    free_run = ref_tokens(m, v, prompt, 12)
     eos = int(free_run[len(prompt) + 2])
-    want = _ref(m, v, prompt, 12, eos_id=eos)
+    want = ref_tokens(m, v, prompt, 12, eos_id=eos)
     stop = len(prompt) + int(np.argmax(want[len(prompt):] == eos))
     engine = ServeEngine(m, v, slots=2, cache_len=32, max_queue=4,
                          decode_block=8, paged=True)
@@ -402,11 +380,11 @@ def test_prefix_cache_hit_and_copy_on_extend(lm):
     ra2 = engine.submit(a, max_new_tokens=6)
     results.update(engine.run())
     np.testing.assert_array_equal(
-        np.asarray(results[ra].tokens), _ref(m, v, a, 6))
+        np.asarray(results[ra].tokens), ref_tokens(m, v, a, 6))
     np.testing.assert_array_equal(
-        np.asarray(results[rb].tokens), _ref(m, v, b, 6))
+        np.asarray(results[rb].tokens), ref_tokens(m, v, b, 6))
     np.testing.assert_array_equal(
-        np.asarray(results[ra2].tokens), _ref(m, v, a, 6))
+        np.asarray(results[ra2].tokens), ref_tokens(m, v, a, 6))
     stats = engine.pool.paging_stats()
     assert stats["prefix_cache_hits_total"] == 2
     assert stats["cow_copies_total"] >= 1  # b's writes entered page 1
@@ -432,7 +410,7 @@ def test_prefix_shared_header_prefills_once(lm):
     results = engine.run()
     for rid, p in zip(rids, prompts):
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, 4),
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, 4),
             err_msg=f"request={rid}")
     assert engine.pool.prefix_hits >= len(prompts) - 1
     assert engine.metrics.to_dict()["prefix_cache_hits_total"] >= 3
@@ -460,7 +438,7 @@ def test_page_pressure_degrades_and_still_completes(lm):
     for rid, p in zip(rids, prompts):
         assert results[rid].status == "completed"
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, 8),
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, 8),
             err_msg=f"request={rid}")
     d = engine.metrics.to_dict()
     assert d["preemptions_total"] + d["degraded_mode"] >= 1
@@ -482,7 +460,7 @@ def test_quarantine_returns_pages(lm):
     assert engine.metrics.quarantined_total == 1
     for rid, p in zip(rids[1:], prompts[1:]):
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, 4))
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, 4))
     assert engine.pool.pages_free == engine.pool.pages_allocatable
     assert sum(engine.pool.snapshot()["refcounts"]) == 0
 
@@ -518,7 +496,7 @@ def test_snapshot_restore_roundtrip_paged(lm):
     by_id = {r: res for r, res in results.items()}
     for rid, p in zip(rids, prompts):
         np.testing.assert_array_equal(
-            np.asarray(by_id[rid].tokens), _ref(m, v, p, 6),
+            np.asarray(by_id[rid].tokens), ref_tokens(m, v, p, 6),
             err_msg=f"request={rid}")
     # drained up to the pages the prefix entries deliberately pin
     assert (rebuilt.pool.pages_free

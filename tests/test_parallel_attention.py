@@ -93,8 +93,8 @@ def test_ring_gqa_gradients_match_dense(rng):
     def loss_dense(q, k, v):
         return jnp.sum(dense_attention(q, k, v, causal=True) ** 2)
 
-    gr = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
+    gd = jax.jit(jax.grad(loss_dense, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gr, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-4, rtol=1e-4)
@@ -134,8 +134,8 @@ def test_ring_gradients_match_dense(rng):
     def loss_ring(q, k, v):
         return jnp.sum(ring_attention(q, k, v, mesh, causal=True) ** 2)
 
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
+    gd = jax.jit(jax.grad(loss_dense, argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gd, gr):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    atol=1e-4, rtol=1e-4)
@@ -161,8 +161,8 @@ def test_transformer_impls_agree(rng):
             "transformer_lm", vocab_size=64, d_model=32, heads=4, depth=2,
             max_len=16, attn_impl=impl, mesh=None if impl == "dense" else mesh,
         )
-        variables = graph.init(jax.random.PRNGKey(0), ids)
-        outs[impl] = np.asarray(graph.apply(variables, ids))
+        variables = jax.jit(graph.init)(jax.random.PRNGKey(0), ids)
+        outs[impl] = np.asarray(jax.jit(graph.apply)(variables, ids))
     # same params (same init seed), same math -> same logits
     np.testing.assert_allclose(outs["ring"], outs["dense"], atol=2e-2,
                                rtol=2e-2)

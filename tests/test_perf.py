@@ -35,35 +35,14 @@ from mmlspark_tpu.core.telemetry import (
     MetricRegistry,
     SpanTracer,
 )
-from mmlspark_tpu.models import build_model, generate
 from mmlspark_tpu.serve import ServeEngine
 from mmlspark_tpu.testing.compile_guard import serve_compile_guard
-
-PERIOD = 4
-
-
-def _tiny(**kw):
-    cfg = dict(vocab_size=8, d_model=32, heads=2, depth=2, max_len=32)
-    cfg.update(kw)
-    return build_model("transformer_lm", **cfg)
-
-
-def _train_lm(m, steps=30, seq=16):
-    from mmlspark_tpu.testing.datagen import overfit_periodic_lm
-
-    return overfit_periodic_lm(m, steps=steps, seq=seq, period=PERIOD)
+from tests.serve_helpers import ref_tokens, trained_lm
 
 
 @pytest.fixture(scope="module")
 def lm():
-    m = _tiny()
-    v, ids = _train_lm(m)
-    return m, v, ids
-
-
-def _ref(m, v, prompt, max_new):
-    out = generate(m, v, np.asarray(prompt, np.int32)[None], max_new)
-    return np.asarray(out)[0]
+    return trained_lm()
 
 
 # -- cost analysis: real programs and the unavailable fallback -------------
@@ -447,7 +426,7 @@ def test_analytics_keep_sync_and_compile_contracts(lm, monkeypatch):
         monkeypatch.undo()
 
     np.testing.assert_array_equal(
-        np.asarray(res.tokens), _ref(m, v, prompt, 17)
+        np.asarray(res.tokens), ref_tokens(m, v, prompt, 17)
     )
     assert syncs["n"] <= 2, f"host syncs: {syncs['n']} (> 1 per block)"
 
@@ -496,7 +475,7 @@ def test_analytics_keep_contracts_sharded(lm, monkeypatch):
         monkeypatch.undo()
 
     np.testing.assert_array_equal(
-        np.asarray(res.tokens), _ref(m, v, prompt, 9)
+        np.asarray(res.tokens), ref_tokens(m, v, prompt, 9)
     )
     assert syncs["n"] <= 2, f"host syncs: {syncs['n']} (> 1 per block)"
     fams = engine.metrics.to_dict()["perf_families"]

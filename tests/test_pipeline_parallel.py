@@ -103,13 +103,15 @@ def test_pipelined_lm_forward_matches_stage_loop():
     )
     ids = np.random.default_rng(0).integers(0, 32, size=(8, 8))
     ids = jnp.asarray(ids, jnp.int32)
-    variables = graph.init(jax.random.PRNGKey(0), ids[:1])
-    out = graph.apply(variables, ids)
+    # under jit: eager, init and apply compile a program an operation
+    variables = jax.jit(graph.init)(jax.random.PRNGKey(0), ids[:1])
+    apply = jax.jit(graph.apply)
+    out = apply(variables, ids)
     assert out.shape == (8, 8, 32)
 
     # reference: run the same stages sequentially (batch of 1 triggers the
     # non-pipelined fallback path inside apply)
-    outs = [graph.apply(variables, ids[i : i + 1]) for i in range(8)]
+    outs = [apply(variables, ids[i : i + 1]) for i in range(8)]
     want = jnp.concatenate(outs, axis=0)
     # bfloat16 compute: batched vs batch-1 runs fuse differently
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -125,10 +127,14 @@ def test_pipelined_lm_output_node():
         depth=2, max_len=4, mesh=mesh,
     )
     ids = jnp.zeros((2, 4), jnp.int32)
-    variables = graph.init(jax.random.PRNGKey(0), ids[:1])
-    trunk = graph.apply(variables, ids, output_node="stages")
+    variables = jax.jit(graph.init)(jax.random.PRNGKey(0), ids[:1])
+
+    def upto(node):
+        return jax.jit(lambda v, x: graph.apply(v, x, output_node=node))
+
+    trunk = upto("stages")(variables, ids)
     assert trunk.shape == (2, 4, 8)  # d_model features, not logits
-    emb = graph.apply(variables, ids, output_node="embed")
+    emb = upto("embed")(variables, ids)
     assert emb.shape == (2, 4, 8)
     with pytest.raises(FriendlyError):
         graph.apply(variables, ids, output_node="stage")  # typo must raise

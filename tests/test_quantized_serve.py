@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 
 from mmlspark_tpu.core.exceptions import FriendlyError
-from mmlspark_tpu.models import build_model
 from mmlspark_tpu.ops.flash_attention import flash_decode, paged_flash_decode
 from mmlspark_tpu.ops.kv_cache import (
     kv_head_scales,
@@ -35,8 +34,7 @@ from mmlspark_tpu.serve import ServeEngine
 from mmlspark_tpu.serve.cache_pool import SlotCachePool
 from mmlspark_tpu.serve.paging import PagedCachePool
 from mmlspark_tpu.testing.compile_guard import serve_compile_guard
-
-PERIOD = 4
+from tests.serve_helpers import init_lm, tiny_lm, trained_lm
 
 #: accepted greedy-stream divergence vs the bf16 oracle at smoke scale:
 #: one int8 rounding flip near an argmax tie cascades for the rest of
@@ -45,18 +43,12 @@ PERIOD = 4
 FLIP_BUDGET = 0.25
 
 
-def _tiny(**kw):
-    cfg = dict(vocab_size=8, d_model=32, heads=2, depth=2, max_len=32)
-    cfg.update(kw)
-    return build_model("transformer_lm", **cfg)
-
-
 @pytest.fixture(scope="module")
 def raw_lm():
     """Random-init model — enough for pool/accounting/validation
     tests, which never compare token streams."""
-    m = _tiny()
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    m = tiny_lm()
+    v = init_lm(m)
     return m, v
 
 
@@ -64,11 +56,7 @@ def raw_lm():
 def lm():
     """Trained model for the parity soaks: confident logits make the
     flip budget meaningful instead of measuring argmax ties."""
-    from mmlspark_tpu.testing.datagen import overfit_periodic_lm
-
-    m = _tiny()
-    v, ids = overfit_periodic_lm(m, steps=30, seq=16, period=PERIOD)
-    return m, v, ids
+    return trained_lm()
 
 
 def _flip_rate(streams_a: dict, streams_b: dict) -> float:

@@ -16,14 +16,12 @@ import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 
 from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.core.metrics_contracts import MetricData
-from mmlspark_tpu.models import generate
 from mmlspark_tpu.serve import ServeEngine
 from mmlspark_tpu.testing.compile_guard import compile_guard
-from tests.serve_helpers import tiny_lm, train_lm
+from tests.serve_helpers import init_lm, ref_tokens, tiny_lm, trained_lm
 
 
 # -- token parity (the acceptance test) ------------------------------------
@@ -38,11 +36,10 @@ def test_staggered_arrivals_match_generate(config):
     be byte-identical to a single-request ``generate()`` call, and the
     fused decode step must have compiled exactly once — requests joining
     and leaving mid-flight never retrace it."""
-    m = tiny_lm(**config)
-    v, ids = train_lm(m)
+    m, v, ids = trained_lm(**config)
     prompts = [np.asarray(ids[0, :n]) for n in (4, 6, 7)]
     want = {
-        i: np.asarray(generate(m, v, p[None], max_new_tokens=8))[0]
+        i: ref_tokens(m, v, p, 8)
         for i, p in enumerate(prompts)
     }
 
@@ -72,14 +69,13 @@ def test_more_requests_than_slots_still_match():
     """Queue pressure: 4 requests through 1 slot — pure sequential
     reuse of the same slot buffers (stale K/V from the previous tenant
     must be invisible)."""
-    m = tiny_lm()
-    v, ids = train_lm(m)
+    m, v, ids = trained_lm()
     prompts = [np.asarray(ids[0, :n]) for n in (4, 5, 6, 8)]
     engine = ServeEngine(m, v, slots=1, cache_len=32, max_queue=4)
     rids = [engine.submit(p, max_new_tokens=6) for p in prompts]
     results = engine.run()
     for rid, p in zip(rids, prompts):
-        want = np.asarray(generate(m, v, p[None], max_new_tokens=6))[0]
+        want = ref_tokens(m, v, p, 6)
         np.testing.assert_array_equal(np.asarray(results[rid].tokens), want)
     # distinct XLA programs, one per ladder block size actually run —
     # never one per token or per scan iteration
@@ -87,10 +83,9 @@ def test_more_requests_than_slots_still_match():
 
 
 def test_eos_retires_early():
-    m = tiny_lm()
-    v, ids = train_lm(m)
+    m, v, ids = trained_lm()
     prompt = np.asarray(ids[0, :4])
-    ref = np.asarray(generate(m, v, prompt[None], max_new_tokens=8))[0]
+    ref = ref_tokens(m, v, prompt, 8)
     eos = int(ref[5])  # the 2nd generated token, by construction
     engine = ServeEngine(m, v, slots=2, cache_len=32)
     rid = engine.submit(prompt, max_new_tokens=8, eos_id=eos)
@@ -106,8 +101,7 @@ def test_deadline_expiry_in_queue():
     """With 1 slot busy on a long request, a queued request whose
     deadline passes expires WITHOUT ever being admitted (no prefill, no
     tokens) — deterministic in ticks."""
-    m = tiny_lm()
-    v, ids = train_lm(m, steps=5)
+    m, v, ids = trained_lm()
     engine = ServeEngine(m, v, slots=1, cache_len=32, max_queue=2)
     rid_a = engine.submit(np.asarray(ids[0, :4]), max_new_tokens=10)
     rid_b = engine.submit(np.asarray(ids[0, :5]), max_new_tokens=4,
@@ -125,8 +119,7 @@ def test_run_max_ticks_attaches_partial_results():
     work: the raised FriendlyError carries ``err.results`` with every
     completed request plus the pending ones retired as ``"stalled"``,
     and the engine is left drained (not busy, pool empty)."""
-    m = tiny_lm()
-    v, ids = train_lm(m, steps=5)
+    m, v, ids = trained_lm()
     engine = ServeEngine(m, v, slots=1, cache_len=32, max_queue=4,
                          decode_block=1)
     rid_short = engine.submit(np.asarray(ids[0, :4]), max_new_tokens=2)
@@ -150,12 +143,11 @@ def test_expire_active_slot_forces_device_state_dead():
     """Expiring an ACTIVE request must kill its device-side row — live
     mask False, position 0 — immediately, so the fused decode spends no
     flash-decode KV traffic on a corpse and the slot is re-leasable."""
-    m = tiny_lm()
-    v, ids = train_lm(m, steps=5)
+    m, v, ids = trained_lm()
     engine = ServeEngine(m, v, slots=2, cache_len=32, max_queue=4,
                          decode_block=1)
     prompt_b = np.asarray(ids[0, :5])
-    ref_b = generate(m, v, prompt_b[None], 10)[0]
+    ref_b = ref_tokens(m, v, prompt_b, 10)
     rid_a = engine.submit(np.asarray(ids[0, :4]), max_new_tokens=12,
                           deadline_ticks=2)
     rid_b = engine.submit(prompt_b, max_new_tokens=10)
@@ -184,12 +176,11 @@ def test_expired_slot_releases_same_tick():
     """The slot freed by an active-request expiry is safe to re-lease
     in the SAME tick: the replacement prefills into it immediately and
     its stream matches ``generate()`` (no stale KV bleed-through)."""
-    m = tiny_lm()
-    v, ids = train_lm(m, steps=5)
+    m, v, ids = trained_lm()
     engine = ServeEngine(m, v, slots=1, cache_len=32, max_queue=4,
                          decode_block=1)
     prompt_b = np.asarray(ids[0, :5])
-    ref_b = generate(m, v, prompt_b[None], 6)[0]
+    ref_b = ref_tokens(m, v, prompt_b, 6)
     rid_a = engine.submit(np.asarray(ids[0, :4]), max_new_tokens=12,
                           deadline_ticks=2)
     rid_b = engine.submit(prompt_b, max_new_tokens=6)  # waits for the slot
@@ -204,7 +195,7 @@ def test_expired_slot_releases_same_tick():
 
 def test_queue_full_raises_typed_error():
     m = tiny_lm()
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     engine = ServeEngine(m, v, slots=1, cache_len=32, max_queue=2)
     engine.submit(np.ones(4, np.int32), max_new_tokens=2)
     engine.submit(np.ones(4, np.int32), max_new_tokens=2)
@@ -216,7 +207,7 @@ def test_queue_full_raises_typed_error():
 
 def test_submit_validation():
     m = tiny_lm()
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     engine = ServeEngine(m, v, slots=1, cache_len=16)
     with pytest.raises(FriendlyError, match="1-D"):
         engine.submit(np.ones((2, 4), np.int32), max_new_tokens=2)
@@ -246,14 +237,14 @@ def test_submit_validation():
 
 def test_engine_build_guards():
     m = tiny_lm()
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     # learned position table bounds cache_len
     with pytest.raises(FriendlyError, match="position table"):
         ServeEngine(m, v, cache_len=64)
     # sliding-window models roll their cache; the linear slot pool
     # refuses rather than silently mis-serving long requests
     mw = tiny_lm(window=6)
-    vw = mw.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    vw = init_lm(mw)
     with pytest.raises(FriendlyError, match="window"):
         ServeEngine(mw, vw, cache_len=32)
     ServeEngine(mw, vw, cache_len=6)  # cache_len <= window is fine
@@ -263,8 +254,7 @@ def test_engine_build_guards():
 
 
 def test_metrics_dict_and_snapshot():
-    m = tiny_lm()
-    v, ids = train_lm(m, steps=5)
+    m, v, ids = trained_lm()
     engine = ServeEngine(m, v, slots=2, cache_len=32)
     engine.submit(np.asarray(ids[0, :4]), max_new_tokens=3)
     engine.submit(np.asarray(ids[0, :6]), max_new_tokens=3)

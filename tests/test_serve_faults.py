@@ -36,37 +36,16 @@ from mmlspark_tpu.core.faults import (
     is_transient,
     parse_fault_spec,
 )
-from mmlspark_tpu.models import build_model, generate
 from mmlspark_tpu.serve import ServeEngine
 from mmlspark_tpu.testing.compile_guard import serve_compile_guard
-
-PERIOD = 4
+from tests.serve_helpers import ref_tokens, trained_lm
 
 TERMINAL = {"completed", "expired", "failed", "stalled"}
 
 
-def _train_lm(m, steps=30, seq=16):
-    from mmlspark_tpu.testing.datagen import overfit_periodic_lm
-
-    return overfit_periodic_lm(m, steps=steps, seq=seq, period=PERIOD)
-
-
-def _tiny(**kw):
-    cfg = dict(vocab_size=8, d_model=32, heads=2, depth=2, max_len=32)
-    cfg.update(kw)
-    return build_model("transformer_lm", **cfg)
-
-
 @pytest.fixture(scope="module")
 def lm():
-    m = _tiny()
-    v, ids = _train_lm(m)
-    return m, v, ids
-
-
-def _ref(m, v, prompt, max_new):
-    out = generate(m, v, np.asarray(prompt, np.int32)[None], max_new)
-    return np.asarray(out)[0]
+    return trained_lm()
 
 
 # -- injector unit tests (pure host, no engine) ----------------------------
@@ -171,7 +150,7 @@ def test_transient_faults_retry_transparently(lm):
     for rid, p in zip(rids, prompts):
         assert results[rid].status == "completed"
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, 6)
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, 6)
         )
     assert engine.metrics.retries_total == 4
     assert engine.metrics.faults_injected_total == 4
@@ -190,7 +169,7 @@ def test_stall_fault_slows_but_never_fails(lm):
     res = engine.run()[rid]
     assert res.status == "completed"
     np.testing.assert_array_equal(
-        np.asarray(res.tokens), _ref(m, v, prompt, 6)
+        np.asarray(res.tokens), ref_tokens(m, v, prompt, 6)
     )
     assert inj.counts.get("stall") == 2
     assert engine.metrics.retries_total == 0  # a stall is not an error
@@ -215,7 +194,7 @@ def test_prefill_fault_beyond_retries_quarantines_one_request(lm):
     for rid, n in ((rids[0], 4), (rids[2], 6)):
         assert results[rid].status == "completed"
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, row[:n], 5)
+            np.asarray(results[rid].tokens), ref_tokens(m, v, row[:n], 5)
         )
     assert engine.metrics.quarantined_total == 1
     assert engine.metrics.failed == 1
@@ -236,7 +215,7 @@ def test_prefill_poison_quarantines_before_results(lm):
     assert results[rid_bad].generated == 0  # the poison never landed
     assert results[rid_ok].status == "completed"
     np.testing.assert_array_equal(
-        np.asarray(results[rid_ok].tokens), _ref(m, v, row[:5], 5)
+        np.asarray(results[rid_ok].tokens), ref_tokens(m, v, row[:5], 5)
     )
     assert engine.metrics.quarantined_total == 1
 
@@ -265,14 +244,14 @@ def test_decode_poison_quarantines_only_that_row(lm):
         else:
             assert res.status == "completed"
             np.testing.assert_array_equal(
-                np.asarray(res.tokens), _ref(m, v, p, 8)
+                np.asarray(res.tokens), ref_tokens(m, v, p, 8)
             )
     # the quarantined slot is re-leasable: fresh traffic completes
     rid2 = engine.submit(row[:4], max_new_tokens=4)
     res2 = engine.run()[rid2]
     assert res2.status == "completed"
     np.testing.assert_array_equal(
-        np.asarray(res2.tokens), _ref(m, v, row[:4], 4)
+        np.asarray(res2.tokens), ref_tokens(m, v, row[:4], 4)
     )
 
 
@@ -293,7 +272,7 @@ def test_oom_steps_down_ladder_and_recovers(lm):
     for rid, n in zip(rids, (4, 5)):
         assert results[rid].status == "completed"
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, row[:n], 20)
+            np.asarray(results[rid].tokens), ref_tokens(m, v, row[:n], 20)
         )
     # two OOMs walked the cap 8 -> 4 -> 2: the degraded dispatch ran a
     # SMALLER ladder size (already compiled — that is the whole point),
@@ -323,7 +302,7 @@ def test_oom_at_ladder_floor_preempts_and_resumes(lm):
     for rid, n in ((rid_a, 4), (rid_b, 5)):
         assert results[rid].status == "completed"
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, row[:n], 6)
+            np.asarray(results[rid].tokens), ref_tokens(m, v, row[:n], 6)
         )
     assert not engine.degraded  # admission cap re-escalated
 
@@ -332,6 +311,10 @@ def test_oom_at_ladder_floor_preempts_and_resumes(lm):
 
 
 def test_crash_drill_restore_is_bit_identical(lm):
+    """Four requests over two slots, killed at tick 2. With two requests
+    nothing waits in the queue when the engine dies, and the snapshot's
+    ``queued`` half (re-admission after ``restore``) is never read; with
+    half the budget every stream ends before the kill. So the size stays."""
     m, v, ids = lm
     row = np.asarray(ids[0])
     prompts = [row[:4], row[:5], row[:6], row[:3]]
@@ -356,7 +339,7 @@ def test_crash_drill_restore_is_bit_identical(lm):
     for rid, p in zip(rids, prompts):
         assert results[rid].status == "completed"
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, 8),
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, 8),
             err_msg=f"request {rid} diverged across the crash",
         )
     # new requests on the restored engine get FRESH ids
@@ -385,6 +368,12 @@ def test_restore_guards(lm):
 
 
 def _chaos_soak(m, v, ids, seed, mesh=None):
+    """Eight requests under four kinds of fault drawn at every hook. At
+    half the requests a seed fires its hooks half as often, and at these
+    rates (0.02 to 0.08 a firing) some seed then draws no ``oom`` or no
+    ``poison`` at all: the ladder's step down and the quarantine would go
+    unwalked under fire. So the size stays; what it costs is one engine's
+    programs and a reference program a prompt length (``ref_tokens``)."""
     row = np.asarray(ids[0])
     rng = np.random.default_rng(seed)
     lengths = rng.integers(2, 9, size=8)
@@ -420,7 +409,7 @@ def _chaos_soak(m, v, ids, seed, mesh=None):
             n_completed += 1
             # unfaulted (and resumed) requests stay token-identical
             np.testing.assert_array_equal(
-                np.asarray(res.tokens), _ref(m, v, p, int(n)),
+                np.asarray(res.tokens), ref_tokens(m, v, p, int(n)),
                 err_msg=f"seed={seed} mesh={mesh} request={rid}",
             )
     assert n_completed >= 1  # the engine kept serving under fire
@@ -463,7 +452,7 @@ def test_disabled_injection_compiles_same_program_set(lm):
         results = engine.run()
     for rid, n in zip(rids, (4, 6)):
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, row[:n], 6)
+            np.asarray(results[rid].tokens), ref_tokens(m, v, row[:n], 6)
         )
     assert engine.metrics.retries_total == 0
     assert engine.metrics.faults_injected_total == 0

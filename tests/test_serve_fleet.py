@@ -34,7 +34,6 @@ from mmlspark_tpu.core.faults import (
     FaultInjector,
     parse_fault_spec,
 )
-from mmlspark_tpu.models import build_model, generate
 from mmlspark_tpu.serve import (
     AutoscalePolicy,
     DisaggFleet,
@@ -43,32 +42,12 @@ from mmlspark_tpu.serve import (
     parse_autoscale_spec,
 )
 from mmlspark_tpu.testing.compile_guard import serve_compile_guard
-
-PERIOD = 4
-
-
-def _train_lm(m, steps=30, seq=16):
-    from mmlspark_tpu.testing.datagen import overfit_periodic_lm
-
-    return overfit_periodic_lm(m, steps=steps, seq=seq, period=PERIOD)
-
-
-def _tiny(**kw):
-    cfg = dict(vocab_size=8, d_model=32, heads=2, depth=2, max_len=32)
-    cfg.update(kw)
-    return build_model("transformer_lm", **cfg)
+from tests.serve_helpers import ref_tokens, trained_lm
 
 
 @pytest.fixture(scope="module")
 def lm():
-    m = _tiny()
-    v, ids = _train_lm(m)
-    return m, v, ids
-
-
-def _ref(m, v, prompt, max_new):
-    out = generate(m, v, np.asarray(prompt, np.int32)[None], max_new)
-    return np.asarray(out)[0]
+    return trained_lm()
 
 
 def _assert_parity(m, v, results, gids, prompts, max_new):
@@ -77,7 +56,7 @@ def _assert_parity(m, v, results, gids, prompts, max_new):
         res = results[gid]
         assert res.status == "completed", f"gid={gid}: {res.status}"
         np.testing.assert_array_equal(
-            np.asarray(res.tokens), _ref(m, v, p, max_new),
+            np.asarray(res.tokens), ref_tokens(m, v, p, max_new),
             err_msg=f"gid={gid}",
         )
 
@@ -228,7 +207,7 @@ def test_fleet_prefix_index_cross_replica_hit(lm):
     assert fleet.fleet_prefill_tokens_saved_total == 2 * len(p)
     # the prefill replica never saw the repeats
     assert fleet.engine(0).metrics.submitted == prefills_before
-    oracle = _ref(m, v, p, 8)
+    oracle = ref_tokens(m, v, p, 8)
     for gid, results in ((g0, r0), (g1, res), (g2, res)):
         np.testing.assert_array_equal(
             np.asarray(results[gid].tokens), oracle, err_msg=f"{gid}")
@@ -328,7 +307,7 @@ def test_decode_replica_kill_failover_bit_identical(lm):
     for gid, p, b in zip(gids, prompts, budgets):
         assert results[gid].status == "completed"
         np.testing.assert_array_equal(
-            np.asarray(results[gid].tokens), _ref(m, v, p, b),
+            np.asarray(results[gid].tokens), ref_tokens(m, v, p, b),
             err_msg=f"gid={gid}",
         )
     assert fleet.replica_state(1) in ("healthy", "degraded")
@@ -417,7 +396,7 @@ def test_autoscaler_scales_up_under_burst_and_drains_back(lm):
     for gid, p in zip(gids, prompts):
         assert results[gid].status == "completed"
         np.testing.assert_array_equal(
-            np.asarray(results[gid].tokens), _ref(m, v, p, 8))
+            np.asarray(results[gid].tokens), ref_tokens(m, v, p, 8))
     # idle fleet shrinks back to the baseline floor
     for _ in range(12):
         fleet.step()
@@ -552,7 +531,7 @@ def _hedge_prefix_drill(m, v, ids, mesh=None):
     results = rs.run()
     assert rs.hedges_total == 1
     np.testing.assert_array_equal(
-        np.asarray(results[gid].tokens), _ref(m, v, p, 12))
+        np.asarray(results[gid].tokens), ref_tokens(m, v, p, 12))
     for i in range(2):
         pool = rs.engine(i).pool
         total, mapped = pool.refcount_audit()
@@ -563,7 +542,7 @@ def _hedge_prefix_drill(m, v, ids, mesh=None):
     g2 = rs.submit(p, 12)
     res2 = rs.run()
     np.testing.assert_array_equal(
-        np.asarray(res2[g2].tokens), _ref(m, v, p, 12))
+        np.asarray(res2[g2].tokens), ref_tokens(m, v, p, 12))
     for i in range(2):
         total, mapped = rs.engine(i).pool.refcount_audit()
         assert total == mapped

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 
 from mmlspark_tpu.core.exceptions import FriendlyError
-from mmlspark_tpu.models import build_model, generate
 from mmlspark_tpu.ops.kv_cache import (
     KV_SCALE_MARGIN,
     HeadMajorKV,
@@ -23,7 +22,13 @@ from mmlspark_tpu.ops.kv_cache import (
 )
 from mmlspark_tpu.serve import ServeEngine, SlotCachePool
 from mmlspark_tpu.testing.compile_guard import jit_cache_size
-from tests.serve_helpers import tiny_lm, train_lm
+from tests.serve_helpers import (
+    TINY,
+    init_lm,
+    ref_tokens,
+    tiny_lm,
+    trained_lm,
+)
 
 
 # -- slot pool -------------------------------------------------------------
@@ -31,7 +36,7 @@ from tests.serve_helpers import tiny_lm, train_lm
 
 def test_slot_pool_lease_free_accounting():
     m = tiny_lm()
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     pool = SlotCachePool(m, v, slots=3, cache_len=16)
     assert pool.free_count == 3 and pool.leased_count == 0
     assert pool.utilization == 0.0
@@ -59,7 +64,7 @@ def test_slot_pool_lease_free_accounting():
 
 def test_slot_pool_guards():
     m = tiny_lm()
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     with pytest.raises(FriendlyError, match="slots"):
         SlotCachePool(m, v, slots=0, cache_len=16)
     with pytest.raises(FriendlyError, match="cache_len"):
@@ -74,7 +79,7 @@ def _random_pool(kv_dtype, slots, cache_len, seed=0, **model):
     must leave alone is told from one it never touched, with all but
     one slot leased."""
     m = tiny_lm(max_len=64, **model)
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     pool = SlotCachePool(m, v, slots=slots, cache_len=cache_len,
                          kv_dtype=kv_dtype)
     rng = np.random.default_rng(seed)
@@ -216,7 +221,7 @@ def test_write_prefill_compiles_one_program_a_source_shape():
     shape one more. The geometry is this test's own, so nothing an
     earlier test compiled can stand in for either."""
     m = tiny_lm(d_model=48, heads=3, max_len=64)
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     pool = SlotCachePool(m, v, slots=4, cache_len=40)
     for _ in range(4):
         pool.lease()
@@ -240,7 +245,7 @@ def test_pool_write_compiles_once_a_prefill_bucket():
     of one prefill bucket only the first may report a compile, whatever
     the prompts' lengths."""
     m = tiny_lm(d_model=48, heads=3, max_len=64)
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     engine = ServeEngine(m, v, slots=3, cache_len=48)
     rng = np.random.default_rng(0)
     lengths = (9, 13, 16, 11, 20, 31, 10, 27, 17)
@@ -277,17 +282,18 @@ def test_head_major_pool_serves_generates_tokens(config, packed):
     two to a row of 128 lanes) and the decode step writes and reads them
     where they lie: five requests over two slots, so slots retire and
     are leased again mid-run, give ``generate()``'s tokens one for one.
-    Groups under 8 take several KV heads a grid step, a group of 8 one."""
-    config = dict(config)
-    name = config.pop("model", "transformer_lm")
-    cfg = dict(vocab_size=8, d_model=32, heads=2, depth=2, max_len=32)
-    cfg.update(config)
-    m = build_model(name, **cfg)
-    v, ids = train_lm(m)
-    prompts = [np.asarray(ids[0, :n]) for n in (4, 9, 6, 3, 7)]
+    Groups under 8 take several KV heads a grid step, a group of 8 one.
+
+    Prompts of two lengths, one a prefill bucket (five lengths before,
+    over the same two buckets), retire and re-lease the same, and the
+    reference compiles a program a length; the engine still sees five
+    different (prompt, budget) pairs."""
+    cfg = {**TINY, **config}
+    m, v, ids = trained_lm(**config)
+    prompts = [np.asarray(ids[0, o:o + n])
+               for o, n in ((0, 4), (1, 9), (2, 4), (3, 9), (1, 4))]
     budgets = (8, 5, 9, 6, 8)
-    want = [np.asarray(generate(m, v, p[None], max_new_tokens=n))[0]
-            for p, n in zip(prompts, budgets)]
+    want = [ref_tokens(m, v, p, n) for p, n in zip(prompts, budgets)]
     engine = ServeEngine(m, v, slots=2, cache_len=32, decode_block=4)
     hk = cfg.get("kv_heads") or cfg["heads"]
     d = cfg["d_model"] // cfg["heads"]
@@ -325,7 +331,7 @@ def test_the_pools_layout_is_its_holders(holder):
         pytest.skip("needs 4 devices")
     hk, d = (2, 64) if holder == "bf16-heads-of-64" else (2, 16)
     m = tiny_lm(d_model=hk * d, heads=hk)
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     engine = ServeEngine(m, v, slots=2, cache_len=32, **options)
     rng = np.random.default_rng(0)
     for n in (5, 9, 3):

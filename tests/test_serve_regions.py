@@ -16,16 +16,12 @@ import sys
 import numpy as np
 import pytest
 
-import jax
-import jax.numpy as jnp
-
-from mmlspark_tpu.models import generate
 from mmlspark_tpu.serve import ServeEngine
 from mmlspark_tpu.testing.compile_guard import (
     compile_guard,
     serve_compile_guard,
 )
-from tests.serve_helpers import tiny_lm, train_lm
+from tests.serve_helpers import init_lm, ref_tokens, tiny_lm, trained_lm
 
 
 # -- compile-count invariants (bucketed prefill + fused decode) -------------
@@ -37,8 +33,7 @@ def test_mixed_length_soak_pins_compile_counts():
     exactly once and bucketed prefill at most once per power-of-two
     bucket — NOT once per distinct length — while every request still
     matches single-request ``generate()`` byte for byte."""
-    m = tiny_lm()
-    v, ids = train_lm(m)
+    m, v, ids = trained_lm()
     lengths = [4, 1, 12, 7, 8, 3, 10, 2, 5, 9]  # raggedy on purpose
     prompts = [np.asarray(ids[0, :n]) for n in lengths]
     engine = ServeEngine(m, v, slots=2, cache_len=32, max_queue=16)
@@ -52,7 +47,7 @@ def test_mixed_length_soak_pins_compile_counts():
                 results.update({r.id: r for r in engine.step()})
         results.update(engine.run())
     for rid, p in zip(rids, prompts):
-        want = np.asarray(generate(m, v, p[None], max_new_tokens=4))[0]
+        want = ref_tokens(m, v, p, 4)
         np.testing.assert_array_equal(np.asarray(results[rid].tokens), want)
     # the 10 distinct lengths landed in at most 2 buckets (8 and 16):
     # far fewer programs than the per-length prefill would have traced
@@ -86,7 +81,7 @@ def test_every_admission_and_every_tick_leave_their_regions(options):
     ``serve.first_token`` inside it, at most 8 region events an admission
     and 6 a tick: counts, so nothing here can flake on a timing."""
     m = tiny_lm()
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     engine = ServeEngine(m, v, slots=2, cache_len=32, **options)
     rng = np.random.default_rng(0)
     rids = [engine.submit(rng.integers(0, 8, size=n).astype(np.int32),
@@ -171,7 +166,7 @@ def test_an_admission_behind_a_block_in_flight_keeps_its_fetch_target(
     alone). Arrivals into an engine that is not full are what reaches
     that state: no retirement has rebound ``pool.live`` in between."""
     m = tiny_lm()
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 8, size=n).astype(np.int32)
                for n in (5, 9, 3, 12)]
@@ -205,7 +200,7 @@ def test_fetch_region_feeds_the_host_sync_account():
     """``serve.fetch``'s own interval is what ``record_host_sync`` gets,
     in both loops: the fetch is timed once."""
     m = tiny_lm()
-    v = m.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    v = init_lm(m)
     for options in ({}, {"async_host": True}):
         engine = ServeEngine(m, v, slots=2, cache_len=32, **options)
         engine.submit(np.arange(5, dtype=np.int32) % 8, max_new_tokens=6)

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 
 from mmlspark_tpu.core.exceptions import FriendlyError
-from mmlspark_tpu.models import build_model, generate
 from mmlspark_tpu.parallel import (
     TRANSFORMER_TP_RULES,
     make_mesh,
@@ -34,33 +33,12 @@ from mmlspark_tpu.testing.compile_guard import (
     compile_guard,
     serve_compile_guard,
 )
-
-PERIOD = 4
-
-
-def _train_lm(m, steps=30, seq=16):
-    from mmlspark_tpu.testing.datagen import overfit_periodic_lm
-
-    return overfit_periodic_lm(m, steps=steps, seq=seq, period=PERIOD)
-
-
-def _tiny(**kw):
-    cfg = dict(vocab_size=8, d_model=32, heads=2, depth=2, max_len=32)
-    cfg.update(kw)
-    return build_model("transformer_lm", **cfg)
+from tests.serve_helpers import ref_tokens, trained_lm
 
 
 @pytest.fixture(scope="module")
 def lm():
-    m = _tiny()
-    v, ids = _train_lm(m)
-    return m, v, ids
-
-
-def _ref(m, v, prompt, max_new, eos_id=None):
-    out = generate(m, v, np.asarray(prompt, np.int32)[None], max_new,
-                   eos_id=eos_id)
-    return np.asarray(out)[0]
+    return trained_lm()
 
 
 # -- mesh spec parsing -----------------------------------------------------
@@ -120,7 +98,7 @@ def test_sharded_parity_ragged_prompts_and_joins(lm, mesh_axes):
 
     for rid, p, n in zip(rids, prompts, budgets):
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, n),
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, n),
             err_msg=f"mesh={mesh_axes} request={rid}",
         )
     assert engine.decode_compile_count <= engine.num_decode_blocks
@@ -133,9 +111,9 @@ def test_sharded_mid_block_eos(lm):
     and matches generate() with the same eos_id byte for byte."""
     m, v, ids = lm
     prompt = np.asarray(ids[0, :3])
-    free_run = _ref(m, v, prompt, 12)
+    free_run = ref_tokens(m, v, prompt, 12)
     eos = int(free_run[len(prompt) + 2])
-    full = _ref(m, v, prompt, 12, eos_id=eos)
+    full = ref_tokens(m, v, prompt, 12, eos_id=eos)
     stop = len(prompt) + int(np.argmax(full[len(prompt):] == eos))
     want = full[:stop + 1]
 
@@ -175,7 +153,7 @@ def test_sharded_retick_compiles_zero_new_programs(lm):
         rid = engine.submit(row[:4], max_new_tokens=9)
         res = engine.run()[rid]
     np.testing.assert_array_equal(
-        np.asarray(res.tokens), _ref(m, v, row[:4], 9)
+        np.asarray(res.tokens), ref_tokens(m, v, row[:4], 9)
     )
 
 
@@ -209,7 +187,7 @@ def test_sharded_one_host_sync_per_block(lm, monkeypatch):
     monkeypatch.undo()
 
     np.testing.assert_array_equal(
-        np.asarray(res.tokens), _ref(m, v, prompt, 9)
+        np.asarray(res.tokens), ref_tokens(m, v, prompt, 9)
     )
     assert syncs["n"] <= 2, f"host syncs: {syncs['n']} (> 1 per block)"
 
@@ -244,12 +222,12 @@ def test_sharded_expire_active_slot_device_state(lm):
     results.update(engine.run())
     assert results[rid_b].status == "completed"
     np.testing.assert_array_equal(
-        np.asarray(results[rid_b].tokens), _ref(m, v, prompt_b, 10)
+        np.asarray(results[rid_b].tokens), ref_tokens(m, v, prompt_b, 10)
     )
     assert results[rid_c].status == "completed"
     np.testing.assert_array_equal(
         np.asarray(results[rid_c].tokens),
-        _ref(m, v, np.asarray(ids[0, :6]), 4),
+        ref_tokens(m, v, np.asarray(ids[0, :6]), 4),
     )
 
 

@@ -38,35 +38,14 @@ from mmlspark_tpu.core.faults import (
     TransientFault,
     parse_fault_spec,
 )
-from mmlspark_tpu.models import build_model, generate
 from mmlspark_tpu.serve import ReplicaSet, ServeEngine
 from mmlspark_tpu.testing.compile_guard import serve_compile_guard
-
-PERIOD = 4
-
-
-def _train_lm(m, steps=30, seq=16):
-    from mmlspark_tpu.testing.datagen import overfit_periodic_lm
-
-    return overfit_periodic_lm(m, steps=steps, seq=seq, period=PERIOD)
-
-
-def _tiny(**kw):
-    cfg = dict(vocab_size=8, d_model=32, heads=2, depth=2, max_len=32)
-    cfg.update(kw)
-    return build_model("transformer_lm", **cfg)
+from tests.serve_helpers import ref_tokens, trained_lm
 
 
 @pytest.fixture(scope="module")
 def lm():
-    m = _tiny()
-    v, ids = _train_lm(m)
-    return m, v, ids
-
-
-def _ref(m, v, prompt, max_new):
-    out = generate(m, v, np.asarray(prompt, np.int32)[None], max_new)
-    return np.asarray(out)[0]
+    return trained_lm()
 
 
 class _FakeClock:
@@ -86,7 +65,7 @@ def _assert_parity(m, v, results, gids, prompts, max_new):
         res = results[gid]
         assert res.status == "completed", f"gid={gid}: {res.status}"
         np.testing.assert_array_equal(
-            np.asarray(res.tokens), _ref(m, v, p, max_new),
+            np.asarray(res.tokens), ref_tokens(m, v, p, max_new),
             err_msg=f"gid={gid}",
         )
 
@@ -104,7 +83,12 @@ def _assert_engine_pins(engine):
 def test_routing_parity_and_load_split(lm):
     """Baseline: two replicas behind the facade serve a staggered
     arrival schedule bit-identically to ``generate()``, both replicas
-    take work, and each engine's compile pins hold under the guard."""
+    take work, and each engine's compile pins hold under the guard.
+
+    Six requests for the set's four slots: two wait, and a slot of each
+    replica is leased a second time. Three requests never fill a replica,
+    so neither the wait behind a full replica nor the second lease would
+    be routed; two replicas are the fewest that split a load. Stays."""
     m, v, ids = lm
     rs = ReplicaSet(m, v, replicas=2, slots=2, cache_len=32,
                     max_queue=8, decode_block=4, retry_backoff_s=0.0)
@@ -156,7 +140,13 @@ def _kill_drill(m, v, ids, mesh=None):
     must still complete EVERY request bit-identically to a no-failure
     run, with per-replica compile pins intact. Mixed budgets make some
     requests complete between the snapshot and the kill, so the
-    reconciliation's exactly-once cancel path runs too."""
+    reconciliation's exactly-once cancel path runs too.
+
+    Six requests, two of them short: with three, which replica takes the
+    one short request decides whether any stream ends between replica
+    0's snapshot (tick 2) and its kill (tick 3), and the cancel path can
+    go unrun; one replica has nowhere to fail over to. So the size stays
+    for both the one-device and the 2x2-mesh case."""
     inj = FaultInjector([Fault("serve.decode", "kill", tick=3,
                                replica=0)])
     rs = ReplicaSet(m, v, replicas=2, slots=4, cache_len=32,
@@ -172,7 +162,7 @@ def _kill_drill(m, v, ids, mesh=None):
     for gid, p, b in zip(gids, prompts, budgets):
         assert results[gid].status == "completed"
         np.testing.assert_array_equal(
-            np.asarray(results[gid].tokens), _ref(m, v, p, b),
+            np.asarray(results[gid].tokens), ref_tokens(m, v, p, b),
             err_msg=f"mesh={mesh} gid={gid}",
         )
     for i in range(2):
@@ -251,7 +241,7 @@ def test_hedging_first_committed_wins_exactly_once(lm):
     assert rs.hedge_wasted_tokens_total > 0
     assert list(results) == [gid]
     np.testing.assert_array_equal(
-        np.asarray(results[gid].tokens), _ref(m, v, p, 12))
+        np.asarray(results[gid].tokens), ref_tokens(m, v, p, 12))
     md = rs.metrics_dict()
     assert md["hedges_total"] == 1
     assert md["hedge_wasted_tokens_total"] == rs.hedge_wasted_tokens_total
@@ -318,7 +308,7 @@ def test_drain_last_replica_finishes_in_place(lm):
     rs.drain(0)
     results = rs.run()
     np.testing.assert_array_equal(
-        np.asarray(results[gid].tokens), _ref(m, v, p, 8))
+        np.asarray(results[gid].tokens), ref_tokens(m, v, p, 8))
     rs.step()  # idle draining replica retires on the next tick
     assert rs.replica_state(0) == "drained"
     assert rs.drains_total == 1
@@ -386,7 +376,7 @@ def test_engine_killed_parks_resources_deterministically(lm):
     results = rebuilt.run()
     for rid, p in zip(rids, prompts):
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, 6),
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, 6),
             err_msg=f"request={rid}")
 
 
@@ -420,7 +410,7 @@ def test_snapshot_fault_keeps_previous_checkpoint(lm):
     results = rebuilt.run()
     for rid, p in zip(rids, prompts):
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, 10),
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, 10),
             err_msg=f"request={rid}")
 
 
@@ -470,7 +460,7 @@ def test_paged_prefix_mesh_snapshot_roundtrip_under_faults(lm):
     results = rebuilt.run()
     for rid, p in zip(rids, prompts):
         np.testing.assert_array_equal(
-            np.asarray(results[rid].tokens), _ref(m, v, p, 6),
+            np.asarray(results[rid].tokens), ref_tokens(m, v, p, 6),
             err_msg=f"request={rid}")
     pg2 = rebuilt.pool.snapshot()
     refs2 = sum(pg2["npages"]) + sum(

@@ -35,25 +35,14 @@ from mmlspark_tpu.core.tracehub import (
     TelemetryHub,
     _RegistryView,
 )
-from mmlspark_tpu.models import build_model
 from mmlspark_tpu.serve import DisaggFleet, ReplicaSet, ServeEngine
 from mmlspark_tpu.testing.compile_guard import serve_compile_guard
-
-PERIOD = 4
-
-
-def _train_lm(m, steps=30, seq=16):
-    from mmlspark_tpu.testing.datagen import overfit_periodic_lm
-
-    return overfit_periodic_lm(m, steps=steps, seq=seq, period=PERIOD)
+from tests.serve_helpers import trained_lm
 
 
 @pytest.fixture(scope="module")
 def lm():
-    m = build_model("transformer_lm", vocab_size=8, d_model=32, heads=2,
-                    depth=2, max_len=32)
-    v, ids = _train_lm(m)
-    return m, v, ids
+    return trained_lm()
 
 
 # -- registry views ---------------------------------------------------------
