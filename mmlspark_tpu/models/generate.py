@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from mmlspark_tpu.core.exceptions import FriendlyError
-from mmlspark_tpu.models.graph import _accepts_kwarg
+from mmlspark_tpu.models.graph import FINAL_NODE, _accepts_kwarg
 from mmlspark_tpu.ops.kv_cache import (
     LATENT_ROWS,
     LINEAR,
@@ -150,7 +150,8 @@ def init_cache(graph, variables, batch: int, total: int) -> dict:
 
 
 def _cached_apply(graph, variables, ids, cache, pos, rolled=False,
-                  step=False, live=None, valid=None, counters=None):
+                  step=False, live=None, valid=None, counters=None,
+                  head=True):
     """One forward over ``ids`` (B, T) starting at absolute position
     ``pos`` (traced ok), reading/writing the K/V cache. Returns
     (logits (B, T, V), new cache). ``rolled`` switches the blocks to
@@ -164,10 +165,13 @@ def _cached_apply(graph, variables, ids, cache, pos, rolled=False,
     ``valid`` ((B, T) bool) marks the real tokens for blocks that route
     (a pad or a dead row routes nowhere), and such a block hands back a
     third value, its counters, which land in ``counters[name]`` when a
-    dict is given."""
+    dict is given. ``head=False`` stops before the graph's final node and
+    returns its input, the last hidden states, in the logits' place."""
     x = ids
     new_cache = dict(cache)
     for name, mod in graph.blocks:
+        if not head and name == FINAL_NODE:
+            continue
         v = variables[name]
         if name in cache:
             kwargs = {"cache": cache[name], "pos": pos, "rolled": rolled}
@@ -304,6 +308,142 @@ def make_decode_block(graph, pad_id: int = 0):
     return decode_block
 
 
+def make_denoise_block(graph):
+    """Build the fused program of a model that GENERATES BY DIFFUSION OVER
+    BLOCKS (``graph.extra["block"]`` = L, ``["denoise_steps"]`` = S,
+    ``["mask_id"]``): a loop of micro-steps over whole blocks, S
+    denoising steps and then the block's clean close, every slot at the
+    same phase (a dispatch starts every slot at a block's start).
+
+    A DENOISING micro-step runs each slot's L rows at positions ``pos ..
+    pos + L - 1`` (a position not yet committed reads ``mask_id``)
+    against the slot's clean prefix and the block's own rows, both ways
+    (``ops.kv_cache.decode_step`` with T = L); takes each masked
+    position's greedy token (``mask_id`` excluded) and its confidence
+    (the softmax probability of that token, in float32, compared as its
+    log); and COMMITS the most confident masked positions by the static
+    schedule: of the ``n`` positions masked when the block started, step
+    ``s`` commits ``n // S``, one more while ``s < n % S`` (ties to the
+    earlier position). The CLOSE runs the clean block's L rows: the K/V
+    that stay in the pool are those of all L tokens revealed, attending
+    each other. It serves the block, advances the slot by L and folds the
+    token budget into ``live``; the next block starts fully masked.
+
+    The returned function's signature::
+
+        denoise_block(variables, buffers, pos, live, tok, masked, rem,
+                      n_blocks, most)
+
+    - ``pos`` (S,) int32: each slot's block start, a multiple of L (its
+      clean prefix); ``live`` (S,) bool; both donated and returned
+    - ``tok`` (S, L) int32, ``masked`` (S, L) bool: the block each slot
+      starts at: all masked, or a prompt's tail committed and the rest
+      masked
+    - ``rem`` (S,) int32: tokens each slot may still serve; a slot goes
+      dead at the close of the block that reaches it
+    - ``n_blocks``: blocks to run (traced: one program for every count),
+      at most ``most`` (static: the output's size)
+
+    Returns ``(blocks (S, most, L) int32, live, buffers, pos, counts)``:
+    each block's tokens as it closed (the first one's committed prompt
+    tail included: the host serves what was masked), and ``counts``
+    ``{"denoise_steps", "tokens_committed", "blocks_closed"}``, summed
+    over live slots and micro-steps. For a graph that routes tokens to
+    experts (:func:`counts_routing`) a sixth value follows: the routing
+    counters of :func:`routing_totals`, summed over the micro-steps."""
+    length = int(graph.extra["block"])
+    steps = int(graph.extra["denoise_steps"])
+    mask_id = int(graph.extra["mask_id"])
+    per_block = steps + 1
+    routed = counts_routing(graph)
+    head_name, head_mod = graph.blocks[-1]
+
+    def denoise_block(variables, buffers, pos, live, tok, masked, rem,
+                      n_blocks, most):
+        slots = pos.shape[0]
+        order = jnp.arange(length)
+        earlier = order[None, :] < order[:, None]   # [p, q]: q before p
+
+        def commit(ops):
+            x, tok, masked, n0, phase, live = ops
+            logits = head_mod.apply(variables[head_name], x)  # (S, L, V)
+            logits = jnp.where(jnp.arange(logits.shape[-1]) == mask_id,
+                               -jnp.inf, logits.astype(jnp.float32))
+            best = greedy_next(logits)
+            top = logits.max(axis=-1)
+            conf = top - jax.nn.logsumexp(logits, axis=-1)
+            conf = jnp.where(masked, conf, -jnp.inf)
+            # a position's rank among the slot's: the positions above it
+            # (more confident, or as confident and earlier)
+            above = ((conf[:, None, :] > conf[:, :, None])
+                     | ((conf[:, None, :] == conf[:, :, None])
+                        & earlier[None]))
+            rank = above.sum(axis=-1)
+            count = n0 // steps + (phase < n0 % steps)
+            take = masked & (rank < count[:, None]) & live[:, None]
+            return (jnp.where(take, best, tok), masked & ~take,
+                    take.sum().astype(jnp.int32))
+
+        def keep(ops):
+            _, tok, masked, *_ = ops
+            return tok, masked, jnp.int32(0)
+
+        def micro(i, carry):
+            tok, masked, n0, buffers, pos, live, rem, out, counts, stats = \
+                carry
+            phase = i % per_block
+            ids = jnp.where(masked, mask_id, tok)
+            routing = {} if routed else None
+            x, buffers = _cached_apply(
+                graph, variables, ids, buffers, pos, step=True, live=live,
+                valid=(jnp.broadcast_to(live[:, None], ids.shape)
+                       if routed else None),
+                counters=routing, head=False)
+            tok, masked, taken = jax.lax.cond(
+                phase < steps, commit, keep,
+                (x, tok, masked, n0, phase, live))
+            close = phase == steps
+            out = jnp.where(close, jax.lax.dynamic_update_slice(
+                out, tok[:, None], (0, i // per_block, 0)), out)
+            closing = close & live
+            rem = rem - jnp.where(closing, n0, 0)
+            pos = jnp.where(closing, pos + length, pos)
+            live = live & ~(closing & (rem <= 0))
+            masked = masked | close
+            n0 = jnp.where(close, length, n0)
+            counts = {
+                "denoise_steps": counts["denoise_steps"] + jnp.where(
+                    close, 0, live.sum(dtype=jnp.int32)),
+                "tokens_committed": counts["tokens_committed"] + taken,
+                "blocks_closed": counts["blocks_closed"]
+                + closing.sum(dtype=jnp.int32),
+            }
+            if routed:
+                stats = jax.tree_util.tree_map(
+                    jnp.add, stats, routing_totals(routing))
+            return (tok, masked, n0, buffers, pos, live, rem, out, counts,
+                    stats)
+
+        zero = jnp.int32(0)
+        stats = None
+        if routed:
+            n = sum(1 for _, mod in graph.blocks
+                    if getattr(mod, "routed", False))
+            stats = {key: jnp.zeros((n,), jnp.int32) for key in
+                     ("expert_pairs", "experts_hit", "expert_rows")}
+        carry = (tok, masked, masked.sum(axis=-1, dtype=jnp.int32), buffers,
+                 pos, live, rem, jnp.zeros((slots, most, length), jnp.int32),
+                 {"denoise_steps": zero, "tokens_committed": zero,
+                  "blocks_closed": zero}, stats)
+        (_, _, _, buffers, pos, live, _, out, counts, stats) = \
+            jax.lax.fori_loop(0, n_blocks * per_block, micro, carry)
+        if routed:
+            return out, live, buffers, pos, counts, stats
+        return out, live, buffers, pos, counts
+
+    return denoise_block
+
+
 def _roll_prefill_cache(cache, p: int, window: int) -> dict:
     """Fold a linear prefill cache (buffers of length ``p``) into
     circular window buffers of length ``window``: the last
@@ -335,6 +475,12 @@ def _validate_causal_decode(graph, prompt, max_new_tokens: int):
         raise FriendlyError(
             f"decoding needs a causal LM; '{graph.name}' has "
             "causal=False (bidirectional logits leak future positions)"
+        )
+    if graph.extra.get("block"):
+        raise FriendlyError(
+            f"'{graph.name}' generates by diffusion over blocks of "
+            f"{graph.extra['block']}, not a token at a time; serve it with "
+            "ServeEngine, whose denoising program runs whole blocks"
         )
     if max_new_tokens < 1:
         raise FriendlyError(

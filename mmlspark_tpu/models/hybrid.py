@@ -12,8 +12,9 @@ up-projections of ONE compressed row a position,
 :class:`LatentAttention`; ``conv``: no attention at all, a gated short
 convolution over the last ``conv_kernel`` positions,
 :class:`ShortConv`) and an FFN kind (``dense``: SwiGLU; ``routed``:
-sigmoid top-k routing over ``n_experts`` experts of which this holder has
-a stated range,
+top-k routing, sigmoid with a selection bias or softmax over all
+(``router``), over ``n_experts`` experts of which this holder has a
+stated range,
 :func:`mmlspark_tpu.parallel.expert.moe_ffn_held`, beside an always-on
 shared SwiGLU where ``shared_d_ff`` gives one). Around them: RMSNorm,
 projections without biases, query and key heads of one width and value
@@ -32,6 +33,14 @@ every position and all heads, a convolution block a STATE of
 head-major, which is the layout the decode kernel
 (:func:`mmlspark_tpu.ops.flash_attention.flash_decode_grouped`) streams
 without a copy.
+
+A stack whose ``block`` is L > 0 GENERATES BY DIFFUSION OVER BLOCKS of L
+positions on a grid from position 0 (the SDAR line): its attention is
+BLOCK-causal, position ``i`` seeing ``j`` iff ``j // L <= i // L``, in a
+prompt's forward and in a denoising step alike, and a step runs a block's
+L rows at once against the slot's clean prefix and the block itself
+(:func:`mmlspark_tpu.models.generate.make_denoise_block`; ``mask_id`` is
+what a position not yet committed reads).
 
 Products run in bfloat16 with float32 accumulation, norms, the router and
 the logits in float32; the residual stream is float32.
@@ -59,6 +68,9 @@ from mmlspark_tpu.ops import kv_cache
 from mmlspark_tpu.ops.attention import dense_attention
 
 FULL, SWA, MLA, CONV = "full", "swa", "mla", "conv"
+#: the kernels' name for a block-causal full layer (``attn_block_prefill``,
+#: ``attn_block_decode``)
+BLOCK = "block"
 DENSE_FFN, ROUTED_FFN = "dense", "routed"
 
 
@@ -107,6 +119,9 @@ class HybridAttention(nn.Module):
     # (one gain for all heads of a kind), before the rotation
     qk_norm: bool = False
     eps: float = 1e-5
+    # > 0: block-causal over blocks of this many positions (a model that
+    # generates by diffusion over blocks); full attention only
+    block: int = 0
 
     @nn.compact
     def __call__(self, x, cache=None, pos=None, decode=False, live=None):
@@ -142,11 +157,17 @@ class HybridAttention(nn.Module):
         k = apply_rope(k, positions, base=self.rope_base,
                        rotary_dim=self.rotary_dim,
                        interleave=self.rope_interleave)
-        kind = FULL if self.window is None else SWA
+        kind = BLOCK if self.block else FULL if self.window is None else SWA
         new_cache = None
         if cache is None:
             o = self._prompt_attention(q, k, v, sink, kind)
-        elif decode and t == 1 and not kv_cache.is_linear(cache):
+        elif self.block and kv_cache.is_linear(cache) and not (
+                isinstance(pos, int) and pos == 0):
+            raise ParamError(
+                "a block-causal layer's cached forward is a prompt's from "
+                "position 0 or a denoising step over the serving pool")
+        elif decode and (t == 1 or self.block) and not kv_cache.is_linear(
+                cache):
             # the serving pool's entry, as the pool allocates it by
             # cache_spec: a window block's rows are a ring
             o, new_cache = kv_cache.decode_step(
@@ -168,7 +189,8 @@ class HybridAttention(nn.Module):
 
     def _prompt_attention(self, q, k, v, sink, kind: str):
         return _prompt_attention(self.attn_impl, q, k, v, kind,
-                                 window=self.window, sink=sink)
+                                 window=self.window, sink=sink,
+                                 causal_block=self.block or 1)
 
 
 def _positions(cache, pos, t: int):
@@ -182,18 +204,19 @@ def _positions(cache, pos, t: int):
 
 
 def _prompt_attention(attn_impl: str, q, k, v, kind: str, *, window=None,
-                      sink=None):
+                      sink=None, causal_block: int = 1):
     """A prompt's causal attention over its own K/V, by the flash forward
-    kernel where the block runs it."""
+    kernel where the block runs it; block-causal over blocks of
+    ``causal_block`` positions where that is > 1."""
     if resolve_attn_impl(attn_impl) != FLASH:
         return dense_attention(q, k, v, causal=True, window=window,
-                               sink=sink)
+                               sink=sink, causal_block=causal_block)
     from mmlspark_tpu.ops.flash_attention import flash_attention
 
     # named apart from ``attn``: the trace tells the kinds apart
     with jax.named_scope(f"attn_{kind}_prefill"):
         return flash_attention(q, k, v, causal=True, window=window,
-                               sink=sink)
+                               sink=sink, causal_block=causal_block)
 
 
 class LatentAttention(nn.Module):
@@ -357,16 +380,19 @@ class RoutedFFN(nn.Module):
     param_dtype: Any = jnp.float32
     shared_d_ff: int = 0    # an always-on, unweighted SwiGLU beside the sum
     scale: float = 1.0      # on every routing weight, after normalising
+    score: str = "sigmoid"  # the router's kind (parallel/expert.router_topk)
 
     @nn.compact
     def __call__(self, x, valid=None):
-        from mmlspark_tpu.parallel.expert import moe_ffn_held
+        from mmlspark_tpu.parallel.expert import SIGMOID, moe_ffn_held
 
         d = x.shape[-1]
         router = self.param("router", nn.initializers.normal(0.02),
                             (d, self.n_experts), self.param_dtype)
-        bias = self.param("select_bias", nn.initializers.zeros,
-                          (self.n_experts,), self.param_dtype)
+        # a softmax router chooses by its scores alone: no selection bias
+        bias = None if self.score != SIGMOID else self.param(
+            "select_bias", nn.initializers.zeros, (self.n_experts,),
+            self.param_dtype)
         w_gate, w_up, w_down = _Experts(self.held, d, self.d_ff,
                                         self.param_dtype, name="experts")()
         # the router reads the normed stream in float32, the experts in
@@ -375,7 +401,7 @@ class RoutedFFN(nn.Module):
             x, router, bias, w_gate.astype(self.dtype),
             w_up.astype(self.dtype), w_down.astype(self.dtype),
             top_k=self.top_k, first=self.first, valid=valid,
-            scale=self.scale,
+            scale=self.scale, score=self.score,
         )
         if self.shared_d_ff:
             # every holder has the shared expert whole, for its own
@@ -427,6 +453,8 @@ class HybridBlock(nn.Module):
     # over a stream ``conv_width`` wide (what the state's rows are)
     conv_kernel: int = 0
     conv_width: int = 0
+    block: int = 0           # > 0: block-causal (HybridAttention.block)
+    router: str = "sigmoid"  # the routed FFN's router kind
 
     def cache_spec(self) -> tuple:
         """``(kind, rows, kv_heads, key width, value width)``: what the
@@ -474,7 +502,7 @@ class HybridBlock(nn.Module):
                 self.window, self.rope_base, self.rotary_dim,
                 self.value_scale, self.sink, self.attn_impl, self.dtype,
                 self.param_dtype, self.rope_interleave, self.qk_norm,
-                self.eps, name="attn")
+                self.eps, block=self.block, name="attn")
         attn = attend(y, cache=cache, pos=pos, decode=decode, live=live)
         new_cache = None
         if cache is not None:
@@ -486,7 +514,8 @@ class HybridBlock(nn.Module):
             y, counters = RoutedFFN(
                 self.n_experts, self.top_k, self.d_ff, self.held[0],
                 self.held[1], self.dtype, self.param_dtype,
-                self.shared_d_ff, self.routed_scale, name="moe",
+                self.shared_d_ff, self.routed_scale, score=self.router,
+                name="moe",
             )(y, valid)
         else:
             y = _swiglu(y.astype(self.dtype), self.d_ff, "mlp", self.dtype,
@@ -560,6 +589,10 @@ def hybrid_lm(
     routed_scale: float = 1.0,
     qk_norm: bool = False,
     conv_kernel: int = 3,
+    router: str = "sigmoid",
+    block: int = 0,
+    denoise_steps: int = 0,
+    mask_id: int = -1,
 ) -> NamedGraph:
     """Causal decoder LM with a per-layer pattern: ``attention[i]`` in
     (``"full"``, ``"swa"``, ``"mla"``, ``"conv"``) and ``ffn[i]`` in
@@ -578,7 +611,15 @@ def hybrid_lm(
     the router's ``n_experts`` experts this holder has (default: all);
     ``shared_d_ff`` > 0 gives every routed layer an always-on shared
     SwiGLU of that width, ``routed_scale`` multiplies the routing
-    weights."""
+    weights; ``router`` is ``"sigmoid"`` (with a selection bias) or
+    ``"softmax"`` (over all experts, the chosen weights renormalised).
+
+    ``block`` = L > 0 makes the stack one that GENERATES BY DIFFUSION
+    OVER BLOCKS of L positions: every layer ``full`` and block-causal,
+    ``denoise_steps`` (1..L) denoising steps a block, ``mask_id`` the
+    token a position not yet committed reads (excluded from every
+    choice). The serving engine then generates whole blocks
+    (``models/generate.make_denoise_block``)."""
     attention, ffn = tuple(attention), tuple(ffn)
     if not attention or len(attention) != len(ffn):
         raise ParamError(
@@ -604,6 +645,18 @@ def hybrid_lm(
             f"qk_rope_head_dim ({qk_rope_head_dim}) = head_dim ({head_dim})")
     if shared_d_ff < 0:
         raise ParamError(f"shared_d_ff must be >= 0, got {shared_d_ff}")
+    if router not in ("sigmoid", "softmax"):
+        raise ParamError(
+            f"router must be 'sigmoid' or 'softmax', got {router!r}")
+    if block and not (
+            int(block) >= 1 and set(attention) == {FULL}
+            and 1 <= int(denoise_steps) <= int(block)
+            and 0 <= int(mask_id) < vocab_size):
+        raise ParamError(
+            f"block={block} generates by diffusion over blocks: every layer "
+            f"'{FULL}' (got {sorted(set(attention))}), 1 <= denoise_steps "
+            f"({denoise_steps}) <= block, and a mask_id ({mask_id}) inside "
+            f"the vocabulary ({vocab_size})")
     for kind in ffn:
         if kind not in (DENSE_FFN, ROUTED_FFN):
             raise ParamError(
@@ -666,6 +719,8 @@ def hybrid_lm(
             qk_norm=bool(qk_norm) and a_kind in (FULL, SWA),
             conv_kernel=int(conv_kernel) if a_kind == CONV else 0,
             conv_width=int(d_model) if a_kind == CONV else 0,
+            block=int(block),
+            router=router,
         )))
     blocks.append((FINAL_NODE, HybridHead(vocab_size, norm_eps,
                                           param_dtype=dtype)))
@@ -687,5 +742,7 @@ def hybrid_lm(
             # per-token dropless routing is causal: a pad routes nowhere
             # and takes nothing from a real token
             "routing_drops": False,
+            **({"block": int(block), "denoise_steps": int(denoise_steps),
+                "mask_id": int(mask_id)} if block else {}),
         },
     )

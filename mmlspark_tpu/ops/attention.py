@@ -48,10 +48,12 @@ def mask_value(*, kernel: bool) -> float:
     return KERNEL_NEG_INF if kernel else NEG_INF
 
 
-def decode_live_lengths(pos, batch: int, live=None):
+def decode_live_lengths(pos, batch: int, live=None, rows: int = 1):
     """Per-row LIVE KV lengths for a single-token decode step writing at
     absolute position ``pos``: the step's own K/V lands at ``pos``, so
-    positions ``[0, pos]`` are live — length ``pos + 1``.
+    positions ``[0, pos]`` are live — length ``pos + 1``. A step that
+    writes ``rows`` rows from ``pos`` on (a denoising step's block) makes
+    ``pos + rows`` live.
 
     This is the one definition of the decode off-by-one shared by the
     dense cache read (``dense_attention(..., q_offset=pos)`` masks
@@ -70,18 +72,22 @@ def decode_live_lengths(pos, batch: int, live=None):
     pos = jnp.asarray(pos, jnp.int32)
     if not pos.ndim:
         pos = jnp.broadcast_to(pos, (batch,))
-    lengths = pos + 1
+    lengths = pos + rows
     if live is not None:
         lengths = jnp.where(live, lengths, 0)
     return lengths
 
 
 def causal_block_mask(q_len: int, kv_len: int, q_offset, kv_offset,
-                      window: int | None = None):
+                      window: int | None = None, causal_block: int = 1):
     """Additive mask (q_len, kv_len) for a block of a causal attention
     matrix whose global coordinates start at (q_offset, kv_offset);
     ``window=W`` additionally masks keys older than ``qpos - W + 1``
-    (the causal sliding window).
+    (the causal sliding window). ``causal_block=L`` > 1 makes the mask
+    BLOCK-causal, as a model that generates by diffusion over blocks of
+    ``L`` positions reads its clean blocks: query ``i`` sees key ``j`` iff
+    ``j // L <= i // L``, so the positions of one block see each other
+    both ways.
 
     Offsets may be traced scalars (ring steps compute the kv offset from
     the rotating source index) — only the lengths must be static.
@@ -105,7 +111,8 @@ def causal_block_mask(q_len: int, kv_len: int, q_offset, kv_offset,
     else:
         qi = q_offset + jnp.arange(q_len)[:, None]
         kj = kv_offset + jnp.arange(kv_len)[None, :]
-    dead = kj > qi
+    dead = (kj > qi if causal_block == 1
+            else kj // causal_block > qi // causal_block)
     if window is not None:
         dead = dead | (kj <= qi - window)
     return jnp.where(dead, NEG_INF, 0.0).astype(jnp.float32)
@@ -224,7 +231,8 @@ def rolled_window_attention(q, k, v, pos, *, scale=None, sink=None):
 
 def dense_attention(q, k, v, *, causal: bool = False,
                     window: int | None = None, scale=None,
-                    q_offset: int = 0, kv_offset: int = 0, sink=None):
+                    q_offset: int = 0, kv_offset: int = 0, sink=None,
+                    causal_block: int = 1):
     """Reference multi-head attention, (B, S, H, D) layout.
 
     Single fused einsum-softmax-einsum — exactly what XLA fuses well on one
@@ -237,8 +245,11 @@ def dense_attention(q, k, v, *, causal: bool = False,
     The values may be narrower or wider than the keys (the output takes
     the values' width), and ``sink`` (H,), one learned logit a query
     head, joins the softmax's denominator only
-    (:func:`sink_denominator`).
+    (:func:`sink_denominator`). ``causal_block`` > 1 (with ``causal``)
+    makes the mask block-causal (:func:`causal_block_mask`).
     """
+    if causal_block != 1 and (not causal or window is not None):
+        raise ValueError("causal_block requires causal=True and no window")
     if window is not None:
         if not causal:
             raise ValueError("window requires causal=True")
@@ -255,7 +266,8 @@ def dense_attention(q, k, v, *, causal: bool = False,
     ) * scale
     if causal:
         s = s + causal_block_mask(q.shape[1], k.shape[1], q_offset,
-                                  kv_offset, window=window)
+                                  kv_offset, window=window,
+                                  causal_block=causal_block)
     m = s.max(axis=-1, keepdims=True)
     m = jnp.where(jnp.isneginf(m), 0.0, m)
     p = jnp.exp(s - m)

@@ -157,10 +157,11 @@ def _kernel_axes(mesh, batch: int, kv_heads: int):
 #   _live_k_range  — [lo, hi] of live K blocks for Q block qi (clamps)
 
 
-def _block_live(qi, ki, *, causal: bool, window: int | None, blk: int):
+def _block_live(qi, ki, *, causal: bool, window: int | None, blk: int,
+                causal_block: int = 1):
     live = True
     if causal:
-        live = ki * blk <= qi * blk + blk - 1
+        live = ki * blk <= _last_key(qi, blk, causal_block)
     if window is not None:
         # the OLDEST query row in block qi (pos qi*blk) attends the
         # block's oldest keys, >= qi*blk - window + 1; a K block whose
@@ -170,8 +171,21 @@ def _block_live(qi, ki, *, causal: bool, window: int | None, blk: int):
     return live
 
 
+def _last_key(qi, blk: int, causal_block: int):
+    """The last key position any query row of block ``qi`` sees: the
+    block's last row, or under a block-causal mask (``causal_block`` L >
+    1: query ``i`` sees key ``j`` iff ``j // L <= i // L``) the last
+    position of that row's block of ``L``, which may lie in the grid's
+    next block when ``L`` does not divide ``blk``."""
+    last = qi * blk + blk - 1
+    if causal_block == 1:
+        return last
+    return last // causal_block * causal_block + causal_block - 1
+
+
 def _dead_mask(qi, ki, shape, *, causal: bool, window: int | None,
-               seq_len: int, blk: int, with_q_pad: bool = False):
+               seq_len: int, blk: int, with_q_pad: bool = False,
+               causal_block: int = 1):
     """Boolean (blk, blk) mask of entries that must NOT attend (always
     includes the padded-key mask; callers skip the call entirely on the
     pad-free non-causal no-window path)."""
@@ -183,17 +197,21 @@ def _dead_mask(qi, ki, shape, *, causal: bool, window: int | None,
         if with_q_pad:
             dead = dead | (qpos >= seq_len)
         if causal:
-            dead = dead | (kpos > qpos)
+            dead = dead | (kpos > qpos if causal_block == 1
+                           else kpos // causal_block > qpos // causal_block)
         if window is not None:
             dead = dead | (kpos <= qpos - window)
     return dead
 
 
-def _live_k_range(qi, *, window: int | None, blk: int):
+def _live_k_range(qi, *, window: int | None, blk: int,
+                  causal_block: int = 1):
     """[lo, hi_unbounded) of K blocks live for Q block qi under causal
     (+ optional window) masking; used to clamp streamed-side index maps
     so dead iterations re-reference a resident tile (no DMA)."""
-    hi = qi  # causal: nothing right of the diagonal block
+    # causal: nothing right of the diagonal block, or of the block that
+    # holds the last key a block-causal mask reaches
+    hi = qi if causal_block == 1 else _last_key(qi, blk, causal_block) // blk
     if window is None:
         lo = jnp.zeros_like(qi)
     else:
@@ -208,7 +226,7 @@ def _live_k_range(qi, *, window: int | None, blk: int):
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
                 scale: float, causal: bool, window: int | None, blk: int,
                 seq_len: int, with_lse: bool, masked: bool,
-                has_sink: bool = False):
+                has_sink: bool = False, causal_block: int = 1):
     # a learned per-head sink (inference only) rides in as one more
     # operand, a (1, 1, LANES) lanes-replicated logit of this head
     sink_ref = None
@@ -232,7 +250,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    live = _block_live(qi, ki, causal=causal, window=window, blk=blk)
+    live = _block_live(qi, ki, causal=causal, window=window, blk=blk,
+                       causal_block=causal_block)
 
     @pl.when(live)
     def _update():
@@ -247,7 +266,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         if masked or causal or window is not None:
             s = jnp.where(
                 _dead_mask(qi, ki, s.shape, causal=causal, window=window,
-                           seq_len=seq_len, blk=blk),
+                           seq_len=seq_len, blk=blk,
+                           causal_block=causal_block),
                 NEG_INF, s,
             )
 
@@ -323,7 +343,7 @@ def _block_rows(block: int | None, q, v) -> int:
 
 def _flash_forward(q, k, v, *, causal: bool, window: int | None,
                    scale: float, block: int | None, interpret: bool,
-                   with_lse: bool = True, sink=None):
+                   with_lse: bool = True, sink=None, causal_block: int = 1):
     b, s, h, d = q.shape
     dv = v.shape[-1]  # the values (and the output) may differ in width
     # grouped-query attention: K/V may carry fewer heads (h_kv) than Q;
@@ -353,7 +373,8 @@ def _flash_forward(q, k, v, *, causal: bool, window: int | None,
     # (~halving causal K/V traffic)
     if causal:
         def kv_im(bh, i, j):
-            lo, hi = _live_k_range(i, window=window, blk=blk)
+            lo, hi = _live_k_range(i, window=window, blk=blk,
+                                   causal_block=causal_block)
             return (bh // g, jnp.clip(j, lo, hi), 0)
     else:
         kv_im = lambda bh, i, j: (bh // g, j, 0)  # noqa: E731
@@ -374,6 +395,8 @@ def _flash_forward(q, k, v, *, causal: bool, window: int | None,
                                      lambda bh, i, j: (bh, 0, 0),
                                      memory_space=pltpu.VMEM))
         extra["has_sink"] = True
+    if causal_block != 1:
+        extra["causal_block"] = causal_block
     res = pl.pallas_call(
         partial(_fwd_kernel, scale=scale, causal=causal, window=window,
                 blk=blk, seq_len=s, with_lse=with_lse,
@@ -646,7 +669,8 @@ def _build(causal: bool, window: int | None, scale_key, block: int | None,
 def flash_attention(q, k, v, *, causal: bool = False,
                     window: int | None = None, scale=None,
                     block: int | None = None,
-                    interpret: bool | None = None, mesh=None, sink=None):
+                    interpret: bool | None = None, mesh=None, sink=None,
+                    causal_block: int = 1):
     """Blockwise fused attention, (B, S, H, D) layout, exact output AND
     exact gradients — both directions O(S·d) memory.
 
@@ -678,6 +702,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
     ``q`` and ``k`` (the output takes its width). Either makes the call
     INFERENCE ONLY (the forward kernel alone, no VJP, no mesh): these
     are the serving prefill's variants.
+
+    ``causal_block=L`` > 1 (with ``causal``, no window) makes the mask
+    BLOCK-causal: query ``i`` sees key ``j`` iff ``j // L <= i // L``, the
+    clean prefix of a model that generates by diffusion over blocks of
+    ``L``. A grid block above the diagonal is live only where that mask
+    reaches into it (none, where ``L`` divides the grid's rows).
+    Inference only, like a sink.
     """
     if not (q.dtype == k.dtype == v.dtype):
         # matmuls feed the MXU native-dtype operands (no f32 upcast),
@@ -702,20 +733,28 @@ def flash_attention(q, k, v, *, causal: bool = False,
         if int(window) < 1:
             raise ValueError(f"window must be >= 1, got {window}")
         window = int(window)
+    if causal_block != 1 and (not causal or window is not None
+                              or int(causal_block) < 1):
+        raise ValueError(
+            f"flash_attention causal_block={causal_block} is a block-causal "
+            "mask: it needs causal=True, no window and a block >= 1"
+        )
     if interpret is None:
         from mmlspark_tpu.core.env import is_tpu
 
         interpret = not is_tpu()
-    if sink is not None or v.shape[-1] != q.shape[-1]:
+    if sink is not None or v.shape[-1] != q.shape[-1] or causal_block != 1:
         if mesh is not None:
             raise ValueError(
-                "flash_attention with a sink or with values of another "
-                "width than the keys is not run under a mesh"
+                "flash_attention with a sink, a block-causal mask or with "
+                "values of another width than the keys is not run under a "
+                "mesh"
             )
         out, _ = _flash_forward(
             q, k, v, causal=causal, window=window,
             scale=scale if scale else q.shape[-1] ** -0.5, block=block,
             interpret=bool(interpret), with_lse=False, sink=sink,
+            causal_block=int(causal_block),
         )
         return out
     fn = _build(causal, window, scale, block, bool(interpret))
@@ -1235,6 +1274,22 @@ def _row_write_kernel(at_ref, *refs, tile: int):
         ).astype(out_ref.dtype)
 
 
+def _rows_write_kernel(at_ref, *refs, tile: int, rows: int):
+    # _row_write_kernel for ``rows`` consecutive rows from ``at``, all
+    # inside the one tile that holds ``at``
+    first = at_ref[pl.program_id(0)] % tile
+    n = len(refs) // 3
+    for new_ref, old_ref, out_ref in zip(refs[:n], refs[n:2 * n],
+                                         refs[2 * n:]):
+        at = jax.lax.broadcasted_iota(jnp.int32, old_ref.shape, 2)
+        out = old_ref[...].astype(jnp.float32)
+        for j in range(rows):
+            out = jnp.where(at == first + j,
+                            new_ref[:, :, j:j + 1, :].astype(jnp.float32),
+                            out)
+        out_ref[...] = out.astype(out_ref.dtype)
+
+
 def _interpret(interpret: bool | None) -> bool:
     if interpret is None:
         from mmlspark_tpu.core.env import is_tpu
@@ -1267,6 +1322,19 @@ def latent_row_write(rows, new, at, *, interpret: bool | None = None):
     (out,) = _cache_row_write((rows[:, None],), (new[:, None],), at,
                               interpret=_interpret(interpret))
     return out[:, 0]
+
+
+def cache_rows_write(k, v, k_new, v_new, at, *,
+                     interpret: bool | None = None):
+    """:func:`cache_row_write` for ``T`` consecutive rows a batch row:
+    rows ``at[b] .. at[b] + T - 1`` of every head of batch row ``b``
+    taken from ``k_new`` (B, Hkv, T, Dk) and ``v_new`` (B, Hkv, T, Dv),
+    in place on donated caches. ``at[b]`` is a multiple of ``T`` (a
+    denoising step writes the rows of one block on the grid of blocks),
+    so the rows lie in ONE tile of whole sublanes and of ``T``, the only
+    tile the kernel fetches and writes back."""
+    return _cache_rows_write((k, v), (k_new, v_new), at,
+                             interpret=_interpret(interpret))
 
 
 # jitted where they stand: a decode block calls each kernel once a layer
@@ -1310,6 +1378,42 @@ def _cache_row_write(caches: tuple, news: tuple, at, *, interpret: bool):
         name="cache_row_write",
     )(at, *(new[:, :, None].astype(c.dtype)
             for new, c in zip(news, caches)), *caches)
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def _cache_rows_write(caches: tuple, news: tuple, at, *, interpret: bool):
+    b, hk, L, _ = caches[0].shape
+    t = news[0].shape[2]
+    tile = np.lcm(32 // caches[0].dtype.itemsize, t)
+    if L % tile:
+        tile = L
+    at = jnp.clip(jnp.asarray(at, jnp.int32), 0, L - t)
+
+    def new_spec(new):
+        return pl.BlockSpec((1, hk, t, new.shape[3]),
+                            lambda i, at: (i, 0, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    def old_spec(cache):
+        return pl.BlockSpec((1, hk, tile, cache.shape[3]),
+                            lambda i, at: (i, 0, at[i] // tile, 0),
+                            memory_space=pltpu.VMEM)
+
+    n = len(caches)
+    return pl.pallas_call(
+        partial(_rows_write_kernel, tile=int(tile), rows=t),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b,),
+            in_specs=[*map(new_spec, news), *map(old_spec, caches)],
+            out_specs=[*map(old_spec, caches)],
+        ),
+        out_shape=tuple(jax.ShapeDtypeStruct(c.shape, c.dtype)
+                        for c in caches),
+        input_output_aliases={1 + n + i: i for i in range(n)},
+        interpret=bool(interpret),
+        name="cache_row_write",
+    )(at, *(new.astype(c.dtype) for new, c in zip(news, caches)), *caches)
 
 
 # the work list's one grid axis walks a row's blocks and then the next
@@ -1378,16 +1482,22 @@ def flash_decode_grouped(q, k, v, lengths, *, sink=None, scale=None,
                          block: int = _DECODE_GROUP_BLOCK,
                          interpret: bool | None = None,
                          name: str | None = None, values_in_keys: int = 0):
-    """Length-aware decode attention for ONE query token per row over
+    """Length-aware decode attention for the query rows of each slot over
     HEAD-MAJOR caches, a KV head's whole group of query heads per grid
     step.
 
-    ``q`` is (B, 1, H, Dk); ``k`` is (B, Hkv, L, Dk) and ``v`` is
+    ``q`` is (B, T, H, Dk); ``k`` is (B, Hkv, L, Dk) and ``v`` is
     (B, Hkv, L, Dv), ``Hkv`` dividing ``H`` (query head ``i`` reads KV
-    head ``i // (H // Hkv)``); ``lengths`` is (B,) int32, row ``b``
-    attending cache rows ``[0, lengths[b])`` and nothing else
-    (``lengths[b] == 0`` yields zeros). ``sink`` ((H,) float) joins the
-    softmax's denominator only. Returns (B, 1, H, Dv) in ``q``'s dtype.
+    head ``i // (H // Hkv)``); ``lengths`` is (B,) int32, EVERY query row
+    of slot ``b`` attending cache rows ``[0, lengths[b])`` and nothing
+    else (``lengths[b] == 0`` yields zeros). ``sink`` ((H,) float) joins
+    the softmax's denominator only. Returns (B, T, H, Dv) in ``q``'s
+    dtype. ``T`` is 1 for an autoregressive step; a denoising step of a
+    model that generates by diffusion over blocks has ``T`` > 1 rows a
+    slot, the block's, which see the slot's clean prefix and the block's
+    own rows alike: a KV head's ``T`` x group query rows are then one
+    group of the same grid step (the rows fold into the head axis), so
+    the cache streams once for all of them.
 
     Caches whose rows are ``f * Dk`` and ``f * Dv`` wide are PACKED
     (``ops.kv_cache.lane_pack``): ``(B, Hkv / f, L, f * Dk)``, ``f``
@@ -1416,6 +1526,23 @@ def flash_decode_grouped(q, k, v, lengths, *, sink=None, scale=None,
     scores and the weighted sum. ``Hkv`` is then 1 (a group of at least
     8 query heads), ``Dv`` whole lanes, and ``scale`` the caller's (the
     rows' width is not the width the scores were trained at)."""
+    b, t, h = q.shape[:3]
+    if t > 1:
+        # query head i of row r becomes head (i // g) * T * g + r * g +
+        # i % g of one row: KV head i // g keeps reading it, now among a
+        # group of T * g
+        hk = k.shape[1] * (k.shape[3] // q.shape[3] if k.ndim == 4 else 1)
+        g = h // hk
+        fold = q.reshape(b, t, hk, g, -1).transpose(0, 2, 1, 3, 4)
+        if sink is not None:
+            sink = jnp.broadcast_to(sink.reshape(hk, 1, g),
+                                    (hk, t, g)).reshape(-1)
+        out = flash_decode_grouped(
+            fold.reshape(b, 1, h * t, -1), k, v, lengths, sink=sink,
+            scale=scale, block=block, interpret=interpret, name=name,
+            values_in_keys=values_in_keys)
+        return out.reshape(b, hk, t, g, -1).transpose(0, 2, 1, 3, 4).reshape(
+            b, t, h, -1)
     return _flash_decode_grouped(
         q, k, v, jnp.asarray(lengths), sink, scale=scale, block=block,
         interpret=_interpret(interpret), name=name,

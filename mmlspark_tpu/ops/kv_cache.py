@@ -1,6 +1,7 @@
 """The KV cache's entry formats, and the two things a program does with
-an entry: a prefill writes rows into it, a decode step appends one row a
-slot and attends over the live rows.
+an entry: a prefill writes rows into it, a decode step writes a slot's
+rows of this step (one, or a denoising step's block) and attends over the
+live rows.
 
 One home for what the models (``models/``) and the pools (``serve/``)
 both have to know, below both. An ENTRY is what one block's cache is, in
@@ -327,6 +328,9 @@ def _head_major_step(entry, q, k, v, pos, live, *, window, sink, name, mesh,
                      rolled):
     from mmlspark_tpu.ops import flash_attention as kernels
 
+    if q.shape[1] > 1:
+        return _head_major_block_step(entry, q, k, v, pos, live,
+                                      window=window, sink=sink, name=name)
     b, rows = q.shape[0], entry.k.shape[2]
     _fused_step_only(entry, q, pos, window, rows=rows)
     # a ring: position p lies in row p % rows
@@ -343,6 +347,32 @@ def _head_major_step(entry, q, k, v, pos, live, *, window, sink, name, mesh,
         lengths = jnp.minimum(lengths, rows)
     # the kernel is jitted where it stands, so no module's scope names
     # it: ``name`` is what the trace shows, where the decode metrics look
+    return kernels.flash_decode_grouped(q, *new, lengths, sink=sink,
+                                        name=name), new
+
+
+def _head_major_block_step(entry, q, k, v, pos, live, *, window, sink, name):
+    """A denoising step of a model that generates by diffusion over
+    blocks: ``T`` rows a slot from ``pos`` (a multiple of ``T``) on, the
+    block's, written in place over whatever an earlier step of the block
+    wrote there (the last pass over a block, its clean close, writes the
+    rows that stay), then every row of the block read against the slot's
+    rows ``[0, pos + T)``: the clean prefix and the block itself, both
+    ways."""
+    from mmlspark_tpu.ops import flash_attention as kernels
+
+    b, t = q.shape[:2]
+    if window is not None or not jnp.ndim(pos):
+        raise ParamError(
+            "a step of several rows a slot is a denoising step over the "
+            "pool's full-length rows at per-row positions; got window "
+            f"{window}, pos of rank {jnp.ndim(pos)}")
+    # (b, t, hk, d) -> the entry's heads and width (lane_pack), rows third
+    packed = (b, t, entry.k.shape[1], -1)
+    new = HeadMajorKV(*kernels.cache_rows_write(
+        *entry, k.reshape(packed).transpose(0, 2, 1, 3),
+        v.reshape(packed).transpose(0, 2, 1, 3), pos))
+    lengths = decode_live_lengths(pos, b, live=live, rows=t)
     return kernels.flash_decode_grouped(q, *new, lengths, sink=sink,
                                         name=name), new
 
@@ -512,17 +542,22 @@ _STEPS = {Int8Rows: _int8_rows_step, HeadMajorKV: _head_major_step,
 
 def decode_step(entry, q, k, v, pos, live=None, *, window=None, sink=None,
                 name=None, mesh=None, rolled: bool = False, scale=None):
-    """One decode step over ``entry``, whatever its layout: append this
-    step's K/V row for every slot at ``pos`` and attend ``q`` over the
-    live rows. ``q`` is (B, 1, H, dk), ``k``/``v`` (B, 1, hk, d); ``pos``
-    is (B,) per-row positions (the serve engine's fused decode step,
-    which every pool-only layout requires) or, for linear rows, a scalar
-    (``generate()``, ``rolled`` where its buffers are circular). ``live``
-    ((B,) bool) zeroes dead rows' lengths, so the length-aware kernels
-    skip their cache traffic. The block's static facts: its ``window``
-    (None: full attention), its learned ``sink`` (head-major entries
-    only), the ``name`` its decode kernel has in a trace, its ``mesh``.
-    Returns ``(o, new entry)``: ``o`` (B, 1, H, dv), the entry of the
+    """One decode step over ``entry``, whatever its layout: write this
+    step's K/V rows for every slot from ``pos`` on and attend ``q`` over
+    the live rows. ``q`` is (B, T, H, dk), ``k``/``v`` (B, T, hk, d):
+    ``T`` is 1 for an autoregressive step, and a denoising step of a
+    model that generates by diffusion over blocks has the block's ``T``
+    rows, which a :class:`HeadMajorKV` entry of full-length rows takes
+    at per-row ``pos`` (a multiple of ``T``): every row of the block then
+    sees ``[0, pos + T)``. ``pos`` is (B,) per-row positions (the serve
+    engine's fused decode step, which every pool-only layout requires)
+    or, for linear rows, a scalar (``generate()``, ``rolled`` where its
+    buffers are circular). ``live`` ((B,) bool) zeroes dead rows'
+    lengths, so the length-aware kernels skip their cache traffic. The
+    block's static facts: its ``window`` (None: full attention), its
+    learned ``sink`` (head-major entries only), the ``name`` its decode
+    kernel has in a trace, its ``mesh``.
+    Returns ``(o, new entry)``: ``o`` (B, T, H, dv), the entry of the
     same type and leaves.
 
     A :class:`LatentRows` entry takes ``k`` (B, T, 1, dk), this call's

@@ -172,7 +172,13 @@ def moe_ffn_dropless(x, gate_w, w_in, b_in, w_out, b_out):
     return out.reshape(b, t, d).astype(x.dtype)
 
 
-def router_topk(x, router_w, select_bias, top_k: int, scale: float = 1.0):
+#: the routers' score kinds: ``sigmoid`` with a selection bias (the
+#: DeepSeek-V3 line), ``softmax`` over all experts (the Qwen3-MoE line)
+SIGMOID, SOFTMAX = "sigmoid", "softmax"
+
+
+def router_topk(x, router_w, select_bias, top_k: int, scale: float = 1.0,
+                score: str = SIGMOID):
     """Sigmoid scores, the choice by score plus a selection bias, the
     weights by score alone (the "noaux_tc" router of the DeepSeek-V3
     line, one group). ``x`` is (N, D); ``router_w`` (D, E);
@@ -181,11 +187,29 @@ def router_topk(x, router_w, select_bias, top_k: int, scale: float = 1.0):
     ``scale * z_e / (sum of the chosen z + 1e-20)`` (``scale`` is the
     line's ``routed_scaling_factor``, applied after the normalisation).
     The bias moves the choice and never a weight. All of it in float32,
-    the product at full precision: a choice is a step, not a rounding."""
-    z = jax.nn.sigmoid(jnp.dot(
+    the product at full precision: a choice is a step, not a rounding.
+
+    ``score="softmax"``: ``r = softmax(x W)`` over all ``E`` experts, the
+    ``top_k`` largest (taken on the logits, whose order softmax keeps
+    without rounding them together), weights ``r_e`` over the sum of the
+    chosen ``r`` (``norm_topk_prob``), times ``scale``; no selection
+    bias (``select_bias`` may be None)."""
+    logits = jnp.dot(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
-    ))
+    )
+    if score == SOFTMAX:
+        _, experts = jax.lax.top_k(logits, top_k)
+        chosen = jnp.take_along_axis(jax.nn.softmax(logits, axis=-1),
+                                     experts, axis=-1)
+        weights = chosen / chosen.sum(axis=-1, keepdims=True)
+        if scale != 1.0:
+            weights = weights * jnp.float32(scale)
+        return experts.astype(jnp.int32), weights
+    if score != SIGMOID:
+        raise ParamError(
+            f"router scores are '{SIGMOID}' or '{SOFTMAX}', got {score!r}")
+    z = jax.nn.sigmoid(logits)
     _, experts = jax.lax.top_k(z + select_bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(z, experts, axis=-1)
     weights = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20)
@@ -254,7 +278,7 @@ def held_tiles(tokens: int, held: int, top_k: int,
 
 def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, *,
                  top_k: int, first: int, valid=None, scale: float = 1.0,
-                 interpret: bool | None = None):
+                 interpret: bool | None = None, score: str = SIGMOID):
     """The part of a routed SwiGLU layer that THIS holder's experts give.
 
     The router scores all ``E`` experts (``router_w`` (D, E)) and every
@@ -266,7 +290,8 @@ def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, *,
     layer (expert parallelism's contract; on one chip there is no
     exchange to run). ``x`` is (B, T, D); ``valid`` ((B, T) bool) marks
     the real tokens: a pad or a dead row routes nowhere. ``scale``
-    multiplies every routing weight (:func:`router_topk`).
+    multiplies every routing weight and ``score`` is the router's kind
+    (:func:`router_topk`).
 
     Dropless at every length and fixed in shape: the held pairs are
     ranked inside their expert, every expert's rows are padded to whole
@@ -286,7 +311,7 @@ def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, *,
     n = b * t
     flat = x.reshape(n, d)
     experts, weights = router_topk(flat, router_w, select_bias, top_k,
-                                   scale)
+                                   scale, score)
     local = experts - first
     mine = (local >= 0) & (local < held)
     if valid is not None:

@@ -39,6 +39,15 @@ against the pool's ``cache_len``; ``step()`` runs one scheduler tick
 on it; ``run()`` loops ``step()`` until idle. Decode is greedy
 (temperature-0) — identical tokens to ``generate()`` per request, which
 is the engine's correctness contract.
+
+A model that GENERATES BY DIFFUSION OVER BLOCKS (a graph whose ``extra``
+has a ``block`` length, ``hybrid_lm(block=...)``) is served by the same
+loop with two other programs, chosen at construction: admission prefills
+the prompt's whole blocks under a block-causal mask and emits no token,
+and the tick runs ONE denoising program over whole blocks
+(``models.generate.make_denoise_block``) that commits several tokens a
+slot a step and delivers whole blocks (docs/SERVING.md "Block
+generation").
 """
 
 from __future__ import annotations
@@ -77,6 +86,7 @@ from mmlspark_tpu.models.generate import (
     greedy_next,
     init_cache,
     make_decode_block,
+    make_denoise_block,
     routing_totals,
 )
 from mmlspark_tpu.parallel.mesh import make_mesh, parse_mesh_axes
@@ -158,6 +168,24 @@ class ServeEngine:
                 f"serving needs a causal LM; '{graph.name}' has "
                 "causal=False"
             )
+        #: a model that generates by diffusion over blocks of this many
+        #: positions (``hybrid_lm``'s ``block``); 0: a token at a time
+        self._block_len = int(graph.extra.get("block") or 0)
+        if self._block_len:
+            refused = [what for what, on in (
+                ("async_host", async_host), ("paged", paged),
+                (f"kv_dtype={kv_dtype!r}", kv_dtype != "bf16"),
+                ("a mesh", mesh is not None),
+                ("prefill_chunk", prefill_chunk is not None),
+                (f"role={role!r} (the fleet's KV hand-off)",
+                 role != "both")) if on]
+            if refused:
+                raise FriendlyError(
+                    f"'{graph.name}' generates by diffusion over blocks of "
+                    f"{self._block_len}; its denoising program runs on one "
+                    "device over the dense bf16 pool, with a synchronous "
+                    f"host loop and whole prefills: drop {', '.join(refused)}"
+                )
         max_len = graph.input_shape[0] if graph.input_shape else None
         if cache_len is None:
             if not max_len:
@@ -612,6 +640,52 @@ class ServeEngine:
             registry=self.metrics.registry, recorder=self.recorder,
             expected_programs=self.num_decode_blocks,
         )
+        if self._block_len:
+            self._block_programs(_deq)
+
+    def _block_programs(self, deq) -> None:
+        """The two programs of a model that generates by diffusion over
+        blocks, in the places of the prefill and the decode block: a
+        prefill of a prompt's whole blocks that stops before the head
+        (no token comes of it), and the denoising program, ONE for every
+        count of blocks (the count is traced; at most ``decode_block``
+        tokens' worth, ``_most_blocks``)."""
+        graph, routed = self.graph, self._routed
+        self._denoise_steps = int(graph.extra["denoise_steps"])
+        self._most_blocks = max(1, self.decode_block // self._block_len)
+
+        def _prefill(variables, prompt, length):
+            # (1, B) padded prompt whose first ``length`` positions are
+            # whole blocks -> a length-B linear cache; pads route nowhere
+            cache = init_cache(graph, variables, 1, prompt.shape[1])
+            _, cache = _cached_apply(
+                graph, deq(variables), prompt, cache, 0,
+                valid=(jnp.arange(prompt.shape[1]) < length)[None, :]
+                if routed else None,
+                counters={} if routed else None, head=False)
+            return cache
+
+        self._prefill = RetraceWatchdog(
+            ProgramCountingJit(jax.jit(_prefill)), "serve.prefill",
+            registry=self.metrics.registry, recorder=self.recorder,
+            expected_programs=self.num_prefill_buckets,
+        )
+        denoise, most = make_denoise_block(graph), self._most_blocks
+
+        # named as the token engine's program: the trace's decode metrics
+        # find a decode program by this name
+        def decode_block(variables, buffers, pos, live, tok, masked, rem,
+                         n_blocks):
+            return denoise(deq(variables), buffers, pos, live, tok, masked,
+                           rem, n_blocks, most)
+
+        self._decode = RetraceWatchdog(
+            ProgramCountingJit(jax.jit(decode_block,
+                                       donate_argnums=(1, 2, 3))),
+            "serve.decode",
+            registry=self.metrics.registry, recorder=self.recorder,
+            expected_programs=1,
+        )
 
     # -- prefill buckets ---------------------------------------------------
 
@@ -685,7 +759,11 @@ class ServeEngine:
         this engine — one per ladder size T in {1, 2, 4, ...,
         decode_block}, the ceiling the compile-guard tests pin decode
         to. Scan iterations inside a block share one program; only
-        distinct static scan lengths compile separately."""
+        distinct static scan lengths compile separately. A model that
+        generates by diffusion over blocks has ONE denoising program,
+        whose count of blocks is traced."""
+        if self._block_len:
+            return 1
         return self.decode_block.bit_length()
 
     # -- fault handling ----------------------------------------------------
@@ -898,6 +976,9 @@ class ServeEngine:
                     f"'{self.graph.name}', got range [{lo}, {hi}]"
                 )
         total = int(prompt.size) + max_new_tokens
+        if self._block_len:
+            # the last block is denoised whole, past the budget's end
+            total = -(-total // self._block_len) * self._block_len
         if total > self.cache_len:
             raise FriendlyError(
                 f"prompt ({prompt.size}) + max_new_tokens "
@@ -1080,6 +1161,8 @@ class ServeEngine:
         brought in is quarantined alone, into ``finished``. ``region``
         is the ``serve.admit_one`` this runs under, where it does.
         Returns the first tokens emitted (0 or 1)."""
+        if self._block_len:
+            return self._admit_block(tick, finished, region)
         req = self._sched.pop_next()
         slot = self.pool.lease()
         if region is not None:
@@ -1427,6 +1510,69 @@ class ServeEngine:
         if done is not None:
             finished.append(done)
         return 1
+
+    def _admit_block(self, tick: int, finished: list, region=None) -> int:
+        """:meth:`_admit_one` for a model that generates by diffusion
+        over blocks: the prompt's (and a resume prefix's) WHOLE blocks
+        through the block-causal prefill into the leased slot, and what
+        is left of it inside a block kept as the committed start of the
+        slot's first block. No token comes of an admission, so the host
+        waits for nothing here: the prefill runs ahead of the next
+        denoising program. The ``request`` span's ``prefill`` event still
+        marks the prefill's end on the host, with its bucket. Returns
+        0."""
+        req = self._sched.pop_next()
+        slot = self.pool.lease()
+        if region is not None:
+            region.count(slot=slot)
+        span = self._spans.get(req.id)
+        if span is not None:
+            span.event("admitted", tick=tick, slot=slot)
+        seq = (np.concatenate([req.prompt, req.prefix])
+               if len(req.prefix) else req.prompt)
+        start = len(seq) // self._block_len * self._block_len
+        bucket = self.prefill_bucket(start)
+        padded = np.full((bucket,), self.pad_id, np.int32)
+        padded[:start] = seq[:start]
+        family = f"prefill[{bucket}]"
+        attempts, landed = 0, False
+        with self._tracer.region("serve.prefill", tick=tick, request=req.id,
+                                 bucket=bucket) as timed:
+            while True:
+                try:
+                    if self._faults is not None:
+                        self._faults.fire("serve.prefill", tick=tick,
+                                          request=req.id,
+                                          replica=self._replica)
+                    with self._tracer.region("serve.prefill_dispatch",
+                                             request=req.id):
+                        cache = self._prefill(
+                            self.variables, jnp.asarray(padded[None]),
+                            np.int32(start))
+                    self._pool_write(req.id, slot, cache, start)
+                    landed = True
+                    break
+                except Exception as e:
+                    if is_resource_exhausted(e):
+                        self._note_oom(tick, "serve.prefill")
+                    elif not is_transient(e):
+                        raise
+                    attempts += 1
+                    if attempts > self._retry_limit:
+                        break
+                    self._backoff(attempts)
+        if not landed:
+            finished.append(self._quarantine_unactivated(
+                req, slot, tick, "prefill_failed"))
+            return 0
+        ms = round(timed.ms, 3)
+        if span is not None:
+            span.event("prefill", tick=tick, bucket=bucket, ms=ms, reused=0)
+        self.metrics.perf.record_dispatch(family, timed.ms / 1e3, tokens=0)
+        self.recorder.record("dispatch", tick=tick, family=family, ms=ms,
+                             tokens=0)
+        self._sched.activate_block(slot, req, start, seq[start:], tick)
+        return 0
 
     def _pool_write(self, request: int, slot: int, cache: dict,
                     length: int, start: int = 0) -> None:
@@ -2102,6 +2248,8 @@ class ServeEngine:
         remaining batch — every request gets a definite terminal status
         instead of wedging ``run()``. Appends terminal results to
         ``finished``; returns the real tokens consumed this tick."""
+        if self._block_len:
+            return self._denoise_phase(tick, finished)
         attempts = 0
         while self._sched.active:
             n_active = len(self._sched.active)
@@ -2208,6 +2356,118 @@ class ServeEngine:
                 retire.count(finished=len(finished) - before)
             return n_tokens
         return 0
+
+    def _denoise_phase(self, tick: int, finished: list) -> int:
+        """:meth:`_decode_phase` for a model that generates by diffusion
+        over blocks: ONE denoising program (``models.generate.
+        make_denoise_block``) over whole blocks for every active slot, as
+        many blocks as the slot that needs the fewest still needs, at
+        most ``decode_block`` tokens' worth, behind the same retry and
+        degradation. Every slot starts it at a block's start: admission
+        happens between dispatches, and a dispatch ends on a block's
+        close. Returns the tokens served."""
+        length = self._block_len
+        attempts = 0
+        while self._sched.active:
+            states = list(self._sched.active.items())
+            pre_pos = {slot: st.pos for slot, st in states}
+            tok, masked, rem, need = self._sched.denoise_inputs(
+                length, self.pad_id)
+            n_blocks = max(1, min(need, self._block_cap // length))
+            steps = n_blocks * (self._denoise_steps + 1)
+            try:
+                with self._tracer.region("serve.decode", tick=tick,
+                                         block=steps) as issue:
+                    if self._faults is not None:
+                        self._faults.fire("serve.decode", tick=tick,
+                                          replica=self._replica)
+                    blocks, live, buffers, positions, counts, *stats = \
+                        self._decode(
+                            self.variables, self.pool.buffers,
+                            self.pool.positions, self.pool.live,
+                            jnp.asarray(tok), jnp.asarray(masked),
+                            jnp.asarray(rem), np.int32(n_blocks))
+                    self.pool.buffers = buffers
+                    self.pool.positions = positions
+                    self.pool.live = live
+            except Exception as e:
+                if is_resource_exhausted(e):
+                    self._note_oom(tick, "serve.decode")
+                elif not is_transient(e):
+                    raise
+                attempts += 1
+                if attempts > self._retry_limit:
+                    for slot, _st in states:
+                        if slot in self._sched.active:
+                            finished.append(self._quarantine_slot(
+                                slot, tick, "decode_failed"))
+                    return 0
+                self._backoff(attempts)
+                continue
+            block = {"toks": (blocks, counts), "live": live, "stats": stats,
+                     "t_block": steps, "states": states,
+                     "pre_pos": pre_pos, "n_blocks": n_blocks,
+                     "family": f"denoise[T={steps}]"}
+            toks_h, _live_h, done = self._fetch_block(block, tick)
+            with self._tracer.region("serve.retire", tick=tick) as retire:
+                before = len(finished)
+                n_tokens = self._consume_blocks(
+                    block, done - issue.t0, toks_h, tick, finished)
+                retire.count(finished=len(finished) - before)
+            return n_tokens
+        return 0
+
+    def _consume_blocks(self, block: dict, decode_s: float, fetched,
+                        tick: int, finished: list) -> int:
+        """Fold one fetched denoising program into the scheduler:
+        validate, serve, account, retire. The ``dispatch`` event carries
+        the program's counters beside the routing ones: the live slots'
+        denoising steps, the tokens they committed and the blocks they
+        closed. Returns the tokens served."""
+        states, pre_pos = block["states"], block["pre_pos"]
+        steps, family = block["t_block"], block["family"]
+        if fetched is None:
+            for slot, _st in states:
+                if slot in self._sched.active:
+                    finished.append(self._quarantine_slot(
+                        slot, tick, "device_get_failed"))
+            return 0
+        blocks_h, counts_h = fetched
+        blocks_h = np.asarray(blocks_h)
+        bad = (blocks_h < 0).any(axis=(1, 2))
+        if self._vocab is not None:
+            bad |= (blocks_h >= int(self._vocab)).any(axis=(1, 2))
+        for slot, _st in states:
+            if bad[slot] and slot in self._sched.active:
+                finished.append(self._quarantine_slot(
+                    slot, tick, "poisoned_token"))
+        blk_finished, served, ran = self._sched.consume_blocks(
+            blocks_h, block["n_blocks"], tick)
+        n_tokens = sum(served.values())
+        per_block, length = self._denoise_steps + 1, self._block_len
+        # every micro-step of a block reads its slot's clean prefix and
+        # the block's own rows
+        live_kv = sum(per_block * (b + 1) * length + per_block * pre_pos[slot]
+                      for slot, r in ran.items() for b in range(r))
+        self.metrics.record_decode(
+            len(states), decode_s, tokens_emitted=n_tokens, block=steps,
+            live_kv=live_kv, cache_len=self.cache_len)
+        self.metrics.perf.record_dispatch(family, decode_s, tokens=n_tokens)
+        decode_ms = round(decode_s * 1e3, 3)
+        self.recorder.record(
+            "dispatch", tick=tick, family=family, ms=decode_ms,
+            tokens=n_tokens, **block.get("routing", {}),
+            **{name: int(v) for name, v in counts_h.items()})
+        for slot, st in states:
+            span = self._spans.get(st.req.id)
+            if span is not None:
+                span.event("decode", tick=tick, pos=pre_pos[slot],
+                           n_active=len(states), block=steps,
+                           blocks=ran.get(slot, 0),
+                           tokens=served.get(slot, 0), step_ms=decode_ms)
+        finished.extend(blk_finished)
+        self._note_clean_dispatch(tick)
+        return n_tokens
 
     def _consume_block(self, block: dict, decode_s: float, toks_h,
                        live_h, tick: int, finished: list) -> int:
@@ -2486,6 +2746,11 @@ class ServeEngine:
         ``serve.handoff`` fault hook; a payload that cannot land falls
         back to a full local prefill. Returns the new engine-local
         id."""
+        if self._block_len:
+            raise FriendlyError(
+                f"'{self.graph.name}' generates by diffusion over blocks; "
+                "a KV hand-off carries one token a time's first token and "
+                "linear rows: resubmit the request instead")
         prompt = np.asarray(payload["prompt"], np.int32)
         prefix = np.asarray(payload.get("prefix", ()), np.int32)
         max_new_tokens = int(payload["max_new_tokens"])
@@ -2658,6 +2923,11 @@ class ServeEngine:
         tiny and device-layout-agnostic (a single-device snapshot
         restores onto a mesh engine, and vice versa). Call between
         ``step()``s; hand the dict to :meth:`restore` after a crash."""
+        if self._block_len:
+            raise FriendlyError(
+                f"'{self.graph.name}' generates by diffusion over blocks; "
+                "its snapshot would have to carry each slot's block in "
+                "progress, which it does not yet: no snapshot or restore")
         active = []
         for slot, st in sorted(self._sched.active.items()):
             req = st.req

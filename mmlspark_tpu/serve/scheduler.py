@@ -96,6 +96,11 @@ class _SlotState:
     last_token: int
     out: list = field(default_factory=list)
     first_token_tick: int = 0
+    #: a model that generates by diffusion over blocks: ``pos`` is the
+    #: start of the slot's next block, and ``tail`` the committed tokens
+    #: its first block starts with (a prompt's end inside a block; empty
+    #: once that block is served)
+    tail: np.ndarray = field(default_factory=lambda: _EMPTY_PREFIX)
 
 
 @dataclass
@@ -221,6 +226,79 @@ class ContinuousBatchScheduler:
             return self._finish(st, "completed", tick)
         self.active[slot] = st
         return None
+
+    def activate_block(self, slot: int, req: ServeRequest, start: int,
+                       tail: np.ndarray, tick: int) -> None:
+        """Install a request of a model that generates by diffusion over
+        blocks: its clean prefix (prompt and any resume prefix) is in
+        the pool up to ``start``, a block's start; ``tail`` is the rest,
+        the committed start of its first block. No token is emitted at
+        admission, so the request always joins the batch."""
+        self.active[slot] = _SlotState(
+            req=req, pos=start, last_token=0, out=list(req.prefix),
+            first_token_tick=tick, tail=np.asarray(tail, np.int32))
+
+    def denoise_inputs(self, length: int, pad_id: int
+                       ) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
+        """Host-side inputs of one denoising program over whole blocks of
+        ``length``: the ``(S, length)`` tokens and mask each slot's next
+        block starts with (a first block's committed tail, the rest
+        masked), the ``(S,)`` tokens each may still serve, and the
+        fewest BLOCKS any active slot still needs: the engine runs no
+        more, so no budget ends before a dispatch's last block. Free
+        slots carry a masked block and no budget."""
+        s = self.pool.num_slots
+        tok = np.full((s, length), pad_id, np.int32)
+        masked = np.ones((s, length), bool)
+        rem = np.zeros((s,), np.int32)
+        need = []
+        for slot, st in self.active.items():
+            tail = len(st.tail)
+            tok[slot, :tail] = st.tail
+            masked[slot, :tail] = False
+            rem[slot] = left = st.req.max_new_tokens - len(st.out)
+            # the first block serves its masked positions, each later
+            # one a whole block
+            need.append(1 + max(0, -(-(left - (length - tail)) // length)))
+        return tok, masked, rem, min(need)
+
+    def consume_blocks(self, blocks: np.ndarray, n_blocks: int,
+                       tick: int) -> tuple[list[RequestResult],
+                                           dict[int, int], dict[int, int]]:
+        """Fold one denoising program's ``(S, most, L)`` closed blocks
+        into per-slot state: each active slot serves, block by block, the
+        positions that were masked when the block started, until its
+        budget or its EOS retires it (a block the budget ends inside was
+        denoised whole and is served up to the budget). Returns
+        ``(finished, {slot: tokens served}, {slot: blocks it ran})``."""
+        finished: list[RequestResult] = []
+        served: dict[int, int] = {}
+        ran: dict[int, int] = {}
+        length = blocks.shape[2]
+        for slot, st in list(self.active.items()):
+            req, taken = st.req, 0
+            for b in range(n_blocks):
+                ran[slot] = b + 1
+                block = blocks[slot, b, len(st.tail):]
+                st.tail = _EMPTY_PREFIX
+                st.pos += length
+                done = False
+                for nxt in block:
+                    nxt = int(nxt)
+                    st.out.append(nxt)
+                    st.last_token = nxt
+                    taken += 1
+                    if len(st.out) >= req.max_new_tokens or (
+                            req.eos_id is not None and nxt == req.eos_id):
+                        done = True
+                        break
+                if done:
+                    del self.active[slot]
+                    self.pool.free(slot)
+                    finished.append(self._finish(st, "completed", tick))
+                    break
+            served[slot] = taken
+        return finished, served, ran
 
     def decode_block_inputs(
         self, pad_id: int

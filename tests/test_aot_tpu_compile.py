@@ -710,3 +710,86 @@ def test_lfm2_decode_block_prefill_and_pool_write_fit_one_v5e(
     assert not _pool_copies(compiled.as_text(), (slots, 4, rows, 128))
     with capsys.disabled():
         print(capsys.readouterr().out, end="")
+
+
+# -- sdar-30b-a3b-chat.blockgen-backlog: the denoising program and the widest
+# prefill at the cell's own size, from the configuration's file
+
+
+def test_sdar_denoising_program_and_prefill_fit_one_v5e(chip, monkeypatch,
+                                                        capsys):
+    """The cell's denoising program (6 layers of K/V ``(64, 4, 4096,
+    128)``, 4 rows a slot a micro-step, the block count traced) and its
+    2,048-row block-causal prefill compile for the described v5e inside
+    15.75 GB: every pool entry updated in place, no pool-sized copy, the
+    block read and its rows' write once a layer, the expert layer's three
+    products once a layer, and the logits' 155 MB never a temporary."""
+    import json
+    from pathlib import Path
+
+    from mmlspark_tpu.models import build_model
+    from mmlspark_tpu.models.generate import (
+        _cached_apply,
+        init_cache,
+        make_denoise_block,
+    )
+    from mmlspark_tpu.serve.cache_pool import SlotCachePool
+
+    monkeypatch.setattr("mmlspark_tpu.core.env.is_tpu", lambda: True)
+    cfg = json.loads((Path(__file__).resolve().parent.parent / "benchmark"
+                      / "configs" / "sdar-30b-a3b-chat.json").read_text())
+    graph = build_model("hybrid_lm", **cfg["program"]["model"])
+    variables = jax.eval_shape(
+        graph.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    slots, rows = (cfg["program"]["engine"][k] for k in ("slots",
+                                                          "cache_len"))
+    pool = SlotCachePool(graph, variables, 1, rows)
+    buffers = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((slots,) + a.shape[1:], a.dtype),
+        pool.buffers)
+    one = slots * 4 * rows * 128 * 2
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_)
+    tok = jax.ShapeDtypeStruct((slots, 4), jnp.int32)
+    masked = jax.ShapeDtypeStruct((slots, 4), jnp.bool_)
+    count = jax.ShapeDtypeStruct((), jnp.int32)
+    block = make_denoise_block(graph)
+    compiled = jax.jit(
+        lambda v, b, pos, lv, t, m, rem, n: block(v, b, pos, lv, t, m, rem,
+                                                  n, 8),
+        donate_argnums=(1, 2, 3),
+    ).lower(*_on(chip, [variables, buffers, ints, live, tok, masked, ints,
+                        count])).compile()
+    text = compiled.as_text()
+    assert not _pool_copies(text, (slots, 4, rows, 128))
+    for kernel, calls in (("attn_block_decode", 6), ("cache_row_write", 6),
+                          ("moe_gate", 6), ("moe_down", 6)):
+        assert len(set(re.findall(rf"%({kernel}\.\d+) = ", text))) == calls
+    memory = compiled.memory_analysis()
+    print("sdar denoising program: arguments",
+          memory.argument_size_in_bytes, "aliased", memory.alias_size_in_bytes,
+          "temporaries", memory.temp_size_in_bytes)
+    assert memory.alias_size_in_bytes >= 12 * one
+    assert memory.temp_size_in_bytes < 64 << 20
+    limit = int(15.75 * 2 ** 30)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < limit
+
+    def prefill(v, prompt, length):
+        cache = init_cache(graph, v, 1, prompt.shape[1])
+        return _cached_apply(
+            graph, v, prompt, cache, 0, head=False, counters={},
+            valid=(jnp.arange(prompt.shape[1]) < length)[None, :])[1]
+
+    compiled = jax.jit(prefill).lower(*_on(chip, [
+        variables, jax.ShapeDtypeStruct((1, 2048), jnp.int32), count])
+    ).compile()
+    assert "attn_block_prefill" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    print("sdar prefill 2048: arguments", memory.argument_size_in_bytes,
+          "temporaries", memory.temp_size_in_bytes)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes + 12 * one) < limit
+    with capsys.disabled():
+        print(capsys.readouterr().out, end="")
