@@ -109,8 +109,8 @@ GLOBAL_LEAVES = mimo.GLOBAL_LEAVES
 WEIGHT_STD = mimo.WEIGHT_STD
 #: a token whose row's eighth and ninth router logits lie closer than
 #: this in any layer of the step that commits it is left out. At the
-#: cell's size (``tools/blockgen_tie_readings.py``, my chip run, PR 40,
-#: 8 requests of 1,024 served tokens): a row's least margin over the six
+#: cell's size (``tools/blockgen_tie_readings.py``, measured on one v5e
+#: chip, 8 requests of 1,024 served tokens): a row's least margin over the six
 #: layers is under 0.001 for a tenth of the tokens and under 0.0068 for
 #: half; at widths 0, 0.003, 0.01 and 0.03 the rule keeps 8,192, 6,066,
 #: 2,917 and 362 tokens, whose widest gap reads 0.079, 0.059, 0.043 and

@@ -7,14 +7,14 @@ the harness's own run, then the reference's replay over the same sampled
 requests once in float32 and once for every ``--modes``; every width is
 then read off the replays' margins, at no further cost.
 
-    chiprun --timeout 1800 -- python tools/blockgen_tie_readings.py \\
+    python tools/blockgen_tie_readings.py \\
         --workload sdar-30b-a3b-chat.blockgen-backlog --seed 11 \\
         --seconds 30 --ties 0 0.003 0.01 0.03 \\
-        --modes fp8 causal_block dirty_close no_topk_norm no_qk_norm
+        --modes fp8 causal_block dirty_close no_topk_norm no_qk_norm \\
+        --out blockgen_tie_readings.jsonl   # one chip, up to half an hour
 
 One JSON line a width (and one with the margins' and gaps' quantiles, one
-a mode with its time), to stdout and
-``chiprun_out/blockgen_tie_readings.jsonl``.
+a mode with its time), to stdout and to the file ``--out`` names.
 """
 
 from __future__ import annotations
@@ -31,8 +31,6 @@ import numpy as np  # noqa: E402
 
 from benchmark import family, run  # noqa: E402
 from tools.route_tie_readings import emit, window  # noqa: E402
-
-OUT = ROOT / "chiprun_out" / "blockgen_tie_readings.jsonl"
 
 
 def replays(fam, samples, length: int, key, mode: str, layers) -> dict:
@@ -54,7 +52,10 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=15.0)
     ap.add_argument("--ties", type=float, nargs="+", required=True)
     ap.add_argument("--modes", nargs="*", default=[])
+    ap.add_argument("--out", type=Path, required=True,
+                    help="file the JSON lines are appended to")
     args = ap.parse_args(argv)
+    out = args.out
     manifest = run.load_json(str(ROOT), "BENCHMARK.json")
     files = run.cell_files(manifest, args.workload, str(ROOT))
     run.chips_or_exit(1)
@@ -63,14 +64,14 @@ def main(argv=None) -> int:
                          files["control_mode"])
     state, samples, length = window(fam, files["mix"], args.seed,
                                     args.seconds)
-    emit(OUT, workload=args.workload, seed=args.seed, run=state["numbers"])
+    emit(out, workload=args.workload, seed=args.seed, run=state["numbers"])
     key = family.seed_key(args.seed)
     sizes = fam.reference._frozen(fam.sz)
     layers = [fam.reference._layer_params(key, i, sizes)
               for i in range(fam.sz["layers"])]
     t0 = time.monotonic()
     ref = replays(fam, samples, length, key, "f32", layers)
-    emit(OUT, tokens=int(ref["gap"].size), cut=int(ref["cut"].sum()),
+    emit(out, tokens=int(ref["gap"].size), cut=int(ref["cut"].sum()),
          seconds=round(time.monotonic() - t0, 1), **{
              f"{name}_quantiles": [round(float(np.quantile(ref[name], q)), 5)
                                    for q in (0.01, 0.05, 0.1, 0.25, 0.5,
@@ -81,13 +82,13 @@ def main(argv=None) -> int:
         t0 = time.monotonic()
         lows[mode] = replays(fam, samples, length, key, mode,
                              layers)["low_gap"]
-        emit(OUT, mode=mode, seconds=round(time.monotonic() - t0, 1),
+        emit(out, mode=mode, seconds=round(time.monotonic() - t0, 1),
              control_gap_all=float(np.where(ref["cut"], 0.0, lows[mode])
                                    .max()))
     for tie in args.ties:
         keep = fam.reference.kept(dict(fam.sz, tie=tie), ref)
         gaps = np.where(keep, ref["gap"], 0.0)
-        emit(OUT, tie=tie, kept=int(keep.sum()),
+        emit(out, tie=tie, kept=int(keep.sum()),
              served_gap=float(gaps.max()),
              top5=[round(float(g), 4) for g in np.sort(gaps)[-5:]],
              **{f"control_gap.{mode}": float(np.where(keep, low, 0.0).max())
