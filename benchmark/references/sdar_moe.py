@@ -31,29 +31,25 @@ against the clean prefix and the block's own rows, both ways, and commits
 the masked positions of highest confidence (the softmax probability of
 the greedy token, ``mask_id`` excluded), ``n // S`` a step of the ``n``
 masked at the start, one more while ``s < n % S``; then the clean block
-closes. :func:`served_gaps_fn` REPLAYS that over a served request: ONE
-clean block-causal forward over the prompt and the served tokens gives
-every layer's K/V of the clean prefix. That is exact: under the
-block-causal mask no row of a finished block depends on any later
-position, so the clean forward's rows of a block are the rows its close
-wrote, given the prefix's. Each step then runs the ``L`` rows of every
-generated block, in every state of its commitments that the static
-schedule allows, against those K/V and the block's own rows; the
-committed values are the SERVED tokens, and a token's gap (its reference
-logit below the reference's best, ``mask_id`` excluded) is read at the
-step that commits it. WHICH positions a step commits is not compared:
-under seeded random weights the masked positions of a block read the
-same inputs but their rotation, so their confidences differ by a
-rounding; block by block, the order of commitment under which the
-block's widest gap is least is the one read (the CPU tests hold the
-program's order to its own model's confidences). Left out of the
-comparison, and counted (a line on standard error says how many tokens
-were kept of each request):
-
-- a token whose row met a ROUTER near-tie (its eighth and ninth router
-  logits within :data:`TIE`) in any layer of the step that commits it;
-- a trailing block the budget cut: its unserved tokens were inputs of
-  its later steps.
+closes. :func:`replay` REPLAYS that over a served request: ONE clean
+block-causal forward over the prompt and the served tokens gives every
+layer's K/V of the clean prefix. That is exact: under the block-causal
+mask no row of a finished block depends on any later position, so the
+clean forward's rows of a block are the rows its close wrote, given the
+prefix's. Each step then runs the ``L`` rows of every generated block,
+in every state of its commitments that the static schedule allows,
+against those K/V and the block's own rows, the committed values being
+the SERVED tokens; a token's gap (its reference logit below the
+reference's best, ``mask_id`` excluded) is read at the step that commits
+it. Which positions a step commits is held to the REFERENCE'S OWN
+confidences: an order is read only if at each step no position it
+commits lies more than ``ORDER_TIE`` below one it leaves masked (the
+reference's own order always is; the width is what the program's
+rounding can flip). Of the allowed orders, block by block, the one under
+which the block's widest gap is least is read. Every served token is
+compared but those of a trailing block the budget cut: its unserved
+tokens were inputs of its later steps (a line on standard error says how
+many were compared).
 
 ``mode`` is the arithmetic of the linear layers (``f32``; ``bf16``,
 ``int8``, ``fp8`` round both operands: the control) or one of the four
@@ -62,8 +58,9 @@ the rows of its block at or before it), ``dirty_close`` (a generated
 block's K/V from its last denoising pass: the positions the last step
 committed still masked), ``no_topk_norm`` (the chosen experts weighted
 by ``r_e`` unnormalised), ``no_qk_norm`` (the heads' norms left out). A
-mode's token at each position is its greedy token at the step at which
-the float32 replay commits that position, with the same inputs.
+mode's token at each row is its greedy token on the same inputs, and its
+gaps are read as the served tokens' are: block by block under the
+allowed order whose widest gap is least.
 
 Parameters are made layer by layer from the key, every number one that
 bfloat16 holds exactly (the ``mimo_v2_flash`` reference says why and
@@ -107,18 +104,10 @@ ROUTED_LEAVES = {name: leaf for name, leaf in mimo.ROUTED_LEAVES.items()
                  if name != "select_bias"}
 GLOBAL_LEAVES = mimo.GLOBAL_LEAVES
 WEIGHT_STD = mimo.WEIGHT_STD
-#: a token whose row's eighth and ninth router logits lie closer than
-#: this in any layer of the step that commits it is left out. At the
-#: cell's size (``tools/blockgen_tie_readings.py``, measured on one v5e
-#: chip, 8 requests of 1,024 served tokens): a row's least margin over the six
-#: layers is under 0.001 for a tenth of the tokens and under 0.0068 for
-#: half; at widths 0, 0.003, 0.01 and 0.03 the rule keeps 8,192, 6,066,
-#: 2,917 and 362 tokens, whose widest gap reads 0.079, 0.059, 0.043 and
-#: 0.021, where the float8 control reads 0.276 at the first three and
-#: 0.162 at the last. The gap needs no rule to stay under the controls
-#: (one router choice of eight, weighted by its share, moves a logit by
-#: little); the rule takes the widest sound readings down by half
-TIE = 0.01
+#: a step's commitment is read under an order of the block's positions
+#: that the reference's own confidences (logs of probabilities) allow: no
+#: position committed lies more than this below one left masked
+ORDER_TIE = 0.03
 
 
 def sizes(cfg: dict) -> dict:
@@ -144,7 +133,7 @@ def sizes(cfg: dict) -> dict:
         "block_len": int(model["block"]),
         "denoise_steps": int(model["denoise_steps"]),
         "mask_id": int(model["mask_id"]),
-        "tie": TIE,
+        "order_tie": ORDER_TIE,
     }
 
 
@@ -245,20 +234,15 @@ def sees(qpos, kpos, sz: dict, mode: str):
 
 
 def route(h, p, sz: dict, mode: str):
-    """The chosen experts (1, T, k), their weights, and the MARGIN of the
-    choice (1, T): how far the last chosen router logit lies above the
-    first left out (a near-tie where it is under ``sz["tie"]``). In
-    float32 whatever the mode: a choice is a step, not a rounding."""
-    k = sz["top_k"]
+    """The chosen experts (1, T, k) and their weights. In float32
+    whatever the mode: a choice is a step, not a rounding."""
     logits = jnp.matmul(h, p["router_w"], precision=HI)
-    top, experts = jax.lax.top_k(logits, k + 1)
-    margin = top[..., k - 1] - top[..., k]
-    experts = experts[..., :k]
+    _, experts = jax.lax.top_k(logits, sz["top_k"])
     chosen = jnp.take_along_axis(jax.nn.softmax(logits, axis=-1), experts,
                                  axis=-1)
     if mode != "no_topk_norm":
         chosen = chosen / chosen.sum(-1, keepdims=True)
-    return experts, chosen, margin
+    return experts, chosen
 
 
 def experts_linear(xs, w, sizes, mode: str):
@@ -277,9 +261,9 @@ def moe(h, p, sz: dict, mode: str, share: tuple | None = None):
     """What the experts ``share = (first, count)`` (default: all) add:
     each (token, expert) pair that fell on one of them through that
     expert's SwiGLU, weighted by the router's weight (the pairs sorted by
-    expert, :func:`experts_linear`); and the routing's margins."""
+    expert, :func:`experts_linear`)."""
     first, count = share or sz["held"]
-    experts, weights, margin = route(h, p, sz, mode)
+    experts, weights = route(h, p, sz, mode)
     flat = h.reshape(-1, h.shape[-1])
     local = experts.reshape(-1) - first
     mine = (local >= 0) & (local < count)
@@ -298,26 +282,23 @@ def moe(h, p, sz: dict, mode: str, share: tuple | None = None):
     w = jnp.where(mine, weights.reshape(-1), 0.0)[order]
     ys = jnp.where(mine[order][:, None], ys * w[:, None], 0.0)
     out = jnp.zeros_like(flat).at[order // sz["top_k"]].add(ys)
-    return out.reshape(h.shape), margin
+    return out.reshape(h.shape)
 
 
 def ffn_half(x, p, sz: dict, mode: str):
-    out, margin = moe(mimo.rms_norm(x, p["ln2_g"], sz["eps"]), p, sz, mode)
-    return x + out, margin
+    return x + moe(mimo.rms_norm(x, p["ln2_g"], sz["eps"]), p, sz, mode)
 
 
 def block(x, p, sz: dict, mode: str):
     """One layer over the whole stream ``x`` (1, T, d), block-causal:
-    the stream after it, its keys and values, and its routing's
-    margins."""
+    the stream after it, its keys and values."""
     t = x.shape[1]
     positions = jnp.arange(t)
     q, k, v = qkv(mimo.rms_norm(x, p["ln1_g"], sz["eps"]), p, sz, positions,
                   mode)
     x = x + attend(q, k, v, sees(positions, positions, sz, mode), p, sz,
                    mode)
-    x, margin = ffn_half(x, p, sz, mode)
-    return x, k, v, margin
+    return ffn_half(x, p, sz, mode), k, v
 
 
 head = mimo.head
@@ -336,7 +317,7 @@ def block_rows(x, keys, values, rows, group, p, sz: dict, mode: str):
     """One layer over a denoising step's block rows ``x`` (1, R, d) at
     positions ``rows`` (R,): each row sees the clean rows ``keys`` /
     ``values`` (1, T, hk, D) before its block's start, and the rows of its
-    own ``group`` (one block in one state of its commitments) both ways
+    own ``group`` (its block) both ways
     (causal under the ``causal_block`` fault). A pad row (position -1,
     a group of its own) sees its group's rows."""
     length = sz["block_len"]
@@ -381,7 +362,7 @@ def _layer_params(key, i, sizes):
 
 @functools.partial(jax.jit, static_argnames=("m", "sizes"))
 def _clean_layer(x, p, m, sizes):
-    return block(x, _f32(p), dict(sizes), m)[:3]
+    return block(x, _f32(p), dict(sizes), m)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "sizes"))
@@ -400,9 +381,11 @@ def _logits(x, g, m, sizes):
 
 @jax.jit
 def _numbers(logits, served):
-    """Per row: the best logit and the served token's."""
+    """Per row: the best logit, the served token's, and the log of the
+    softmax's sum (the row's confidence is the best less it)."""
     return (logits.max(-1),
-            jnp.take_along_axis(logits, served[:, None], axis=1)[:, 0])
+            jnp.take_along_axis(logits, served[:, None], axis=1)[:, 0],
+            jax.nn.logsumexp(logits, axis=-1))
 
 
 @jax.jit
@@ -422,15 +405,45 @@ def _clean(glob, layers, seq, sizes, m):
 
 
 def _rows(glob, layers, kv, ids, rows, group, sizes, m):
-    """The last hidden states of block rows and each row's least routing
-    margin over the layers."""
-    x = glob["wte"][jnp.asarray(ids)][None]
-    margin = jnp.full(ids.shape, jnp.inf)
+    """The last hidden states of block rows."""
+    x = glob["wte"][ids][None]
     for p, (k, v) in zip(layers, kv):
-        x, layer = _rows_layer(x, k, v, jnp.asarray(rows), jnp.asarray(group),
-                               p, m, sizes)
-        margin = jnp.minimum(margin, layer[0])
-    return x, margin
+        x = _rows_layer(x, k, v, rows, group, p, m, sizes)
+    return x
+
+
+def _passes(glob, layers, kvs: dict, ids, rows, served, sizes) -> tuple:
+    """Block rows, ``ids`` at positions ``rows`` (each block's ``L`` rows
+    in one state a group), in passes of ``ROW_BUCKET`` rows. Over the
+    float32 K/V ``kvs["f32"]``: per row, the gap of the ``served`` token
+    below the best logit and the row's confidence (the best logit less
+    the log of the softmax's sum); over each other mode's K/V: the gap of
+    the token that mode puts first."""
+    sz = dict(sizes)
+    n = len(ids)
+    total = -(-n // ROW_BUCKET) * ROW_BUCKET
+
+    def pad(a, fill):
+        return np.pad(a, (0, total - n), constant_values=fill)
+
+    ids, rows, served = pad(ids, sz["mask_id"]), pad(rows, -1), pad(served, 0)
+    group = np.arange(total) // sz["block_len"]
+    gaps = {m: np.zeros(total, np.float32) for m in kvs}
+    conf = np.zeros(total, np.float32)
+    for lo in range(0, total, ROW_BUCKET):
+        cut = slice(lo, lo + ROW_BUCKET)
+        args = (jnp.asarray(ids[cut]), jnp.asarray(rows[cut]),
+                jnp.asarray(group[cut]), sizes)
+        ref = _logits(_rows(glob, layers, kvs["f32"], *args, "f32"), glob,
+                      "f32", sizes)
+        best, at, lse = _numbers(ref, jnp.asarray(served[cut]))
+        gaps["f32"][cut], conf[cut] = best - at, best - lse
+        for m, kv in kvs.items():
+            if m != "f32":
+                low = _logits(_rows(glob, layers, kv, *args, m), glob, m,
+                              sizes)
+                gaps[m][cut] = best - _at_argmax(ref, low)
+    return {m: g[:n] for m, g in gaps.items()}, conf[:n]
 
 
 def _contexts(masked: list, counts: list) -> list:
@@ -457,30 +470,66 @@ def _chains(masked: list, counts: list, before=frozenset(), step=0):
             yield (before,) + tail
 
 
-def replay(sz: dict, key, seq, first: int, n: int, mode: str = "f32",
-           glob=None, layers=None) -> dict:
+def _orders(masked: list, counts: list, row_of) -> list:
+    """Every order of commitment of a block's ``masked`` positions, as
+    rows of the replay: ``at`` (the row that reads each position at the
+    step that commits it), ``step`` (that step) and ``checks`` (per step,
+    the rows it commits and the rows it leaves masked, both read in that
+    step's state). ``row_of(step, state, p)`` is position ``p``'s row."""
+    out = []
+    for chain in _chains(masked, counts):
+        after = list(chain[1:]) + [frozenset(masked)]
+        step = [next(s for s, done in enumerate(after) if p in done)
+                for p in masked]
+        checks = []
+        for s, state in enumerate(chain):
+            taken = [row_of(s, state, p) for p in masked
+                     if p in after[s] and p not in state]
+            left = [row_of(s, state, p) for p in masked if p not in after[s]]
+            if taken and left:
+                checks.append((np.asarray(taken), np.asarray(left)))
+        out.append({"at": np.asarray([row_of(s, chain[s], p)
+                                      for p, s in zip(masked, step)]),
+                    "step": np.asarray(step), "checks": checks})
+    return out
+
+
+def _pick(conf, orders: list, value, tie: float) -> dict:
+    """Of a block's ``orders``, those the reference's confidences
+    ``conf`` (per row) allow (at each step no position committed lies
+    more than ``tie`` below one left masked: the reference's own order
+    always is), the one under which the widest ``value`` (per row) is
+    least."""
+    return min((o for o in orders
+                if all(conf[taken].min() >= conf[left].max() - tie
+                       for taken, left in o["checks"])),
+               key=lambda o: value[o["at"]].max())
+
+
+def replay(sz: dict, key, seq, first: int, n: int, modes=(), glob=None,
+           layers=None) -> dict:
     """The denoising of one served request replayed (the module's
     docstring): per served token (n,), its ``gap`` below the reference's
-    best at the step that commits it, ``low_gap`` (with a ``mode`` other
-    than ``f32``: the gap of the token ``mode`` puts first there), its
-    row's least router ``margin`` in any layer of that step, ``cut`` (its
-    block the budget cut) and its ``block``. ``seq`` (1, T) holds the
-    prompt and the served tokens from ``first`` on.
+    best at the step that commits it, ``low[mode]`` for each of ``modes``
+    (the gap of the token ``mode`` puts first), ``cut`` (its block the
+    budget cut) and its ``block``. ``seq`` (1, T) holds the prompt and
+    the served tokens from ``first`` on.
 
-    Which positions a step commits is, under seeded random weights, a
-    rounding: positions that are all masked read the same token at every
-    position but their rotation, so their confidences differ in the
-    fourth digit. The replay therefore reads every order of commitment
-    the static schedule allows (6 at 4 masked positions over 2 steps) and
-    takes, block by block, the order under which the block's widest gap
-    is least."""
+    The rows of every generated block in every state of its commitments
+    that the static schedule allows (7 at 4 masked positions over 2
+    steps) run in passes of ``ROW_BUCKET``; block by block, the served
+    gaps are read under the allowed order whose widest gap is least, and
+    each mode's under the allowed order whose widest gap of that mode is
+    least."""
     sizes = _frozen(sz)
     glob = _globals(key, sizes) if glob is None else glob
     layers = layers or [_layer_params(key, i, sizes)
                         for i in range(sz["layers"])]
     length, steps, mask = sz["block_len"], sz["denoise_steps"], sz["mask_id"]
-    seq_h = np.asarray(seq)[0]
-    t = min(seq_h.shape[0], -(-(first + n) // ROW_BUCKET) * ROW_BUCKET)
+    # a cut block's positions past the budget read the padding
+    seq_h = np.pad(np.asarray(seq)[0], (0, length))
+    t = min(seq_h.shape[0] - length,
+            -(-(first + n) // ROW_BUCKET) * ROW_BUCKET)
     seq_d = jnp.asarray(seq_h[None, :t])
     b0 = first // length * length
     n_blocks = -(-(first + n - b0) // length)
@@ -489,101 +538,64 @@ def replay(sz: dict, key, seq, first: int, n: int, mode: str = "f32",
            for b in range(n_blocks)}
     counts = {b: [len(m) // steps + (s < len(m) % steps)
                   for s in range(steps)] for b, m in gen.items()}
-    # the rows of every state of every block at every step, in passes of
-    # ROW_BUCKET rows: (step, block, state) -> first row
-    order, ids, rows = {}, [], []
+    # the rows of every state of every block at every step:
+    # (step, block, state) -> the row of the block's first position
+    first_row, ids, rows = {}, [], []
     for s in range(steps):
         for b in range(n_blocks):
             for state in _contexts(gen[b], counts[b])[s]:
-                order[s, b, state] = len(ids)
+                first_row[s, b, state] = len(ids)
                 start = b0 + b * length
                 ids += [mask if p in gen[b] and p not in state
                         else int(seq_h[start + p]) for p in range(length)]
                 rows += range(start, start + length)
-    total = -(-len(ids) // ROW_BUCKET) * ROW_BUCKET
-    ids = np.asarray(ids + [mask] * (total - len(ids)), np.int32)
-    rows = np.asarray(rows + [-1] * (total - len(rows)), np.int32)
-    group = np.arange(total) // length
-    served = np.where(rows >= 0, seq_h[np.maximum(rows, 0)], 0)
-
-    def passes(m, kv, ref_kv=None):
-        """Per row: the best float32 logit and the served token's, its
-        least margin; with ``ref_kv`` the float32 logit of what ``m``
-        puts first instead of the served token's."""
-        best, at, margin = (np.zeros(total, np.float32) for _ in range(3))
-        for lo in range(0, total, ROW_BUCKET):
-            cut = slice(lo, lo + ROW_BUCKET)
-            x, mg = _rows(glob, layers, ref_kv or kv, ids[cut], rows[cut],
-                          group[cut], sizes, "f32")
-            logits = _logits(x, glob, "f32", sizes)
-            b, a = _numbers(logits, jnp.asarray(served[cut]))
-            if ref_kv is not None:
-                low, _ = _rows(glob, layers, kv, ids[cut], rows[cut],
-                               group[cut], sizes, m)
-                a = _at_argmax(logits, _logits(low, glob, m, sizes))
-            best[cut], at[cut], margin[cut] = b, a, mg
-        return best - at, margin
-
-    gap, margin = passes("f32", _clean(glob, layers, seq_d, sizes, "f32"))
-    # block by block, the order of commitment under which the widest gap
-    # is least, and each token's row in it
-    picked = {}
-    for b in range(n_blocks):
-        start = b0 + b * length
-
-        def step_of(chain, p):
-            """The step that commits ``p`` and its row then."""
-            s = next(s for s in range(steps)
-                     if p not in chain[s] and (s + 1 == steps
-                                               or p in chain[s + 1]))
-            return s, order[s, b, chain[s]] + p
-
-        best_chain = min(
-            _chains(gen[b], counts[b]),
-            key=lambda chain: max(gap[step_of(chain, p)[1]]
-                                  for p in gen[b]))
-        for p in gen[b]:
-            picked[start + p] = step_of(best_chain, p)
-    at = np.asarray([picked[q][1] for q in range(first, first + n)])
-    low_gap = np.zeros(n, np.float32)
-    if mode != "f32":
+    ids, rows = np.asarray(ids, np.int32), np.asarray(rows, np.int32)
+    served = seq_h[rows]
+    orders = [_orders(gen[b], counts[b],
+                      lambda s, state, p, b=b: first_row[s, b, state] + p)
+              for b in range(n_blocks)]
+    clean = {"f32": _clean(glob, layers, seq_d, sizes, "f32")}
+    gaps, conf = _passes(glob, layers, clean, ids, rows, served, sizes)
+    picked = [_pick(conf, o, gaps["f32"], sz["order_tie"]) for o in orders]
+    kvs = dict(clean)
+    for m in modes:
         dirty = seq_d
-        if mode == "dirty_close":
-            # what the last step committed still masked in the K/V
-            last = [q for q, (s, _) in picked.items() if s == steps - 1]
+        if m == "dirty_close":
+            # what the served order's last step committed still masked in
+            # the K/V
+            last = [b0 + b * length + p for b, o in enumerate(picked)
+                    for p, s in zip(gen[b], o["step"]) if s == steps - 1]
             dirty = seq_d.at[0, jnp.asarray(last, jnp.int32)].set(mask)
-        low, _ = passes(mode, _clean(glob, layers, dirty, sizes, mode),
-                        _clean(glob, layers, seq_d, sizes, "f32"))
-        low_gap = low[at]
+        kvs[m] = _clean(glob, layers, dirty, sizes, m)
+    if modes:
+        gaps, _ = _passes(glob, layers, kvs, ids, rows, served, sizes)
     blocks = (np.arange(first, first + n) - b0) // length
     cut = np.zeros(n_blocks, bool)
     cut[-1] = bool((first + n) % length)
-    return {"gap": gap[at], "low_gap": low_gap, "margin": margin[at],
+    return {"gap": gaps["f32"][np.concatenate([o["at"] for o in picked])],
+            "low": {m: gaps[m][np.concatenate(
+                [_pick(conf, o, gaps[m], sz["order_tie"])["at"]
+                 for o in orders])] for m in modes},
             "cut": cut[blocks], "block": blocks}
-
-
-def kept(sz: dict, r: dict):
-    """Which of a replay's tokens the comparison keeps (the module's
-    docstring)."""
-    return ~r["cut"] & (r["margin"] >= sz["tie"])
 
 
 def served_gaps_fn(sz: dict, key, mode: str = "f32"):
     """What ``check.served_gaps`` calls for each ``(seq, first, n)``:
-    the served gaps and the mode's (n,) of :func:`replay`, nought where a
-    token is left out, and a line on standard error with how many were
-    kept."""
+    the served gaps and the mode's (n,) of :func:`replay`, nought in a
+    trailing block the budget cut, and a line on standard error with how
+    many tokens were compared."""
     sizes = _frozen(sz)
     glob = _globals(key, sizes)
     layers = [_layer_params(key, i, sizes) for i in range(sz["layers"])]
+    modes = () if mode == "f32" else (mode,)
 
     def fn(seq, first, n):
-        r = replay(sz, key, seq, first, n, mode, glob, layers)
-        keep = kept(sz, r)
-        print(f"served_gaps sdar_moe: kept {int(keep.sum())} of {n} served "
-              f"tokens (blocks the budget cut: {int(r['cut'].sum())} "
-              "tokens)", file=sys.stderr)
-        return np.where(keep, r["gap"], 0.0), np.where(keep, r["low_gap"],
-                                                       0.0)
+        r = replay(sz, key, seq, first, n, modes, glob, layers)
+        keep = ~r["cut"]
+        low = r["low"].get(mode, np.zeros(n, np.float32))
+        print(f"served_gaps sdar_moe: compared {int(keep.sum())} of {n} "
+              f"served tokens (blocks the budget cut: "
+              f"{int(r['cut'].sum())} tokens)", file=sys.stderr)
+        return np.where(keep, r["gap"], 0.0), np.where(keep, low, 0.0)
 
     return fn

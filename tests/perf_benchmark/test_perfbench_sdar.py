@@ -8,13 +8,17 @@ reading not ``correct``; the chip's configuration file against the
 catalog's row and the published config's arithmetic.
 
 Readings at this size, on the CPU (matrices N(0, 0.12): logits of about
-3). The program's served tokens: 0.0 on two seeds (every kept token the
-reference's best). Over 12 seeded sequences of 48 tokens on seeds 4 and
-5 the float8 control reads 0.72 and 0.89, ``causal_block`` 0.83 and 2.33,
-``dirty_close`` 3.26 and 3.09, ``no_topk_norm`` 1.04 and 1.02,
-``no_qk_norm`` 1.06 and 1.77 (int8 0.64 and 0.50; bfloat16 0.008, and
-0.12 where a rounding turned one router choice). The cell's limit is
-0.12: a sixth of the smallest control's or fault's reading.
+3; a step's order held to the reference's confidences within
+``ORDER_TIE``). The program's served tokens: 0.0 on two seeds (every
+compared token the reference's best). Over 12 seeded sequences of 48
+tokens on seeds 4 and 5, each mode under its own most favourable allowed
+order, the float8 control reads 0.64 and 1.06, ``causal_block`` 1.37 and
+2.33, ``dirty_close`` 2.92 and 3.09, ``no_topk_norm`` 0.84 and 1.16,
+``no_qk_norm`` 1.00 and 1.75 (int8 0.64 and 0.81; bfloat16 0.27 and
+0.47: on seed 4 one row of 576, where a rounding turned a router
+choice). The
+cell's limit is 0.12: under a fifth of the smallest control's or fault's
+reading.
 """
 
 import json
@@ -34,12 +38,14 @@ MS = 1_000_000
 LAYERS, L = sdar.LAYERS, sdar.BLOCK
 #: the metrics the chip's cell reports from the CPU's run
 REPORTED = sdar.COUNTED + (
-    "decode_step_ms.block", "denoise_tokens_per_step_mean", "tpot_ms_p50",
+    "decode_step_ms.block", "tpot_ms_p50",
     "compiles_in_window.backlog", "device_idle_pct.backlog", "admit_ms_p50",
     "pool_write_ms_p50", "pool_write_dispatches_mean", "expert_pairs_mean",
     "experts_hit_mean")
-#: and the two that only a device gives: listed for the cell, silent here
-DEVICE_ONLY = ("block_gap_ms_p50", "hbm_peak_pct.backlog")
+#: and those that only a device or its trace's host spans give: listed
+#: for the cell, silent here
+DEVICE_ONLY = ("block_gap_ms_p50", "hbm_peak_pct.backlog", "idle_admit_pct",
+               "idle_pool_write_pct")
 
 
 def toy_trace(*_):
@@ -118,8 +124,6 @@ def test_the_tiny_cell_is_correct_and_reports_the_cells_metrics(traced):
                 "first_token_wait_ms_p50"} & set(got)
     assert got["pool_write_dispatches_mean"]["value"] == 1.0
     assert got["decode_step_ms.block"]["value"] == pytest.approx(4.0)
-    # four masked positions a block over two steps: two tokens a step
-    assert 1.0 < got["denoise_tokens_per_step_mean"]["value"] <= 2.0
     for name in sdar.COUNTED:
         assert 0 < got[name]["value"] <= 100.0, (name, got[name])
 

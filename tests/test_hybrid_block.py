@@ -263,7 +263,8 @@ def test_served_tokens_are_the_denoising_of_the_models_own_forward(
         tiny, served, prompt_len, new):
     """The engine serves what the model's own full forward commits step
     by step, to the budget; the float32 reference's replay of the same
-    tokens finds each within rounding of its best where it keeps it."""
+    tokens, committing by its own confidences, finds each within rounding
+    of its best, but in a trailing block the budget cut."""
     params, graph, variables = tiny
     result = served[0][prompt_len]
     assert result.status == "completed"
@@ -273,7 +274,10 @@ def test_served_tokens_are_the_denoising_of_the_models_own_forward(
     seq = np.zeros((1, CACHE), np.int32)
     seq[0, :len(tokens)] = tokens
     r = ref.replay(SZ, KEY, jnp.asarray(seq), prompt_len, new)
-    keep = ref.kept(SZ, r)
+    end = prompt_len + new
+    assert r["cut"].sum() == (end - max(prompt_len, end // L * L)
+                              if end % L else 0)
+    keep = ~r["cut"]
     assert (r["gap"][keep] <= 0.06).all(), r["gap"][keep]
 
 
@@ -413,10 +417,10 @@ def test_the_softmax_router_is_its_equation():
     chosen = np.take_along_axis(r, np.asarray(experts), -1)
     np.testing.assert_allclose(weights, chosen / chosen.sum(-1, keepdims=True),
                                rtol=1e-5)
-    want_e, want_w, _ = ref.route(x[None], {"router_w": w}, SZ, "f32")
+    want_e, want_w = ref.route(x[None], {"router_w": w}, SZ, "f32")
     np.testing.assert_array_equal(experts, want_e[0])
     np.testing.assert_allclose(weights, want_w[0], rtol=1e-5)
-    _, plain, _ = ref.route(x[None], {"router_w": w}, SZ, "no_topk_norm")
+    _, plain = ref.route(x[None], {"router_w": w}, SZ, "no_topk_norm")
     assert (np.asarray(plain).sum(-1) < 0.999).all()
 
 
@@ -430,7 +434,7 @@ def test_two_holders_of_four_experts_add_up_to_the_uncut_layer():
                                   SZ)
          for i, name in enumerate(ref.ROUTED_LEAVES)}
     h = jax.random.normal(jax.random.fold_in(key, 99), (2, 9, D))
-    whole, _ = ref.moe(h, p, SZ, "f32")
+    whole = ref.moe(h, p, SZ, "f32")
 
     @jax.jit
     def holder(first):
@@ -441,7 +445,7 @@ def test_two_holders_of_four_experts_add_up_to_the_uncut_layer():
 
     held = [holder(first) for first in (0, 4)]
     for (out, _), first in zip(held, (0, 4)):
-        part, _ = ref.moe(h, p, SZ, "f32", share=(first, 4))
+        part = ref.moe(h, p, SZ, "f32", share=(first, 4))
         np.testing.assert_allclose(out, part, atol=1e-5)
     np.testing.assert_allclose(sum(out for out, _ in held), whole,
                                atol=2e-5 * float(jnp.abs(whole).max()) + 1e-6)
