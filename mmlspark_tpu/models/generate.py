@@ -31,6 +31,8 @@ Two decode strategies, both fixed-shape and single-jit:
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 
@@ -151,7 +153,7 @@ def init_cache(graph, variables, batch: int, total: int) -> dict:
 
 def _cached_apply(graph, variables, ids, cache, pos, rolled=False,
                   step=False, live=None, valid=None, counters=None,
-                  head=True):
+                  head=True, shared=None):
     """One forward over ``ids`` (B, T) starting at absolute position
     ``pos`` (traced ok), reading/writing the K/V cache. Returns
     (logits (B, T, V), new cache). ``rolled`` switches the blocks to
@@ -166,7 +168,11 @@ def _cached_apply(graph, variables, ids, cache, pos, rolled=False,
     (a pad or a dead row routes nowhere), and such a block hands back a
     third value, its counters, which land in ``counters[name]`` when a
     dict is given. ``head=False`` stops before the graph's final node and
-    returns its input, the last hidden states, in the logits' place."""
+    returns its input, the last hidden states, in the logits' place.
+    ``shared`` (a dict the caller keeps) applies the blocks that take a
+    cache through one jitted function for each configuration of block
+    (:func:`_shared_apply`): a stack of like layers is traced once a
+    shape, not once a layer."""
     x = ids
     new_cache = dict(cache)
     for name, mod in graph.blocks:
@@ -181,7 +187,9 @@ def _cached_apply(graph, variables, ids, cache, pos, rolled=False,
                 kwargs["live"] = live
             if valid is not None and _accepts_kwarg(mod, "valid"):
                 kwargs["valid"] = valid
-            x, new_cache[name], *counted = mod.apply(v, x, **kwargs)
+            apply = mod.apply if shared is None else _shared_apply(
+                shared, mod)
+            x, new_cache[name], *counted = apply(v, x, **kwargs)
             if counted and counters is not None:
                 counters[name] = counted[0]
         elif _accepts_kwarg(mod, "pos"):
@@ -189,6 +197,20 @@ def _cached_apply(graph, variables, ids, cache, pos, rolled=False,
         else:
             x = mod.apply(v, x)
     return x, new_cache
+
+
+def _shared_apply(memo: dict, mod):
+    """``mod.apply`` jitted, ONE function for every block whose
+    configuration (all but its name) is ``mod``'s, kept in ``memo``: the
+    blocks' variables are arguments, so each of them calls the same
+    function at the same shapes, and JAX traces and lowers its body once
+    (the compiler inlines the calls)."""
+    key = (type(mod),) + tuple(
+        (f.name, getattr(mod, f.name)) for f in dataclasses.fields(mod)
+        if f.name not in ("parent", "name"))
+    if key not in memo:
+        memo[key] = jax.jit(mod.apply, static_argnames=("rolled", "decode"))
+    return memo[key]
 
 
 def counts_routing(graph) -> bool:
@@ -312,8 +334,8 @@ def make_denoise_block(graph):
     """Build the fused program of a model that GENERATES BY DIFFUSION OVER
     BLOCKS (``graph.extra["block"]`` = L, ``["denoise_steps"]`` = S,
     ``["mask_id"]``): a loop of micro-steps over whole blocks, S
-    denoising steps and then the block's clean close, every slot at the
-    same phase (a dispatch starts every slot at a block's start).
+    denoising steps a block and then the block's clean close, every slot
+    at the same phase (a dispatch starts every slot at a block's start).
 
     A DENOISING micro-step runs each slot's L rows at positions ``pos ..
     pos + L - 1`` (a position not yet committed reads ``mask_id``)
@@ -328,6 +350,17 @@ def make_denoise_block(graph):
     that stay in the pool are those of all L tokens revealed, attending
     each other. It serves the block, advances the slot by L and folds the
     token budget into ``live``; the next block starts fully masked.
+
+    The close of every block but the dispatch's last RIDES the next
+    block's first denoising step: one micro-step of 2L rows a slot from
+    the closing block's start, its clean rows first, then the next
+    block's, all masked. Under the block-causal mask the clean rows see
+    nothing of the next block (their K/V are the close's), and the
+    masked rows see the clean block (``decode_step``'s ``lead``). The
+    head, the confidences and the commitment read the last L rows only.
+    The dispatch's last block closes in a micro-step of its own, so a
+    dispatch of ``n`` blocks runs ``n * S + 1`` micro-steps, and ends on
+    a close as every dispatch does.
 
     The returned function's signature::
 
@@ -347,22 +380,26 @@ def make_denoise_block(graph):
     Returns ``(blocks (S, most, L) int32, live, buffers, pos, counts)``:
     each block's tokens as it closed (the first one's committed prompt
     tail included: the host serves what was masked), and ``counts``
-    ``{"denoise_steps", "tokens_committed", "blocks_closed"}``, summed
-    over live slots and micro-steps. For a graph that routes tokens to
-    experts (:func:`counts_routing`) a sixth value follows: the routing
-    counters of :func:`routing_totals`, summed over the micro-steps."""
+    ``{"denoise_steps", "tokens_committed", "blocks_closed",
+    "closes_fused"}``, summed over live slots and micro-steps
+    (``closes_fused``: the closes that rode the next block's first
+    step). For a graph that routes tokens to experts
+    (:func:`counts_routing`) a sixth value follows: the routing counters
+    of :func:`routing_totals`, summed over the micro-steps."""
     length = int(graph.extra["block"])
     steps = int(graph.extra["denoise_steps"])
     mask_id = int(graph.extra["mask_id"])
-    per_block = steps + 1
     routed = counts_routing(graph)
     head_name, head_mod = graph.blocks[-1]
 
     def denoise_block(variables, buffers, pos, live, tok, masked, rem,
                       n_blocks, most):
         slots = pos.shape[0]
+        # like layers share one traced body, at each of the two shapes
+        shared = {}
         order = jnp.arange(length)
         earlier = order[None, :] < order[:, None]   # [p, q]: q before p
+        last = n_blocks * steps     # the micro-step of the last close
 
         def commit(ops):
             x, tok, masked, n0, phase, live = ops
@@ -388,41 +425,74 @@ def make_denoise_block(graph):
             _, tok, masked, *_ = ops
             return tok, masked, jnp.int32(0)
 
-        def micro(i, carry):
-            tok, masked, n0, buffers, pos, live, rem, out, counts, stats = \
-                carry
-            phase = i % per_block
-            ids = jnp.where(masked, mask_id, tok)
+        def forward(state, ids, pos, live, valid):
+            _, buffers, stats = state
             routing = {} if routed else None
             x, buffers = _cached_apply(
                 graph, variables, ids, buffers, pos, step=True, live=live,
-                valid=(jnp.broadcast_to(live[:, None], ids.shape)
-                       if routed else None),
-                counters=routing, head=False)
-            tok, masked, taken = jax.lax.cond(
-                phase < steps, commit, keep,
-                (x, tok, masked, n0, phase, live))
-            close = phase == steps
-            out = jnp.where(close, jax.lax.dynamic_update_slice(
-                out, tok[:, None], (0, i // per_block, 0)), out)
+                valid=valid if routed else None, counters=routing,
+                head=False, shared=shared)
+            if routed:
+                stats = jax.tree_util.tree_map(jnp.add, stats,
+                                               routing_totals(routing))
+            return x, buffers, stats
+
+        def once(body, run, state):
+            # a loop of one trip or none: the branch that carries the pool
+            # in place (a cond's branches would each want a copy of it)
+            return jax.lax.fori_loop(0, run.astype(jnp.int32),
+                                     lambda _, state: body(state), state)
+
+        def micro(i, carry):
+            (tok, masked, n0, buffers, pos, live, rem, out, counts, stats,
+             x) = carry
+            # micro-step i is step i % S of block i // S; at a block's
+            # first step after the first, the block before it closes too
+            phase = i % steps
+            close = (i == last) | ((i >= steps) & (phase == 0))
             closing = close & live
+            out = jnp.where(close, jax.lax.dynamic_update_slice(
+                out, tok[:, None], (0, i // steps - 1, 0)), out)
             rem = rem - jnp.where(closing, n0, 0)
+            after = live & ~(closing & (rem <= 0))
+            fused = close & (i < last)
+
+            def two(state):
+                # the closing block's clean rows, then the next block's,
+                # all masked: the slots live until this close read and
+                # write both
+                ids = jnp.concatenate([tok, jnp.full_like(tok, mask_id)], 1)
+                valid = jnp.concatenate([
+                    jnp.broadcast_to(live[:, None], tok.shape),
+                    jnp.broadcast_to(after[:, None], tok.shape)], 1)
+                x, buffers, stats = forward(state, ids, pos, live, valid)
+                return x[:, length:], buffers, stats
+
+            def one(state):
+                # a denoising step, or the last block's close (nothing
+                # masked)
+                return forward(state, jnp.where(masked, mask_id, tok), pos,
+                               live, jnp.broadcast_to(live[:, None],
+                                                      tok.shape))
+
+            state = once(two, fused, (x, buffers, stats))
+            x, buffers, stats = once(one, ~fused, state)
             pos = jnp.where(closing, pos + length, pos)
-            live = live & ~(closing & (rem <= 0))
             masked = masked | close
             n0 = jnp.where(close, length, n0)
+            tok, masked, taken = jax.lax.cond(
+                i < last, commit, keep, (x, tok, masked, n0, phase, after))
             counts = {
                 "denoise_steps": counts["denoise_steps"] + jnp.where(
-                    close, 0, live.sum(dtype=jnp.int32)),
+                    i < last, after.sum(dtype=jnp.int32), 0),
                 "tokens_committed": counts["tokens_committed"] + taken,
                 "blocks_closed": counts["blocks_closed"]
                 + closing.sum(dtype=jnp.int32),
+                "closes_fused": counts["closes_fused"] + jnp.where(
+                    fused, closing.sum(dtype=jnp.int32), 0),
             }
-            if routed:
-                stats = jax.tree_util.tree_map(
-                    jnp.add, stats, routing_totals(routing))
-            return (tok, masked, n0, buffers, pos, live, rem, out, counts,
-                    stats)
+            return (tok, masked, n0, buffers, pos, after, rem, out, counts,
+                    stats, x)
 
         zero = jnp.int32(0)
         stats = None
@@ -431,12 +501,17 @@ def make_denoise_block(graph):
                     if getattr(mod, "routed", False))
             stats = {key: jnp.zeros((n,), jnp.int32) for key in
                      ("expert_pairs", "experts_hit", "expert_rows")}
+        # the last hidden states, which the head reads, are shaped as the
+        # embedding's
+        embed_name, embed = graph.blocks[0]
+        x = jax.eval_shape(embed.apply, variables[embed_name], tok)
         carry = (tok, masked, masked.sum(axis=-1, dtype=jnp.int32), buffers,
                  pos, live, rem, jnp.zeros((slots, most, length), jnp.int32),
-                 {"denoise_steps": zero, "tokens_committed": zero,
-                  "blocks_closed": zero}, stats)
-        (_, _, _, buffers, pos, live, _, out, counts, stats) = \
-            jax.lax.fori_loop(0, n_blocks * per_block, micro, carry)
+                 {name: zero for name in ("denoise_steps", "tokens_committed",
+                                          "blocks_closed", "closes_fused")},
+                 stats, jnp.zeros(x.shape, x.dtype))
+        (_, _, _, buffers, pos, live, _, out, counts, stats, _) = \
+            jax.lax.fori_loop(0, last + (n_blocks > 0), micro, carry)
         if routed:
             return out, live, buffers, pos, counts, stats
         return out, live, buffers, pos, counts

@@ -38,7 +38,8 @@ A stack whose ``block`` is L > 0 GENERATES BY DIFFUSION OVER BLOCKS of L
 positions on a grid from position 0 (the SDAR line): its attention is
 BLOCK-causal, position ``i`` seeing ``j`` iff ``j // L <= i // L``, in a
 prompt's forward and in a denoising step alike, and a step runs a block's
-L rows at once against the slot's clean prefix and the block itself
+L rows at once against the slot's clean prefix and the block itself, or
+2L rows, a block's clean close beside the next block's first step
 (:func:`mmlspark_tpu.models.generate.make_denoise_block`; ``mask_id`` is
 what a position not yet committed reads).
 
@@ -169,10 +170,17 @@ class HybridAttention(nn.Module):
         elif decode and (t == 1 or self.block) and not kv_cache.is_linear(
                 cache):
             # the serving pool's entry, as the pool allocates it by
-            # cache_spec: a window block's rows are a ring
+            # cache_spec: a window block's rows are a ring. A block-causal
+            # step of two blocks' rows is a block's close beside the next
+            # block's step: the first block's rows do not see the second's
+            if self.block and t not in (self.block, 2 * self.block):
+                raise ParamError(
+                    f"a denoising step runs one block of {self.block} rows "
+                    f"a slot or two, got {t}")
             o, new_cache = kv_cache.decode_step(
                 cache, q, k, v, pos, live, window=self.window, sink=sink,
-                name=f"attn_{kind}_decode")
+                name=f"attn_{kind}_decode",
+                lead=t - self.block if self.block else 0)
         else:
             # a linear (B, total, hk, d) cache: prefill, a chunk or a
             # resume against a live prefix, generate()'s decode steps

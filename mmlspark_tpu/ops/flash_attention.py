@@ -56,7 +56,7 @@ the same code path is tested everywhere.
 
 from __future__ import annotations
 
-from functools import lru_cache, partial
+from functools import lru_cache, partial, reduce
 from typing import NamedTuple
 
 import jax
@@ -1164,7 +1164,7 @@ _DECODE_HEADS_SEMANTICS = pltpu.CompilerParams(
 def _decode_group_kernel(len_ref, *refs,
                          scale: float, blk: int, kv_heads: int,
                          kv_axis: int = 1, values_in_keys: int = 0,
-                         work_list: bool = False):
+                         work_list: bool = False, short: tuple = ()):
     # one KV head a step: q (G, Dk), k (blk, Dk), grid row = batch *
     # kv_heads + kv head. Several a step: q (Hb, G, Dk), k (Hb, blk, Dk),
     # the leading dimension a batch of the two products, grid row = the
@@ -1172,6 +1172,9 @@ def _decode_group_kernel(len_ref, *refs,
     # A LATENT cache (``values_in_keys`` > 0) has no value operand: the
     # values are the first ``values_in_keys`` columns of the key rows,
     # which are fetched once and serve both products.
+    # ``short`` = (early, spans): the group's rows in the ``[start, stop)``
+    # spans attend ``[0, length - early)`` (a row that sees no key comes
+    # out as zeros), the others ``[0, length)``
     # Over a WORK LIST (:func:`_decode_work_list`) the one grid axis
     # counts the live blocks of all rows, and two more prefetched lists
     # say which row and which of its blocks a step holds
@@ -1208,10 +1211,20 @@ def _decode_group_kernel(len_ref, *refs,
         ) * scale  # (..., G, blk) f32
         kpos = kb * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape,
                                                    n + 1)
-        s = jnp.where(kpos >= length, NEG_INF, s)
+        if short:
+            early, spans = short
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, n)
+            cut = reduce(jnp.logical_or, [
+                (row >= start) & (row < stop) for start, stop in spans])
+            dead = kpos >= length - jnp.where(cut, early, 0)
+        else:
+            dead = kpos >= length
+        s = jnp.where(dead, NEG_INF, s)
         m_prev = m_scr[..., :1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
+        if short:
+            p = jnp.where(dead, 0.0, p)
         corr = jnp.exp(m_prev - m_new)
         l_scr[...] = jnp.broadcast_to(
             l_scr[..., :1] * corr + p.sum(axis=-1, keepdims=True),
@@ -1274,10 +1287,16 @@ def _row_write_kernel(at_ref, *refs, tile: int):
         ).astype(out_ref.dtype)
 
 
-def _rows_write_kernel(at_ref, *refs, tile: int, rows: int):
+def _rows_write_kernel(at_ref, *refs, tile: int, rows: int, piece: int = 0):
     # _row_write_kernel for ``rows`` consecutive rows from ``at``, all
-    # inside the one tile that holds ``at``
-    first = at_ref[pl.program_id(0)] % tile
+    # inside the one tile that holds ``at``. With ``piece``, the tile is
+    # the one that holds row ``at + j * piece`` (grid axis 1), and the
+    # rows that lie in it are written
+    if piece:
+        at = at_ref[pl.program_id(0)]
+        first = at - (at + pl.program_id(1) * piece) // tile * tile
+    else:
+        first = at_ref[pl.program_id(0)] % tile
     n = len(refs) // 3
     for new_ref, old_ref, out_ref in zip(refs[:n], refs[n:2 * n],
                                          refs[2 * n:]):
@@ -1324,7 +1343,7 @@ def latent_row_write(rows, new, at, *, interpret: bool | None = None):
     return out[:, 0]
 
 
-def cache_rows_write(k, v, k_new, v_new, at, *,
+def cache_rows_write(k, v, k_new, v_new, at, *, align: int = 0,
                      interpret: bool | None = None):
     """:func:`cache_row_write` for ``T`` consecutive rows a batch row:
     rows ``at[b] .. at[b] + T - 1`` of every head of batch row ``b``
@@ -1332,9 +1351,18 @@ def cache_rows_write(k, v, k_new, v_new, at, *,
     in place on donated caches. ``at[b]`` is a multiple of ``T`` (a
     denoising step writes the rows of one block on the grid of blocks),
     so the rows lie in ONE tile of whole sublanes and of ``T``, the only
-    tile the kernel fetches and writes back."""
+    tile the kernel fetches and writes back. ``align`` (static, dividing
+    ``T``) says ``at[b]`` is a multiple of ``align`` only (a step over
+    two blocks starts at the earlier one): the rows are then written in
+    pieces of ``align``, one a grid step, each in the one tile that
+    holds it."""
+    t = k_new.shape[2]
+    if align and t % align:
+        raise ValueError(f"align ({align}) must divide the {t} rows")
     return _cache_rows_write((k, v), (k_new, v_new), at,
-                             interpret=_interpret(interpret))
+                             interpret=_interpret(interpret),
+                             **({"align": int(align)}
+                                if 0 < align < t else {}))
 
 
 # jitted where they stand: a decode block calls each kernel once a layer
@@ -1380,31 +1408,39 @@ def _cache_row_write(caches: tuple, news: tuple, at, *, interpret: bool):
             for new, c in zip(news, caches)), *caches)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def _cache_rows_write(caches: tuple, news: tuple, at, *, interpret: bool):
+@partial(jax.jit, static_argnames=("interpret", "align"))
+def _cache_rows_write(caches: tuple, news: tuple, at, *, interpret: bool,
+                      align: int = 0):
     b, hk, L, _ = caches[0].shape
     t = news[0].shape[2]
-    tile = np.lcm(32 // caches[0].dtype.itemsize, t)
+    # rows from a multiple of ``align`` lie in up to t // align tiles of
+    # whole sublanes and of ``align``: one a step of grid axis 1
+    tile = np.lcm(32 // caches[0].dtype.itemsize, align or t)
     if L % tile:
         tile = L
     at = jnp.clip(jnp.asarray(at, jnp.int32), 0, L - t)
 
     def new_spec(new):
         return pl.BlockSpec((1, hk, t, new.shape[3]),
-                            lambda i, at: (i, 0, 0, 0),
+                            lambda i, *_: (i, 0, 0, 0),
                             memory_space=pltpu.VMEM)
 
+    def tile_of(i, *rest):
+        *j, at = rest
+        if j:
+            return i, 0, (at[i] + j[0] * align) // tile, 0
+        return i, 0, at[i] // tile, 0
+
     def old_spec(cache):
-        return pl.BlockSpec((1, hk, tile, cache.shape[3]),
-                            lambda i, at: (i, 0, at[i] // tile, 0),
+        return pl.BlockSpec((1, hk, tile, cache.shape[3]), tile_of,
                             memory_space=pltpu.VMEM)
 
     n = len(caches)
     return pl.pallas_call(
-        partial(_rows_write_kernel, tile=int(tile), rows=t),
+        partial(_rows_write_kernel, tile=int(tile), rows=t, piece=align),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b,),
+            grid=(b, t // align) if align else (b,),
             in_specs=[*map(new_spec, news), *map(old_spec, caches)],
             out_specs=[*map(old_spec, caches)],
         ),
@@ -1481,7 +1517,8 @@ def decode_grid_work(lengths, *, kv_heads: int, cache_len: int,
 def flash_decode_grouped(q, k, v, lengths, *, sink=None, scale=None,
                          block: int = _DECODE_GROUP_BLOCK,
                          interpret: bool | None = None,
-                         name: str | None = None, values_in_keys: int = 0):
+                         name: str | None = None, values_in_keys: int = 0,
+                         lead: int = 0):
     """Length-aware decode attention for the query rows of each slot over
     HEAD-MAJOR caches, a KV head's whole group of query heads per grid
     step.
@@ -1497,7 +1534,11 @@ def flash_decode_grouped(q, k, v, lengths, *, sink=None, scale=None,
     slot, the block's, which see the slot's clean prefix and the block's
     own rows alike: a KV head's ``T`` x group query rows are then one
     group of the same grid step (the rows fold into the head axis), so
-    the cache streams once for all of them.
+    the cache streams once for all of them. ``lead`` (static, 0 to
+    ``T - 1``) makes it a step over TWO blocks, a closed one and the next: the
+    first ``lead`` rows of each slot attend ``[0, lengths[b] - (T -
+    lead))``, leaving the last ``T - lead`` keys (the later block's
+    rows) out, in the same grid step as the rest.
 
     Caches whose rows are ``f * Dk`` and ``f * Dv`` wide are PACKED
     (``ops.kv_cache.lane_pack``): ``(B, Hkv / f, L, f * Dk)``, ``f``
@@ -1527,32 +1568,44 @@ def flash_decode_grouped(q, k, v, lengths, *, sink=None, scale=None,
     8 query heads), ``Dv`` whole lanes, and ``scale`` the caller's (the
     rows' width is not the width the scores were trained at)."""
     b, t, h = q.shape[:3]
+    if not 0 <= lead < t:
+        raise ValueError(
+            f"lead ({lead}) counts the leading rows of a step of {t} rows "
+            "a slot that end before the others: 0 to T - 1")
+    short = ()
     if t > 1:
         # query head i of row r becomes head (i // g) * T * g + r * g +
         # i % g of one row: KV head i // g keeps reading it, now among a
         # group of T * g
-        hk = k.shape[1] * (k.shape[3] // q.shape[3] if k.ndim == 4 else 1)
+        f = k.shape[3] // q.shape[3] if k.ndim == 4 else 1
+        hk = k.shape[1] * f
         g = h // hk
         fold = q.reshape(b, t, hk, g, -1).transpose(0, 2, 1, 3, 4)
         if sink is not None:
             sink = jnp.broadcast_to(sink.reshape(hk, 1, g),
                                     (hk, t, g)).reshape(-1)
-        out = flash_decode_grouped(
-            fold.reshape(b, 1, h * t, -1), k, v, lengths, sink=sink,
-            scale=scale, block=block, interpret=interpret, name=name,
-            values_in_keys=values_in_keys)
-        return out.reshape(b, hk, t, g, -1).transpose(0, 2, 1, 3, 4).reshape(
-            b, t, h, -1)
-    return _flash_decode_grouped(
+        if lead:
+            # a group of the kernel holds the folded rows of the f KV
+            # heads packed in a cache row, T * g each: the first lead * g
+            # of each end early
+            short = (t - lead, tuple((i * t * g, i * t * g + lead * g)
+                                     for i in range(f)))
+        q = fold.reshape(b, 1, h * t, -1)
+    out = _flash_decode_grouped(
         q, k, v, jnp.asarray(lengths), sink, scale=scale, block=block,
         interpret=_interpret(interpret), name=name,
-        values_in_keys=int(values_in_keys))
+        values_in_keys=int(values_in_keys), short=short)
+    if t > 1:
+        return out.reshape(b, hk, t, g, -1).transpose(0, 2, 1, 3, 4).reshape(
+            b, t, h, -1)
+    return out
 
 
 @partial(jax.jit, static_argnames=("scale", "block", "interpret", "name",
-                                   "values_in_keys"))
+                                   "values_in_keys", "short"))
 def _flash_decode_grouped(q, k, v, lengths, sink, *, scale, block: int,
-                          interpret: bool, name, values_in_keys: int = 0):
+                          interpret: bool, name, values_in_keys: int = 0,
+                          short: tuple = ()):
     if q.ndim != 4 or q.shape[1] != 1:
         raise ValueError(
             "flash_decode_grouped takes a SINGLE query token per row: q "
@@ -1587,10 +1640,10 @@ def _flash_decode_grouped(q, k, v, lengths, sink, *, scale, block: int,
         g = h // (k.shape[1] * f)
         mine = jax.nn.one_hot((jnp.arange(h) // g) % f, f, dtype=q.dtype)
         wide = (q[..., None, :] * mine[:, :, None]).reshape(b, 1, h, f * dk)
-        out = flash_decode_grouped(
-            wide, k, v, lengths, sink=sink,
+        out = _flash_decode_grouped(
+            wide, k, v, lengths, sink,
             scale=dk ** -0.5 if scale is None else scale, block=block,
-            interpret=interpret, name=name)
+            interpret=interpret, name=name, short=short)
         out = out.reshape(b, 1, h, f, v.shape[3] // f)
         return (out * mine[:, :, None]).sum(axis=3)
     if not (q.dtype == k.dtype == v.dtype):
@@ -1640,7 +1693,7 @@ def _flash_decode_grouped(q, k, v, lengths, sink, *, scale, block: int,
 
         out = pl.pallas_call(
             partial(_decode_group_kernel, scale=scale, blk=blk, kv_heads=1,
-                    kv_axis=2),
+                    kv_axis=2, short=short),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(b, hk // hb, n_blk),
@@ -1708,7 +1761,8 @@ def _flash_decode_grouped(q, k, v, lengths, sink, *, scale, block: int,
 
     out = pl.pallas_call(
         partial(_decode_group_kernel, scale=scale, blk=blk, kv_heads=hk,
-                values_in_keys=values_in_keys, work_list=listed),
+                values_in_keys=values_in_keys, work_list=listed,
+                short=short),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1 + len(lists),
             grid=grid,

@@ -325,12 +325,13 @@ def _int8_rows_step(entry, q, k, v, pos, live, *, window, sink, name, mesh,
 
 
 def _head_major_step(entry, q, k, v, pos, live, *, window, sink, name, mesh,
-                     rolled):
+                     rolled, lead=0):
     from mmlspark_tpu.ops import flash_attention as kernels
 
     if q.shape[1] > 1:
         return _head_major_block_step(entry, q, k, v, pos, live,
-                                      window=window, sink=sink, name=name)
+                                      window=window, sink=sink, name=name,
+                                      lead=lead)
     b, rows = q.shape[0], entry.k.shape[2]
     _fused_step_only(entry, q, pos, window, rows=rows)
     # a ring: position p lies in row p % rows
@@ -351,14 +352,18 @@ def _head_major_step(entry, q, k, v, pos, live, *, window, sink, name, mesh,
                                         name=name), new
 
 
-def _head_major_block_step(entry, q, k, v, pos, live, *, window, sink, name):
+def _head_major_block_step(entry, q, k, v, pos, live, *, window, sink, name,
+                           lead=0):
     """A denoising step of a model that generates by diffusion over
     blocks: ``T`` rows a slot from ``pos`` (a multiple of ``T``) on, the
     block's, written in place over whatever an earlier step of the block
     wrote there (the last pass over a block, its clean close, writes the
     rows that stay), then every row of the block read against the slot's
     rows ``[0, pos + T)``: the clean prefix and the block itself, both
-    ways."""
+    ways. With ``lead`` > 0 the step runs TWO blocks from ``pos`` (a
+    multiple of ``lead``): the first ``lead`` rows, a block that closes
+    here, read ``[0, pos + lead)``, and the other ``T - lead``, the next
+    block, read ``[0, pos + T)``."""
     from mmlspark_tpu.ops import flash_attention as kernels
 
     b, t = q.shape[:2]
@@ -371,10 +376,10 @@ def _head_major_block_step(entry, q, k, v, pos, live, *, window, sink, name):
     packed = (b, t, entry.k.shape[1], -1)
     new = HeadMajorKV(*kernels.cache_rows_write(
         *entry, k.reshape(packed).transpose(0, 2, 1, 3),
-        v.reshape(packed).transpose(0, 2, 1, 3), pos))
+        v.reshape(packed).transpose(0, 2, 1, 3), pos, align=lead))
     lengths = decode_live_lengths(pos, b, live=live, rows=t)
     return kernels.flash_decode_grouped(q, *new, lengths, sink=sink,
-                                        name=name), new
+                                        name=name, lead=lead), new
 
 
 def _paged_step(entry, q, k, v, pos, live, *, window, sink, name, mesh,
@@ -541,7 +546,8 @@ _STEPS = {Int8Rows: _int8_rows_step, HeadMajorKV: _head_major_step,
 
 
 def decode_step(entry, q, k, v, pos, live=None, *, window=None, sink=None,
-                name=None, mesh=None, rolled: bool = False, scale=None):
+                name=None, mesh=None, rolled: bool = False, scale=None,
+                lead: int = 0):
     """One decode step over ``entry``, whatever its layout: write this
     step's K/V rows for every slot from ``pos`` on and attend ``q`` over
     the live rows. ``q`` is (B, T, H, dk), ``k``/``v`` (B, T, hk, d):
@@ -549,14 +555,17 @@ def decode_step(entry, q, k, v, pos, live=None, *, window=None, sink=None,
     model that generates by diffusion over blocks has the block's ``T``
     rows, which a :class:`HeadMajorKV` entry of full-length rows takes
     at per-row ``pos`` (a multiple of ``T``): every row of the block then
-    sees ``[0, pos + T)``. ``pos`` is (B,) per-row positions (the serve
-    engine's fused decode step, which every pool-only layout requires)
-    or, for linear rows, a scalar (``generate()``, ``rolled`` where its
-    buffers are circular). ``live`` ((B,) bool) zeroes dead rows'
-    lengths, so the length-aware kernels skip their cache traffic. The
-    block's static facts: its ``window`` (None: full attention), its
-    learned ``sink`` (head-major entries only), the ``name`` its decode
-    kernel has in a trace, its ``mesh``.
+    sees ``[0, pos + T)``. ``lead`` > 0 (static) makes such a step one
+    over two blocks from ``pos`` (a multiple of ``lead``): a block's close
+    in its first ``lead`` rows, which see ``[0, pos + lead)``, and the
+    next block's first denoising step in the rest. ``pos`` is (B,)
+    per-row positions (the serve engine's fused decode step, which every
+    pool-only layout requires) or, for linear rows, a scalar
+    (``generate()``, ``rolled`` where its buffers are circular). ``live``
+    ((B,) bool) zeroes dead rows' lengths, so the length-aware kernels
+    skip their cache traffic. The block's static facts: its ``window``
+    (None: full attention), its learned ``sink`` (head-major entries
+    only), the ``name`` its decode kernel has in a trace, its ``mesh``.
     Returns ``(o, new entry)``: ``o`` (B, T, H, dv), the entry of the
     same type and leaves.
 
@@ -574,7 +583,13 @@ def decode_step(entry, q, k, v, pos, live=None, *, window=None, sink=None,
         raise ParamError(
             "only latent entries are read at a given scale; got a "
             f"{type(entry).__name__} entry")
+    if lead and not (isinstance(entry, HeadMajorKV) and q.shape[1] > 1):
+        raise ParamError(
+            "a step over two blocks (lead) runs several rows a slot over a "
+            f"head-major entry; got a {type(entry).__name__} entry and "
+            f"{q.shape[1]} rows")
     step = _STEPS.get(type(entry), _linear_step)
     return step(entry, q, k, v, pos, live, window=window, sink=sink,
                 name=name, mesh=mesh, rolled=rolled,
-                **({} if scale is None else {"scale": scale}))
+                **({} if scale is None else {"scale": scale}),
+                **({"lead": lead} if lead else {}))

@@ -117,14 +117,19 @@ def _routing_drops(graph) -> bool:
                                 graph.extra.get("n_experts")))
 
 
-def _routing_attrs(stats, steps: int) -> dict:
+def _routing_attrs(stats, steps: int, passes: int | None = None) -> dict:
     """The routing counters a decode block or a prefill fetched beside
-    its tokens, as event attributes: per routed layer and per micro-step
-    the pairs that fell on held experts, the held experts hit and the
-    rows the expert products multiplied, pad rows of their tiles too."""
+    its tokens, as event attributes: per routed layer the held experts
+    hit per micro-step, and per PASS (``passes``, the micro-steps where
+    not given) the pairs that fell on held experts and the rows the
+    expert products multiplied, pad rows of their tiles too. A pass is
+    one token a slot, or one block's rows a slot: a denoising program's
+    step that closes a block beside the next block's step is two."""
     if not stats:
         return {}
-    return {name: round(float(np.mean(total)) / max(steps, 1), 3)
+    per = {"experts_hit": steps}
+    return {name: round(float(np.mean(total))
+                        / max(per.get(name, passes or steps), 1), 3)
             for name, total in stats.items()}
 
 
@@ -2099,8 +2104,8 @@ class ServeEngine:
                         (block["toks"], block["live"], block["stats"])
                     )
                     block["routing"] = _routing_attrs(
-                        stats_h and stats_h[0], block["t_block"]
-                    )
+                        stats_h and stats_h[0], block["t_block"],
+                        block.get("passes"))
                     break
                 except Exception as e:
                     if not (is_transient(e) or is_resource_exhausted(e)):
@@ -2374,7 +2379,8 @@ class ServeEngine:
             tok, masked, rem, need = self._sched.denoise_inputs(
                 length, self.pad_id)
             n_blocks = max(1, min(need, self._block_cap // length))
-            steps = n_blocks * (self._denoise_steps + 1)
+            # every close but the last rides the next block's first step
+            steps = n_blocks * self._denoise_steps + 1
             try:
                 with self._tracer.region("serve.decode", tick=tick,
                                          block=steps) as issue:
@@ -2407,6 +2413,7 @@ class ServeEngine:
             block = {"toks": (blocks, counts), "live": live, "stats": stats,
                      "t_block": steps, "states": states,
                      "pre_pos": pre_pos, "n_blocks": n_blocks,
+                     "passes": n_blocks * (self._denoise_steps + 1),
                      "family": f"denoise[T={steps}]"}
             toks_h, _live_h, done = self._fetch_block(block, tick)
             with self._tracer.region("serve.retire", tick=tick) as retire:
@@ -2444,11 +2451,14 @@ class ServeEngine:
         blk_finished, served, ran = self._sched.consume_blocks(
             blocks_h, block["n_blocks"], tick)
         n_tokens = sum(served.values())
-        per_block, length = self._denoise_steps + 1, self._block_len
-        # every micro-step of a block reads its slot's clean prefix and
-        # the block's own rows
-        live_kv = sum(per_block * (b + 1) * length + per_block * pre_pos[slot]
-                      for slot, r in ran.items() for b in range(r))
+        per_block, length = self._denoise_steps, self._block_len
+        # every denoising step of a block reads its slot's clean prefix
+        # and the block's own rows (a step that closes the block before
+        # it too: that block's rows lie inside them); the last close reads
+        # the prefix and the blocks the slot ran
+        live_kv = sum(per_block * ((b + 1) * length + pre_pos[slot])
+                      for slot, r in ran.items() for b in range(r)) + sum(
+            pre_pos[slot] + r * length for slot, r in ran.items())
         self.metrics.record_decode(
             len(states), decode_s, tokens_emitted=n_tokens, block=steps,
             live_kv=live_kv, cache_len=self.cache_len)

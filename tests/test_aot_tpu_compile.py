@@ -719,11 +719,13 @@ def test_lfm2_decode_block_prefill_and_pool_write_fit_one_v5e(
 def test_sdar_denoising_program_and_prefill_fit_one_v5e(chip, monkeypatch,
                                                         capsys):
     """The cell's denoising program (6 layers of K/V ``(64, 4, 4096,
-    128)``, 4 rows a slot a micro-step, the block count traced) and its
+    128)``, 4 rows a slot a micro-step, or 8 where a block's close rides
+    the next block's first step, the block count traced) and its
     2,048-row block-causal prefill compile for the described v5e inside
     15.75 GB: every pool entry updated in place, no pool-sized copy, the
-    block read and its rows' write once a layer, the expert layer's three
-    products once a layer, and the logits' 155 MB never a temporary."""
+    block read and its rows' write once a layer of each of the two
+    forward passes (4 rows and 8), the expert layer's three products
+    likewise, and the logits' 155 MB never a temporary."""
     import json
     from pathlib import Path
 
@@ -762,8 +764,9 @@ def test_sdar_denoising_program_and_prefill_fit_one_v5e(chip, monkeypatch,
                         count])).compile()
     text = compiled.as_text()
     assert not _pool_copies(text, (slots, 4, rows, 128))
-    for kernel, calls in (("attn_block_decode", 6), ("cache_row_write", 6),
-                          ("moe_gate", 6), ("moe_down", 6)):
+    for kernel, calls in (("attn_block_decode", 12),
+                          ("cache_row_write", 12), ("moe_gate", 12),
+                          ("moe_down", 12)):
         assert len(set(re.findall(rf"%({kernel}\.\d+) = ", text))) == calls
     memory = compiled.memory_analysis()
     print("sdar denoising program: arguments",

@@ -3,9 +3,10 @@
 reference of the family they were written for
 (``benchmark/references/sdar_moe.py``, the one copy): a block-causal
 prefill, then denoising steps of a block's rows through the serving pool
-and the clean close that rewrites them; the engine's admission with no
-first token, prompts and budgets that end inside a block; the kernels'
-block-causal mask and several query rows a slot; and what the engine
+and the clean close that rewrites them, on its own or riding the next
+block's first step; the engine's admission with no first token, prompts
+and budgets that end inside a block; the kernels' block-causal mask and
+several query rows a slot, of one block or two; and what the engine
 refuses for such a model.
 
 The size is tiny and of the benchmark cut's shape: every layer full
@@ -24,7 +25,12 @@ from benchmark.adapters import sdar_moe as adapter
 from benchmark.references import sdar_moe as ref
 from mmlspark_tpu.core.exceptions import FriendlyError, ParamError
 from mmlspark_tpu.models import build_model
-from mmlspark_tpu.models.generate import _cached_apply, generate, init_cache
+from mmlspark_tpu.models.generate import (
+    _cached_apply,
+    generate,
+    init_cache,
+    make_denoise_block,
+)
 from mmlspark_tpu.ops.attention import dense_attention
 from mmlspark_tpu.ops.flash_attention import (
     cache_rows_write,
@@ -34,6 +40,7 @@ from mmlspark_tpu.ops.flash_attention import (
 from mmlspark_tpu.parallel.expert import moe_ffn_held, router_topk
 from mmlspark_tpu.serve.cache_pool import SlotCachePool
 from mmlspark_tpu.serve.engine import ServeEngine
+from mmlspark_tpu.testing.compile_guard import jit_cache_size
 
 VOCAB, CACHE, D, HEADS, HK, DK = 96, 64, 64, 8, 2, 16
 L, STEPS, MASK = 4, 2, 95
@@ -281,6 +288,88 @@ def test_served_tokens_are_the_denoising_of_the_models_own_forward(
     assert (r["gap"][keep] <= 0.06).all(), r["gap"][keep]
 
 
+@pytest.fixture(scope="module")
+def denoising(tiny):
+    """The denoising program alone over a pool of three slots, jitted once
+    for every count of blocks, and a clean block-causal prefill."""
+    _, graph, variables = tiny
+    pool = SlotCachePool(graph, variables, slots=3, cache_len=CACHE)
+    program = jax.jit(make_denoise_block(graph), static_argnames=("most",))
+
+    @jax.jit
+    def prefill(variables, padded):
+        cache = init_cache(graph, variables, 1, padded.shape[1])
+        return _cached_apply(graph, variables, padded, cache, 0, head=False)
+
+    return pool, program, prefill
+
+
+@pytest.mark.parametrize("n_blocks", [1, 2, 4])
+def test_the_fused_close_serves_the_replays_tokens_and_leaves_clean_rows(
+        tiny, denoising, n_blocks):
+    """A dispatch of ``n`` blocks closes every block but its last inside
+    the next block's first step. Slot 0 (a prompt's tail in its first
+    block) runs all ``n`` blocks and stays live; slot 1 (a prompt on a
+    block's edge) closes after one; slot 2 holds no request. Each serves
+    what the model's own full forward commits step by step, the pool
+    holds the K/V of the clean block-causal forward over everything the
+    slot closed, the counters are those of separate closes, and one
+    program serves every count."""
+    _, graph, variables = tiny
+    pool, program, prefill = denoising
+    prompts = {0: _tokens(13, 70 + n_blocks), 1: _tokens(8, 80 + n_blocks)}
+    budgets = {0: L - 1 + L * (n_blocks - 1), 1: L}
+    tok = np.zeros((3, L), np.int32)
+    masked = np.ones((3, L), bool)
+    rem = np.array([100, L, 0], np.int32)
+    for slot, prompt in prompts.items():
+        assert pool.lease() == slot
+        start = len(prompt) // L * L
+        padded = np.zeros((1, 16), np.int32)
+        padded[0, :start] = prompt[:start]
+        _, cache = prefill(variables, jnp.asarray(padded))
+        pool.write_prefill(slot, cache, start)
+        tail = prompt[start:]
+        tok[slot, :len(tail)] = tail
+        masked[slot, :len(tail)] = False
+    out, live, pool.buffers, pos, counts, _ = program(
+        variables, pool.buffers, pool.positions, pool.live, jnp.asarray(tok),
+        jnp.asarray(masked), jnp.asarray(rem), jnp.int32(n_blocks), most=4)
+    assert jit_cache_size(program) == 1
+    out = np.asarray(out)
+    ran = {0: n_blocks, 1: 1}
+    for slot, prompt in prompts.items():
+        start = len(prompt) // L * L
+        served = out[slot, :ran[slot]].ravel()[len(prompt) - start:]
+        assert list(served[:budgets[slot]]) == replay(
+            graph, variables, list(prompt), budgets[slot])
+        seq = np.zeros((1, 32), np.int32)
+        clean = list(prompt[:start]) + list(out[slot, :ran[slot]].ravel())
+        seq[0, :len(clean)] = clean
+        _, cache = prefill(variables, jnp.asarray(seq))
+        for name, (k, v) in cache.items():
+            entry = pool.buffers[name]
+            for got, want in ((entry.k, k), (entry.v, v)):
+                got = np.asarray(got[slot, :, :len(clean)], np.float32)
+                want = np.moveaxis(np.asarray(want[0, :len(clean)],
+                                              np.float32), 1, 0)
+                np.testing.assert_allclose(got, want, atol=0.03, rtol=0.03)
+    assert np.asarray(live).tolist() == [True, False, False]
+    assert np.asarray(pos)[:2].tolist() == [12 + L * n_blocks, 8 + L]
+    counts = {name: int(c) for name, c in counts.items()}
+    # slot 0 commits its first block's 3 masked positions, then 4 a block;
+    # slot 1 its one block's 4
+    assert counts["denoise_steps"] == STEPS * (n_blocks + 1)
+    assert counts["tokens_committed"] == sum(budgets.values())
+    assert counts["blocks_closed"] == n_blocks + 1
+    # the last close closes slot 0 (live at the end) and, in a dispatch
+    # of one block, slot 1 as well
+    last_closed = int(np.asarray(live).sum()) + (n_blocks == 1)
+    assert counts["closes_fused"] == counts["blocks_closed"] - last_closed
+    for slot in sorted(prompts, reverse=True):   # leased again as 0, 1
+        pool.free(slot)
+
+
 def test_admission_emits_no_token_and_blocks_are_counted(served):
     """An admission's ``prefill`` event still names its bucket and its
     dispatch carries no token; a denoising program's ``dispatch`` carries
@@ -309,6 +398,31 @@ def test_admission_emits_no_token_and_blocks_are_counted(served):
                                                "decode", "completed"}
     assert all("blocks" in e["attrs"] for e in events_of
                if e["name"] == "decode")
+
+
+def test_a_dispatch_reports_the_micro_steps_it_ran(served):
+    """A dispatch of ``n`` blocks runs ``n * S + 1`` micro-steps: its
+    family and its slots' ``decode`` events say so, and the held experts
+    hit are per micro-step of that count. The pairs are per pass of a
+    block's rows, ``n * (S + 1)`` of them: every live slot routes a
+    block's rows at each of a block's S steps and at its close."""
+    _, events, _ = served
+    ticks = {}
+    for e in events:
+        if e["name"] == "decode" and e.get("span_name") == "request":
+            ticks.setdefault(e["tick"], []).append(e["attrs"])
+    blocks = [e for e in events if e["name"] == "dispatch"
+              and e["attrs"]["family"].startswith("denoise")]
+    assert blocks and len(blocks) == len(ticks)
+    for e in blocks:
+        a, slots = e["attrs"], ticks[e["tick"]]
+        n = max(s["blocks"] for s in slots)
+        steps = n * STEPS + 1
+        assert a["family"] == f"denoise[T={steps}]"
+        assert all(s["block"] == steps for s in slots)
+        assert 0 < a["experts_hit"] <= MODEL["n_experts"]
+        assert a["expert_pairs"] == len(slots) * L * MODEL["top_k"]
+        assert a["blocks_closed"] - a["closes_fused"] == len(slots)
 
 
 @pytest.mark.parametrize("how", [
@@ -396,6 +510,83 @@ def test_the_grouped_read_takes_a_blocks_rows_and_the_write_places_them():
         want = dense_attention(q[slot:slot + 1], keys, values)
         np.testing.assert_allclose(got[slot:slot + 1], want, atol=2e-5,
                                    rtol=2e-5)
+
+
+def _masked_attention(q, k, v, ends):
+    """Attention of ``q`` (B, T, H, d) over unpacked head-major ``k`` /
+    ``v`` (B, Hkv, L, d), query row ``r`` of slot ``b`` reading the rows
+    ``[0, ends[b, r])`` (zeros where that is empty), in float64."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    b, t, h, d = q.shape
+    g = h // k.shape[1]
+    out = np.zeros(q.shape[:3] + (v.shape[3],))
+    for slot in range(b):
+        for r in range(t):
+            n = int(ends[slot, r])
+            if n <= 0:
+                continue
+            for i in range(h):
+                s = k[slot, i // g, :n] @ q[slot, r, i] / np.sqrt(d)
+                p = np.exp(s - s.max())
+                out[slot, r, i] = p @ v[slot, i // g, :n] / p.sum()
+    return out
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["unpacked", "packed"])
+@pytest.mark.parametrize("lead", [0, 4], ids=["lead-0", "lead-4"])
+def test_a_step_over_two_blocks_reads_each_block_to_its_own_end(packed, lead):
+    """Eight query rows a slot from a multiple of four, across the edge of
+    a 16-row tile too: the write places them in place (in pieces where
+    the start is a multiple of four only), and with ``lead`` 4 the first
+    four rows of a slot read its rows ``[0, length - 4)``, the last four
+    ``[0, length)``, over a cache streamed in two blocks. A slot of length
+    0 (dead) reads nothing; one of 3 leaves its leading rows nothing.
+    With ``lead`` 0 the eight rows are the plain fold into the heads, bit
+    for bit."""
+    key = jax.random.PRNGKey(9)
+    b, t, h, hk, rows = 6, 8, 16, 2, 64
+    d = 64 if packed else 128
+    f = 2 if packed else 1            # KV heads side by side in a row
+    q = jax.random.normal(key, (b, t, h, d), jnp.float32)
+    kc, vc = (jax.random.normal(jax.random.fold_in(key, i), (b, hk, rows, d))
+              for i in (1, 2))
+    kn, vn = (jax.random.normal(jax.random.fold_in(key, i), (b, hk, t, d))
+              for i in (3, 4))
+
+    def pack(a):  # (b, hk, n, d) -> (b, hk / f, n, f * d)
+        n = a.shape[2]
+        return jnp.moveaxis(jnp.moveaxis(a, 1, 2).reshape(
+            b, n, hk // f, f * d), 2, 1)
+
+    at = jnp.asarray([12, 0, 24, 56, 4, 20], jnp.int32)
+    k2, v2 = cache_rows_write(pack(kc), pack(vc), pack(kn), pack(vn), at,
+                              align=L, interpret=True)
+    want_k, want_v = np.array(kc), np.array(vc)
+    for slot in range(b):
+        lo = int(at[slot])
+        want_k[slot, :, lo:lo + t] = kn[slot]
+        want_v[slot, :, lo:lo + t] = vn[slot]
+    np.testing.assert_array_equal(k2, pack(want_k))
+    np.testing.assert_array_equal(v2, pack(want_v))
+    lengths = (at + t).at[1].set(0).at[4].set(3)
+    got = flash_decode_grouped(q, k2, v2, lengths, block=32, lead=lead,
+                               interpret=True)
+    ends = np.repeat(np.asarray(lengths)[:, None], t, axis=1)
+    if lead:
+        ends[:, :lead] -= t - lead
+    np.testing.assert_allclose(got, _masked_attention(q, want_k, want_v,
+                                                      ends),
+                               atol=2e-5, rtol=2e-5)
+    assert not np.asarray(got[1]).any()
+    assert not np.asarray(got[4, :lead]).any()
+    if not lead:
+        # the parent's path: the rows folded into the heads of one row
+        g = h // hk
+        fold = q.reshape(b, t, hk, g, d).transpose(0, 2, 1, 3, 4)
+        one = flash_decode_grouped(fold.reshape(b, 1, h * t, d), k2, v2,
+                                   lengths, block=32, interpret=True)
+        np.testing.assert_array_equal(got, one.reshape(
+            b, hk, t, g, -1).transpose(0, 2, 1, 3, 4).reshape(b, t, h, -1))
 
 
 # -- the softmax router ----------------------------------------------------------
