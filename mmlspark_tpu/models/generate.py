@@ -39,9 +39,11 @@ import jax.numpy as jnp
 from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.models.graph import FINAL_NODE, _accepts_kwarg
 from mmlspark_tpu.ops.kv_cache import (
+    INDEXED_ROWS,
     LATENT_ROWS,
     LINEAR,
     STATE_ROWS,
+    IndexedRows,
     LatentRows,
     StateRows,
     latent_width,
@@ -111,7 +113,7 @@ def cache_geometry(graph, variables) -> dict:
     the int8 rows). Raises :class:`FriendlyError` for a graph whose
     blocks declare another geometry."""
     geometry = {}
-    for name, (kind, _rows, hk, dk, dv) in cache_specs(
+    for name, (kind, _rows, hk, dk, dv, *_) in cache_specs(
             graph, variables).items():
         if kind != LINEAR or dk != dv:
             raise FriendlyError(
@@ -134,10 +136,17 @@ def init_cache(graph, variables, batch: int, total: int) -> dict:
     (:class:`~mmlspark_tpu.ops.kv_cache.LatentRows`); one that declares a
     ``state`` gets its convolution's input at every position, ``(B,
     total, W)`` (:class:`~mmlspark_tpu.ops.kv_cache.StateRows`), of which
-    the pool keeps the last rows below a prompt's true length."""
+    the pool keeps the last rows below a prompt's true length. One that
+    declares ``indexed`` rows gets them and its index keys, ``(B, total,
+    Di)`` (:class:`~mmlspark_tpu.ops.kv_cache.IndexedRows`)."""
     cache = {}
-    for name, (kind, _rows, hk, dk, dv) in cache_specs(
+    for name, (kind, _rows, hk, dk, dv, *more) in cache_specs(
             graph, variables).items():
+        if kind == INDEXED_ROWS:
+            cache[name] = IndexedRows(
+                jnp.zeros((batch, total, latent_width(dk)), jnp.bfloat16),
+                jnp.zeros((batch, total, more[0]), jnp.bfloat16))
+            continue
         if kind == LATENT_ROWS:
             cache[name] = LatentRows(jnp.zeros(
                 (batch, total, latent_width(dk)), jnp.bfloat16))
@@ -220,17 +229,36 @@ def counts_routing(graph) -> bool:
     return any(getattr(mod, "routed", False) for _, mod in graph.blocks)
 
 
+def counts_sparse(graph) -> bool:
+    """Whether ``graph`` has blocks that read a chosen subset of their
+    latent rows (``block.sparse``): its decode block then counts the rows
+    they read, and applies like blocks through one jitted function, which
+    hands the choice from layer to layer."""
+    return any(getattr(mod, "sparse", False) for _, mod in graph.blocks)
+
+
 def routing_totals(counters: dict) -> dict:
     """Per-block routing counters ``{block: {"pairs", "hit", "rows"}}``
     as three vectors over the routed blocks, in the graph's order:
-    ``expert_pairs``, ``experts_hit`` and ``expert_rows``."""
+    ``expert_pairs``, ``experts_hit`` and ``expert_rows``. Blocks that
+    read chosen rows add ``dsa_rows_read`` and ``dsa_rows_live``, two
+    vectors over them: the rows their decode step read, and the live rows
+    it chose them from."""
     names = sorted(counters, key=lambda n: (len(n), n))
-    return {
-        total: jnp.stack([counters[n][counter] for n in names])
+    routed = [n for n in names if "pairs" in counters[n]]
+    totals = {
+        total: jnp.stack([counters[n][counter] for n in routed])
         for total, counter in (("expert_pairs", "pairs"),
                                ("experts_hit", "hit"),
                                ("expert_rows", "rows"))
-    }
+    } if routed else {}
+    sparse = [n for n in names if "rows_read" in counters[n]]
+    if sparse:
+        totals.update({
+            total: jnp.stack([counters[n][counter] for n in sparse])
+            for total, counter in (("dsa_rows_read", "rows_read"),
+                                   ("dsa_rows_live", "rows_live"))})
+    return totals
 
 
 def greedy_next(logits):
@@ -273,7 +301,9 @@ def make_decode_block(graph, pad_id: int = 0):
     "experts_hit", "expert_rows"}``, per routed block the (token,
     expert) pairs that fell on held experts, the held experts hit and
     the rows their products multiplied, summed over the block's
-    micro-steps; it comes back in the same fetch as the tokens.
+    micro-steps; it comes back in the same fetch as the tokens. A graph
+    whose blocks read chosen latent rows (:func:`counts_sparse`) has it
+    too, with ``dsa_rows_read`` and ``dsa_rows_live`` per such block.
     Parity contract: a row's token stream is bit-identical to
     single-request greedy ``generate()`` up to and including its EOS /
     last budgeted token; columns after that are pads the host discards.
@@ -289,9 +319,13 @@ def make_decode_block(graph, pad_id: int = 0):
     (docs/SERVING.md "Sharded serving").
     """
 
-    routed = counts_routing(graph)
+    sparse = counts_sparse(graph)
+    routed = counts_routing(graph) or sparse
 
     def decode_block(variables, buffers, pos, live, tok, rem, eos, t):
+        # a sparse stack's like layers share one traced body
+        shared = {} if sparse else None
+
         def micro(carry, _):
             tok, buffers, pos, live, rem = carry
             # write tok's K/V at pos, attend over [0, pos], next logits.
@@ -303,6 +337,7 @@ def make_decode_block(graph, pad_id: int = 0):
                 graph, variables, tok[:, None], buffers, pos,
                 step=True, live=live,
                 valid=live[:, None] if routed else None, counters=counters,
+                shared=shared,
             )
             nxt = greedy_next(logits[:, 0])
             emit = jnp.where(live, nxt, jnp.asarray(pad_id, jnp.int32))

@@ -55,6 +55,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from mmlspark_tpu.core.exceptions import ParamError
 from mmlspark_tpu.models.graph import FINAL_NODE, NamedGraph
@@ -92,6 +93,10 @@ class TokenEmbed(nn.Module):
     vocab_size: int
     d_model: int
     param_dtype: Any = jnp.float32
+    # > 1: the residual's streams, each the embedding; ``select``: a
+    # sparse stack's (stream, choice) pair, the choice empty
+    streams: int = 1
+    select: bool = False
 
     @nn.compact
     def __call__(self, ids, pos=None):
@@ -99,7 +104,14 @@ class TokenEmbed(nn.Module):
         # the cached forward can hand every block the same arguments
         tok = nn.Embed(self.vocab_size, self.d_model,
                        param_dtype=self.param_dtype, name="token")(ids)
-        return tok.astype(jnp.float32)
+        tok = tok.astype(jnp.float32)
+        if self.streams > 1:
+            tok = jnp.broadcast_to(tok[..., None, :],
+                                   (*tok.shape[:-1], self.streams,
+                                    self.d_model))
+        if self.select:
+            return tok, jnp.zeros((*ids.shape, 0), jnp.int32)
+        return tok
 
 
 class HybridAttention(nn.Module):
@@ -258,9 +270,24 @@ class LatentAttention(nn.Module):
     attn_impl: str = "dense"
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    # > 0: the query's low rank (``q_a``, ``q_a_norm``, ``q_b``)
+    q_lora_rank: int = 0
+    # an elementwise sigmoid gate on every head's output, from the
+    # layer's normed input, before ``attn_out``
+    gate: bool = False
+    sink: bool = False       # a learned logit per head in the denominator
+    # > 0: DeepSeek sparse attention, each query reading the ``topk``
+    # latent rows of highest index score alone (:class:`Indexer`, of
+    # ``index_heads`` x ``index_dim``, where ``index_own``; else the
+    # choice handed down from the layer above is read)
+    index_topk: int = 0
+    index_heads: int = 0
+    index_dim: int = 0
+    index_own: bool = False
 
     @nn.compact
-    def __call__(self, x, cache=None, pos=None, decode=False, live=None):
+    def __call__(self, x, cache=None, pos=None, decode=False, live=None,
+                 sel=None):
         from mmlspark_tpu.ops.rope import apply_rope
 
         b, t, d_model = x.shape
@@ -277,7 +304,13 @@ class LatentAttention(nn.Module):
                               preferred_element_type=jnp.float32
                               ).astype(self.dtype)
 
-        q = proj("q", h * (dn + dr))(x).reshape(b, t, h, dn + dr)
+        c_q = None
+        if self.q_lora_rank:
+            c_q = RMSNorm(self.eps, self.param_dtype, name="q_a_norm")(
+                proj("q_a", self.q_lora_rank)(x)).astype(self.dtype)
+            q = proj("q_b", h * (dn + dr))(c_q).reshape(b, t, h, dn + dr)
+        else:
+            q = proj("q", h * (dn + dr))(x).reshape(b, t, h, dn + dr)
         kv_a = proj("kv_a", rank + dr)(x)
         c = RMSNorm(self.eps, self.param_dtype, name="kv_norm")(
             kv_a[..., :rank]).astype(self.dtype)
@@ -292,6 +325,17 @@ class LatentAttention(nn.Module):
         k_rope = rotate(kv_a[..., None, rank:])            # (b, t, 1, dr)
         # the cached row: what a later step's scores and sums read
         rows = jnp.concatenate((c, k_rope[:, :, 0]), axis=-1)[:, :, None]
+        sink = None
+        if self.sink:
+            sink = self.param("sink", nn.initializers.zeros, (h,),
+                              self.param_dtype).astype(jnp.float32)
+        if self.index_topk:
+            o, new_cache, sel, count = self._sparse(
+                x, c_q, q_nope, q_rope, rows[:, :, 0], w_kvb, cache, pos,
+                live, sel, sink, positions, product)
+            out = proj("attn_out", d_model)(
+                self._gated(x, o, proj).reshape(b, t, h * dv))
+            return out, new_cache, sel, count
         new_cache = None
         if cache is None or (isinstance(pos, int) and pos == 0):
             # expanded, over this call's own rows only
@@ -301,7 +345,7 @@ class LatentAttention(nn.Module):
                 axis=-1)
             o = _prompt_attention(
                 self.attn_impl, jnp.concatenate((q_nope, q_rope), axis=-1),
-                k, kv[..., dn:], MLA)
+                k, kv[..., dn:], MLA, sink=sink)
             if cache is not None:
                 new_cache = kv_cache.write_latent_rows(cache, rows[:, :, 0],
                                                        pos)
@@ -312,10 +356,116 @@ class LatentAttention(nn.Module):
             o_lat, new_cache = kv_cache.decode_step(
                 cache, jnp.concatenate((q_lat, q_rope), axis=-1), rows,
                 rows[..., :rank], pos, live, name=f"attn_{MLA}_decode",
-                scale=(dn + dr) ** -0.5)
+                scale=(dn + dr) ** -0.5, sink=sink)
             o = product("bthc,chv->bthv", o_lat, w_kvb[..., dn:])
+        if self.gate:
+            o = self._gated(x, o, proj)
         out = proj("attn_out", d_model)(o.reshape(b, t, h * dv))
         return out if new_cache is None else (out, new_cache)
+
+    def _gated(self, x, o, proj):
+        """``o`` (B, T, H, dv) times ``sigmoid(x W_gate)`` element by
+        element, where the layer has the gate."""
+        if not self.gate:
+            return o
+        b, t, h, dv = o.shape
+        g = jax.nn.sigmoid(proj("gate", h * dv)(x).astype(jnp.float32))
+        return (o.astype(jnp.float32) * g.reshape(b, t, h, dv)).astype(
+            self.dtype)
+
+    def _sparse(self, x, c_q, q_nope, q_rope, rows, w_kvb, cache, pos, live,
+                sel, sink, positions, product):
+        """The sparse mode: each query reads the ``index_topk`` latent rows
+        that its own indexer (``index_own``) or the layer above chose
+        (``sel``), absorbed (:func:`mmlspark_tpu.ops.kv_cache.sparse_step`),
+        whatever the call: without a cache this call's rows are the
+        entry. Returns ``(o, new cache or None, sel, count)``."""
+        b, t = x.shape[:2]
+        dn, dr, rank = self.nope_dim, self.rope_dim, self.kv_lora_rank
+        index = None
+        if self.index_own:
+            index = Indexer(self.index_heads, self.index_dim, dr,
+                            self.rope_base, self.rope_interleave,
+                            self.dtype, self.param_dtype, name="indexer")(
+                x, c_q, positions)
+        elif sel is None:
+            raise ParamError(
+                "a sparse latent layer without its own indexer reads the "
+                "choice of the layer above it; none was handed down")
+        entry, at = cache, pos
+        if cache is None:
+            width = kv_cache.latent_width(rank + dr)
+            zeros = jnp.zeros((b, t, width), self.dtype)
+            entry, at = (kv_cache.IndexedRows(
+                zeros, jnp.zeros((b, t, self.index_dim), self.dtype))
+                if self.index_own else kv_cache.LatentRows(zeros)), 0
+        q_lat = product("bthn,chn->bthc", q_nope, w_kvb[..., :dn])
+        o_lat, new, sel, count = kv_cache.sparse_step(
+            entry, jnp.concatenate((q_lat, q_rope), axis=-1), rows, at, live,
+            index=index, sel=None if self.index_own else sel,
+            topk=self.index_topk, scale=(dn + dr) ** -0.5, values=rank,
+            sink=sink)
+        o = product("bthc,chv->bthv", o_lat, w_kvb[..., dn:])
+        return o, None if cache is None else new, sel, count
+
+
+class Indexer(nn.Module):
+    """DeepSeek-V3.2's lightning indexer: ``heads`` queries of ``dim``
+    from the query's latent ``c_q``, ONE key a position ``LayerNorm(x
+    W_k)``, head weights ``x W_w / sqrt(heads * dim)``; rotary positions
+    on the first ``rope_dim`` numbers of queries and key. Returns ``(q^I
+    (B, T, heads, dim), w (B, T, heads) float32, k^I (B, T, dim))``, what
+    :func:`mmlspark_tpu.ops.sparse_attention.index_select` scores."""
+
+    heads: int
+    dim: int
+    rope_dim: int
+    rope_base: float
+    rope_interleave: bool = False
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, c_q, positions):
+        from mmlspark_tpu.ops.rope import apply_rope
+
+        b, t, _ = x.shape
+
+        def proj(name, width, inp):
+            return nn.Dense(width, use_bias=False, dtype=self.dtype,
+                            param_dtype=self.param_dtype, name=name)(inp)
+
+        rotate = partial(apply_rope, positions=positions,
+                         base=self.rope_base, rotary_dim=self.rope_dim,
+                         interleave=self.rope_interleave)
+        q = rotate(proj("wq", self.heads * self.dim, c_q).reshape(
+            b, t, self.heads, self.dim))
+        k = LayerNorm(1e-6, self.param_dtype, name="k_norm")(
+            proj("wk", self.dim, x))
+        k = rotate(k.astype(self.dtype)[:, :, None])[:, :, 0]
+        w = proj("weights", self.heads, x).astype(jnp.float32) * (
+            (self.heads * self.dim) ** -0.5)
+        return q, w, k
+
+
+class LayerNorm(nn.Module):
+    """LayerNorm with a gain and a bias, in float32."""
+
+    eps: float = 1e-6
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        d = x.shape[-1]
+        scale = self.param("scale", nn.initializers.ones, (d,),
+                           self.param_dtype)
+        bias = self.param("bias", nn.initializers.zeros, (d,),
+                          self.param_dtype)
+        x = x.astype(jnp.float32)
+        mean = x.mean(axis=-1, keepdims=True)
+        var = jnp.square(x - mean).mean(axis=-1, keepdims=True)
+        return ((x - mean) * jax.lax.rsqrt(var + self.eps)
+                * scale.astype(jnp.float32) + bias.astype(jnp.float32))
 
 
 class ShortConv(nn.Module):
@@ -389,6 +539,7 @@ class RoutedFFN(nn.Module):
     shared_d_ff: int = 0    # an always-on, unweighted SwiGLU beside the sum
     scale: float = 1.0      # on every routing weight, after normalising
     score: str = "sigmoid"  # the router's kind (parallel/expert.router_topk)
+    limit: float = 0.0      # > 0: every SwiGLU clamped (_swiglu)
 
     @nn.compact
     def __call__(self, x, valid=None):
@@ -410,25 +561,33 @@ class RoutedFFN(nn.Module):
             w_up.astype(self.dtype), w_down.astype(self.dtype),
             top_k=self.top_k, first=self.first, valid=valid,
             scale=self.scale, score=self.score,
+            **({"limit": self.limit} if self.limit else {}),
         )
         if self.shared_d_ff:
             # every holder has the shared expert whole, for its own
             # tokens: it is no part of what expert parallelism divides
             out = out + _swiglu(x.astype(self.dtype), self.shared_d_ff,
-                                "shared", self.dtype, self.param_dtype)
+                                "shared", self.dtype, self.param_dtype,
+                                self.limit)
         return out, counters
 
 
-def _swiglu(y, d_ff: int, prefix: str, dtype, param_dtype):
+def _swiglu(y, d_ff: int, prefix: str, dtype, param_dtype,
+            limit: float = 0.0):
     """``(silu(y W_gate) * (y W_up)) W_out`` without biases, its three
     matrices named ``<prefix>_gate``, ``_up`` and ``_out`` in the calling
-    module."""
+    module. ``limit`` > 0 clamps: ``silu(min(g, limit)) * clip(u,
+    -limit, limit)``."""
     def dense(name, width):
         return nn.Dense(width, use_bias=False, dtype=dtype,
                         param_dtype=param_dtype, name=f"{prefix}_{name}")
 
-    return dense("out", y.shape[-1])(
-        nn.silu(dense("gate", d_ff)(y)) * dense("up", d_ff)(y))
+    if not limit:
+        return dense("out", y.shape[-1])(
+            nn.silu(dense("gate", d_ff)(y)) * dense("up", d_ff)(y))
+    gate = jnp.minimum(dense("gate", d_ff)(y), limit)
+    up = jnp.clip(dense("up", d_ff)(y), -limit, limit)
+    return dense("out", y.shape[-1])(nn.silu(gate) * up)
 
 
 class HybridBlock(nn.Module):
@@ -463,6 +622,19 @@ class HybridBlock(nn.Module):
     conv_width: int = 0
     block: int = 0           # > 0: block-causal (HybridAttention.block)
     router: str = "sigmoid"  # the routed FFN's router kind
+    # a latent layer's query low rank, output gate and sink
+    # (LatentAttention's)
+    q_lora_rank: int = 0
+    gate: bool = False
+    # DeepSeek sparse attention: (topk, index heads, index width, own
+    # indexer) of a latent layer, () for none. A block of a stack with
+    # sparse layers carries ``(stream, choice)`` from block to block
+    index: tuple = ()
+    # > 1: the residual is this many streams, mixed around each sublayer
+    # (:class:`HyperMix`: ``(magnitude, eps)`` in ``hc``)
+    hc_streams: int = 1
+    hc: tuple = (2.0, 1e-6)
+    swiglu_limit: float = 0.0    # > 0: every SwiGLU clamped (_swiglu)
 
     def cache_spec(self) -> tuple:
         """``(kind, rows, kv_heads, key width, value width)``: what the
@@ -471,10 +643,16 @@ class HybridBlock(nn.Module):
         of ``window`` rows, a latent block every position's ONE row
         ``[c ; k_rope]``, whose first ``kv_lora_rank`` columns are the
         values, a convolution block a state of ``conv_kernel - 1`` rows
-        the stream's width: no heads, and nothing for values."""
+        the stream's width: no heads, and nothing for values. A latent
+        block that runs its own indexer keeps ``indexed`` rows, and a
+        sixth number: the width of the index key it keeps a position."""
         if self.conv_kernel:
             return (kv_cache.STATE_ROWS, self.conv_kernel - 1, 1,
                     self.conv_width, 0)
+        if self.kv_lora_rank and self.index and self.index[3]:
+            return (kv_cache.INDEXED_ROWS, None, 1,
+                    self.kv_lora_rank + self.rotary_dim, self.kv_lora_rank,
+                    self.index[2])
         if self.kv_lora_rank:
             return (kv_cache.LATENT_ROWS, None, 1,
                     self.kv_lora_rank + self.rotary_dim, self.kv_lora_rank)
@@ -486,54 +664,168 @@ class HybridBlock(nn.Module):
     def routed(self) -> bool:
         return self.ffn == ROUTED_FFN
 
+    @property
+    def sparse(self) -> bool:
+        """Whether this block reads a chosen subset of its latent rows
+        (DeepSeek sparse attention): its decode step then counts the rows
+        it read and the live rows it chose them from."""
+        return bool(self.index)
+
     @nn.compact
     def __call__(self, x, cache=None, pos=None, rolled=False,
                  decode=False, live=None, valid=None):
+        """The block over the residual ``x``: ``(B, T, C)``, or ``(B, T,
+        n, C)`` for ``n = hc_streams`` streams, or in a stack of sparse
+        layers the pair ``(residual, choice)``: the rows the layer that
+        scored last chose, ``(B, T, k)``, which a layer without its own
+        indexer reads and hands on. With several streams each sublayer
+        ``F`` runs on the streams' mix ``H_pre X`` and leaves ``H_res X +
+        H_post^T F`` (:class:`HyperMix`), else ``X + F(X)``. Returns the
+        residual, and with a cache the new entry and the counters: the
+        routing's, and a sparse block's decode step adds ``rows_read``
+        (the chosen rows read over live slots) and ``rows_live`` (the
+        rows they were chosen from)."""
         if rolled:
             raise ParamError(
                 "a hybrid block rolls no cache of its own: generate() "
                 "keeps it linear, the serving pool keeps the ring"
             )
-        y = RMSNorm(self.eps, self.param_dtype, name="ln1")(x)
-        if self.conv_kernel:
-            attend = ShortConv(self.conv_kernel, self.dtype,
-                               self.param_dtype, name="conv")
-        elif self.kv_lora_rank:
-            attend = LatentAttention(
-                self.heads, self.head_dim - self.rotary_dim,
-                self.rotary_dim, self.v_head_dim, self.kv_lora_rank,
-                self.rope_base, self.rope_interleave, self.eps,
-                self.attn_impl, self.dtype, self.param_dtype, name="attn")
-        else:
-            attend = HybridAttention(
-                self.heads, self.kv_heads, self.head_dim, self.v_head_dim,
-                self.window, self.rope_base, self.rotary_dim,
-                self.value_scale, self.sink, self.attn_impl, self.dtype,
-                self.param_dtype, self.rope_interleave, self.qk_norm,
-                self.eps, block=self.block, name="attn")
-        attn = attend(y, cache=cache, pos=pos, decode=decode, live=live)
-        new_cache = None
-        if cache is not None:
-            attn, new_cache = attn
-        x = x + attn
-        y = RMSNorm(self.eps, self.param_dtype, name="ln2")(x)
-        counters = None
+        sel = None
+        if self.index:
+            x, sel = x
+        mix = partial(HyperMix, self.hc[0], self.hc[1], self.eps, self.dtype,
+                      self.param_dtype)
+
+        def sublayer(name, x, f):
+            if self.hc_streams == 1:
+                out, *rest = f(x)
+                return x + out, rest
+            pre, post, res = mix(name=name)(x)
+            with jax.named_scope("hc_pre"):
+                inp = jnp.einsum("...n,...nc->...c", pre, x)
+            out, *rest = f(inp)
+            return hc_mix(res, x, post, out), rest
+
+        def attend(inp):
+            y = RMSNorm(self.eps, self.param_dtype, name="ln1")(inp)
+            kwargs = {"cache": cache, "pos": pos, "decode": decode,
+                      "live": live}
+            if self.conv_kernel:
+                attn = ShortConv(self.conv_kernel, self.dtype,
+                                 self.param_dtype, name="conv")
+            elif self.kv_lora_rank:
+                topk, heads, width, own = self.index or (0, 0, 0, False)
+                attn = LatentAttention(
+                    self.heads, self.head_dim - self.rotary_dim,
+                    self.rotary_dim, self.v_head_dim, self.kv_lora_rank,
+                    self.rope_base, self.rope_interleave, self.eps,
+                    self.attn_impl, self.dtype, self.param_dtype,
+                    q_lora_rank=self.q_lora_rank, gate=self.gate,
+                    sink=self.sink, index_topk=topk, index_heads=heads,
+                    index_dim=width, index_own=own, name="attn")
+                if self.index:
+                    return attn(y, sel=sel, **kwargs)
+            else:
+                attn = HybridAttention(
+                    self.heads, self.kv_heads, self.head_dim,
+                    self.v_head_dim, self.window, self.rope_base,
+                    self.rotary_dim, self.value_scale, self.sink,
+                    self.attn_impl, self.dtype, self.param_dtype,
+                    self.rope_interleave, self.qk_norm, self.eps,
+                    block=self.block, name="attn")
+            got = attn(y, **kwargs)
+            return got if cache is not None else (got,)
+
+        def ffn(inp):
+            return self._ffn(RMSNorm(self.eps, self.param_dtype,
+                                     name="ln2")(inp), valid)
+
+        x, got = sublayer("hc_attn", x, attend)
+        new_cache, counters = (got or [None])[0], {}
+        if self.index:
+            new_cache, sel, count = got
+            if decode and live is not None and jnp.ndim(pos):
+                counters = {"rows_read": count[:, 0].sum(dtype=jnp.int32),
+                            "rows_live": ((pos + 1) * live.astype(
+                                jnp.int32)).sum(dtype=jnp.int32)}
+        x, (routing,) = sublayer("hc_ffn", x, ffn)
+        counters.update(routing or {})
+        out = (x, sel) if self.index else x
+        if cache is None:
+            return out
+        return (out, new_cache, counters) if counters else (out, new_cache)
+
+    def _ffn(self, y, valid):
+        """The block's FFN over its normed input, and the routing
+        counters (None for a dense FFN)."""
         if self.routed:
-            y, counters = RoutedFFN(
+            return RoutedFFN(
                 self.n_experts, self.top_k, self.d_ff, self.held[0],
                 self.held[1], self.dtype, self.param_dtype,
                 self.shared_d_ff, self.routed_scale, score=self.router,
-                name="moe",
+                limit=self.swiglu_limit, name="moe",
             )(y, valid)
-        else:
-            y = _swiglu(y.astype(self.dtype), self.d_ff, "mlp", self.dtype,
-                        self.param_dtype)
-        out = x + y
-        if new_cache is None:
-            return out
-        if counters is None:
-            return out, new_cache
-        return out, new_cache, counters
+        return _swiglu(y.astype(self.dtype), self.d_ff, "mlp", self.dtype,
+                       self.param_dtype, self.swiglu_limit), None
+
+
+class HyperMix(nn.Module):
+    """The maps of a manifold-constrained hyper-connection (mHC) around
+    one sublayer of a residual of ``n`` streams ``X`` (..., n, C): with
+    ``x = RMSNorm(vec X)`` (no gain) and ``[p ; q ; r] = x phi``,
+    ``H_pre = sigmoid(a_pre p + b_pre)`` (n), ``H_post = magnitude *
+    sigmoid(a_post q + b_post)`` (n) and ``H_res = SinkhornKnopp(exp(a_res
+    r + b_res))`` (n x n, ``iters`` alternations of row and column
+    normalisation, ``eps`` in every division). Returns the three, float32;
+    the product ``x phi`` in the compute dtype with float32 accumulation."""
+
+    magnitude: float = 2.0
+    eps: float = 1e-6
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    iters: int = 20
+
+    @nn.compact
+    def __call__(self, x):
+        n, c = x.shape[-2:]
+        width = 2 * n + n * n
+        phi = self.param("phi", nn.initializers.normal(0.02), (n * c, width),
+                         self.param_dtype)
+        bias = self.param("bias", nn.initializers.zeros, (width,),
+                          self.param_dtype).astype(jnp.float32)
+        alpha = self.param("alpha", nn.initializers.constant(0.1), (3,),
+                           self.param_dtype).astype(jnp.float32)
+        with jax.named_scope("hc_maps"):
+            flat = x.reshape(*x.shape[:-2], n * c).astype(jnp.float32)
+            flat = flat * jax.lax.rsqrt(
+                jnp.mean(jnp.square(flat), axis=-1, keepdims=True)
+                + self.norm_eps)
+            z = jnp.dot(flat.astype(self.dtype), phi.astype(self.dtype),
+                        preferred_element_type=jnp.float32)
+            pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + bias[:n])
+            post = self.magnitude * jax.nn.sigmoid(
+                alpha[1] * z[..., n:2 * n] + bias[n:2 * n])
+            res = jnp.exp(alpha[2] * z[..., 2 * n:] + bias[2 * n:])
+            res = res.reshape(*res.shape[:-1], n, n)
+            for _ in range(self.iters):
+                res = res / (res.sum(-1, keepdims=True) + self.eps)
+                res = res / (res.sum(-2, keepdims=True) + self.eps)
+        return pre, post, res
+
+
+def hc_mix(res, x, post, out):
+    """``H_res X + H_post^T F`` for the streams ``x`` (..., n, C) float32,
+    ``res`` (..., n, n), ``post`` (..., n) and a sublayer's output ``out``
+    (..., C): by the ``hc_mix`` kernel (:func:`mmlspark_tpu.ops.
+    stream_mix.stream_mix`)."""
+    from mmlspark_tpu.ops.stream_mix import stream_mix
+
+    lead, (n, c) = x.shape[:-2], x.shape[-2:]
+    rows = int(np.prod(lead))
+    new = stream_mix(res.reshape(rows, n, n), x.reshape(rows, n, c),
+                     post.reshape(rows, n), out.reshape(rows, c))
+    return new.reshape(x.shape)
 
 
 class HybridHead(nn.Module):
@@ -541,10 +833,24 @@ class HybridHead(nn.Module):
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    # a stack of several streams sums them before the norm; a sparse
+    # stack hands the head its (stream, choice) pair
+    streams: int = 1
+    select: bool = False
+    fp32: bool = False       # the head's matrix and product in float32
 
     @nn.compact
     def __call__(self, x):
+        if self.select:
+            x = x[0]
+        if self.streams > 1:
+            x = x.sum(axis=-2)
         x = RMSNorm(self.eps, self.param_dtype, name="ln_f")(x)
+        if self.fp32:
+            return nn.Dense(self.vocab_size, use_bias=False,
+                            dtype=jnp.float32, param_dtype=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST,
+                            name="head")(x)
         x = nn.Dense(self.vocab_size, use_bias=False, dtype=self.dtype,
                      param_dtype=self.param_dtype, name="head")(
             x.astype(self.dtype))
@@ -601,6 +907,18 @@ def hybrid_lm(
     block: int = 0,
     denoise_steps: int = 0,
     mask_id: int = -1,
+    q_lora_rank: int = 0,
+    attn_gate: bool = False,
+    latent_sink: bool = False,
+    index_topk: int = 0,
+    index_heads: int = 0,
+    index_dim: int = 0,
+    indexer: tuple | None = None,
+    hc_streams: int = 1,
+    hc_magnitude: float = 2.0,
+    hc_eps: float = 1e-6,
+    swiglu_limit: float = 0.0,
+    head_fp32: bool = False,
 ) -> NamedGraph:
     """Causal decoder LM with a per-layer pattern: ``attention[i]`` in
     (``"full"``, ``"swa"``, ``"mla"``, ``"conv"``) and ``ffn[i]`` in
@@ -627,7 +945,26 @@ def hybrid_lm(
     ``denoise_steps`` (1..L) denoising steps a block, ``mask_id`` the
     token a position not yet committed reads (excluded from every
     choice). The serving engine then generates whole blocks
-    (``models/generate.make_denoise_block``)."""
+    (``models/generate.make_denoise_block``).
+
+    The latent layers' further options: ``q_lora_rank`` > 0 gives the
+    query a low rank (``q_a``, an RMSNorm, ``q_b``), ``attn_gate`` an
+    elementwise sigmoid gate on every head's output (from the layer's
+    normed input), ``latent_sink`` a learned logit per head in the
+    softmax's denominator. ``index_topk`` > 0 makes every latent layer
+    DEEPSEEK-SPARSE: a query reads the ``index_topk`` latent rows of
+    highest index score alone; ``indexer[i]`` is ``"full"`` where layer
+    ``i`` runs its own lightning indexer (``index_heads`` heads of
+    ``index_dim``, keys kept in the cache beside the latent rows) and
+    ``"shared"`` where it reads the choice of the nearest ``full`` layer
+    above it. ``hc_streams`` = n > 1 makes the residual n streams, the
+    embedding copied into each and summed before the final norm, mixed
+    around every sublayer by manifold-constrained hyper-connections
+    (:class:`HyperMix`; ``hc_magnitude`` the post map's scale, ``hc_eps``
+    in the Sinkhorn divisions); both take latent layers only.
+    ``swiglu_limit`` > 0 clamps every SwiGLU (``silu(min(g, limit)) *
+    clip(u, -limit, limit)``), ``head_fp32`` keeps the head's matrix and
+    product in float32."""
     attention, ffn = tuple(attention), tuple(ffn)
     if not attention or len(attention) != len(ffn):
         raise ParamError(
@@ -665,6 +1002,22 @@ def hybrid_lm(
             f"'{FULL}' (got {sorted(set(attention))}), 1 <= denoise_steps "
             f"({denoise_steps}) <= block, and a mask_id ({mask_id}) inside "
             f"the vocabulary ({vocab_size})")
+    index = tuple(indexer or ())
+    if index_topk and not (
+            set(attention) == {MLA} and len(index) == len(attention)
+            and set(index) <= {FULL, "shared"} and index[0] == FULL
+            and index_heads > 0 and index_dim > 0
+            and 0 < int(qk_rope_head_dim) < index_dim):
+        raise ParamError(
+            f"index_topk={index_topk} makes every layer a sparse '{MLA}' "
+            f"one: got kinds {sorted(set(attention))}, indexer {index} "
+            "(one 'full' or 'shared' a layer, the first 'full'), "
+            f"index_heads {index_heads}, index_dim {index_dim} (wider "
+            f"than the rotary part, {qk_rope_head_dim})")
+    if int(hc_streams) > 1 and set(attention) != {MLA}:
+        raise ParamError(
+            f"hc_streams={hc_streams} mixes the streams of latent "
+            f"('{MLA}') layers only, got {sorted(set(attention))}")
     for kind in ffn:
         if kind not in (DENSE_FFN, ROUTED_FFN):
             raise ParamError(
@@ -699,8 +1052,9 @@ def hybrid_lm(
     d_ff = d_ff or 4 * d_model
     expert_d_ff = expert_d_ff or d_ff
     dtype = _dtype(param_dtype)
+    streams, sparse = max(int(hc_streams), 1), bool(index_topk)
     blocks: list[tuple[str, Any]] = [
-        ("embed", TokenEmbed(vocab_size, d_model, dtype))
+        ("embed", TokenEmbed(vocab_size, d_model, dtype, streams, sparse))
     ]
     for i, (a_kind, f_kind) in enumerate(zip(attention, ffn)):
         swa, latent = a_kind == SWA, a_kind == MLA
@@ -713,7 +1067,8 @@ def hybrid_lm(
                 (swa_rope_base or rope_base) if swa else rope_base),
             rotary_dim=int(qk_rope_head_dim) if latent else rotary_dim,
             value_scale=float(value_scale),
-            sink=bool(swa_sink if swa else full_sink and a_kind == FULL),
+            sink=bool(latent_sink if latent else swa_sink if swa
+                      else full_sink and a_kind == FULL),
             ffn=f_kind,
             d_ff=expert_d_ff if routed else d_ff,
             n_experts=n_experts if routed else 0,
@@ -729,9 +1084,18 @@ def hybrid_lm(
             conv_width=int(d_model) if a_kind == CONV else 0,
             block=int(block),
             router=router,
+            q_lora_rank=int(q_lora_rank) if latent else 0,
+            gate=bool(attn_gate) and latent,
+            index=((int(index_topk), int(index_heads), int(index_dim),
+                    index[i] == FULL) if sparse else ()),
+            hc_streams=streams,
+            hc=(float(hc_magnitude), float(hc_eps)),
+            swiglu_limit=float(swiglu_limit),
         )))
     blocks.append((FINAL_NODE, HybridHead(vocab_size, norm_eps,
-                                          param_dtype=dtype)))
+                                          param_dtype=dtype,
+                                          streams=streams, select=sparse,
+                                          fp32=bool(head_fp32))))
     return NamedGraph(
         name="hybrid_lm",
         blocks=blocks,

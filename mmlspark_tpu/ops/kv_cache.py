@@ -46,10 +46,13 @@ from mmlspark_tpu.ops.attention import (
 #: (models/hybrid.py, serve/cache_pool.py), and ``latent`` (a row for every
 #: position that is no K/V pair: :class:`LatentRows`) and ``state`` (a
 #: CONSTANT number of rows a slot, whatever its length: the inputs a causal
-#: short convolution still needs, :class:`SlotState`)
+#: short convolution still needs, :class:`SlotState`) and ``indexed``
+#: (latent rows with the lightning indexer's key of every position beside
+#: them: :class:`IndexedRows`)
 LINEAR, FULL_ROWS, RING_ROWS = "linear", "full", "ring"
 LATENT_ROWS = "latent"
 STATE_ROWS = "state"
+INDEXED_ROWS = "indexed"
 
 #: headroom multiplied onto the prefill amax when fixing a slot's int8
 #: quantization scale: decode steps quantize with the SAME scale
@@ -113,6 +116,21 @@ class LatentRows(NamedTuple):
     decode block; ``bf16[S, L, 640]`` is held as the kernel reads it."""
 
     rows: Any
+
+
+class IndexedRows(NamedTuple):
+    """A latent entry of a layer that runs DeepSeek sparse attention's
+    lightning indexer: the latent ``rows`` (B, rows, W) as a
+    :class:`LatentRows` entry holds them, and beside them ``keys`` (B,
+    rows, Di), the indexer's ONE key a position (no heads), which its
+    queries score to choose the rows a query reads (:func:`sparse_step`).
+    A layer that reuses another's choice keeps plain :class:`LatentRows`.
+    ``generate()``, a prefill's cache, a chunked fill's carry and the
+    serving pool hold it alike; what differs is the step, as for
+    :class:`LatentRows`."""
+
+    rows: Any
+    keys: Any
 
 
 class StateRows(NamedTuple):
@@ -538,6 +556,73 @@ def state_step(entry, proj, taps, pos, live=None, *,
           else contextlib.nullcontext()):
         conv = _causal_taps(g, before, taps)
     return (c_gate * conv).astype(proj.dtype), new
+
+
+def sparse_step(entry, q, row, pos, live=None, *, index=None, sel=None,
+                topk: int, scale: float, values: int, sink=None):
+    """One call of a DeepSeek-sparse latent layer over ``entry``: write
+    this call's latent ``row`` (B, T, dk) (and, in an
+    :class:`IndexedRows` entry, its index key, ``index[2]`` (B, T, Di))
+    from ``pos`` on, choose, and read each query's chosen rows alone.
+    ``q`` (B, T, H, dk) is the ABSORBED query. The choice is the layer's
+    own where ``index`` = ``(q^I (B, T, Hi, Di), w (B, T, Hi), k^I)`` is
+    given: the ``topk`` positions of highest index score
+    (:func:`mmlspark_tpu.ops.sparse_attention.index_select`); else
+    ``sel`` (B, T, k), the choice of the layer that scored, reused. Every
+    query at position ``p`` reads the first ``min(k, p + 1)`` of its
+    choices, ``live`` False reads none.
+
+    ``pos`` (B,) with ``T`` = 1 is the engine's fused step (rows written
+    in place, kernels named ``index_decode`` and ``attn_dsa_decode``); a
+    scalar ``pos`` is a prefill, a chunk against a live prefix or
+    ``generate()``'s steps (``index_prefill``, ``attn_dsa_prefill``).
+    Returns ``(o (B, T, H, values), new entry, sel, count (B, T))``."""
+    from mmlspark_tpu.ops import flash_attention as kernels
+    from mmlspark_tpu.ops import sparse_attention as dsa
+
+    indexed = isinstance(entry, IndexedRows)
+    if not isinstance(entry, (LatentRows, IndexedRows)) or (
+            indexed != (index is not None)) or (sel is None) == (
+            index is None):
+        raise ParamError(
+            "a sparse latent layer scores over IndexedRows with its own "
+            "indexer, or reuses a choice over LatentRows; got a "
+            f"{type(entry).__name__} entry, index "
+            f"{'given' if index is not None else 'absent'}, sel "
+            f"{'given' if sel is not None else 'absent'}")
+    b, t = q.shape[:2]
+    keys = entry.keys if indexed else None
+    if jnp.ndim(pos) and t == 1:
+        phase = "decode"
+        rows = kernels.latent_row_write(entry.rows,
+                                        _lanes(row[:, 0], entry), pos)
+        if indexed:
+            keys = kernels.latent_row_write(
+                keys, index[2][:, 0].astype(keys.dtype), pos)
+        first = jnp.asarray(pos, jnp.int32)
+    elif jnp.ndim(pos):
+        raise ParamError("per-row cache positions (the serve engine's "
+                         "fused decode step) are single-token")
+    else:
+        phase = "prefill"
+        rows = jax.lax.dynamic_update_slice(entry.rows, _lanes(row, entry),
+                                            (0, pos, 0))
+        if indexed:
+            keys = jax.lax.dynamic_update_slice(
+                keys, index[2].astype(keys.dtype), (0, pos, 0))
+        first = jnp.full((b,), pos, jnp.int32)
+    new = IndexedRows(rows, keys) if indexed else LatentRows(rows)
+    if indexed:
+        sel = dsa.index_select(index[0].astype(keys.dtype), index[1], keys,
+                               first, topk, name=f"index_{phase}")
+    # a query at position p reads the first min(k, p + 1) of its choices
+    count = jnp.minimum(first[:, None] + jnp.arange(t) + 1, sel.shape[-1])
+    if live is not None:
+        count = jnp.where(live[:, None], count, 0)
+    o = dsa.sparse_latent_read(_lanes(q, entry), rows, sel, count,
+                               sink=sink, scale=scale, values=values,
+                               name=f"attn_dsa_{phase}")
+    return o, new, sel, count
 
 
 _STEPS = {Int8Rows: _int8_rows_step, HeadMajorKV: _head_major_step,

@@ -278,7 +278,8 @@ def held_tiles(tokens: int, held: int, top_k: int,
 
 def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, *,
                  top_k: int, first: int, valid=None, scale: float = 1.0,
-                 interpret: bool | None = None, score: str = SIGMOID):
+                 interpret: bool | None = None, score: str = SIGMOID,
+                 limit: float = 0.0):
     """The part of a routed SwiGLU layer that THIS holder's experts give.
 
     The router scores all ``E`` experts (``router_w`` (D, E)) and every
@@ -291,7 +292,8 @@ def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, *,
     exchange to run). ``x`` is (B, T, D); ``valid`` ((B, T) bool) marks
     the real tokens: a pad or a dead row routes nowhere. ``scale``
     multiplies every routing weight and ``score`` is the router's kind
-    (:func:`router_topk`).
+    (:func:`router_topk`). ``limit`` > 0 clamps every expert's SwiGLU:
+    ``silu(min(g, limit)) * clip(u, -limit, limit)``.
 
     Dropless at every length and fixed in shape: the held pairs are
     ranked inside their expert, every expert's rows are padded to whole
@@ -345,6 +347,9 @@ def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, *,
     xs = flat[token_of_row].astype(w_gate.dtype)
     gate = gmm(xs, w_gate, name="moe_gate")
     up = gmm(xs, w_up, name="moe_up")
+    if limit:
+        gate = jnp.minimum(gate.astype(jnp.float32), limit)
+        up = jnp.clip(up.astype(jnp.float32), -limit, limit)
     mid = (jax.nn.silu(gate.astype(jnp.float32))
            * up.astype(jnp.float32)).astype(w_down.dtype)
     y = gmm(mid, w_down, name="moe_down")

@@ -47,11 +47,13 @@ from mmlspark_tpu.core.exceptions import FriendlyError
 from mmlspark_tpu.models.generate import cache_specs
 from mmlspark_tpu.ops.kv_cache import (
     FULL_ROWS,
+    INDEXED_ROWS,
     LATENT_ROWS,
     LINEAR,
     RING_ROWS,
     STATE_ROWS,
     HeadMajorKV,
+    IndexedRows,
     Int8Rows,
     LatentRows,
     SlotState,
@@ -137,11 +139,16 @@ def _write_slot(buffers, positions, live, prefill_cache, slot, start,
             new_buffers[name] = SlotState(jax.lax.dynamic_update_slice(
                 entry.rows, row.astype(entry.rows.dtype), (slot, 0)))
             continue
-        if isinstance(entry, LatentRows):
-            filled = prefill_cache[name].rows[0, :entry.rows.shape[1]]
-            row = jnp.arange(filled.shape[0])[:, None]
-            new_buffers[name] = LatentRows(_put_rows(
-                entry.rows, filled, slot, (row >= start) & (row < length)))
+        if isinstance(entry, (LatentRows, IndexedRows)):
+            # the latent rows, and an indexed entry's index keys beside
+            # them, row for row
+            placed = []
+            for pool, filled in zip(entry, prefill_cache[name]):
+                filled = filled[0, :pool.shape[1]]
+                row = jnp.arange(filled.shape[0])[:, None]
+                placed.append(_put_rows(pool, filled, slot,
+                                        (row >= start) & (row < length)))
+            new_buffers[name] = type(entry)(*placed)
             continue
         if isinstance(entry, HeadMajorKV):
             placed = []
@@ -263,7 +270,7 @@ class SlotCachePool:
             self.kinds = {name: declared.get(name, FULL_ROWS)
                           for name in specs}
         geometry = {name: (hk, dk)
-                    for name, (_k, _r, hk, dk, _dv) in specs.items()}
+                    for name, (_k, _r, hk, dk, *_) in specs.items()}
         self.mesh = mesh
         if mesh is not None:
             data = int(mesh.shape.get(DATA_AXIS, 1))
@@ -291,7 +298,7 @@ class SlotCachePool:
             msize = int(mesh.shape.get(MODEL_AXIS, 1))
             self._kv_shardings = {}
         self.buffers = {}
-        for name, (_kind, rows, hk, d, dv) in specs.items():
+        for name, (_kind, rows, hk, d, dv, *more) in specs.items():
             # K and V must be DISTINCT arrays: the engine's decode step
             # donates the whole buffer pytree (donate_argnums), and a
             # pair aliasing one allocation cannot be donated twice —
@@ -302,6 +309,13 @@ class SlotCachePool:
                 # decode kernel streams (rows, W) tiles of a slot
                 entry = LatentRows(jnp.zeros(
                     (slots, cache_len, latent_width(d)), store_dtype))
+            elif kind == INDEXED_ROWS:
+                # the latent rows and, beside them, the indexer's one key
+                # a position, which the index kernel streams (rows, Di)
+                entry = IndexedRows(
+                    jnp.zeros((slots, cache_len, latent_width(d)),
+                              store_dtype),
+                    jnp.zeros((slots, cache_len, more[0]), store_dtype))
             elif kind == STATE_ROWS:
                 # a slot's ``rows`` inputs side by side, whatever
                 # cache_len is: what the conv_decode kernel shifts in place
@@ -568,9 +582,17 @@ class SlotCachePool:
         pool, by the kind of the entries they land in: a ring takes the
         last rows it has room for."""
         out = {LINEAR: 0, FULL_ROWS: 0, RING_ROWS: 0, LATENT_ROWS: 0,
-               STATE_ROWS: 0}
+               STATE_ROWS: 0, INDEXED_ROWS: 0}
         for name, entry in self.buffers.items():
             kind = self.kinds.get(name, LINEAR)
+            if kind == INDEXED_ROWS:
+                # the latent rows count as latent, the keys beside them as
+                # the index's
+                out[LATENT_ROWS] += ((length - start) * entry.rows.shape[2]
+                                     * entry.rows.dtype.itemsize)
+                out[kind] += ((length - start) * entry.keys.shape[2]
+                              * entry.keys.dtype.itemsize)
+                continue
             if kind == STATE_ROWS:
                 # the slot's whole state, a row nought where the prompt is
                 # shorter: the same bytes at every admission
@@ -593,13 +615,17 @@ class SlotCachePool:
     def bytes_by_kind(self, length: int, start: int = 0) -> dict:
         """``{"bytes_full", "bytes_ring", "bytes_latent", "bytes_state"}``
         of a write of rows ``[start, length)``, for a pool that holds typed
-        entries; nothing for a pool of linear rows."""
+        entries (and ``bytes_index``, the index keys, where it holds
+        ``indexed`` ones); nothing for a pool of linear rows."""
         if not self.kinds:
             return {}
         by = self._write_bytes(length, start)
-        return {"bytes_full": by[FULL_ROWS], "bytes_ring": by[RING_ROWS],
-                "bytes_latent": by[LATENT_ROWS],
-                "bytes_state": by[STATE_ROWS]}
+        out = {"bytes_full": by[FULL_ROWS], "bytes_ring": by[RING_ROWS],
+               "bytes_latent": by[LATENT_ROWS],
+               "bytes_state": by[STATE_ROWS]}
+        if INDEXED_ROWS in self.kinds.values():
+            out["bytes_index"] = by[INDEXED_ROWS]
+        return out
 
     # -- accounting for telemetry ------------------------------------------
 

@@ -166,6 +166,7 @@ class ServeEngine:
                  quantize_weights: bool = False,
                  role: str = "both",
                  prefill_chunk: int | None = None,
+                 max_fills: int | None = None,
                  async_host: bool = False,
                  registry=None):
         if not graph.extra.get("causal", False):
@@ -259,6 +260,17 @@ class ServeEngine:
                     "requires bucketed prefill — drop prefill_chunk"
                 )
         self._prefill_chunk = prefill_chunk
+        # at most this many chunked fills at once (the queue waits; None:
+        # no cap). Every fill in flight advances one chunk a tick, so the
+        # cap is the tick's prefill budget beside its decode block, as
+        # prefill_chunk is a fill's; each fill also holds a carry of the
+        # whole cache_len for every layer until it lands
+        if max_fills is not None and (prefill_chunk is None
+                                      or int(max_fills) < 1):
+            raise FriendlyError(
+                f"max_fills ({max_fills}) caps the chunked fills in flight: "
+                "it needs prefill_chunk and at least 1")
+        self._max_fills = max_fills
         # pipelined async host loop (docs/SERVING.md "Async host
         # loop"): dispatch block N+1 behind block N's in-flight
         # execution and only then fetch N's tokens, so host work
@@ -1091,6 +1103,8 @@ class ServeEngine:
                 # admission cap: memory-pressure degradation admits
                 # fewer concurrent requests than the pool has slots
                 and self.pool.leased_count < self._admit_cap
+                and (self._max_fills is None
+                     or len(self._sched.filling) < self._max_fills)
             ):
                 head = self._sched.queue[0]
                 if (self._prefill_chunk is not None

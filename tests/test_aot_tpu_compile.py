@@ -796,3 +796,92 @@ def test_sdar_denoising_program_and_prefill_fit_one_v5e(chip, monkeypatch,
             + memory.output_size_in_bytes + 12 * one) < limit
     with capsys.disabled():
         print(capsys.readouterr().out, end="")
+
+
+# -- hy4-preview.longdoc-backlog: the fused decode block over the pool of
+# latent rows and index keys, and a chunk of the chunked fill, at the cell's
+# own size from the configuration's file
+
+
+def test_hy4_decode_block_and_chunk_fit_one_v5e(chip, monkeypatch):
+    """The cell's fused decode block (5 sparse latent layers of 4 streams,
+    16 slots x 32,768 rows, two of them with index keys) and one 4,096-row
+    chunk of the fill against the carry of every layer compile for the
+    described v5e. Weights and pool take 12.7 GB; the block updates the
+    pool in place and its temporaries stay under 0.6 GB; a chunk's under
+    1.7 GB, so that one runs beside the pool and two fills' carries.
+    Every kernel stands under its name: the index scores once a layer that
+    runs its own indexer, the read once a layer, the streams' update twice
+    a layer."""
+    import json
+    from pathlib import Path
+
+    from mmlspark_tpu.models import build_model
+    from mmlspark_tpu.models.generate import (
+        _cached_apply,
+        greedy_next,
+        init_cache,
+        make_decode_block,
+    )
+    from mmlspark_tpu.ops.kv_cache import IndexedRows, LatentRows
+    from mmlspark_tpu.serve.cache_pool import SlotCachePool
+
+    monkeypatch.setattr("mmlspark_tpu.core.env.is_tpu", lambda: True)
+    cfg = json.loads((Path(__file__).resolve().parent.parent / "benchmark"
+                      / "configs" / "hy4-preview.json").read_text())
+    graph = build_model("hybrid_lm", **cfg["program"]["model"])
+    variables = jax.eval_shape(
+        graph.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    engine = cfg["program"]["engine"]
+    slots, rows, width = (engine[k] for k in ("slots", "cache_len",
+                                              "prefill_chunk"))
+    pool = SlotCachePool(graph, variables, 1, 1024)
+    buffers = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((slots, rows) + a.shape[2:], a.dtype),
+        pool.buffers)
+    kinds = [type(entry) for entry in buffers.values()]
+    assert kinds == [IndexedRows] * 2 + [LatentRows] * 3
+    limit = int(15.75 * 2 ** 30)
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    live = jax.ShapeDtypeStruct((slots,), jnp.bool_)
+    block = make_decode_block(graph)
+    compiled = jax.jit(
+        lambda v, b, pos, lv, tok, rem, eos: block(
+            v, b, pos, lv, tok, rem, eos, 4),
+        donate_argnums=(1, 2, 3),
+    ).lower(*_on(chip, [variables, buffers, ints, live, ints, ints, ints])
+            ).compile()
+    text = compiled.as_text()
+    assert not _pool_copies(text, (slots, rows, 640))
+    for kernel, calls in (("index_decode", 2), ("attn_dsa_decode", 5),
+                          ("hc_mix", 10), ("moe_gate", 4)):
+        assert len(set(re.findall(rf"%({kernel}\.\d+) = ", text))) == calls
+    memory = compiled.memory_analysis()
+    pool_bytes = slots * rows * (5 * 640 + 2 * 128) * 2
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < 0.6e9
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < limit
+
+    def chunk(v, ids, cache, pos, last):
+        logits, cache = _cached_apply(graph, v, ids, cache, pos)
+        cur = jax.lax.dynamic_slice_in_dim(logits, last, 1, axis=1)[:, 0]
+        return greedy_next(cur), cache
+
+    carry = jax.eval_shape(lambda: init_cache(graph, variables, 1, rows))
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    compiled = jax.jit(chunk).lower(*_on(chip, [
+        variables, jax.ShapeDtypeStruct((1, width), jnp.int32), carry,
+        scalar, scalar])).compile()
+    text = compiled.as_text()
+    for kernel, calls in (("index_prefill", 2), ("attn_dsa_prefill", 5),
+                          ("hc_mix", 10)):
+        assert len(set(re.findall(rf"%({kernel}\.\d+) = ", text))) == calls
+    memory = compiled.memory_analysis()
+    carry_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(carry))
+    assert memory.temp_size_in_bytes < 1.7e9
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes + pool_bytes + 2 * carry_bytes
+            ) < limit

@@ -45,6 +45,9 @@ def test_chunk_validation(lm):
             ServeEngine(m, v, slots=1, cache_len=32, prefill_chunk=bad)
     with pytest.raises(FriendlyError, match="exceeds cache_len"):
         ServeEngine(m, v, slots=1, cache_len=32, prefill_chunk=64)
+    for kw in ({"max_fills": 1}, {"max_fills": 0, "prefill_chunk": 8}):
+        with pytest.raises(FriendlyError, match="max_fills"):
+            ServeEngine(m, v, slots=1, cache_len=32, **kw)
     moe = build_model(
         "transformer_lm_moe", vocab_size=8, d_model=16, heads=2,
         depth=1, n_experts=2, max_len=16,
@@ -108,6 +111,26 @@ def test_chunked_parity_ragged_prompts_and_mid_fill_joins(lm):
     # the tentpole pin: one program per chunk bucket, ceiling included
     assert engine.prefill_compile_count <= engine.num_chunk_buckets == 1
     assert engine.metrics.chunked_prefills_total >= len(prompts) + 1
+
+
+def test_max_fills_caps_the_fills_in_flight(lm):
+    """``max_fills=1``: three long prompts over three free slots start
+    one fill at a time, the others wait in the queue, and every stream
+    is still generate()'s."""
+    m, v, ids = lm
+    row = np.asarray(ids[0])
+    prompts = [row[:12], row[1:13], row[2:14]]
+    engine = ServeEngine(m, v, slots=3, cache_len=32, max_queue=8,
+                         decode_block=4, prefill_chunk=8, max_fills=1)
+    rids = [engine.submit(p, max_new_tokens=5) for p in prompts]
+    results, most = {}, 0
+    while engine.busy:
+        results.update({r.id: r for r in engine.step()})
+        most = max(most, len(engine._sched.filling))
+    assert most == 1
+    for rid, p in zip(rids, prompts):
+        np.testing.assert_array_equal(np.asarray(results[rid].tokens),
+                                      ref_tokens(m, v, p, 5))
 
 
 def test_chunked_parity_mid_fill_eos_and_tiny_budget(lm):
