@@ -48,6 +48,7 @@ from mmlspark_tpu.ops.kv_cache import (
     StateRows,
     latent_width,
 )
+from mmlspark_tpu.ops.sparse_attention import prefill_read_rows
 
 
 def cache_specs(graph, variables) -> dict:
@@ -259,6 +260,22 @@ def routing_totals(counters: dict) -> dict:
             for total, counter in (("dsa_rows_read", "rows_read"),
                                    ("dsa_rows_live", "rows_live"))})
     return totals
+
+
+def sparse_prefill_rows(graph, cache: dict, start: int, width: int) -> dict:
+    """What the sparse latent reads of a chunk of ``width`` queries from
+    position ``start`` over ``cache`` (the fill's batch-1 carry) do, summed
+    over the blocks that read chosen rows (:func:`counts_sparse`):
+    ``dsa_rows_staged``, the rows their kernel copied into VMEM, and
+    ``dsa_rows_picked``, the rows their queries read
+    (:func:`mmlspark_tpu.ops.sparse_attention.prefill_read_rows`, from the
+    shapes alone). Empty for a graph with no such block."""
+    rows = [prefill_read_rows(start, width, cache[name].rows, mod.index[0])
+            for name, mod in graph.blocks if getattr(mod, "sparse", False)]
+    if not rows:
+        return {}
+    staged, picked = map(sum, zip(*rows))
+    return {"dsa_rows_staged": staged, "dsa_rows_picked": picked}
 
 
 def greedy_next(logits):

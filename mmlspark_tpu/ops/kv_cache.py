@@ -619,9 +619,13 @@ def sparse_step(entry, q, row, pos, live=None, *, index=None, sel=None,
     count = jnp.minimum(first[:, None] + jnp.arange(t) + 1, sel.shape[-1])
     if live is not None:
         count = jnp.where(live[:, None], count, 0)
-    o = dsa.sparse_latent_read(_lanes(q, entry), rows, sel, count,
-                               sink=sink, scale=scale, values=values,
-                               name=f"attn_dsa_{phase}")
+    # a decode step's slots read rows of their own, a prefill's queries
+    # one request's: those are staged in VMEM once a call
+    q, kw = _lanes(q, entry), dict(sink=sink, scale=scale, values=values,
+                                   name=f"attn_dsa_{phase}")
+    o = (dsa.sparse_latent_read(q, rows, sel, count, **kw)
+         if phase == "decode"
+         else dsa.staged_latent_read(q, rows, sel, count, first, **kw))
     return o, new, sel, count
 
 

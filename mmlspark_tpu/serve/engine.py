@@ -88,6 +88,7 @@ from mmlspark_tpu.models.generate import (
     make_decode_block,
     make_denoise_block,
     routing_totals,
+    sparse_prefill_rows,
 )
 from mmlspark_tpu.parallel.mesh import make_mesh, parse_mesh_axes
 from mmlspark_tpu.parallel.sharding import (
@@ -1739,6 +1740,8 @@ class ServeEngine:
             with self._tracer.region("serve.prefill", tick=tick,
                                      request=req.id,
                                      bucket=width) as timed:
+                timed.count(**sparse_prefill_rows(
+                    self.graph, fs.carry["cache"], start, width))
                 while True:
                     try:
                         if self._faults is not None:
@@ -1800,6 +1803,8 @@ class ServeEngine:
         stale = False
         with self._tracer.region("serve.prefill", tick=tick,
                                  request=req.id, bucket=width) as timed:
+            timed.count(**sparse_prefill_rows(
+                self.graph, fs.carry["cache"], start, width))
             while True:
                 try:
                     if self._faults is not None:

@@ -427,6 +427,14 @@ def _pool_copies(text: str, shape: tuple) -> list:
     return found
 
 
+def _gathers(text: str, shape: tuple) -> list:
+    """The ``gather`` instructions of an optimised HLO module whose result
+    is ``shape``."""
+    dims = ",".join(map(str, shape))
+    return [line.strip()[:160] for line in text.splitlines()
+            if re.search(rf"= \w+\[{dims}\]\S* gather\(", line)]
+
+
 @pytest.mark.parametrize("config,slots,kv_dtype", [
     (GPT2_SMALL, 8, "bf16"), (GPT2_SMALL, 8, "int8"),
     (GPT2_LARGE, LARGE_SLOTS, "bf16"),
@@ -812,7 +820,8 @@ def test_hy4_decode_block_and_chunk_fit_one_v5e(chip, monkeypatch):
     1.7 GB, so that one runs beside the pool and two fills' carries.
     Every kernel stands under its name: the index scores once a layer that
     runs its own indexer, the read once a layer, the streams' update twice
-    a layer."""
+    a layer. A decode step's chosen rows are gathered by XLA, a chunk's
+    inside the read kernel."""
     import json
     from pathlib import Path
 
@@ -856,6 +865,9 @@ def test_hy4_decode_block_and_chunk_fit_one_v5e(chip, monkeypatch):
     for kernel, calls in (("index_decode", 2), ("attn_dsa_decode", 5),
                           ("hc_mix", 10), ("moe_gate", 4)):
         assert len(set(re.findall(rf"%({kernel}\.\d+) = ", text))) == calls
+    # a decode step's 16 slots read rows of their own: XLA gathers each
+    # slot's 2,048 chosen rows once a layer, as before
+    assert len(_gathers(text, (slots, 2048, 640))) == 5
     memory = compiled.memory_analysis()
     pool_bytes = slots * rows * (5 * 640 + 2 * 128) * 2
     assert memory.alias_size_in_bytes >= pool_bytes
@@ -878,6 +890,9 @@ def test_hy4_decode_block_and_chunk_fit_one_v5e(chip, monkeypatch):
     for kernel, calls in (("index_prefill", 2), ("attn_dsa_prefill", 5),
                           ("hc_mix", 10)):
         assert len(set(re.findall(rf"%({kernel}\.\d+) = ", text))) == calls
+    # a chunk's read gathers its 16 queries' rows inside the kernel, from
+    # the prefix it staged in VMEM: XLA gathers none
+    assert not _gathers(text, (16, 2048, 640))
     memory = compiled.memory_analysis()
     carry_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree_util.tree_leaves(carry))

@@ -253,6 +253,25 @@ def test_the_engine_serves_generates_tokens_and_counts_its_reads(tiny):
         assert attrs["dsa_rows_read"] < attrs["dsa_rows_live"]
 
 
+def test_a_chunked_fill_counts_the_rows_its_sparse_reads_staged(tiny):
+    """Each chunk of a fill records on its ``serve.prefill`` event the rows
+    its five sparse reads staged in VMEM and the rows their queries read.
+    A prompt of 27 tokens in chunks of 16: positions 0-15, then the final
+    chunk slid to the ladder's width, 16-31 (the pads read too). The
+    carry's 64 rows are one staging block, so every call of 16 queries
+    stages all 64; a query at ``p`` reads ``min(8, p + 1)`` rows."""
+    _, graph, variables = tiny
+    engine = ServeEngine(graph, variables, slots=1, cache_len=CACHE,
+                         prefill_chunk=16, max_fills=1, decode_block=2)
+    engine.submit(np.arange(27, dtype=np.int32) % VOCAB, 2)
+    engine.run()
+    events = [e["attrs"] for e in engine.recorder.events()
+              if e["name"] == "serve.prefill"]
+    assert [(a["dsa_rows_staged"], a["dsa_rows_picked"]) for a in events] == [
+        (5 * 64, 5 * (1 + 2 + 3 + 4 + 5 + 6 + 7 + 8 * 9)),
+        (5 * 64, 5 * 8 * 16)]
+
+
 @pytest.mark.parametrize("how", ["paged", "int8", "mesh"])
 def test_the_pools_that_hold_linear_rows_refuse_index_keys(tiny, how):
     _, graph, variables = tiny
@@ -350,6 +369,60 @@ def test_the_reference_skips_blocks_past_the_last_compared_position(
     for a, b in zip(whole, cut):
         np.testing.assert_array_equal(np.asarray(a)[:48], np.asarray(b)[:48])
     assert not np.asarray(cut[0])[48:].any()
+
+
+@pytest.mark.parametrize("sink", [True, False], ids=["sink", "no-sink"])
+@pytest.mark.parametrize("first", [0, 1000, 5003],
+                         ids=["short", "across-2048", "long-off-block"])
+def test_the_staged_prefill_read_is_the_gathered_read(first, sink):
+    """A prefill's read staged in VMEM and gathered inside the kernel gives
+    what XLA's gather and the grouped decode kernel give, number for number
+    (the same blocks of 1,024 chosen rows, the same online softmax): over a
+    prefix shorter than a query's 2,048 choices (query ``p`` reads ``p +
+    1`` rows), one that crosses 2,048 in the call, and one much longer
+    whose first query lies off a staging block; 20 queries, so the second
+    call is cut; a query that reads nothing (count 0) comes out as zeros."""
+    b, t, h, width, values, k, length = 1, 20, 8, 256, 128, 2048, 8192
+    ks = jax.random.split(jax.random.PRNGKey(first), 4)
+    rows = jax.random.normal(ks[0], (b, length, width)).astype(jnp.bfloat16)
+    q = jax.random.normal(ks[1], (b, t, h, width)).astype(jnp.bfloat16)
+    pos = first + jnp.arange(t)
+    # each query's choice among the positions it may see; past its count
+    # the choice names any row, as a top-k over dead scores does
+    sel = jax.random.randint(ks[2], (b, t, k), 0, length)
+    count = jnp.minimum(pos + 1, k)[None].at[0, 3].set(0)
+    sel = jnp.where(jnp.arange(k) < (pos + 1)[:, None], sel % (pos + 1)[:, None],
+                    sel).astype(jnp.int32)
+    kw = dict(sink=jax.random.normal(ks[3], (h,)) if sink else None,
+              scale=0.1, values=values, name="attn_dsa_prefill")
+    assert sparse_attention.stage_block(rows) == 1024
+    got = sparse_attention.staged_latent_read(q, rows, sel, count, first, **kw)
+    want = sparse_attention.sparse_latent_read(q, rows, sel, count, **kw)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    assert not np.asarray(got[0, 3], np.float32).any()
+
+
+@pytest.mark.parametrize("dtype,length", [(jnp.float32, 256),
+                                          (jnp.bfloat16, 264)],
+                         ids=["float32-rows", "part-tile"])
+def test_rows_the_prefill_read_cannot_stage_take_the_gathered_read(
+        dtype, length):
+    """Rows the staged read cannot view as words of two (not 16-bit, or
+    not whole tiles of 16 rows) are read by XLA's gather and the grouped
+    kernel, and the counter says nothing was staged."""
+    b, t, h, width, k, first = 1, 4, 8, 256, 64, 100
+    ks = jax.random.split(jax.random.PRNGKey(length), 3)
+    rows = jax.random.normal(ks[0], (b, length, width)).astype(dtype)
+    q = jax.random.normal(ks[1], (b, t, h, width)).astype(dtype)
+    sel = jax.random.randint(ks[2], (b, t, k), 0, first + 1, jnp.int32)
+    count = jnp.full((b, t), k, jnp.int32)
+    kw = dict(sink=None, scale=0.1, values=128, name="attn_dsa_prefill")
+    assert sparse_attention.stage_block(rows) == 0
+    assert sparse_attention.prefill_read_rows(first, t, rows, k) == (0, t * k)
+    got = sparse_attention.staged_latent_read(q, rows, sel, count, first, **kw)
+    want = sparse_attention.sparse_latent_read(q, rows, sel, count, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_the_stream_mix_kernel_is_its_reference():
